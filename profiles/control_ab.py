@@ -22,7 +22,7 @@ Run:
 
 Prints one JSON line (the same dict bench.py embeds as
 `control_fusion_ab`) plus a readable table. Numbers are only comparable
-WITHIN one invocation (same process, same tunnel conditions) — exactly
+WITHIN one invocation (same process, same host conditions) — exactly
 like every other same-process A/B in bench.py.
 """
 

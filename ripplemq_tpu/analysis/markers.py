@@ -33,6 +33,9 @@ FAST_MODULES = {
     "test_broker",
     "test_chain",
     "test_chaos",               # ~20 s: fixed-seed chaos smoke (3 seeds)
+    "test_chip_smoke",          # ~30 s: chip_smoke.py --tiny on CPU (3
+                                # broker + 2 client processes) must
+                                # pass every check but the device's
     "test_client",
     "test_cold_restart",
     "test_control_fusion",
@@ -69,7 +72,6 @@ FAST_MODULES = {
     "test_process_cluster",     # ~20 s: real-subprocess broker boot
     "test_read_batching",
     "test_read_cache",
-    "test_readme_bench",
     "test_settle_pipeline",
     "test_settled_gap",
     "test_slo",                 # fake-clock control-loop units

@@ -74,6 +74,11 @@ def main(argv: list[str] | None = None) -> int:
 
     configure_logging(args.log_level, json_lines=args.log_json,
                       broker_id=args.broker_id)
+    # Any broker can come to own the engine programs (genesis controller
+    # or promoted standby): place the compile cache before the first one.
+    from ripplemq_tpu.utils.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
 
     if args.coordinator is not None:
         # Join the global mesh BEFORE any other JAX use: after this,
@@ -133,14 +138,14 @@ def main(argv: list[str] | None = None) -> int:
     signal.signal(signal.SIGINT, _on_signal)
     signal.signal(signal.SIGTERM, _on_signal)
 
-    server.start()
-    role = "controller" if server.is_controller else "frontend"
-    print(
-        f"ripplemq-tpu broker {args.broker_id} ({role}) serving on "
-        f"{server.addr}",
-        flush=True,
-    )
     try:
+        server.start()
+        role = "controller" if server.is_controller else "frontend"
+        print(
+            f"ripplemq-tpu broker {args.broker_id} ({role}) serving on "
+            f"{server.addr}",
+            flush=True,
+        )
         while not stop.wait(timeout=1.0):
             pass
     finally:

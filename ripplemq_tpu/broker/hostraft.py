@@ -521,7 +521,8 @@ class RaftRunner:
 
     def stop(self) -> None:
         self._stop.set()
-        self._thread.join(timeout=2)
+        if self._thread.ident is not None:  # never started: nothing to join
+            self._thread.join(timeout=2)
         self._pool.shutdown(wait=False)
 
     def handle_rpc(self, msg: dict) -> dict:

@@ -47,7 +47,6 @@ from __future__ import annotations
 import threading
 import time
 from concurrent.futures import Future
-from concurrent.futures import TimeoutError as FuturesTimeoutError
 from typing import Callable, Optional
 
 from ripplemq_tpu.broker.dataplane import NotCommittedError
@@ -351,7 +350,7 @@ class _Sender(threading.Thread):
             group, sseq, rpc_fut, t_frame, t_sent = inflight[0]
             try:
                 resp = rpc_fut.result(timeout=0.1)
-            except (TimeoutError, FuturesTimeoutError):
+            except TimeoutError:
                 if self._stopped:
                     fail_inflight(ReplicationError("sender stopped"))
                     return
@@ -671,10 +670,7 @@ class RoundReplicator:
                     fut.result(timeout=0.05)
                     acked.append(bid)
                     break
-                # concurrent.futures.TimeoutError is a distinct class from
-                # the builtin before Python 3.11 — catching only the
-                # builtin let ack-poll timeouts escape as round failures.
-                except (TimeoutError, FuturesTimeoutError):
+                except TimeoutError:
                     if not self.active():
                         raise FencedError("controller deposed (local metadata)")
                     if (

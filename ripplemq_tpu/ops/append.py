@@ -4,11 +4,10 @@ The hot op of the whole system. Each committed round must write, for every
 partition p that committed, a [B, SB] block of packed rows at that
 partition's log end `base[p]` — a variable row offset per partition.
 
-XLA offers two lowerings, both bad on TPU (measured, v5e, P=1024, B=32,
-SB=128, R=5):
-- vmapped `dynamic_update_slice`: ~99 ms/round (P serialized windowed
-  updates);
-- batched row `scatter`: ~19 ms/round (row-serial scatter, 163k rows).
+XLA offers two lowerings, both bad on TPU:
+- vmapped `dynamic_update_slice`: P serialized windowed updates;
+- batched row `scatter`: a row-serial scatter (R x P x B rows per
+  round).
 
 The Pallas kernel instead issues ONE async DMA per (replica, partition) —
 a contiguous [B, SB] window, in place via input/output aliasing, no copy
@@ -47,6 +46,28 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ripplemq_tpu.core.config import ALIGN
+
+
+def append_backend(slot_bytes: int, platform: str | None = None) -> str:
+    """Which write phase a program built for `platform` (default: this
+    process's default backend) runs: "pallas" on a TPU, "xla" (the row
+    scatter) anywhere else — CPU is the test platform, chosen from
+    outside the program. On a TPU there is NO quiet scatter: a row
+    width Mosaic cannot take (the lane dim must be 128-aligned) is an
+    error here, at engine build (parallel.engine.make_local_fns /
+    make_spmd_fns), not a row-serial write path nobody asked for."""
+    if platform is None:
+        platform = jax.default_backend()
+    if platform != "tpu":
+        return "xla"
+    if slot_bytes % 128:
+        raise ValueError(
+            f"slot_bytes={slot_bytes} on a TPU backend: the append "
+            f"kernel's DMA windows need a 128-aligned row width, and "
+            f"the XLA row scatter is not a serving path on TPU — use a "
+            f"multiple of 128"
+        )
+    return "pallas"
 
 
 def _pick_k(P: int, target: int = 8) -> int:
@@ -341,9 +362,8 @@ def append_rows_active(log_data, entries, slot_ids, base, do_write, *,
     full-B windows — or extent-class windows when `extents` is given;
     do_write [R, P]); additionally each partition appears at most once
     in slot_ids per round."""
-    SB = log_data.shape[-1]
     if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu" and SB % 128 == 0
+        use_pallas = append_backend(log_data.shape[-1]) == "pallas"
     if use_pallas or interpret:
         return _append_active_pallas(log_data, entries, slot_ids, base,
                                      do_write, extents=extents,
@@ -355,7 +375,9 @@ def append_rows_active(log_data, entries, slot_ids, base, do_write, *,
 def append_rows(log_data, entries, base, do_write, *, extents=None,
                 use_pallas: bool | None = None,
                 interpret: bool = False):
-    """Dispatch: Pallas kernel on TPU, XLA scatter elsewhere.
+    """Dispatch: Pallas kernel on TPU, XLA scatter elsewhere
+    (append_backend; the engine bindings decide once at build and pass
+    `use_pallas` explicitly).
 
     Inputs: log_data [R, P, S, SB] (donated/aliased in place on the pallas
     path), entries [P, B, SB] packed rows, base [P] (leader log end,
@@ -363,11 +385,8 @@ def append_rows(log_data, entries, base, do_write, *, extents=None,
     rows (packed mode: clip each window to the partition's extent class;
     None = full legacy windows).
     """
-    SB = log_data.shape[-1]
     if use_pallas is None:
-        # Mosaic additionally requires the row byte width (the lane dim)
-        # to be 128-aligned; odd-sized debug configs fall back to XLA.
-        use_pallas = jax.default_backend() == "tpu" and SB % 128 == 0
+        use_pallas = append_backend(log_data.shape[-1]) == "pallas"
     if use_pallas or interpret:
         return _append_pallas(log_data, entries, base, do_write,
                               extents=extents, interpret=interpret)

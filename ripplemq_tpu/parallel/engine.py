@@ -36,12 +36,11 @@ from ripplemq_tpu.core.state import (
     unfuse_state,
 )
 from ripplemq_tpu.core import step as core_step
-from ripplemq_tpu.ops.append import append_rows, append_rows_active
-
-try:  # jax>=0.6 exposes shard_map at top level
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
+from ripplemq_tpu.ops.append import (
+    append_backend,
+    append_rows,
+    append_rows_active,
+)
 
 
 class LocalEngineFns(NamedTuple):
@@ -56,6 +55,7 @@ class LocalEngineFns(NamedTuple):
     read_offset: Callable[..., jax.Array]
     resync: Callable[..., ReplicaState]
     init_from: Callable[[ReplicaState], ReplicaState]  # single-replica image -> [R] state
+    append_backend: str  # "pallas" | "xla" — the write phase compiled in
 
 
 class SpmdEngineFns(NamedTuple):
@@ -70,6 +70,7 @@ class SpmdEngineFns(NamedTuple):
     read_offset: Callable[..., jax.Array]
     resync: Callable[..., ReplicaState]
     init_from: Callable[[ReplicaState], ReplicaState]
+    append_backend: str
     mesh: Mesh
 
 
@@ -102,6 +103,11 @@ def _resync(cfg: EngineConfig, state: ReplicaState, src: jax.Array,
 
 def make_local_fns(cfg: EngineConfig) -> LocalEngineFns:
     R = cfg.replicas
+    # The write phase is chosen ONCE, here (ops.append.append_backend):
+    # the Pallas kernel on a TPU — an unsupported slot_bytes raises —
+    # and the XLA scatter on the CPU test platform.
+    backend = append_backend(cfg.slot_bytes)
+    pallas = backend == "pallas"
     rep_idx = jnp.arange(R, dtype=jnp.int32)
     default_quorum = jnp.full((cfg.partitions,), cfg.quorum, jnp.int32)
 
@@ -144,7 +150,7 @@ def make_local_fns(cfg: EngineConfig) -> LocalEngineFns:
         new_state, ctl = vctrl(state, inp, rep_idx, alive, quorum, trim)
         log_data = append_rows(
             state.log_data, inp.entries, ctl.out.base[0] % cfg.slots,
-            ctl.do_write, extents=_ext(ctl)
+            ctl.do_write, extents=_ext(ctl), use_pallas=pallas
         )
         new_state = new_state._replace(log_data=log_data)
         # outputs are replica-invariant after the psum; take replica 0's copy
@@ -158,21 +164,20 @@ def make_local_fns(cfg: EngineConfig) -> LocalEngineFns:
     @functools.partial(jax.jit, donate_argnums=(0,))
     def _step_many_j(state, inputs: StepInput, alive, quorum, trim):
         # K chained rounds in ONE dispatch: `inputs` leaves carry a
-        # leading chain axis [K, ...]. Dispatch latency (which dominates
-        # behind a network tunnel: ~ms per launch vs ~tens of µs of
-        # compute for a small round) amortizes over the chain; each scan
-        # iteration is a COMPLETE quorum round — ballot before write,
-        # atomic, commit advanced — so chaining changes throughput, not
-        # semantics. alive/quorum/trim are chain-constant, which gives
-        # the per-slot committed-prefix property the host batcher relies
-        # on (broker.dataplane burst drain): once a slot's round fails
-        # (quorum/capacity under fixed conditions), every later round of
-        # the chain fails too.
+        # leading chain axis [K, ...]. The fixed per-launch cost
+        # (dispatch, and the resolver's host fetch of the result)
+        # amortizes over the chain; each scan iteration is a COMPLETE
+        # quorum round — ballot before write, atomic, commit advanced —
+        # so chaining changes throughput, not semantics. alive/quorum/
+        # trim are chain-constant, which gives the per-slot committed-
+        # prefix property the host batcher relies on (broker.dataplane
+        # burst drain): once a slot's round fails (quorum/capacity under
+        # fixed conditions), every later round of the chain fails too.
         def body(st, inp):
             new_st, ctl = vctrl(st, inp, rep_idx, alive, quorum, trim)
             log = append_rows(
                 st.log_data, inp.entries, ctl.out.base[0] % cfg.slots,
-                ctl.do_write, extents=_ext(ctl)
+                ctl.do_write, extents=_ext(ctl), use_pallas=pallas
             )
             return (
                 new_st._replace(log_data=log),
@@ -197,7 +202,8 @@ def make_local_fns(cfg: EngineConfig) -> LocalEngineFns:
         new_state, ctl = vctrl(state, inp, rep_idx, alive, quorum, trim)
         log_data = append_rows_active(
             state.log_data, entries_c, slot_ids,
-            ctl.out.base[0] % cfg.slots, ctl.do_write, extents=_ext(ctl)
+            ctl.out.base[0] % cfg.slots, ctl.do_write, extents=_ext(ctl),
+            use_pallas=pallas,
         )
         new_state = new_state._replace(log_data=log_data)
         return new_state, jax.tree.map(lambda x: x[0], ctl.out)
@@ -216,7 +222,7 @@ def make_local_fns(cfg: EngineConfig) -> LocalEngineFns:
             new_st, ctl = vctrl(st, inp, rep_idx, alive, quorum, trim)
             log = append_rows_active(
                 st.log_data, ec, ids, ctl.out.base[0] % cfg.slots,
-                ctl.do_write, extents=_ext(ctl)
+                ctl.do_write, extents=_ext(ctl), use_pallas=pallas
             )
             return (
                 new_st._replace(log_data=log),
@@ -300,7 +306,7 @@ def make_local_fns(cfg: EngineConfig) -> LocalEngineFns:
 
     return LocalEngineFns(_init, _step, _step_many, _step_sparse,
                           _step_many_sparse, _vote, _read, _read_many,
-                          _read_offset, _resync_fn, _init_from)
+                          _read_offset, _resync_fn, _init_from, backend)
 
 
 # ---------------------------------------------------------------------------
@@ -384,16 +390,12 @@ def spmd_arg_shardings(mesh: Mesh, chain: bool = False):
 
 def _smap(f, mesh, in_specs, out_specs):
     """shard_map with the varying-manual-axes checker off: the Pallas
-    write kernel's out_shape carries no vma annotation, which newer JAX
+    write kernel's out_shape carries no vma annotation, which JAX
     rejects under check_vma inside shard_map on TPU. The checker is a
     static lint, not a semantics change; the engine's replication
     invariants are asserted dynamically by tests/test_spmd.py."""
-    try:
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=False)
-    except TypeError:  # older jax: no check_vma parameter
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def make_spmd_fns(cfg: EngineConfig, mesh: Mesh) -> SpmdEngineFns:
@@ -406,6 +408,11 @@ def make_spmd_fns(cfg: EngineConfig, mesh: Mesh) -> SpmdEngineFns:
     if cfg.partitions % part_shards:
         raise ValueError("partitions must divide evenly over the part axis")
     local_P = cfg.partitions // part_shards
+    # Same one-time write-phase choice as the local binding, priced at
+    # the platform of the devices this mesh actually spans.
+    backend = append_backend(cfg.slot_bytes,
+                             mesh.devices.flat[0].platform)
+    pallas = backend == "pallas"
 
     # cfg.fused_control under shard_map: the same stacked-ctrl layout and
     # fused ops as the local binding (core.step.replica_control_fused),
@@ -497,6 +504,7 @@ def make_spmd_fns(cfg: EngineConfig, mesh: Mesh) -> SpmdEngineFns:
             st.log_data[None], inp.entries, ctl.out.base % cfg.slots,
             ctl.do_write[None],
             extents=ctl.extent if cfg.packed_writes else None,
+            use_pallas=pallas,
         )
         new_st = new_st._replace(log_data=log_data[0])
         # out is psum-replicated over "replica"; gather it over "part".
@@ -572,6 +580,7 @@ def make_spmd_fns(cfg: EngineConfig, mesh: Mesh) -> SpmdEngineFns:
             st.log_data[None], entries_c, _local_ids(slot_ids),
             ctl.out.base % cfg.slots, ctl.do_write[None],
             extents=ctl.extent if cfg.packed_writes else None,
+            use_pallas=pallas,
         )
         new_st = new_st._replace(log_data=log_data[0])
         return _expand(new_st), _gather_part(ctl.out)
@@ -799,4 +808,4 @@ def make_spmd_fns(cfg: EngineConfig, mesh: Mesh) -> SpmdEngineFns:
 
     return SpmdEngineFns(_init, _step, _step_many, _step_sparse,
                          _step_many_sparse, _vote, _read, _read_many,
-                         _read_offset, _resync_fn, _place, mesh)
+                         _read_offset, _resync_fn, _place, backend, mesh)

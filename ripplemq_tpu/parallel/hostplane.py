@@ -50,7 +50,6 @@ import queue
 import threading
 import time
 from concurrent.futures import Future
-from concurrent.futures import TimeoutError as FuturesTimeoutError
 from typing import Optional
 
 from ripplemq_tpu.obs.lockwitness import make_lock
@@ -408,9 +407,7 @@ class _WorkerHandle:
             ) from None
         try:
             return fut.result(timeout=timeout_s)
-        # concurrent.futures.TimeoutError is a distinct class from the
-        # builtin before Python 3.11 — catch both (the repo-wide rule).
-        except (TimeoutError, FuturesTimeoutError):
+        except TimeoutError:
             raise WorkerUnavailableError(
                 f"host worker {self.idx} unresponsive after {timeout_s}s"
             ) from None

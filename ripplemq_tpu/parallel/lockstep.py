@@ -107,6 +107,7 @@ class LockstepController:
         self._seq = 0
         self._lock = make_lock("LockstepController._lock")
         self.mesh = inner.mesh
+        self.append_backend = inner.append_backend
         # Set (to a reason string) the first time a broadcast or replay
         # fails: the mesh is permanently out of lockstep — no later call
         # can succeed, and the broker reading this flag must surrender

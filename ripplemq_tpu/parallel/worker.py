@@ -57,6 +57,10 @@ def main(argv: list[str] | None = None) -> int:
     if args.local_devices:
         jax.config.update("jax_platforms", "cpu")
 
+    from ripplemq_tpu.utils.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
+
     from ripplemq_tpu.parallel.lockstep import LOCKSTEP_TYPE, LockstepWorker
     from ripplemq_tpu.parallel.mesh import init_distributed
     from ripplemq_tpu.utils.logs import configure_logging, get_logger

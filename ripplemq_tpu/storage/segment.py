@@ -90,11 +90,21 @@ def _load_native() -> Optional[ctypes.CDLL]:
             if force or not os.path.exists(so_path) or (
                 os.path.getmtime(so_path) < os.path.getmtime(src_path)
             ):
-                subprocess.run(
-                    ["g++", "-O2", "-fPIC", "-std=c++17", "-shared",
-                     "-o", so_path, src_path],
-                    check=True, capture_output=True, timeout=120,
-                )
+                # Build to a private name, then rename: the brokers of a
+                # cluster start together and all find the library
+                # missing, and g++ writing `-o so_path` in place lets a
+                # sibling dlopen a half-written file.
+                tmp_path = f"{so_path}.{os.getpid()}.tmp"
+                try:
+                    subprocess.run(
+                        ["g++", "-O2", "-fPIC", "-std=c++17", "-shared",
+                         "-o", tmp_path, src_path],
+                        check=True, capture_output=True, timeout=120,
+                    )
+                    os.replace(tmp_path, so_path)
+                finally:
+                    if os.path.exists(tmp_path):
+                        os.remove(tmp_path)
             return ctypes.CDLL(so_path)
 
         try:

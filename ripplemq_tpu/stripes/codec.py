@@ -180,8 +180,8 @@ def encode_group(records: Iterable[tuple[int, int, int, bytes]],
     ONE gf_matmul computes the parity block (data stripes are plain
     slices of the blob — the identity rows of the extended generator
     need no compute). `kw` routes to ops/rs.gf_matmul (use_pallas /
-    platform / interpret); the default picks the Pallas kernel on a TPU
-    backend and the XLA bit-linear fallback elsewhere."""
+    interpret); the default picks the Pallas kernel when the process's
+    default backend is a TPU and the XLA bit-linear form elsewhere."""
     blob = serialize_records(records)
     blob_crc = zlib.crc32(blob) & 0xFFFFFFFF
     n = -(-max(len(blob), 1) // RS_K)  # shard length (ceil; >=1)

@@ -338,7 +338,8 @@ class StripeReplicator:
         # so the below-k refusal counts it out before a round queues.
         # None → every member counts (tests / bare planes).
         self.live_fn = live_fn
-        # Extra kwargs for encode_group (tests pin platform="cpu").
+        # Extra kwargs for encode_group (ops/rs.gf_matmul: use_pallas /
+        # interpret).
         self.encode_kw = dict(encode_kw or {})
         if metrics is not None and getattr(metrics, "enabled", True):
             self._h_encode_us = metrics.histogram("stripes.encode_us")

@@ -343,3 +343,21 @@ def test_pallas_uniform_predicate_boundaries(spoiler):
     )
     want = np.asarray(append_rows_xla(log, entries, base, do_write))
     np.testing.assert_array_equal(got, want)
+
+
+def test_no_quiet_scatter_on_a_tpu_backend():
+    """The write phase is chosen once, at engine build: the kernel on a
+    TPU, the scatter on the CPU test platform — and a row width the
+    kernel cannot take is an ERROR on a TPU, never a silent scatter."""
+    from ripplemq_tpu.core.config import EngineConfig
+    from ripplemq_tpu.ops.append import append_backend
+    from ripplemq_tpu.parallel.engine import make_local_fns
+
+    assert append_backend(128, "tpu") == "pallas"
+    assert append_backend(64, "cpu") == "xla"
+    with pytest.raises(ValueError, match="multiple of 128"):
+        append_backend(64, "tpu")
+    # On this (CPU) process the binding reports what it compiled in.
+    cfg = EngineConfig(partitions=4, replicas=3, slots=64, slot_bytes=64,
+                       max_batch=8, read_batch=8)
+    assert make_local_fns(cfg).append_backend == "xla"

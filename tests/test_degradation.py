@@ -25,6 +25,7 @@ from tests.helpers import small_cfg
 
 class _Inner:
     mesh = None
+    append_backend = "xla"
 
     def __init__(self) -> None:
         self.init_calls = 0

@@ -249,6 +249,20 @@ def test_admin_stats_schema_lock():
             f"{set(stats['engine']) ^ STATS_ENGINE_KEYS}"
         )
         assert set(stats["engine"]["settle"]) == STATS_SETTLE_KEYS
+        # The device block reports what the engine runs on — here the
+        # CPU test platform, where the write phase is the XLA scatter
+        # and one device holds all three replicas of the local binding.
+        device = stats["engine"]["device"]
+        assert set(device) == {"platform", "device_kind", "device_count",
+                               "append_backend", "mesh", "replica_devices",
+                               "peak_bytes_in_use"}
+        assert device["platform"] == "cpu"
+        assert device["append_backend"] == "xla"
+        assert device["mesh"] is None
+        assert len(device["replica_devices"]) == 3
+        assert len({tuple(d) for d in device["replica_devices"]}) == 1
+        # In-memory round stores (no data dir) never run the native writer.
+        assert stats["store_native"] is False
         assert set(stats["metadata"]) == {"role", "term", "leader_hint"}
         assert set(stats["controller"]) == {"id", "epoch", "standbys",
                                             "is_self"}

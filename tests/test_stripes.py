@@ -43,7 +43,7 @@ RECORDS = [
 
 
 def _frames(records=RECORDS, epoch=1, gsn=5, **kw):
-    return encode_group(records, epoch, gsn, platform="cpu", **kw)
+    return encode_group(records, epoch, gsn, **kw)
 
 
 # ------------------------------------------------------------- codec
@@ -53,8 +53,7 @@ def test_any_k_subset_reconstructs_byte_for_byte():
     parsed = {i: parse_frame(f) for i, f in enumerate(frames)}
     assert all(p is not None for p in parsed.values())
     for subset in itertools.combinations(range(N), RS_K):
-        got = reconstruct_group({i: parsed[i] for i in subset},
-                                platform="cpu")
+        got = reconstruct_group({i: parsed[i] for i in subset})
         assert got == RECORDS, f"subset {subset} diverged"
 
 
@@ -78,7 +77,7 @@ def test_frame_crc_corruption_is_missing_never_wrong():
     # A rotted stripe degrades the group to the remaining k, exactly.
     parsed = {i: parse_frame(f) for i, f in enumerate(frames)}
     survivors = {i: parsed[i] for i in (1, 2, 4)}
-    assert reconstruct_group(survivors, platform="cpu") == RECORDS
+    assert reconstruct_group(survivors) == RECORDS
 
 
 def test_wire_bytes_scale_with_k_plus_m_over_k():
@@ -104,8 +103,7 @@ def test_stripe_assignment_covers_all_stripes_deterministically():
 def test_empty_group_roundtrip():
     frames = _frames([], epoch=2, gsn=0)
     parsed = {i: parse_frame(f) for i, f in enumerate(frames)}
-    assert reconstruct_group({0: parsed[0], 3: parsed[3], 4: parsed[4]},
-                             platform="cpu") == []
+    assert reconstruct_group({0: parsed[0], 3: parsed[3], 4: parsed[4]}) == []
 
 
 # ---------------------------------------------------- recovery matrix
@@ -122,8 +120,7 @@ def _holder_stores(groups, members=(10, 11, 12, 13, 14)):
     stores: dict[int, list] = {b: [] for b in members}
     prev = 0
     for epoch, gsn, records in groups:
-        frames = encode_group(records, epoch, gsn, settled_floor=prev,
-                              platform="cpu")
+        frames = encode_group(records, epoch, gsn, settled_floor=prev)
         prev = gsn
         for i, f in enumerate(frames):
             stores[held[i]].append(
@@ -154,7 +151,6 @@ def test_rebuild_from_any_k_holder_subset_matrix():
         got = rebuild_records(
             iter(stores[local]),
             [(f"peer{b}", _fetcher(stores[b])) for b in peers],
-            platform="cpu",
         )
         assert got == want, f"survivors {subset} diverged"
 
@@ -169,7 +165,6 @@ def test_below_k_holders_refuse_into_the_ladder():
             rebuild_records(
                 iter(stores[local]),
                 [(f"peer{b}", _fetcher(stores[b])) for b in peers],
-                platform="cpu",
             )
 
     # Same shortfall with a peer UNREACHABLE → transient, retryable.
@@ -181,7 +176,6 @@ def test_below_k_holders_refuse_into_the_ladder():
         rebuild_records(
             iter(stores[local]),
             [(f"peer{members[1]}", down)],
-            platform="cpu",
         )
 
 
@@ -203,7 +197,7 @@ def test_torn_tail_groups_drop_but_midstream_loss_refuses():
         r for r in merged
         if r[2] != tail_gsn or r[1] in (0, 1)
     ]
-    got = rebuild_records(iter(tail_short), [], platform="cpu")
+    got = rebuild_records(iter(tail_short), [])
     assert got == [r for _, _, recs in GROUPS[:-1] for r in recs]
 
     # The SAME shortfall mid-stream is acked-data loss: refuse.
@@ -212,7 +206,7 @@ def test_torn_tail_groups_drop_but_midstream_loss_refuses():
         if r[2] != mid_gsn or r[1] in (0, 1)
     ]
     with pytest.raises(StripeDataLossError):
-        rebuild_records(iter(mid_short), [], platform="cpu")
+        rebuild_records(iter(mid_short), [])
     del keep
 
 
@@ -228,27 +222,24 @@ def test_tombstoned_group_drops_even_below_the_settled_floor():
     ok1 = [(1, 0, 0, b"settled-one" * 4)]
     nacked = [(1, 0, 8, b"nacked" * 10)]
     ok2 = [(1, 0, 8, b"settled-two" * 4)]
-    for i, f in enumerate(encode_group(ok1, 1, 10, platform="cpu")):
+    for i, f in enumerate(encode_group(ok1, 1, 10)):
         recs.append((REC_STRIPE, i, 10, f))
     # Only ONE stripe of the nacked group ever landed...
-    f_nacked = encode_group(nacked, 1, 11, settled_floor=10,
-                            platform="cpu")
+    f_nacked = encode_group(nacked, 1, 11, settled_floor=10)
     recs.append((REC_STRIPE, 0, 11, f_nacked[0]))
     # ...plus its tombstone (plane._fail_groups), and a LATER settled
     # group whose floor has passed the nacked gsn.
-    tomb = encode_group([], 1, 11, tombstone=True, settled_floor=10,
-                        platform="cpu")
+    tomb = encode_group([], 1, 11, tombstone=True, settled_floor=10)
     recs.append((REC_STRIPE, 0, 11, tomb[0]))
-    for i, f in enumerate(encode_group(ok2, 1, 12, settled_floor=11,
-                                       platform="cpu")):
+    for i, f in enumerate(encode_group(ok2, 1, 12, settled_floor=11)):
         recs.append((REC_STRIPE, i, 12, f))
-    got = rebuild_records(iter(recs), [], platform="cpu")
+    got = rebuild_records(iter(recs), [])
     assert got == ok1 + ok2
     # WITHOUT the tombstone the same leftovers are (correctly) read as
     # settled-and-lost: quarantine-grade.
     no_tomb = [r for r in recs if r[3] != tomb[0]]
     with pytest.raises(StripeDataLossError):
-        rebuild_records(iter(no_tomb), [], platform="cpu")
+        rebuild_records(iter(no_tomb), [])
 
 
 def test_catchup_groups_replay_before_same_epoch_live_groups():
@@ -260,12 +251,11 @@ def test_catchup_groups_replay_before_same_epoch_live_groups():
     live = [(1, 0, 8, b"live-rows" * 4)]
     prefix = [(1, 0, 0, b"prefix-rows" * 8)]
     recs = []
-    for i, f in enumerate(encode_group(live, 3, 50, platform="cpu")):
+    for i, f in enumerate(encode_group(live, 3, 50)):
         recs.append((REC_STRIPE, i, 50, f))
-    for i, f in enumerate(encode_group(prefix, 3, 90, catchup=True,
-                                       platform="cpu")):
+    for i, f in enumerate(encode_group(prefix, 3, 90, catchup=True)):
         recs.append((REC_STRIPE, i, 90, f))
-    got = rebuild_records(iter(recs), [], platform="cpu")
+    got = rebuild_records(iter(recs), [])
     assert got == prefix + live
 
 
@@ -400,7 +390,7 @@ def test_repl_stripes_handler_refuses_corrupt_frames(tmp_path):
         )
         standby = st["controller"]["standbys"][0]
         epoch = st["controller"]["epoch"]
-        frames = encode_group(RECORDS, epoch, 999_999, platform="cpu")
+        frames = encode_group(RECORDS, epoch, 999_999)
         bad = bytearray(frames[0])
         bad[25] ^= 0xFF
         resp = cluster.brokers[standby].dispatch({
