@@ -1,4 +1,5 @@
-"""Trace-event AND span-kind vocabularies: emit sites match the docs.
+"""Trace-event, span-kind AND host-stage vocabularies: emit sites match
+the docs.
 
 The flight recorder (`obs/trace.py`) is only a diagnosis surface if
 the event names it records are a CLOSED VOCABULARY: timeline tooling,
@@ -9,17 +10,23 @@ has the same shape and the same failure mode: the assembler, the
 trace_view renderer, and the acceptance harness all key on span KINDS,
 so the kinds are a second closed vocabulary under the same rule.
 
+The host stages (`obs/stages.py`) are the third: their names become
+registry histograms AND profiler annotations, which the benchmark's
+data files and anyone reading a profiler trace key on.
+
 - `obs/trace.py` owns the canonical `EVENT_TYPES` frozenset;
-  `obs/spans.py` owns the canonical `SPAN_KINDS` frozenset.
+  `obs/spans.py` owns the canonical `SPAN_KINDS` frozenset;
+  `obs/stages.py` owns the canonical `STAGE_NAMES` frozenset.
 - Every library emit site — a positional string literal handed to a
-  `.record("name", ...)` call, or to a `.span("kind", ...)` /
-  `.span_at("kind", ...)` call — must name a member. (The chaos
+  `.record("name", ...)` call, to a `.span("kind", ...)` /
+  `.span_at("kind", ...)` call, or to a `.stage("name", ...)` call —
+  must name a member. (The chaos
   HISTORY's `history.record(op=...)` calls are keyword-only and thus
   naturally out of scope; histories are operation logs, not traces.)
 - Every member must still have at least one emit site (a dead name is
   a renamed event whose documentation now lies).
-- Every event must appear in the README Observability section; every
-  span kind in the README Causal-tracing section.
+- Every event and every stage must appear in the README Observability
+  section; every span kind in the README Causal-tracing section.
 """
 
 from __future__ import annotations
@@ -38,6 +45,8 @@ TRACE_PATH = "ripplemq_tpu/obs/trace.py"
 VOCAB_NAME = "EVENT_TYPES"
 SPANS_PATH = "ripplemq_tpu/obs/spans.py"
 SPAN_VOCAB_NAME = "SPAN_KINDS"
+STAGES_PATH = "ripplemq_tpu/obs/stages.py"
+STAGE_VOCAB_NAME = "STAGE_NAMES"
 SCAN_ROOTS = ("ripplemq_tpu",)
 README_PATH = "README.md"
 README_HEADING = "## Observability"
@@ -141,4 +150,10 @@ def check(repo: Repo) -> list[Finding]:
             repo, span_vocab, SPANS_PATH, SPAN_VOCAB_NAME,
             ("span", "span_at"), SPAN_README_HEADING, "span kind",
             "readme::span_section"))
+
+    if repo.exists(STAGES_PATH):
+        findings.extend(_check_vocab(
+            repo, vocabulary(repo.tree(STAGES_PATH), STAGE_VOCAB_NAME),
+            STAGES_PATH, STAGE_VOCAB_NAME, ("stage",), README_HEADING,
+            "host stage", "readme::section"))
     return findings

@@ -124,11 +124,11 @@ _PID_WINDOW = 8
 
 class _Pending:
     __slots__ = ("payloads", "rows", "future", "rounds_left", "pid", "seq",
-                 "tctx")
+                 "tctx", "t_submit")
 
     def __init__(self, payloads: list[bytes], future: Future,
                  rounds_left: int, rows=None, pid: int = 0, seq: int = -1,
-                 tctx=None):
+                 tctx=None, t_submit: float = 0.0):
         self.payloads = payloads
         # Appends carry their rows PRE-PACKED (pack_payload_rows on the
         # submitting thread); the drain only memcpys blocks and stamps
@@ -146,6 +146,10 @@ class _Pending:
         # SAMPLED produce, else None: the settle release emits the six
         # round-stage spans attributed to it.
         self.tctx = tctx
+        # metrics.clock() at submit: the drain observes
+        # produce.queue_wait_us from it when it takes this pending into
+        # a round (a requeued pending keeps its first stamp).
+        self.t_submit = t_submit
 
 
 class _PendingOffsets(_Pending):
@@ -209,7 +213,6 @@ class DataPlane:
         self._m_submits = m.counter("produce.submits")
         self._m_messages = m.counter("produce.messages")
         self._m_offsets = m.counter("produce.offset_commits")
-        self._m_dispatch_us = m.histogram("engine.dispatch_us")
         self._m_chain_rounds = m.histogram("engine.chain_rounds")
         self._m_commit_wait_us = m.histogram("settle.commit_wait_us")
         self._m_enter_wait_us = m.histogram("settle.enter_wait_us")
@@ -220,6 +223,26 @@ class DataPlane:
         self._m_retry_exhausted = m.counter("produce.retry_exhausted")
         self._m_read_calls = m.counter("read.calls")
         self._m_read_msgs = m.counter("read.messages")
+        # Host stages (obs/stages.py): each a `<name>_us` histogram on
+        # the registry's clock plus a profiler annotation of the same
+        # name. The five step-thread stages PARTITION that thread's
+        # time (see _run); round.launch keeps the older histogram's
+        # name, engine.dispatch_us.
+        self._m_queue_wait_us = m.histogram("produce.queue_wait_us")
+        self._m_h2d_bytes = m.counter("round.h2d_bytes")
+        self._m_pipeline_full = m.counter("round.pipeline_full")
+        self._st_idle = m.stage("round.idle")
+        self._st_coalesce = m.stage("round.coalesce")
+        self._st_drain = m.stage("round.drain")
+        self._st_lock_wait = m.stage("round.lock_wait")
+        self._st_launch = m.stage("round.launch", "engine.dispatch_us")
+        self._st_fetch = m.stage("round.fetch", None)
+        self._st_standby_wait = m.stage("settle.standby_wait", None)
+        self._st_persist = m.stage("settle.persist", None)
+        # read.serve runs on RPC threads, any number at once, beside
+        # the round's own threads: histogram only, so that a device idle
+        # gap is always named by a stage of the round's pipeline.
+        self._st_read = m.stage("read.serve", annotate=False)
         # Durability mode for the settle-path persist: "async" defers
         # fsync to the store's flusher thread at flush_interval_s cadence
         # (disk lags acks by at most one interval — the PR 3 contract);
@@ -1058,7 +1081,8 @@ class DataPlane:
             self._appends.setdefault(slot, []).append(
                 _Pending(list(payloads), fut, self.max_retry_rounds, rows,
                          pid=pid, seq=seq,
-                         tctx=tctx if self.spans is not None else None)
+                         tctx=tctx if self.spans is not None else None,
+                         t_submit=self.metrics.clock())
             )
             if pid > 0:
                 # Settled batches are moved to the dedup table — and
@@ -1219,6 +1243,11 @@ class DataPlane:
         if not 0 <= slot < self.cfg.partitions:
             raise ValueError(f"partition slot {slot} out of range")
         self._m_read_calls.inc()
+        with self._st_read.timed():  # read.serve: the whole call
+            return self._read(slot, offset, replica, max_msgs)
+
+    def _read(self, slot: int, offset: int, replica: int,
+              max_msgs: Optional[int]) -> tuple[list[bytes], int]:
         gc_races = 0
         while True:
             with self._lock:
@@ -1879,9 +1908,12 @@ class DataPlane:
                 union_a.setdefault(slot, []).extend(taken)
             for slot, toff in rc["offsets"].items():
                 union_o.setdefault(slot, []).extend(toff)
+        h2d = sum(getattr(a, "nbytes", 0) for a in
+                  (*inp, entries_c, slot_ids, alive, quorum, trim))
         return inp, {"chain": chain, "appends": union_a, "offsets": union_o,
                      "entries_c": entries_c, "slot_ids": slot_ids,
-                     "alive": alive, "quorum": quorum, "trim": trim}
+                     "alive": alive, "quorum": quorum, "trim": trim,
+                     "h2d_bytes": h2d}
 
     def _zero_round_template(self):
         """Shared all-zero (counts, off_slots, off_vals, off_counts)
@@ -1940,6 +1972,7 @@ class DataPlane:
         (StepInput, round_ctx) or None if nothing drainable remains."""
         cfg = self.cfg
         P, B, SB, U = cfg.partitions, cfg.max_batch, cfg.slot_bytes, cfg.max_offset_updates
+        now = self.metrics.clock()  # produce.queue_wait_us, per pending
         # Active-set rounds: packed [B, SB] blocks per appending slot
         # (compact device input + the bytes the resolver persists); the
         # StepInput ships only a tiny dummy in the entries field.
@@ -2007,6 +2040,7 @@ class DataPlane:
             fill = 0
             while queue and fill + len(queue[0].payloads) <= cap:
                 pend = queue.pop(0)
+                self._m_queue_wait_us.observe(now - pend.t_submit)
                 n = len(pend.payloads)
                 taken.append((pend, fill, n))
                 fill += n
@@ -2078,7 +2112,28 @@ class DataPlane:
                      "counts": {s: int(counts[s]) for s in blocks}}
 
     def _run(self) -> None:
-        """Step thread: drain → dispatch → hand off to the resolver."""
+        """Step thread: drain → dispatch → hand off to the resolver.
+
+        The thread's time is PARTITIONED into five stages by one lap
+        timer (obs/stages.py StageLap: each boundary is one clock read
+        shared by the stage it closes and the one it opens), so over
+        any window the sums of round.idle_us, round.coalesce_us,
+        round.drain_us, round.lock_wait_us and engine.dispatch_us add
+        up to the window:
+
+        - round.idle      nothing to build: the `_work.wait`, and from
+                          the launch's return to the next loop top the
+                          hand-off to the resolvers (`_inflight.put`
+                          blocks at pipeline_depth outstanding rounds;
+                          round.pipeline_full counts those)
+        - round.coalesce  the coalesce sleep
+        - round.drain     `_drain()`: queues to device-shaped arrays
+        - round.lock_wait waiting for `_device_lock`
+        - round.launch    the launch call under the lock (histogram
+                          engine.dispatch_us)
+        """
+        lap = self.metrics.lap()
+        lap.to(self._st_idle)
         while not self._stop.is_set():
             ctx = None
             try:
@@ -2093,9 +2148,12 @@ class DataPlane:
                             if slot not in self._busy_a
                         )
                     if 0 < npend < self.cfg.max_batch:
+                        lap.to(self._st_coalesce)
                         time.sleep(self.coalesce_s)  # gather the burst
+                lap.to(self._st_drain)
                 work = self._drain()
                 if work is None:
+                    lap.to(self._st_idle)
                     self._work.clear()
                     # Short timeout: pendings for busy slots become
                     # drainable when the resolver clears the slot, which
@@ -2103,8 +2161,12 @@ class DataPlane:
                     self._work.wait(timeout=0.02)
                     continue
                 inp, ctx = work
-                t_dispatch = self.metrics.clock()
+                # t_dispatch stays BEFORE the lock: the downstream
+                # stages (commit fetch, settle entry, acks, persist,
+                # release) measure against it, lock wait included.
+                t_dispatch = lap.to(self._st_lock_wait)
                 with self._device_lock:
+                    lap.to(self._st_launch)
                     try:
                         if len(ctx["chain"]) == 1:
                             self._state, out = self.fns.step_sparse(
@@ -2121,18 +2183,16 @@ class DataPlane:
                     except Exception as e:
                         self._adopt_lockstep_state(e)
                         raise
+                # Stage 1 of the round-lifecycle decomposition ends
+                # here: the (async) device launch call returned.
+                t_dispatched = lap.to(self._st_idle)
+                self._m_h2d_bytes.inc(ctx["h2d_bytes"])
                 self.dispatches += 1
                 live_rounds = sum(
                     1 for rc in ctx["chain"]
                     if rc["appends"] or rc["offsets"]
                 )
                 self.rounds += live_rounds
-                # Stage 1 of the round-lifecycle decomposition: the
-                # (async) device launch call. Stamp t_dispatch in the
-                # ctx so the downstream stages (commit fetch, settle
-                # entry, acks, persist, release) measure against it.
-                t_dispatched = self.metrics.clock()
-                self._m_dispatch_us.observe(t_dispatched - t_dispatch)
                 self._m_chain_rounds.observe_int(live_rounds)
                 ctx["t_dispatch"] = t_dispatch
                 ctx["t_dispatched"] = t_dispatched
@@ -2154,6 +2214,8 @@ class DataPlane:
                 ctx["seq"] = self._dispatch_seq
                 self._dispatch_seq += 1
                 # Blocks at pipeline_depth outstanding rounds (backpressure).
+                if self._inflight.full():
+                    self._m_pipeline_full.inc()
                 self._inflight.put((inp, ctx, out))
                 ctx = None  # now owned by the resolver
             except Exception as e:  # the step thread must never die: fail
@@ -2172,6 +2234,7 @@ class DataPlane:
                         # these slots' shadow before their next round.
                         self._shadow_dirty |= ctx["appends"].keys()
                     self._fail_round(ctx, e)
+        lap.to(None)
 
     def _resolve_loop(self) -> None:
         """Resolver thread: land rounds — several run concurrently, so
@@ -2201,7 +2264,8 @@ class DataPlane:
         seq = ctx["seq"]
         entry = None
         try:
-            committed = np.asarray(out.committed)  # the ONE device fetch
+            with self._st_fetch.timed():
+                committed = np.asarray(out.committed)  # the ONE device fetch
             # Stage 2: dispatch → committed-fetch landed (device execute
             # + D2H). Wall time since the launch, so queueing behind
             # other dispatches is IN the number — this is the latency a
@@ -2379,19 +2443,21 @@ class DataPlane:
             # replay is later-record-wins — the retry's re-append at the
             # same base supersedes the orphaned copy.
             t_wait = self.metrics.clock()
-            if ticket is not None:
-                self.replicate_wait_fn(ticket)
-            elif records and self.replicate_fn is not None:
-                # No begin/wait split available (plain replicate_fn):
-                # synchronous, still strictly in release order.
-                self.replicate_fn(records)
+            with self._st_standby_wait.timed():
+                if ticket is not None:
+                    self.replicate_wait_fn(ticket)
+                elif records and self.replicate_fn is not None:
+                    # No begin/wait split available (plain replicate_fn):
+                    # synchronous, still strictly in release order.
+                    self.replicate_fn(records)
             # Stage 4: the standby-ack barrier as the settle thread
             # experiences it (overlap with the pipelined stream means
             # this can be ~0 even when the RPC itself took longer —
             # repl.frame_us has the raw sender-side number).
             t_acked = self.metrics.clock()
             self._m_standby_ack_us.observe(t_acked - t_wait)
-            self._persist_round(records)
+            with self._st_persist.timed():
+                self._persist_round(records)
             # Stage 5: local persist (store framing + any strict-mode
             # inline fsync; store.append_us/fsync_us decompose further).
             t_persist = self.metrics.clock()

@@ -288,7 +288,7 @@ class BrokerServer:
         self.spans = (
             SpanRing(f"broker{broker_id}",
                      capacity=config.span_ring_slots,
-                     clock=self.metrics.clock)
+                     clock=self.metrics.clock, metrics=self.metrics)
             if config.trace_sample_n > 0 else None
         )
         # Produce-ack latency as the CLIENT of this broker experiences
@@ -338,6 +338,7 @@ class BrokerServer:
                 self.info.host, self.info.port, self.dispatch,
                 workers=config.rpc_workers,
                 raw_handler=self._raw_produce,
+                metrics=self.metrics,
             )
 
         # --- committed-round store ---
@@ -1140,20 +1141,30 @@ class BrokerServer:
         `max_spans` bounds the page, and the response's `cursor` is the
         last served record's seq (== `after` when the page is empty).
         Rings are racy-consistent; assemblers page until the cursor
-        stops moving. trace_sample_n=0 serves empty pages, not errors."""
+        stops moving. trace_sample_n=0 serves empty pages, not errors.
+
+        Beside the page: the ring's loss contract (`first_seq`, the
+        oldest seq held, and `dropped`, the records past `after` that
+        were overwritten before this read — SpanRing.page) and one
+        `clock` anchor, the serving process's three clocks read back to
+        back: `perf_counter` (the span ring's and the registry's),
+        `monotonic_ns` and `time_ns` (a profiler trace's). A reader
+        places this broker's spans on another clock through the anchor
+        and never assumes the three coincide; nothing in the tracing
+        plane itself compares them."""
         after = int(req.get("after", -1))
+        clock = {"perf_counter": time.perf_counter(),
+                 "monotonic_ns": time.monotonic_ns(),
+                 "time_ns": time.time_ns()}
         if self.spans is None:
-            return {"ok": True, "spans": [], "cursor": after}
+            return {"ok": True, "spans": [], "cursor": after,
+                    "first_seq": 0, "dropped": 0, "clock": clock}
         max_spans = req.get("max_spans")
-        recs = self.spans.snapshot(
+        page = self.spans.page(
             after=after,
             max_spans=int(max_spans) if max_spans is not None else None,
         )
-        return {
-            "ok": True,
-            "spans": recs,
-            "cursor": recs[-1]["seq"] if recs else after,
-        }
+        return {"ok": True, "clock": clock, **page}
 
     def _handle_trace(self, req: dict) -> dict:
         """The flight-recorder window (obs/trace.py), oldest first;

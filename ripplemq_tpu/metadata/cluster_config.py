@@ -17,6 +17,7 @@ import yaml
 
 from ripplemq_tpu.core.config import EngineConfig
 from ripplemq_tpu.metadata.models import BrokerInfo, Topic
+from ripplemq_tpu.obs.spans import DEFAULT_SLOTS as _SPAN_RING_SLOTS
 
 
 @dataclasses.dataclass(frozen=True)
@@ -179,11 +180,13 @@ class ClusterConfig:
     # span rings share the metrics plane's monotonic clock domain so
     # the engine's stage timestamps can be attributed verbatim.
     trace_sample_n: int = 0
-    # Per-process span-ring capacity (records, not bytes). Sized like
-    # the flight recorder: large enough that one sampled produce's
-    # spans survive until the next admin.spans page, small enough to
-    # stay cache-resident.
-    span_ring_slots: int = 2048
+    # Per-process span-ring capacity (records, not bytes), allocated
+    # only when trace_sample_n > 0. Sized for a reader that pages the
+    # ring ONCE at the end of a run (benchmarks/run.py): the busiest
+    # ring measured records ~1,600 spans/s for ~35 s. A ring that
+    # overflows says so (spans.overwritten, admin.spans `dropped`);
+    # the default is obs/spans.py's.
+    span_ring_slots: int = _SPAN_RING_SLOTS
     # Runtime lock witness (obs/lockwitness.py): when true, every
     # host-path lock this process creates is a recording wrapper that
     # captures per-thread acquisition orderings, cross-checkable

@@ -32,6 +32,8 @@ import threading
 import time
 from typing import Callable, Optional
 
+from ripplemq_tpu.obs.stages import NULL_LAP, NULL_STAGE, Stage, StageLap
+
 # 40 log2 bins over integer microseconds: bin 39 tops out past 2^39 us
 # (~6.4 days) — everything above clips into the last bin.
 _NBINS = 40
@@ -198,6 +200,26 @@ class Metrics:
             if h is None:
                 h = self._histograms[name] = Histogram()
             return h
+
+    def stage(self, name: str, histogram: Optional[str] = "",
+              annotate: bool = True) -> Stage:
+        """A named host stage (obs/stages.py): histogram `<name>_us` on
+        this registry's clock plus a profiler annotation of the same
+        name. `histogram` names an older histogram that already times
+        the interval (engine.dispatch_us for round.launch), or None for
+        an annotation-only stage. Resolve once, like metric handles."""
+        if not self.enabled:
+            return NULL_STAGE  # type: ignore[return-value]
+        if histogram == "":
+            histogram = f"{name}_us"
+        hist = self.histogram(histogram) if histogram else None
+        return Stage(name, hist, self.clock, annotate)
+
+    def lap(self) -> StageLap:
+        """A stage lap timer for ONE thread (obs/stages.py StageLap)."""
+        if not self.enabled:
+            return NULL_LAP  # type: ignore[return-value]
+        return StageLap(self.clock)
 
     def snapshot(self) -> dict:
         """Wire-encodable summary: counters/gauges verbatim, histograms

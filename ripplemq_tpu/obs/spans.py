@@ -43,6 +43,15 @@ spans are recorded AT END — a span that never ends (crashed process)
 is simply absent, which the assembler treats as a partial trace, not
 an error.
 
+Unlike the flight recorder, the ring may not lose SILENTLY: a reader
+that takes a span's self time as its duration less its children's reads
+a lost child as the parent's own time. The ring therefore counts what
+it records and what it overwrites before any `snapshot` served it
+(`recorded` / `overwritten`, in the broker's registry as
+`spans.recorded` / `spans.overwritten`), and `page()` tells a cursor
+reader the oldest seq still held (`first_seq`) and how many records
+past its cursor are gone (`dropped`).
+
 Span ids are globally unique without coordination: the top 31 bits are
 crc32 of the ring's process label, the bottom 32 the local sequence.
 Two processes can therefore parent each other's spans with nothing but
@@ -62,7 +71,14 @@ import time
 import zlib
 from typing import Callable, Optional
 
-_DEFAULT_SLOTS = 2048
+from ripplemq_tpu.obs.metrics import Counter
+
+# Sized so that a traced benchmark run read ONCE at the end loses
+# nothing: the busiest ring measured (ref-compose.sync's controller,
+# 1 produce in 8 sampled) records ~1,600 spans/s for ~35 s. Allocated
+# only when tracing is configured; see README "Causal tracing" for what
+# it costs in memory.
+DEFAULT_SLOTS = 131072
 
 # The CLOSED span-kind vocabulary — one name per distinct hop a sampled
 # message can take. Checked by ripplelint trace_vocab (emit sites ↔
@@ -213,12 +229,25 @@ class SpanRing:
     per tracing client). Lock-cheap like the flight recorder: slot via
     atomic counter, single-reference stores, racy-consistent snapshot."""
 
-    def __init__(self, proc: str, capacity: int = _DEFAULT_SLOTS,
-                 clock: Optional[Callable[[], float]] = None) -> None:
+    def __init__(self, proc: str, capacity: int = DEFAULT_SLOTS,
+                 clock: Optional[Callable[[], float]] = None,
+                 metrics=None) -> None:
         self.proc = str(proc)
         self._cap = max(16, int(capacity))
         self._buf: list = [None] * self._cap
         self._seq = itertools.count()
+        # Loss accounting (module doc). With the owning broker's
+        # registry the two counts ride admin.metrics_text; a client's
+        # ring keeps them to itself. Plain racy adds, like every
+        # registry counter.
+        if metrics is not None and metrics.enabled:
+            self._recorded = metrics.counter("spans.recorded")
+            self._overwritten = metrics.counter("spans.overwritten")
+        else:
+            self._recorded, self._overwritten = Counter(), Counter()
+        # Highest seq any snapshot has returned: a record at or below
+        # it was served before its slot was reused.
+        self._served = -1
         self._ids = itertools.count(1)
         # 31 bits of proc hash (not 32: ids must stay inside the wire
         # codec's signed-64 range) over 32 bits of local sequence.
@@ -254,11 +283,15 @@ class SpanRing:
 
     def _store(self, kind: str, ctx: TraceContext, parent: int, t0: float,
                dur_s: float, fields: Optional[dict]) -> None:
+        self._put(kind, ctx.trace_id, ctx.span_id, parent, t0,
+                  max(0, int(dur_s * 1e6)), self.proc, fields)
+
+    def _put(self, *rec) -> None:
         seq = next(self._seq)  # atomic slot assignment
-        self._buf[seq % self._cap] = (
-            seq, kind, ctx.trace_id, ctx.span_id, parent, t0,
-            max(0, int(dur_s * 1e6)), self.proc, fields,
-        )
+        self._buf[seq % self._cap] = (seq, *rec)
+        self._recorded.inc()
+        if seq - self._cap > self._served:
+            self._overwritten.inc()  # seq - cap was never served
 
     def ingest(self, records: list[dict]) -> None:
         """Adopt already-built span records from another process (the
@@ -267,9 +300,8 @@ class SpanRing:
         Records keep their ORIGIN proc label and clock domain."""
         for r in records:
             try:
-                seq = next(self._seq)
-                self._buf[seq % self._cap] = (
-                    seq, str(r["kind"]), int(r["trace"]), int(r["span"]),
+                rec = (
+                    str(r["kind"]), int(r["trace"]), int(r["span"]),
                     int(r["parent"]), float(r["t0"]), int(r["dur_us"]),
                     str(r["proc"]),
                     {k: v for k, v in r.items()
@@ -278,6 +310,7 @@ class SpanRing:
                 )
             except (KeyError, TypeError, ValueError):
                 continue  # a malformed record is dropped, never fatal
+            self._put(*rec)
 
     # ------------------------------------------------------------ read
 
@@ -291,6 +324,8 @@ class SpanRing:
         entries.sort(key=lambda e: e[0])
         if max_spans is not None and max_spans >= 0:
             entries = entries[:max_spans]
+        if entries and entries[-1][0] > self._served:
+            self._served = entries[-1][0]
         out = []
         for seq, kind, trace, span, parent, t0, dur_us, proc, fields \
                 in entries:
@@ -299,3 +334,36 @@ class SpanRing:
                        parent=parent, t0=t0, dur_us=dur_us, proc=proc)
             out.append(rec)
         return out
+
+    # ------------------------------------------------------------ loss
+
+    @property
+    def recorded(self) -> int:
+        """Records ever stored (own spans and ingested ones)."""
+        return self._recorded.n
+
+    @property
+    def overwritten(self) -> int:
+        """Records whose slot was reused before any snapshot had
+        served them."""
+        return self._overwritten.n
+
+    def page(self, after: int = -1,
+             max_spans: Optional[int] = None) -> dict:
+        """One cursor page with the ring's loss contract beside it —
+        the body of admin.spans: `spans` (as `snapshot`), `cursor` (the
+        last served seq, == `after` on an empty page), `first_seq` (the
+        oldest seq still held; the next seq on an empty ring) and
+        `dropped` (records with seq > `after` that were overwritten
+        before this read: 0 for a reader that kept up)."""
+        recs = self.snapshot(after=after, max_spans=max_spans)
+        # Seqs are consecutive and slot = seq % cap, so the oldest
+        # record held is `cap` behind the next seq (racy-consistent
+        # with in-flight writers, like the snapshot itself).
+        first = max(0, self.recorded - self._cap)
+        return {
+            "spans": recs,
+            "cursor": recs[-1]["seq"] if recs else after,
+            "first_seq": first,
+            "dropped": max(0, first - (after + 1)),
+        }

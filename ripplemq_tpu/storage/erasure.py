@@ -32,6 +32,7 @@ repair tests).
 
 from __future__ import annotations
 
+import contextlib
 import os
 import struct
 import zlib
@@ -76,9 +77,12 @@ def _shard_length(orig_len: int) -> int:
     return -(-orig_len // K)  # ceil; last data shard is zero-padded
 
 
-def encode_segment(store_dir: str, seg_name: str, **kw) -> list[str]:
+def encode_segment(store_dir: str, seg_name: str, stage=None,
+                   **kw) -> list[str]:
     """Write the K+M shard files for one sealed segment. Atomic per shard
-    (tmp + rename); returns the shard paths. `kw` routes to
+    (tmp + rename); returns the shard paths. `stage(shard_len)`, where
+    given, returns a context manager opened around the RS encode alone
+    (the owning store's `seal.rs_encode` timer). `kw` routes to
     ops/rs.gf_matmul (use_pallas / interpret)."""
     seg_path = os.path.join(store_dir, seg_name)
     with open(seg_path, "rb") as f:
@@ -88,7 +92,8 @@ def encode_segment(store_dir: str, seg_name: str, **kw) -> list[str]:
     padded = np.zeros(K * n, np.uint8)
     padded[: len(raw)] = np.frombuffer(raw, np.uint8)
     data = padded.reshape(K, n)
-    parity = np.asarray(rs_encode(data, k=K, m=M, **kw))
+    with stage(n) if stage is not None else contextlib.nullcontext():
+        parity = np.asarray(rs_encode(data, k=K, m=M, **kw))
     shards = np.concatenate([data, parity], axis=0)
     os.makedirs(_rs_dir(store_dir), exist_ok=True)
     paths = shard_paths(store_dir, seg_name)

@@ -231,10 +231,18 @@ class SegmentStore:
             self._c_append_bytes = metrics.counter("store.append_bytes")
             self._c_records = metrics.counter("store.append_records")
             self._clock = metrics.clock
+            # One sealed segment's RS encode (obs/stages.py), and how
+            # many of them met a shard length this store had not
+            # encoded before: the length is a static jit argument of
+            # ops/rs.py, so each new one compiles a new program.
+            self._st_rs_encode = metrics.stage("seal.rs_encode")
+            self._c_rs_new_shapes = metrics.counter("rs.new_shapes")
         else:
             self._h_append = self._h_fsync = None
             self._c_append_bytes = self._c_records = None
             self._clock = None
+            self._st_rs_encode = self._c_rs_new_shapes = None
+        self._rs_shapes: set[int] = set()  # erasure worker only
         self.segment_bytes = segment_bytes
         self.erasure = erasure
         # Size-capped disk retention: gc() deletes the OLDEST sealed
@@ -525,7 +533,8 @@ class SegmentStore:
         from ripplemq_tpu.storage.erasure import protect_store
 
         try:
-            protect_store(self.directory)
+            protect_store(self.directory, stage=self._seal_stage
+                          if self._st_rs_encode is not None else None)
         except Exception as e:  # derived data: never take the store down
             _log.warning("erasure encode failed for %s: %s: %s",
                          self.directory, type(e).__name__, e)
@@ -534,6 +543,15 @@ class SegmentStore:
             with self._lock:
                 self.erasure_errors.append(f"{type(e).__name__}: {e}")
                 del self.erasure_errors[:-20]
+
+    def _seal_stage(self, shard_len: int):
+        """The timed region storage/erasure.py opens around one sealed
+        segment's RS encode: `seal.rs_encode`, counting a shard length
+        not seen before under rs.new_shapes."""
+        if shard_len not in self._rs_shapes:
+            self._rs_shapes.add(shard_len)
+            self._c_rs_new_shapes.inc()
+        return self._st_rs_encode.timed()
 
     def gc(self) -> list[int]:
         """Delete the oldest sealed segments while their total size

@@ -251,8 +251,20 @@ class TcpServer:
         handler: Handler,
         workers: int = 16,
         raw_handler: Optional[Callable[[bytes], Optional[dict]]] = None,
+        metrics=None,
     ) -> None:
         self._handler = handler
+        # `rpc.queue_wait_us`: frame read to handler start, i.e. the
+        # wait for a pool worker — the wait that starves raft
+        # heartbeats when the pool is smaller than the offered
+        # concurrency. `metrics` is the owning broker's registry; a
+        # bare server (tests, engine workers) observes into no-ops.
+        if metrics is None:
+            from ripplemq_tpu.obs.metrics import Metrics
+
+            metrics = Metrics(enabled=False)
+        self._clock = metrics.clock
+        self._m_queue_wait_us = metrics.histogram("rpc.queue_wait_us")
         # Raw-frame dispatch hook: sees the UNDECODED body before the
         # codec runs and may answer the request itself (the broker's
         # produce fast path peeks routing scalars and ships the frame
@@ -299,7 +311,8 @@ class TcpServer:
                     req_id, body = codec.read_frame(conn)
                 except (ConnectionError, ValueError, OSError):
                     return
-                self._pool.submit(self._handle_one, conn, write_lock, req_id, body)
+                self._pool.submit(self._handle_one, conn, write_lock, req_id,
+                                  body, self._clock())
         finally:
             with self._lock:
                 self._conns.discard(conn)
@@ -308,7 +321,9 @@ class TcpServer:
             except OSError:
                 pass
 
-    def _handle_one(self, conn, write_lock, req_id: int, body: bytes) -> None:
+    def _handle_one(self, conn, write_lock, req_id: int, body: bytes,
+                    t_read: float) -> None:
+        self._m_queue_wait_us.observe(self._clock() - t_read)
         try:
             resp = (self._raw_handler(body)
                     if self._raw_handler is not None else None)

@@ -395,6 +395,41 @@ def test_trace_vocab_fixture_caught(tmp_path):
                     "undocumented::rogue.kind", "dead::kind_renamed_away"}
 
 
+def test_stage_vocab_fixture_caught(tmp_path):
+    """The host stages (obs/stages.py) are the third closed vocabulary
+    under the rule: a `.stage("name")` site outside STAGE_NAMES, a
+    member with no site, and a member the README Observability section
+    does not name are each a finding; a repo without the module has no
+    stage findings at all."""
+    (tmp_path / "ripplemq_tpu/obs").mkdir(parents=True)
+    (tmp_path / "ripplemq_tpu/broker").mkdir(parents=True)
+    (tmp_path / trace_vocab.TRACE_PATH).write_text(
+        'EVENT_TYPES = frozenset({"dispatch"})\n')
+    (tmp_path / trace_vocab.SPANS_PATH).write_text(
+        'SPAN_KINDS = frozenset({"rpc.recv"})\n')
+    (tmp_path / "ripplemq_tpu/broker/server.py").write_text(
+        textwrap.dedent("""
+            class S:
+                def __init__(self, m, ctx):
+                    self.recorder.record("dispatch", n=1)
+                    self.spans.span("rpc.recv", ctx)
+                    self._a = m.stage("round.drain")
+                    self._b = m.stage("round.rogue", None)
+                    self._c = m.stage("round.undocumented")
+        """))
+    (tmp_path / "README.md").write_text(
+        f"{trace_vocab.README_HEADING}\n\n`dispatch` `round.drain` "
+        f"`round.renamed_away`\n\n"
+        f"{trace_vocab.SPAN_README_HEADING}\n\n`rpc.recv`\n")
+    assert trace_vocab.check(Repo(tmp_path)) == []
+    (tmp_path / trace_vocab.STAGES_PATH).write_text(
+        'STAGE_NAMES = frozenset({"round.drain", "round.renamed_away",\n'
+        '                         "round.undocumented"})\n')
+    keys = {f.key for f in trace_vocab.check(Repo(tmp_path))}
+    assert keys == {"undocumented::round.rogue", "dead::round.renamed_away",
+                    "readme::round.undocumented"}
+
+
 def test_trace_vocab_parses_live_set():
     repo = Repo()
     vocab = trace_vocab.vocabulary(repo.tree(trace_vocab.TRACE_PATH))
@@ -407,6 +442,12 @@ def test_trace_vocab_parses_live_set():
     # rule; the cross-process skew pairs must both be present.
     assert {"client.rpc", "rpc.recv", "worker.hop", "worker.serve",
             "repl.send", "repl.apply"} <= kinds
+    stages = trace_vocab.vocabulary(
+        repo.tree(trace_vocab.STAGES_PATH), trace_vocab.STAGE_VOCAB_NAME)
+    # The third closed set: the five stages that partition the step
+    # thread's time are the ones the benchmark's data files read.
+    assert {"round.idle", "round.coalesce", "round.drain",
+            "round.lock_wait", "round.launch"} <= stages
 
 
 # ---- markers: the unmarked-soak class --------------------------------

@@ -721,3 +721,179 @@ def test_admin_metrics_text_surface():
         ft = client.call(front.addr, {"type": "admin.metrics_text"},
                          timeout=5.0)
         assert ft["ok"] and "# TYPE" in ft["text"]
+
+
+# ------------------------------------------------ host stages (ISSUE 24)
+
+
+def _half_second_clock():
+    """A fake clock that advances 0.5 s per read (exact in binary, so
+    sums of differences are exact) and remembers, per reading thread,
+    the first and last value it handed out."""
+    import threading as _threading
+
+    ticks = [0.0]
+    seen: dict[int, list[float]] = {}
+    guard = _threading.Lock()
+
+    def clock():
+        with guard:
+            ticks[0] += 0.5
+            t = ticks[0]
+            span = seen.setdefault(_threading.get_ident(), [t, t])
+            span[1] = t
+        return t
+
+    return clock, seen
+
+
+ROUND_STAGE_HISTOGRAMS = ("round.idle_us", "round.coalesce_us",
+                          "round.drain_us", "round.lock_wait_us",
+                          "engine.dispatch_us")
+
+
+def test_stage_lap_partitions_a_threads_time():
+    """StageLap: each boundary is ONE clock read shared by the stage it
+    closes and the one it opens, so the stages' sums add up to the
+    elapsed time exactly, whatever the order and repetition."""
+    from ripplemq_tpu.obs.metrics import Metrics
+
+    clock, _ = _half_second_clock()
+    m = Metrics(clock=clock)
+    a, b = m.stage("round.drain"), m.stage("round.launch",
+                                           "engine.dispatch_us")
+    only_annotated = m.stage("round.fetch", None)
+    lap = m.lap()
+    t_first = lap.to(a)
+    for st in (b, a, a, only_annotated, b):
+        clock()  # reads inside a stage do not break the closure
+        lap.to(st)
+    t_last = lap.to(None)
+    h = m.snapshot()["histograms"]
+    assert set(h) == {"round.drain_us", "engine.dispatch_us"}
+    total_us = sum(m.histogram(n).total for n in h)
+    # round.fetch keeps no histogram: its 1.0 s is the only part of the
+    # elapsed time that no sum holds.
+    assert total_us == int((t_last - t_first - 1.0) * 1e6)
+    assert h["round.drain_us"]["count"] == 3
+    # timed(): one region, its own object per use.
+    with a.timed():
+        pass
+    assert m.histogram("round.drain_us").count == 4
+
+
+def test_step_thread_round_stages_add_up_to_elapsed_time():
+    """ISSUE 24: the five round.* stages of DataPlane._run partition the
+    step thread's time — on the fake clock their sums equal the time
+    between the thread's first and last clock read exactly — and
+    produce.queue_wait_us holds one observation per drained pending."""
+    from ripplemq_tpu.broker.dataplane import DataPlane
+    from ripplemq_tpu.obs.metrics import Metrics
+    from tests.helpers import small_cfg
+
+    clock, seen = _half_second_clock()
+    m = Metrics(clock=clock)
+    dp = DataPlane(small_cfg(), mode="local", max_retry_rounds=3,
+                   metrics=m, coalesce_s=0.001)
+    dp.start()
+    try:
+        dp.set_leader(0, 0, 1)
+        dp.set_leader(1, 1, 1)
+        # One lone message first: a partial batch is what the step
+        # thread sleeps the coalesce window for.
+        dp.submit_append(0, [b"m0"]).result(timeout=30)
+        futs = [dp.submit_append(i % 2, [b"m%d" % i]) for i in range(1, 12)]
+        for f in futs:
+            f.result(timeout=30)
+        step_ident = dp._thread.ident
+    finally:
+        dp.stop()
+    first, last = seen[step_ident]
+    sums = {n: m.histogram(n).total for n in ROUND_STAGE_HISTOGRAMS}
+    assert sum(sums.values()) == int((last - first) * 1e6), sums
+    # Every stage ran: the plane slept the coalesce window, drained,
+    # waited for the lock, launched and idled.
+    counts = {n: m.histogram(n).count for n in ROUND_STAGE_HISTOGRAMS}
+    assert all(counts.values()), counts
+    assert counts["round.lock_wait_us"] == counts["engine.dispatch_us"] \
+        == dp.dispatches
+    snap = m.snapshot()
+    # No round failed, so every pending was drained exactly once.
+    assert snap["counters"]["produce.round_retries"] == 0
+    assert snap["histograms"]["produce.queue_wait_us"]["count"] == 12
+    assert snap["counters"]["round.h2d_bytes"] > 0
+    assert snap["counters"].get("round.pipeline_full", 0) == 0
+
+
+def test_stage_helper_is_free_when_metrics_are_off():
+    """Under Metrics(enabled=False) the stage helper reads no clock and
+    allocates nothing — the obs=False arm sheds the stages whole."""
+    import gc
+    import tracemalloc
+
+    from ripplemq_tpu.obs.metrics import Metrics
+    from ripplemq_tpu.obs.stages import NULL_LAP, NULL_STAGE
+
+    def boom():
+        raise AssertionError("a disabled registry read its clock")
+
+    off = Metrics(enabled=False, clock=boom)
+    st = off.stage("round.drain")
+    lap = off.lap()
+    assert st is NULL_STAGE and lap is NULL_LAP
+    assert off.stage("round.fetch", None) is NULL_STAGE
+    loop = [None] * 1000  # a range() would allocate its own ints
+    for _ in loop[:16]:  # warm any lazily-built interpreter state
+        with st.timed():
+            pass
+        lap.to(st)
+    gc.collect()
+    tracemalloc.start()
+    base = tracemalloc.get_traced_memory()[0]
+    for _ in loop:
+        with st.timed():
+            pass
+        lap.to(st)
+        lap.to(None)
+    used = tracemalloc.get_traced_memory()[0] - base
+    tracemalloc.stop()
+    # Nothing PER CALL: the only live bytes after 1000 rounds are the
+    # interpreter's own one-off objects (a cached bound `__exit__`, the
+    # int this subtraction made), far under one byte a call.
+    assert used < 256, f"disabled stage path allocated {used} bytes"
+    assert off.snapshot()["histograms"] == {}
+
+
+def test_stages_land_in_a_profiler_trace(tmp_path):
+    """The second clock: a stage open while a jax.profiler session runs
+    is an event of the same name on its thread's host line — what lets
+    a device idle gap be named by the program's stage instead of by
+    whichever runtime event happened to be open."""
+    import jax
+
+    from ripplemq_tpu.obs.metrics import Metrics
+
+    m = Metrics()
+    drain = m.stage("round.drain")
+    fetch = m.stage("round.fetch", None)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        lap = m.lap()
+        lap.to(drain)
+        lap.to(fetch)
+        lap.to(None)
+        with drain.timed():
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    paths = list(tmp_path.rglob("*.xplane.pb"))
+    assert paths, "the profiler wrote no trace"
+    data = jax.profiler.ProfileData.from_file(str(paths[0]))
+    names = [ev.name for plane in data.planes for line in plane.lines
+             for ev in line.events]
+    assert names.count("round.drain") == 2
+    assert names.count("round.fetch") == 1
+    assert m.histogram("round.drain_us").count == 2

@@ -282,3 +282,37 @@ def test_segmentstore_flush_protects_and_recovery_repairs(tmp_path):
     np.testing.assert_array_equal(
         np.asarray(image_before.log_data), np.asarray(image_after.log_data)
     )
+
+
+def test_seal_encode_is_timed_and_new_shard_lengths_counted(tmp_path):
+    """ISSUE 24: with a registry the erasure worker times each sealed
+    segment's RS encode (`seal.rs_encode_us`) and counts the encodes
+    that met a shard length this store had not seen (`rs.new_shapes`:
+    the length is a static jit argument, so each is a new program);
+    without one it times nothing."""
+    from ripplemq_tpu.obs.metrics import Metrics
+
+    m = Metrics()
+    store = SegmentStore(str(tmp_path / "timed"), segment_bytes=1024,
+                         use_native=False, erasure=True, metrics=m)
+    sizes = (700, 700, 900, 700)  # three sealed segments, two lengths
+    for i, n in enumerate(sizes):
+        store.append(REC_APPEND, 0, i * 8, bytes(n))
+        store.append(REC_APPEND, 0, i * 8 + 4, bytes(400))  # rotates
+    store.close()  # joins the worker, then encodes what is left
+    sealed = erasure._segment_names(store.directory)[:-1]
+    assert erasure._protected_names(store.directory) >= set(sealed)
+    lengths = {-(-os.path.getsize(os.path.join(store.directory, s)) // 3)
+               for s in sealed if os.path.getsize(
+                   os.path.join(store.directory, s))}
+    snap = m.snapshot()
+    encoded = snap["histograms"]["seal.rs_encode_us"]["count"]
+    assert encoded >= len(lengths) >= 2
+    assert snap["counters"]["rs.new_shapes"] == len(lengths)
+
+    bare = SegmentStore(str(tmp_path / "bare"), segment_bytes=1024,
+                        use_native=False, erasure=True)
+    bare.append(REC_APPEND, 0, 0, bytes(700))
+    bare.append(REC_APPEND, 0, 8, bytes(700))
+    bare.close()
+    assert erasure._protected_names(bare.directory)

@@ -158,6 +158,64 @@ def test_span_ring_paging_and_ingest():
     assert ctx_from_wire([1.5, 2]) is None
 
 
+def test_span_ring_reports_what_it_lost():
+    """A ring driven past its capacity says exactly what is gone:
+    `overwritten` counts records no snapshot ever served, `first_seq`
+    is the oldest seq still held, `dropped` the records past the
+    reader's cursor that it can no longer have."""
+    ring = SpanRing("broker0", capacity=16)
+    root = TraceContext(derive_trace_id("t", 0), 0)
+    empty = ring.page()
+    assert (empty["first_seq"], empty["dropped"], empty["cursor"]) == (0, 0, -1)
+    for _ in range(40):
+        ring.span("rpc.recv", root).end()
+    assert (ring.recorded, ring.overwritten) == (40, 24)
+    page = ring.page(after=-1, max_spans=4)
+    assert [r["seq"] for r in page["spans"]] == [24, 25, 26, 27]
+    assert (page["first_seq"], page["dropped"], page["cursor"]) == (24, 24, 27)
+    # A reader partway through: it saw up to seq 9, 10..23 are gone.
+    assert ring.page(after=9)["dropped"] == 14
+    # A reader at or past the oldest record lost nothing.
+    rest = ring.page(after=27)
+    assert rest["dropped"] == 0 and rest["cursor"] == 39
+    # Served records may be overwritten without counting as lost; the
+    # count only grows for records nobody read.
+    for _ in range(16):
+        ring.span("rpc.recv", root).end()
+    assert (ring.recorded, ring.overwritten) == (56, 24)
+    ring.span("rpc.recv", root).end()  # reuses seq 40's slot: never served
+    assert ring.overwritten == 25
+    # Ingested records are records too.
+    ring.ingest(ring.snapshot(after=55))
+    assert ring.recorded == 58
+
+
+@pytest.mark.parametrize("page_size", [1, 7, 64])
+def test_span_ring_read_in_time_loses_nothing(page_size):
+    """A reader that pages before the ring laps reads every record and
+    the ring reports no loss, whatever the page size — into the
+    registry when it has one."""
+    from ripplemq_tpu.obs.metrics import Metrics
+
+    m = Metrics()
+    ring = SpanRing("broker0", capacity=32, metrics=m)
+    root = TraceContext(derive_trace_id("t", 0), 0)
+    seen, after = [], -1
+    for burst in range(6):
+        for _ in range(20):  # 20 < capacity between reads
+            ring.span("rpc.recv", root).end()
+        while True:
+            page = ring.page(after=after, max_spans=page_size)
+            assert page["dropped"] == 0
+            if not page["spans"]:
+                break
+            seen += [r["seq"] for r in page["spans"]]
+            after = page["cursor"]
+    assert seen == list(range(120))
+    counters = m.snapshot()["counters"]
+    assert counters == {"spans.recorded": 120, "spans.overwritten": 0}
+
+
 # ---------------------------------------------------------------- assembler
 
 
@@ -240,6 +298,22 @@ def test_spans_roundtrip_inproc_transport():
         records = collect_broker_spans(
             c.client("obs"), [c.broker_addr(b) for b in c.brokers])
         records += prod.spans.snapshot() + cons.spans.snapshot()
+        # The loss contract and the clock anchor ride every page, and a
+        # ring read in time lost nothing — by the page and by the
+        # registry counters admin.metrics_text carries.
+        obs = c.client("obs2")
+        for b in c.brokers:
+            page = obs.call(c.broker_addr(b), {"type": "admin.spans"},
+                            timeout=10.0)
+            assert page["first_seq"] == 0 and page["dropped"] == 0, page
+            assert set(page["clock"]) == {"perf_counter", "monotonic_ns",
+                                          "time_ns"}
+            text = obs.call(c.broker_addr(b),
+                            {"type": "admin.metrics_text"},
+                            timeout=10.0)["text"]
+            assert "ripplemq_spans_overwritten_total 0\n" in text
+            assert (f"ripplemq_spans_recorded_total {len(page['spans'])}\n"
+                    in text)
         prod.close()
         cons.close()
 

@@ -327,3 +327,53 @@ def test_peek_fields_refuses_malformed_frames():
     assert peek_fields(b"\xfe\x01", ("a",)) is None
     raw = encode({"a": 1, "b": b"xy"})
     assert peek_fields(raw[:-1], ("a",)) is None  # truncated
+
+
+@pytest.mark.parametrize("workers,waits", [(8, False), (2, True)])
+def test_rpc_queue_wait_grows_when_pool_is_smaller_than_concurrency(
+        workers, waits):
+    """ISSUE 24: `rpc.queue_wait_us` is the time from a frame's read to
+    its handler's start — the wait for a pool worker. Eight concurrent
+    50 ms requests find a worker at once in a pool of eight and queue
+    three deep behind a pool of two."""
+    import time as _time
+
+    from ripplemq_tpu.obs.metrics import Metrics
+
+    def handler(req):
+        _time.sleep(0.05)
+        return {"ok": True}
+
+    m = Metrics()
+    server = TcpServer("127.0.0.1", 0, handler, workers=workers, metrics=m)
+    server.start()
+    client = TcpClient()
+    try:
+        addr = f"127.0.0.1:{server.port}"
+        futs = [client.call_async(addr, {"type": "x"}) for _ in range(8)]
+        for fut in futs:
+            assert fut.result(timeout=10)["ok"]
+    finally:
+        client.close()
+        server.stop()
+    h = m.histogram("rpc.queue_wait_us")
+    assert h.count == 8  # one observation per RPC
+    if waits:
+        # Waves of two: the last pair waited three handler times.
+        assert h.max >= 120_000 and h.total >= 6 * 45_000, (h.max, h.total)
+    else:
+        assert h.max < 40_000, h.max
+
+
+def test_tcp_server_without_a_registry_observes_nothing():
+    """A bare TcpServer (tests, engine workers) takes no registry: the
+    queue-wait observation is a no-op and nothing else changes."""
+    server = TcpServer("127.0.0.1", 0, lambda req: {"ok": True})
+    server.start()
+    client = TcpClient()
+    try:
+        assert client.call(f"127.0.0.1:{server.port}", {"type": "x"})["ok"]
+    finally:
+        client.close()
+        server.stop()
+    assert server._clock() == 0.0  # the disabled registry's constant clock
