@@ -265,7 +265,7 @@ def role_kernel(spec: dict) -> dict:
     import jax
     import numpy as np
 
-    from ripplemq_tpu.ops.rs import gf_matmul, gf_matmul_ref
+    from ripplemq_tpu.ops.rs import _gf_matmul_jit, gf_matmul, gf_matmul_ref
     from ripplemq_tpu.ops.rs import generator_matrix
     from ripplemq_tpu.stripes.codec import RS_K, RS_M, _shard_class
 
@@ -289,6 +289,7 @@ def role_kernel(spec: dict) -> dict:
             bad.append(n)
     return {"platform": devs[0].platform, "kind": devs[0].device_kind,
             "count": len(devs), "sizes": len(sizes), "max_bytes": max(sizes),
+            "programs": _gf_matmul_jit._cache_size(),
             "mismatched": bad, "mode": "mosaic" if on_tpu else "interpret"}
 
 
@@ -691,7 +692,8 @@ class Smoke:
         self.go(kern)
         k = self._wait(kern, "kernel")[0]
         log(f"rs kernel ({k['mode']}): {k['sizes']} shard sizes up to "
-            f"{k['max_bytes']} B on {k['platform']}/{k['kind']}, "
+            f"{k['max_bytes']} B in {k['programs']} programs (one per "
+            f"shard-length bucket) on {k['platform']}/{k['kind']}, "
             f"mismatched {k['mismatched']}")
         if k["mismatched"]:
             self.fail(f"rs kernel differs from the reference at "

@@ -128,6 +128,7 @@ def gf_invert(matrix) -> tuple[tuple[int, ...], ...]:
 _LANE = 128        # TPU lane width (int32 lanes after packing)
 _BLOCK_ROWS = 512  # packed rows per VMEM block per shard (512·128·4 = 256 KiB)
 _PACK = 4 * _LANE  # bytes per packed lane row
+_BLOCK_BYTES = _BLOCK_ROWS * _PACK  # one shard's bytes in one kernel block
 _ONES = 0x01010101  # bit b of every byte lane of a packed int32 word
 
 
@@ -178,45 +179,59 @@ def _rs_kernel(coeffs, K, in_ref, out_ref):
         ).astype(jnp.uint8)
 
 
-def _gf_matmul_pallas(coeffs, padded, *, interpret=False):
-    K, npad = padded.shape
+def _gf_matmul_pallas(coeffs, shards, *, interpret=False):
+    K, nb = shards.shape  # nb: whole kernel blocks (shard_bucket)
     M = len(coeffs)
-    rows = npad // _PACK
-    tr = min(_BLOCK_ROWS, rows)
-    view = padded.reshape(K, rows, _PACK)
+    rows = nb // _PACK
+    view = shards.reshape(K, rows, _PACK)
     out = pl.pallas_call(
         functools.partial(_rs_kernel, coeffs, K),
-        grid=(rows // tr,),
-        in_specs=[pl.BlockSpec((K, tr, _PACK), lambda g: (0, g, 0))],
-        out_specs=pl.BlockSpec((M, tr, _PACK), lambda g: (0, g, 0)),
+        grid=(rows // _BLOCK_ROWS,),
+        in_specs=[pl.BlockSpec((K, _BLOCK_ROWS, _PACK), lambda g: (0, g, 0))],
+        out_specs=pl.BlockSpec((M, _BLOCK_ROWS, _PACK), lambda g: (0, g, 0)),
         out_shape=jax.ShapeDtypeStruct((M, rows, _PACK), jnp.uint8),
         interpret=interpret,
     )(view)
-    return out.reshape(M, npad)
+    return out.reshape(M, nb)
 
 
-@functools.partial(jax.jit, static_argnums=(0, 2, 3, 4))
-def _gf_matmul_jit(coeffs, shards, n, use_pallas, interpret):
-    K = shards.shape[0]
+def shard_bucket(n: int) -> int:
+    """The shard length the program that serves an [K, n] input is built
+    for: the smallest entry >= n of a ladder of whole kernel blocks.
+
+    The shard length is a shape of the compiled program, and a sealed
+    segment's differs by a record batch every time one rotates — left
+    free, every seal traced, lowered and compiled a program of its own
+    (~2.5 s on the chip-owning broker, under the GIL its step, settle
+    and RPC threads share). So `gf_matmul` zero-pads on the host to this
+    length instead. Up to 2^20 the step is one kernel block (256 KiB);
+    above, a quarter of the power of two below n — the entries are
+    2^k x {1, 1.25, 1.5, 1.75}, at most 25% over. A pure function of n,
+    not an option: nothing about a deployment changes the right answer.
+
+    For the default 64 MiB segments one entry, 24 MiB, holds every
+    segment of (60, 72] MiB: a segment rotates BEFORE the write that
+    would cross segment_bytes, so sealed lengths fall short of it by at
+    most one write (a round's records, a standby's group-commit frame).
+    A store of any other segment size meets two entries at most."""
+    half = 1 << max((n - 1).bit_length() - 1, 0)  # 2^k < n <= 2^(k+1)
+    step = max(_BLOCK_BYTES, half >> 2)
+    return -(-n // step) * step
+
+
+@functools.partial(jax.jit, static_argnums=(0, 2, 3))
+def _gf_matmul_jit(coeffs, shards, use_pallas, interpret):
+    # `shards` arrives at a bucket length (shard_bucket): a whole number
+    # of kernel blocks, so neither form pads or slices on the device.
+    K, nb = shards.shape
     M = len(coeffs)
-    npad = -(-n // _PACK) * _PACK
     if use_pallas or interpret:
-        # Pad to a whole number of kernel blocks: Mosaic requires block
-        # rows divisible by 8 (or equal to the array's), so rather than
-        # shrink the block to whatever divides `rows`, round the array up
-        # (≤ _BLOCK_ROWS·512 B of zeros; zeros encode to zeros).
-        rows = npad // _PACK
-        tr = min(_BLOCK_ROWS, rows)
-        npad = -(-rows // tr) * tr * _PACK
-        padded = jnp.pad(shards, ((0, 0), (0, npad - n)))
-        out = _gf_matmul_pallas(coeffs, padded, interpret=interpret)
-        return out[:, :n]
-    padded = jnp.pad(shards, ((0, 0), (0, npad - n)))
+        return _gf_matmul_pallas(coeffs, shards, interpret=interpret)
     # XLA fallback: same packed math, byte planes packed as shard
-    # quarters (plane q = bytes [q·npad/4, (q+1)·npad/4) — no [..., 4]
+    # quarters (plane q = bytes [q·nb/4, (q+1)·nb/4) — no [..., 4]
     # minor dim, whose TPU tiling would pad 32×).
-    rows = npad // 4 // _LANE
-    planes = padded.reshape(K, 4, rows, _LANE).astype(jnp.int32)
+    rows = nb // 4 // _LANE
+    planes = shards.reshape(K, 4, rows, _LANE).astype(jnp.int32)
     packed = (
         planes[:, 0] | (planes[:, 1] << 8)
         | (planes[:, 2] << 16) | (planes[:, 3] << 24)
@@ -226,18 +241,22 @@ def _gf_matmul_jit(coeffs, shards, n, use_pallas, interpret):
     planes_out = jnp.stack(
         [(out >> (8 * q)) & 0xFF for q in range(4)], axis=1
     ).astype(jnp.uint8)
-    return planes_out.reshape(M, npad)[:, :n]
+    return planes_out.reshape(M, nb)
 
 
 def gf_matmul(coeffs, shards, *, use_pallas: bool | None = None,
-              interpret: bool = False) -> jax.Array:
-    """[M, K] static coefficient matrix ·_gf [K, N] uint8 shards → [M, N].
+              interpret: bool = False) -> np.ndarray:
+    """[M, K] static coefficient matrix ·_gf [K, N] uint8 shards → [M, N],
+    on the host.
 
     `coeffs` must be a tuple of tuples of python ints (it is baked into
     the compiled program; encode uses the fixed generator, reconstruction
-    one of the C(k+m, k) inverses — each pattern compiles once). Shards
-    are zero-padded to the packing width internally (zeros encode to
-    zeros — GF linearity — so the slice back is exact).
+    one of the C(k+m, k) inverses — each pattern compiles once per
+    bucket). Shards are zero-padded ON THE HOST to `shard_bucket(N)` and
+    the product is cut back to N on the host too (zeros encode to zeros
+    — GF linearity — so the cut is exact): a pad or slice on the device
+    would be a program per N again. An input already at a bucket length
+    is passed through uncopied.
 
     Runs on the calling process's DEFAULT backend: the Pallas kernel
     when that is a TPU, the XLA form of the same math on host cores
@@ -247,18 +266,24 @@ def gf_matmul(coeffs, shards, *, use_pallas: bool | None = None,
     not per call site.
     """
     coeffs = tuple(tuple(int(c) for c in row) for row in coeffs)
-    shards = jnp.asarray(shards, jnp.uint8)
+    shards = np.asarray(shards, np.uint8)
     if shards.ndim != 2 or len(coeffs) == 0 or len(coeffs[0]) != shards.shape[0]:
         raise ValueError(
             f"coeffs {len(coeffs)}x{len(coeffs[0]) if coeffs else 0} does not "
             f"match shards {shards.shape}"
         )
-    if shards.shape[1] == 0:
-        return jnp.zeros((len(coeffs), 0), jnp.uint8)
+    K, n = shards.shape
+    if n == 0:
+        return np.zeros((len(coeffs), 0), np.uint8)
+    nb = shard_bucket(n)
+    if nb != n:
+        padded = np.zeros((K, nb), np.uint8)
+        padded[:, :n] = shards
+        shards = padded
     if use_pallas is None:
         use_pallas = jax.default_backend() == "tpu"
-    return _gf_matmul_jit(coeffs, shards, shards.shape[1],
-                          bool(use_pallas), bool(interpret))
+    out = _gf_matmul_jit(coeffs, shards, bool(use_pallas), bool(interpret))
+    return np.asarray(out)[:, :n]
 
 
 # --------------------------------------------------------------------------
@@ -266,7 +291,7 @@ def gf_matmul(coeffs, shards, *, use_pallas: bool | None = None,
 # --------------------------------------------------------------------------
 
 
-def rs_encode(data_shards, k: int = 3, m: int = 2, **kw) -> jax.Array:
+def rs_encode(data_shards, k: int = 3, m: int = 2, **kw) -> np.ndarray:
     """[k, N] data shards → [m, N] parity shards."""
     if data_shards.shape[0] != k:
         raise ValueError(f"expected {k} data shards, got {data_shards.shape}")
@@ -274,7 +299,7 @@ def rs_encode(data_shards, k: int = 3, m: int = 2, **kw) -> jax.Array:
 
 
 def rs_reconstruct(present: dict[int, "np.ndarray"], k: int = 3,
-                   m: int = 2, **kw) -> jax.Array:
+                   m: int = 2, **kw) -> np.ndarray:
     """Rebuild the [k, N] data block from ANY k available shards.
 
     `present` maps shard index (0..k-1 data, k..k+m-1 parity) → [N] bytes.
@@ -285,5 +310,5 @@ def rs_reconstruct(present: dict[int, "np.ndarray"], k: int = 3,
     rows = sorted(present)[:k]
     ext = extended_matrix(k, m)
     inv = gf_invert([ext[r] for r in rows])
-    stacked = jnp.stack([jnp.asarray(present[r], jnp.uint8) for r in rows])
+    stacked = np.stack([np.asarray(present[r], np.uint8) for r in rows])
     return gf_matmul(inv, stacked, **kw)

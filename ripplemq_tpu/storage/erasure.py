@@ -40,7 +40,7 @@ from typing import Optional
 
 import numpy as np
 
-from ripplemq_tpu.ops.rs import rs_encode, rs_reconstruct
+from ripplemq_tpu.ops.rs import rs_encode, rs_reconstruct, shard_bucket
 from ripplemq_tpu.utils.logs import get_logger
 
 _log = get_logger("storage")
@@ -77,6 +77,18 @@ def _shard_length(orig_len: int) -> int:
     return -(-orig_len // K)  # ceil; last data shard is zero-padded
 
 
+def warm_encode(segment_bytes: int, **kw) -> int:
+    """Encode one zeroed input of a full segment's shard length, so the
+    RS program a store of this segment size needs exists before its
+    first seal; returns that shard length. Sealed segments are a write
+    short of segment_bytes (rotation comes before the write that would
+    cross it), which is the same ladder entry unless segment_bytes sits
+    just above one (ops/rs.shard_bucket)."""
+    n = _shard_length(segment_bytes)
+    rs_encode(np.zeros((K, shard_bucket(n)), np.uint8), k=K, m=M, **kw)
+    return n
+
+
 def encode_segment(store_dir: str, seg_name: str, stage=None,
                    **kw) -> list[str]:
     """Write the K+M shard files for one sealed segment. Atomic per shard
@@ -89,16 +101,21 @@ def encode_segment(store_dir: str, seg_name: str, stage=None,
         raw = f.read()
     data_crc = zlib.crc32(raw) & 0xFFFFFFFF
     n = _shard_length(len(raw))
-    padded = np.zeros(K * n, np.uint8)
-    padded[: len(raw)] = np.frombuffer(raw, np.uint8)
-    data = padded.reshape(K, n)
+    # Shard j is raw[j*n:(j+1)*n], the last zero-padded to n. The rows
+    # are laid out at the bucket length the encoder runs at, so the
+    # zeros it needs past n are these and nothing is copied again.
+    data = np.zeros((K, shard_bucket(n)), np.uint8)
+    flat = np.frombuffer(raw, np.uint8)
+    for j in range(K):
+        part = flat[j * n : (j + 1) * n]
+        data[j, : len(part)] = part
     with stage(n) if stage is not None else contextlib.nullcontext():
-        parity = np.asarray(rs_encode(data, k=K, m=M, **kw))
-    shards = np.concatenate([data, parity], axis=0)
+        parity = rs_encode(data, k=K, m=M, **kw)
+    shards = [*data[:, :n], *parity[:, :n]]
     os.makedirs(_rs_dir(store_dir), exist_ok=True)
     paths = shard_paths(store_dir, seg_name)
     for i, path in enumerate(paths):
-        payload = shards[i].tobytes()
+        payload = shards[i]  # a contiguous row: hashed and written in place
         header = _HEADER.pack(
             _MAGIC, _VERSION, i, K, M, len(raw), data_crc,
             zlib.crc32(payload) & 0xFFFFFFFF,
@@ -106,7 +123,8 @@ def encode_segment(store_dir: str, seg_name: str, stage=None,
         tmp = path + ".tmp"
         try:
             with open(tmp, "wb") as f:
-                f.write(header + payload)
+                f.write(header)
+                f.write(payload)
                 f.flush()
                 os.fsync(f.fileno())
             os.replace(tmp, path)
@@ -165,7 +183,7 @@ def reconstruct_segment(store_dir: str, seg_name: str, **kw) -> bytes:
     if all(i in present for i in range(K)):
         data = np.stack([present[i] for i in range(K)])
     else:
-        data = np.asarray(rs_reconstruct(present, k=K, m=M, **kw))
+        data = rs_reconstruct(present, k=K, m=M, **kw)
     raw = data.reshape(-1).tobytes()[:orig_len]
     if (zlib.crc32(raw) & 0xFFFFFFFF) != data_crc:
         raise ShardError(f"{seg_name}: reconstructed bytes fail segment CRC")

@@ -211,7 +211,9 @@ class SegmentStore:
     repair runs in recover_image before replay). The encode runs OFF the
     flush path — flush is the replication step thread's durability
     barrier and must not stall for a whole segment's GF matmul — and an
-    unencoded sealed segment is simply picked up by a later kick."""
+    unencoded sealed segment is simply picked up by a later kick. The
+    same thread's first job, at open, is to compile the program those
+    encodes run (_erasure_warm)."""
 
     def __init__(self, directory: str, segment_bytes: int = 64 << 20,
                  use_native: Optional[bool] = None,
@@ -232,9 +234,10 @@ class SegmentStore:
             self._c_records = metrics.counter("store.append_records")
             self._clock = metrics.clock
             # One sealed segment's RS encode (obs/stages.py), and how
-            # many of them met a shard length this store had not
-            # encoded before: the length is a static jit argument of
-            # ops/rs.py, so each new one compiles a new program.
+            # many RS programs this store has asked for: one per shard
+            # length BUCKET (ops/rs.shard_bucket), the first of them at
+            # open (_erasure_warm), so a rise later is a compile beside
+            # traffic.
             self._st_rs_encode = metrics.stage("seal.rs_encode")
             self._c_rs_new_shapes = metrics.counter("rs.new_shapes")
         else:
@@ -242,7 +245,7 @@ class SegmentStore:
             self._c_append_bytes = self._c_records = None
             self._clock = None
             self._st_rs_encode = self._c_rs_new_shapes = None
-        self._rs_shapes: set[int] = set()  # erasure worker only
+        self._rs_shapes: set[int] = set()  # erasure thread only
         self.segment_bytes = segment_bytes
         self.erasure = erasure
         # Size-capped disk retention: gc() deletes the OLDEST sealed
@@ -280,6 +283,12 @@ class SegmentStore:
             self._handle = None
             self._seg_index = self._next_index()
             self._file = open(self._seg_path(self._seg_index), "ab")
+        if erasure:
+            self._erasure_thread = threading.Thread(
+                target=self._erasure_warm, daemon=True,
+                name="segstore-erasure",
+            )
+            self._erasure_thread.start()
 
     # -- python fallback helpers --
     def _seg_path(self, index: int) -> str:
@@ -536,21 +545,46 @@ class SegmentStore:
             protect_store(self.directory, stage=self._seal_stage
                           if self._st_rs_encode is not None else None)
         except Exception as e:  # derived data: never take the store down
-            _log.warning("erasure encode failed for %s: %s: %s",
-                         self.directory, type(e).__name__, e)
-            # append + del-slice trim must not interleave with another
-            # writer (ownership lint, PR 11): error path, lock is free.
-            with self._lock:
-                self.erasure_errors.append(f"{type(e).__name__}: {e}")
-                del self.erasure_errors[:-20]
+            self._erasure_failed("encode", e)
+
+    def _erasure_warm(self) -> None:
+        """The erasure thread's first job, started at open: build the RS
+        program this store's seals will run, on this process's backend,
+        before there is traffic to stall behind a compile (on the
+        chip-owning broker ~2.5 s under the GIL every serving thread
+        shares). It takes no lock of the store or the dataplane; a kick
+        that finds it running is skipped like any other (the next flush
+        kicks again), so a seal never compiles beside it."""
+        from ripplemq_tpu.storage.erasure import warm_encode
+
+        try:
+            self._count_rs_bucket(warm_encode(self.segment_bytes))
+        except Exception as e:  # a seal will compile it, as before
+            self._erasure_failed("warm-up", e)
+
+    def _erasure_failed(self, what: str, e: Exception) -> None:
+        _log.warning("erasure %s failed for %s: %s: %s",
+                     what, self.directory, type(e).__name__, e)
+        # append + del-slice trim must not interleave with another
+        # writer (ownership lint, PR 11): error path, lock is free.
+        with self._lock:
+            self.erasure_errors.append(f"{type(e).__name__}: {e}")
+            del self.erasure_errors[:-20]
+
+    def _count_rs_bucket(self, shard_len: int) -> None:
+        from ripplemq_tpu.ops.rs import shard_bucket
+
+        bucket = shard_bucket(shard_len)
+        if bucket not in self._rs_shapes:
+            self._rs_shapes.add(bucket)
+            if self._c_rs_new_shapes is not None:
+                self._c_rs_new_shapes.inc()
 
     def _seal_stage(self, shard_len: int):
         """The timed region storage/erasure.py opens around one sealed
         segment's RS encode: `seal.rs_encode`, counting a shard length
-        not seen before under rs.new_shapes."""
-        if shard_len not in self._rs_shapes:
-            self._rs_shapes.add(shard_len)
-            self._c_rs_new_shapes.inc()
+        BUCKET not met before under rs.new_shapes."""
+        self._count_rs_bucket(shard_len)
         return self._st_rs_encode.timed()
 
     def gc(self) -> list[int]:

@@ -11,13 +11,15 @@ MDS property, ops/rs.py), so shipping DISTINCT stripes to distinct
 standbys buys R=5-equivalent 2-loss durability at (k+m)/k ≈ 1.67×
 replication bytes instead of full copies' (R−1)×.
 
-The matmul is jit-compiled per shard length, so shard lengths are padded
-up to a bounded ladder of SIZE CLASSES before encoding (`_shard_class`)
-— compute pads, wire bytes do not: the GF matmul is per-byte-column
-independent, so parity columns beyond the real shard length are zero and
-are trimmed before framing (data stripes ship exactly their slice of the
-blob). Replication byte cost therefore stays (k+m)/k × blob + k+m frame
-headers, independent of the class ladder.
+The matmul's program is shaped by the shard length, so shard lengths are
+padded up to a bounded ladder of SIZE CLASSES before encoding
+(`_shard_class`; `ops/rs.gf_matmul` has since bucketed every input to
+whole kernel blocks itself, so the classes now only decide which bucket
+a group lands in) — compute pads, wire bytes do not: the GF matmul is
+per-byte-column independent, so parity columns beyond the real shard
+length are zero and are trimmed before framing (data stripes ship
+exactly their slice of the blob). Replication byte cost therefore stays
+(k+m)/k × blob + k+m frame headers, independent of the class ladder.
 
 The sealed-segment protection plane (storage/erasure.py) imports RS_K /
 RS_M from here: one geometry, two consumers — the off-path segment

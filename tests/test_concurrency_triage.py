@@ -95,6 +95,7 @@ def test_kick_erasure_serialized_under_store_lock(tmp_path):
     store = SegmentStore(str(tmp_path / "store"), erasure=True,
                          use_native=False)
     try:
+        store.wait_erasure(timeout=60)  # the warm-up at open holds the slot
         store.append(1, 0, 0, b"x" * 16)
         store._erasure_check_t = -10.0  # clear the rate limit
         with store._lock:

@@ -284,12 +284,12 @@ def test_segmentstore_flush_protects_and_recovery_repairs(tmp_path):
     )
 
 
-def test_seal_encode_is_timed_and_new_shard_lengths_counted(tmp_path):
-    """ISSUE 24: with a registry the erasure worker times each sealed
-    segment's RS encode (`seal.rs_encode_us`) and counts the encodes
-    that met a shard length this store had not seen (`rs.new_shapes`:
-    the length is a static jit argument, so each is a new program);
-    without one it times nothing."""
+def test_seal_encode_is_timed_and_new_shard_buckets_counted(tmp_path):
+    """ISSUE 24, 26: with a registry the erasure worker times each sealed
+    segment's RS encode (`seal.rs_encode_us`) and counts the shard
+    length BUCKETS this store has asked a program for (`rs.new_shapes`);
+    lengths that share a bucket share a program. Without a registry it
+    times nothing."""
     from ripplemq_tpu.obs.metrics import Metrics
 
     m = Metrics()
@@ -308,7 +308,8 @@ def test_seal_encode_is_timed_and_new_shard_lengths_counted(tmp_path):
     snap = m.snapshot()
     encoded = snap["histograms"]["seal.rs_encode_us"]["count"]
     assert encoded >= len(lengths) >= 2
-    assert snap["counters"]["rs.new_shapes"] == len(lengths)
+    assert len({rs.shard_bucket(n) for n in lengths}) == 1
+    assert snap["counters"]["rs.new_shapes"] == 1
 
     bare = SegmentStore(str(tmp_path / "bare"), segment_bytes=1024,
                         use_native=False, erasure=True)
@@ -316,3 +317,216 @@ def test_seal_encode_is_timed_and_new_shard_lengths_counted(tmp_path):
     bare.append(REC_APPEND, 0, 8, bytes(700))
     bare.close()
     assert erasure._protected_names(bare.directory)
+
+
+# ------------------------------------------------- the shard-length bucket
+
+_BLOCK = rs._BLOCK_ROWS * rs._PACK
+_S = 64 << 20  # the default segment_bytes
+_FRAME = 8 << 20  # a standby's group-commit frame, the largest one write
+
+
+def test_shard_bucket_is_a_monotone_ladder_of_whole_blocks():
+    rng = np.random.default_rng(26)
+    ns = np.unique(np.concatenate([
+        np.arange(0, 4 * _BLOCK + 2, 509),
+        [(1 << k) + d for k in range(18, 33) for d in (-1, 0, 1)],
+        rng.integers(1, 1 << 32, 4000),
+    ]))
+    bs = [rs.shard_bucket(int(n)) for n in ns]
+    assert bs == sorted(bs)
+    for n, b in zip(ns.tolist(), bs):
+        assert b >= n and b % _BLOCK == 0
+        assert rs.shard_bucket(b) == b  # an entry is its own bucket
+        # never a whole step over: one block up to 2^20, then a quarter
+        # of the power of two below n (at most 25%)
+        assert b - n < max(_BLOCK, n // 4 + 1)
+    assert rs.shard_bucket(0) == 0 and rs.shard_bucket(1) == _BLOCK
+    assert [rs.shard_bucket((1 << 24) + 1 + i * (1 << 22)) >> 20
+            for i in range(4)] == [20, 24, 28, 32]
+
+
+@pytest.mark.parametrize("lo,hi", [
+    (_S, _S + _FRAME),       # ISSUE 26's range: a write past segment_bytes
+    (_S - (4 << 20) + 1, _S),  # what the writers do: a write short of it
+])
+def test_default_segments_meet_one_bucket(lo, hi):
+    # the ladder is monotone (above), so the two ends speak for the range
+    assert rs.shard_bucket(-(-lo // 3)) == rs.shard_bucket(-(-hi // 3)) \
+        == 24 << 20
+
+
+@pytest.mark.parametrize("segment_bytes", [
+    1024, 4096, 32768, 1 << 20, 8 << 20, 48 << 20, 61 << 20, 1 << 30])
+def test_any_segment_size_meets_two_buckets_at_most(segment_bytes):
+    """A sealed segment is at most one write short of segment_bytes; a
+    write is a round's records or a group-commit frame, taken here as up
+    to a sixteenth of the segment and never over 8 MiB. Monotone, so:
+    the full segment's entry is the shortest one's or the next. The
+    tests' small stores fit one kernel block whatever they hold."""
+    short = min(segment_bytes // 16, _FRAME)
+    lo = rs.shard_bucket(-(-(segment_bytes - short) // 3))
+    assert rs.shard_bucket(-(-segment_bytes // 3)) in (
+        lo, rs.shard_bucket(lo + 1))
+    if segment_bytes <= 3 * _BLOCK:
+        assert rs.shard_bucket(-(-2 * segment_bytes // 3)) == _BLOCK
+
+
+@pytest.mark.parametrize("rows", [1, 2, 3, 8, 9, 64, 511, 512, 513])
+def test_bucketed_parity_matches_reference_at_block_edges(rows):
+    """Lengths around whole packed rows, one block (512 rows) and one row
+    into the second block: both device forms against the numpy tables."""
+    coeffs = rs.generator_matrix(3, 2)
+    rng = np.random.default_rng(rows)
+    for n in (rows * rs._PACK - 1, rows * rs._PACK):
+        shards = rng.integers(0, 256, size=(3, n), dtype=np.uint8)
+        ref = rs.gf_matmul_ref(coeffs, shards)
+        xla = rs.gf_matmul(coeffs, shards, use_pallas=False)
+        pal = rs.gf_matmul(coeffs, shards, use_pallas=False, interpret=True)
+        assert xla.shape == pal.shape == (2, n)
+        assert np.array_equal(xla, ref) and np.array_equal(pal, ref)
+
+
+def test_segment_scale_parity_matches_reference():
+    """One shard of a full default segment (chip_smoke.py's size)."""
+    n = -(-_S // 3)
+    assert n == 22_369_622
+    shards = np.random.default_rng(7).integers(
+        0, 256, size=(3, n), dtype=np.uint8)
+    coeffs = rs.generator_matrix(3, 2)
+    assert np.array_equal(rs.gf_matmul(coeffs, shards, use_pallas=False),
+                          rs.gf_matmul_ref(coeffs, shards))
+
+
+def test_every_erasure_pattern_reconstructs_on_a_bucketed_length():
+    n = _BLOCK + 12_345  # padded to two blocks: the grid has two steps
+    rng = np.random.default_rng(11)
+    data = rng.integers(0, 256, size=(3, n), dtype=np.uint8)
+    shards = np.concatenate([data, rs.rs_encode(data, use_pallas=False)])
+    for r in (0, 1, 2):
+        for lost in itertools.combinations(range(5), r):
+            present = {i: shards[i] for i in range(5) if i not in lost}
+            rec = rs.rs_reconstruct(present, use_pallas=False)
+            assert rec.shape == (3, n)
+            assert np.array_equal(rec, data), f"lost {lost}"
+
+
+def _seal(store, base, nbytes):
+    """One record of `nbytes`, then the next: the second rotates."""
+    store.append(REC_APPEND, 0, base, os.urandom(nbytes))
+
+
+def test_five_lengths_one_program_compiled_at_open(tmp_path):
+    """The store builds its RS program when it opens, on the erasure
+    thread; five sealed segments of five lengths then compile nothing
+    (`rs.new_shapes` stays 1, one entry in the jit cache) and only they
+    are timed as seals."""
+    from ripplemq_tpu.obs.metrics import Metrics
+
+    rs._gf_matmul_jit.clear_cache()
+    m = Metrics()
+    store = SegmentStore(str(tmp_path / "s"), segment_bytes=4096,
+                         use_native=False, erasure=True, metrics=m)
+    store.wait_erasure(timeout=60)
+    assert not store._erasure_thread.is_alive()
+    assert m.snapshot()["counters"]["rs.new_shapes"] == 1
+    assert rs._gf_matmul_jit._cache_size() == 1
+    assert "seal.rs_encode_us" not in {
+        k for k, h in m.snapshot()["histograms"].items() if h["count"]}
+    sizes = (2100, 2500, 2900, 3300, 3700)
+    for i, nbytes in enumerate(sizes):
+        _seal(store, i * 8, nbytes)
+    _seal(store, 99, 2100)  # rotates the fifth out
+    store.close()
+    sealed = erasure._segment_names(store.directory)[:-1]
+    assert len({os.path.getsize(os.path.join(store.directory, s))
+                for s in sealed}) == 5
+    assert erasure._protected_names(store.directory) >= set(sealed)
+    snap = m.snapshot()
+    assert snap["histograms"]["seal.rs_encode_us"]["count"] == 5
+    assert snap["counters"]["rs.new_shapes"] == 1
+    assert rs._gf_matmul_jit._cache_size() == 1
+    assert store.erasure_errors == []
+
+
+def test_a_segment_far_short_of_its_size_is_a_second_bucket(tmp_path):
+    """Not one per seal: a store whose writes are most of a segment long
+    seals far short of segment_bytes and meets the ladder entry below
+    the one built at open, once."""
+    from ripplemq_tpu.obs.metrics import Metrics
+
+    m = Metrics()
+    store = SegmentStore(str(tmp_path / "s"), segment_bytes=1 << 20,
+                         use_native=False, erasure=True, metrics=m)
+    for i, nbytes in enumerate((600_000, 610_000, 620_000, 630_000)):
+        _seal(store, i * 8, nbytes)
+    store.close()
+    snap = m.snapshot()
+    assert snap["histograms"]["seal.rs_encode_us"]["count"] == 3
+    assert snap["counters"]["rs.new_shapes"] == 2
+    assert {rs.shard_bucket(-(-(1 << 20) // 3)),
+            rs.shard_bucket(-(-600_040 // 3))} == {2 * _BLOCK, _BLOCK}
+
+
+def _parent_shard_blobs(raw: bytes) -> list[bytes]:
+    """The shard files of one segment as the code before ISSUE 26 wrote
+    them, from the numpy table reference: the segment zero-padded to
+    K*n, cut in K rows, parity over exactly n columns."""
+    import struct
+
+    n = -(-len(raw) // 3)
+    padded = np.zeros(3 * n, np.uint8)
+    padded[: len(raw)] = np.frombuffer(raw, np.uint8)
+    data = padded.reshape(3, n)
+    rows = np.concatenate(
+        [data, rs.gf_matmul_ref(rs.generator_matrix(3, 2), data)])
+    crc = zlib.crc32(raw) & 0xFFFFFFFF
+    return [
+        struct.pack("<IBBBBQII", 0x52535348, 1, i, 3, 2, len(raw), crc,
+                    zlib.crc32(rows[i].tobytes()) & 0xFFFFFFFF)
+        + rows[i].tobytes()
+        for i in range(5)
+    ]
+
+
+def test_shard_files_are_byte_identical_to_the_parents(tmp_path):
+    """The bucket changes what the device is handed, not what is stored:
+    every shard file equals the one the unbucketed code wrote, and a
+    store whose shards that code wrote still verifies and repairs."""
+    store_dir, _ = _fill_store(tmp_path, rounds=60, segment_bytes=4000)
+    sealed = erasure._segment_names(store_dir)[:-1]
+    assert len(sealed) >= 3
+    erasure.protect_store(store_dir)
+    want = {}
+    for name in sealed:
+        with open(os.path.join(store_dir, name), "rb") as f:
+            want[name] = _parent_shard_blobs(f.read())
+        for path, blob in zip(erasure.shard_paths(store_dir, name),
+                              want[name]):
+            with open(path, "rb") as f:
+                assert f.read() == blob, path
+
+    # a parent-written store: only its files, two shards and one segment lost
+    old = str(tmp_path / "old")
+    os.makedirs(os.path.join(old, "rs"))
+    for name in erasure._segment_names(store_dir):
+        with open(os.path.join(store_dir, name), "rb") as f:
+            raw = f.read()
+        with open(os.path.join(old, name), "wb") as f:
+            f.write(raw)
+    for name in sealed:
+        for path, blob in zip(erasure.shard_paths(old, name), want[name]):
+            with open(path, "wb") as f:
+                f.write(blob)
+    assert erasure.repair_store(old) == []  # every shard verifies
+    before = _scan_all(old)
+    victim = sealed[1]
+    os.remove(os.path.join(old, victim))
+    os.remove(erasure.shard_paths(old, victim)[0])
+    os.remove(erasure.shard_paths(old, victim)[3])
+    errors: list = []
+    assert erasure.repair_store(old, errors=errors) == [victim]
+    assert errors == [] and _scan_all(old) == before
+    for path, blob in zip(erasure.shard_paths(old, victim), want[victim]):
+        with open(path, "rb") as f:
+            assert f.read() == blob, path
