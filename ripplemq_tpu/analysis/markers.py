@@ -53,6 +53,7 @@ FAST_MODULES = {
     "test_hostplane_chaos",     # ~35 s: one seeded run + prefix parity
     "test_hostraft",
     "test_idempotence",         # ~25 s: dedup units + failover replay
+    "test_keyed_producer",      # ~10 s: send()/produce.multi vs the reference
     "test_linearizable_reads",  # ~25 s: staged stale-controller clusters
     "test_lint",                # ripplelint fixtures + whole-repo clean run
     "test_lockwitness",         # witness units: private locks, no cluster

@@ -231,6 +231,12 @@ class DataPlane:
         self._m_queue_wait_us = m.histogram("produce.queue_wait_us")
         self._m_h2d_bytes = m.counter("round.h2d_bytes")
         self._m_pipeline_full = m.counter("round.pipeline_full")
+        # What a round is staged as against what it carries (_drain):
+        # the appending partitions of each live round, and the rows of
+        # the [A, B, SB] block stack that holds them (A the active-set
+        # bucket) - produce.messages over round.staged_rows is the fill.
+        self._m_active_slots = m.histogram("round.active_slots")
+        self._m_staged_rows = m.counter("round.staged_rows")
         self._st_idle = m.stage("round.idle")
         self._st_coalesce = m.stage("round.coalesce")
         self._st_drain = m.stage("round.drain")
@@ -967,13 +973,46 @@ class DataPlane:
         sequence ABOVE the table's end is accepted as new — dedup never
         refuses fresh data, it only collapses replays."""
         fut: Future = Future()
+        rows = self._check_and_pack(slot, payloads, fut)
+        if rows is None:
+            return fut
+        return self._submit_rows(slot, list(payloads), rows, pid, seq, fut,
+                                 tctx)
+
+    def submit_appends(self, items: list) -> list[Future]:
+        """`submit_append` for MANY batches at once — the parts of one
+        produce.multi request, as (slot, payloads, pid, seq, tctx) — with
+        the same checks and the same outcome each, but ONE hold of the
+        plane's lock for all of them: a request of a hundred parts must
+        not queue a hundred times behind the step thread's round build
+        (each contended hand-off of that lock costs a GIL switch
+        interval)."""
+        futs: list[Future] = [Future() for _ in items]
+        ready = []
+        for i, (slot, payloads, pid, seq, tctx) in enumerate(items):
+            rows = self._check_and_pack(slot, payloads, futs[i])
+            if rows is not None:
+                ready.append((i, slot, list(payloads), rows, int(pid),
+                              int(seq), tctx))
+        if ready:
+            with self._lock:
+                for i, slot, payloads, rows, pid, seq, tctx in ready:
+                    futs[i] = self._enqueue_locked(
+                        slot, payloads, rows, pid, seq, futs[i], tctx)
+            self._work.set()
+        return futs
+
+    def _check_and_pack(self, slot: int, payloads: list[bytes],
+                        fut: Future):
+        """The row block of one append batch, or None with the refusal
+        set on `fut` (runs off-lock, on the caller's thread)."""
         cfg = self.cfg
         if not 0 <= slot < cfg.partitions:
             fut.set_exception(ValueError(f"partition slot {slot} out of range"))
-            return fut
+            return None
         if not payloads:
             fut.set_exception(ValueError("empty append"))
-            return fut
+            return None
         if len(payloads) > cfg.max_batch:
             # Callers (the broker server) split client batches to fit one
             # round; a single submit never spans rounds.
@@ -982,7 +1021,7 @@ class DataPlane:
                     f"{len(payloads)} payloads exceed max_batch {cfg.max_batch}"
                 )
             )
-            return fut
+            return None
         # Bulk validation (this runs per batch on RPC worker threads —
         # a per-message three-check python loop was a measurable slice
         # of the produce path's CPU): min/max are C-speed passes, and
@@ -994,7 +1033,7 @@ class DataPlane:
                     ValueError("empty messages are not supported (length-0 "
                                "rows mark alignment padding)")
                 )
-                return fut
+                return None
             if max(lens) > cfg.payload_bytes:
                 fut.set_exception(
                     ValueError(
@@ -1002,15 +1041,13 @@ class DataPlane:
                         f"{cfg.payload_bytes}"
                     )
                 )
-                return fut
-            rows = pack_payload_rows(self.cfg, payloads)  # off-lock packing
+                return None
+            return pack_payload_rows(self.cfg, payloads)  # off-lock packing
         except TypeError as e:
             fut.set_exception(
                 TypeError(f"payloads must be bytes: {e}")
             )
-            return fut
-        return self._submit_rows(slot, list(payloads), rows, pid, seq, fut,
-                                 tctx)
+            return None
 
     def submit_packed(self, slot: int, packed, lens: list[int],
                       pid: int = 0, seq: int = -1, tctx=None) -> Future:
@@ -1055,47 +1092,56 @@ class DataPlane:
                      tctx=None) -> Future:
         """Shared enqueue tail of submit_append / submit_packed (the
         caller validated and packed)."""
+        with self._lock:
+            fut = self._enqueue_locked(slot, payloads, rows, int(pid),
+                                       int(seq), fut, tctx)
+        self._work.set()
+        return fut
+
+    def _enqueue_locked(self, slot: int, payloads: list, rows, pid: int,
+                        seq: int, fut: Future, tctx=None) -> Future:
+        """Dedup probe + enqueue of one validated batch (caller holds
+        self._lock, and sets `_work` after). Returns the future the
+        caller answers from: `fut`, or the in-flight round's own when
+        the batch is a concurrent replay."""
         self._m_submits.inc()
         self._m_messages.inc(len(payloads))
-        pid, seq = int(pid), int(seq)
-        with self._lock:
-            if pid > 0:
-                dup = self._pid_lookup_locked(pid, slot, seq, len(payloads))
-                if dup is not None:
-                    fut.set_result(dup)
-                    return fut
-                inflight = self._pid_inflight.get((pid, slot, seq))
-                if inflight is not None:
-                    # Same batch, round still in flight (wire dup /
-                    # concurrent retry): one append, shared outcome.
-                    return inflight
-            if self._log_end[slot] >= _OFFSET_HORIZON:
-                fut.set_exception(
-                    PartitionFullError(
-                        f"partition {slot} reached the int32 offset horizon "
-                        f"({_OFFSET_HORIZON} rows); re-key onto another "
-                        f"partition"
-                    )
-                )
+        if pid > 0:
+            dup = self._pid_lookup_locked(pid, slot, seq, len(payloads))
+            if dup is not None:
+                fut.set_result(dup)
                 return fut
-            self._appends.setdefault(slot, []).append(
-                _Pending(list(payloads), fut, self.max_retry_rounds, rows,
-                         pid=pid, seq=seq,
-                         tctx=tctx if self.spans is not None else None,
-                         t_submit=self.metrics.clock())
+            inflight = self._pid_inflight.get((pid, slot, seq))
+            if inflight is not None:
+                # Same batch, round still in flight (wire dup /
+                # concurrent retry): one append, shared outcome.
+                return inflight
+        if self._log_end[slot] >= _OFFSET_HORIZON:
+            fut.set_exception(
+                PartitionFullError(
+                    f"partition {slot} reached the int32 offset horizon "
+                    f"({_OFFSET_HORIZON} rows); re-key onto another "
+                    f"partition"
+                )
             )
-            if pid > 0:
-                # Settled batches are moved to the dedup table — and
-                # popped from here — by the settle thread under this
-                # same lock, so no dup can slip between the two. FAILED
-                # batches are popped at every terminal-failure site
-                # (_pid_drop_locked): the producer's retry must
-                # re-submit a real append, not attach to a dead future.
-                # (Not a done-callback: those run inline at
-                # set_exception, and several failure sites already hold
-                # this non-reentrant lock.)
-                self._pid_inflight[(pid, slot, seq)] = fut
-        self._work.set()
+            return fut
+        self._appends.setdefault(slot, []).append(
+            _Pending(list(payloads), fut, self.max_retry_rounds, rows,
+                     pid=pid, seq=seq,
+                     tctx=tctx if self.spans is not None else None,
+                     t_submit=self.metrics.clock())
+        )
+        if pid > 0:
+            # Settled batches are moved to the dedup table — and
+            # popped from here — by the settle thread under this
+            # same lock, so no dup can slip between the two. FAILED
+            # batches are popped at every terminal-failure site
+            # (_pid_drop_locked): the producer's retry must
+            # re-submit a real append, not attach to a dead future.
+            # (Not a done-callback: those run inline at
+            # set_exception, and several failure sites already hold
+            # this non-reentrant lock.)
+            self._pid_inflight[(pid, slot, seq)] = fut
         return fut
 
     def _pid_lookup_locked(self, pid: int, slot: int, seq: int,
@@ -1890,6 +1936,9 @@ class DataPlane:
             for a, (slot, block) in enumerate(sorted(rc["entries"].items())):
                 ec[k, a] = block
                 ids[k, a] = slot
+            if rc["appends"] or rc["offsets"]:  # a live round, not padding
+                self._m_active_slots.observe_int(len(rc["entries"]))
+                self._m_staged_rows.inc(A * B)
         if len(rounds) == 1:
             inp = rounds[0][0]
             entries_c, slot_ids = ec[0], ids[0]
@@ -2591,14 +2640,22 @@ class DataPlane:
         if self._host_ring is None:
             return
         S, SB = self.cfg.slots, self.cfg.slot_bytes
+        written: list[tuple[int, int, int]] = []
         for rec_type, slot, base, payload in records:
             if rec_type != REC_APPEND:
                 continue
             rows = np.frombuffer(payload, np.uint8).reshape(-1, SB)
             pos = base % S
             self._host_ring[slot, pos : pos + rows.shape[0]] = rows
-            with self._lock:
-                new_end = base + rows.shape[0]
+            written.append((slot, base, base + rows.shape[0]))
+        if not written:
+            return
+        # ONE hold of the lock for the round's watermarks, after all its
+        # rows are in place (a round of a keyed producer has hundreds of
+        # records; a hold apiece queued the settle thread behind the
+        # readers hundreds of times a round).
+        with self._lock:
+            for slot, base, new_end in written:
                 if self._cache_end[slot] >= base:
                     self._cache_end[slot] = max(
                         new_end, int(self._cache_end[slot])

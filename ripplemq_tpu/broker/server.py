@@ -40,6 +40,7 @@ from __future__ import annotations
 
 import threading
 import time
+from concurrent.futures import Future
 from typing import Callable, Optional
 
 
@@ -98,6 +99,34 @@ class _UpstreamRefusal(Exception):
     def __init__(self, resp: dict) -> None:
         super().__init__(str(resp.get("error", "")))
         self.resp = dict(resp)
+
+
+class _AppendBatch:
+    """The appends of one produce.multi: gathered while its parts are
+    admitted, then submitted TOGETHER — to the local plane under one hold
+    of its lock (`DataPlane.submit_appends`), or, on a leader that is not
+    the controller, as ONE engine.append_multi frame — and answered item
+    by item."""
+
+    def __init__(self, timeout_s: float) -> None:
+        self.items: list[tuple] = []    # (slot, messages, pid, seq, tctx)
+        self.results: list = []         # future | base offset | exception
+        self.timeout_s = timeout_s
+
+    def add(self, slot: int, messages: list, pid: int, seq: int,
+            tctx=None) -> Callable[[], int]:
+        i = len(self.items)
+        self.items.append((slot, messages, pid, seq, tctx))
+
+        def wait() -> int:
+            r = self.results[i]
+            if isinstance(r, Future):
+                return int(r.result(timeout=self.timeout_s))
+            if isinstance(r, Exception):
+                raise r
+            return r
+
+        return wait
 
 
 class _BarrierGate:
@@ -296,6 +325,15 @@ class BrokerServer:
         # _handle_produce. This is the SLO controller's plant output:
         # the p99 it steers toward slo_p99_ack_ms.
         self._m_ack_us = self.metrics.histogram("produce.ack_us")
+        # produce.multi (a keyed producer's request for many
+        # partitions): requests, the parts they carried, and handler
+        # start to the last part's submit_append - the Python cost of
+        # admitting a request, before any wait for a round.
+        self._m_multi_requests = self.metrics.counter(
+            "produce.multi_requests")
+        self._m_multi_parts = self.metrics.counter("produce.multi_parts")
+        self._m_multi_admit_us = self.metrics.histogram(
+            "produce.multi_admit_us")
         # Consume-ack latency, same contract on the read side (observed
         # in _handle_consume around the whole answer — leader, follower,
         # and refusal paths alike): the p99 the SLO controller's consume
@@ -1040,11 +1078,16 @@ class BrokerServer:
                         self.manager.current_follower_leases().items()
                     },
                     "controller_epoch": self.manager.current_epoch(),
+                    # The row cap of one round per partition: what a
+                    # batching producer fills a produce.multi part to.
+                    "max_batch": self.config.engine.max_batch,
                 }
             if t == "meta.propose":
                 return self._handle_meta_propose(req)
             if t == "produce":
                 return self._handle_produce(req)
+            if t == "produce.multi":
+                return self._handle_produce_multi(req)
             if t == "consume":
                 return self._handle_consume(req)
             if t == "offset.commit":
@@ -2092,16 +2135,20 @@ class BrokerServer:
 
     # -- data path ---------------------------------------------------------
 
-    def _check_partition(self, key) -> tuple[Optional[int], Optional[dict]]:
+    def _check_partition(self, key, view=None
+                         ) -> tuple[Optional[int], Optional[dict]]:
         """(engine slot, refusal). Unknown partitions are a TERMINAL error
         (checked before leadership, so clients don't retry nonexistent
         partitions forever); non-leadership is a retryable refusal with a
         hint — unlike the reference, which answered "Not leader" and then
         appended anyway (MessageAppendRequestProcessor.java:29-33)."""
-        slot = self.manager.slot_of(key)
+        if view is not None:  # a produce.multi part: PartitionManager.peek
+            slot, leader = view[1], view[0].leader if view[0] else None
+        else:
+            slot = self.manager.slot_of(key)
+            leader = None if slot is None else self.manager.leader_of(key)
         if slot is None:
             return None, {"ok": False, "error": f"unknown_partition: {key}"}
-        leader = self.manager.leader_of(key)
         if leader != self.broker_id:
             return None, {
                 "ok": False,
@@ -2121,7 +2168,7 @@ class BrokerServer:
                 return [a.to_dict() for a in t.assignments]
         return []
 
-    def _gen_refusal(self, req: dict, key) -> Optional[dict]:
+    def _gen_refusal(self, req: dict, key, view=None) -> Optional[dict]:
         """Partition-generation fence (elastic partitions): a request
         stamped with `pgen` — the generation its sender resolved
         routing under — draws a typed RETRYABLE `stale_partition_gen:`
@@ -2135,7 +2182,10 @@ class BrokerServer:
         pgen = req.get("pgen")
         if pgen is None:
             return None
-        gen = self.manager.generation_of(key)
+        if view is not None:  # a produce.multi part: PartitionManager.peek
+            gen = view[0].generation if view[0] else None
+        else:
+            gen = self.manager.generation_of(key)
         if gen is None or int(pgen) == gen:
             return None
         self._gen_fence_refusals += 1
@@ -2147,13 +2197,13 @@ class BrokerServer:
             "routing": self._topic_routing(key[0]),
         }
 
-    def _retired_refusal(self, key) -> Optional[dict]:
+    def _retired_refusal(self, key, view=None) -> Optional[dict]:
         """Produce-side fence for a merge-retired child: its log stays
         readable for draining, but new writes must land in the parent
         that reabsorbed the range — same typed refusal + routing
         payload as the generation fence, so one client re-resolve
         handles both."""
-        a = self.manager.assignment_of(key)
+        a = view[0] if view is not None else self.manager.assignment_of(key)
         if a is None or a.state != "retired":
             return None
         self._gen_fence_refusals += 1
@@ -2197,6 +2247,77 @@ class BrokerServer:
         finally:
             self._m_ack_us.observe(self.metrics.clock() - t0)
             sp.end()
+
+    def _handle_produce_multi(self, req: dict) -> dict:
+        """One produce request for MANY partitions (a keyed, batching
+        producer: `ProducerClient.send`). `parts` is a list of
+        {topic, partition, messages, seq, pgen, key_span}; `pid` and
+        `producer` are the request's. Every part goes through the checks
+        a `produce` does (quota, generation and key-range fences,
+        leadership, dedup, size), ALL parts are submitted - together,
+        under one hold of the plane's lock - before any is waited for,
+        the request parks this one RPC worker, and the reply
+        answers part by part: {"ok": true, "parts": [{"ok": true,
+        "base_offset", "count"} | {"ok": false, "error", ...}]}. A part
+        is acked exactly when a `produce` of it would be.
+
+        On a leader that is not the controller the admitted parts ride
+        ONE engine.append_multi frame to the controller instead of one
+        engine.append each. The raw-frame peek (`_raw_produce`) does not
+        know this type and leaves it to the canonical decode; with the
+        host plane on, parts are served in this process."""
+        parts = req.get("parts")
+        if not isinstance(parts, list) or not parts:
+            return {"ok": False, "error": "bad_request: empty parts"}
+        sp = NULL_SPAN
+        if self.spans is not None and req.get("tctx") is not None:
+            sp = self.spans.span(
+                "rpc.recv", ctx_from_wire(req["tctx"]),
+                {"op": "produce.multi", "parts": len(parts),
+                 "msgs": sum(len(p["messages"]) for p in parts
+                             if isinstance(p, dict)
+                             and isinstance(p.get("messages"), list))})
+        self._m_multi_requests.inc()
+        self._m_multi_parts.inc(len(parts))
+        t0 = self.metrics.clock()
+        try:
+            tpart = req.get("tpart", 0)
+            batch = _AppendBatch(self.config.rpc_timeout_s)
+            subs = [
+                self._admit_part(part, req, batch,
+                                 sp.ctx if i == tpart else None)
+                for i, part in enumerate(parts)
+            ]
+            if batch.items:
+                self._submit_batch(batch)
+            self._m_multi_admit_us.observe(self.metrics.clock() - t0)
+            return {"ok": True, "parts": [
+                sub if isinstance(sub, dict) else self._produce_collect(*sub)
+                for sub in subs
+            ]}
+        finally:
+            self._m_ack_us.observe(self.metrics.clock() - t0)
+            sp.end()
+
+    def _admit_part(self, part, req: dict, batch, tctx):
+        """One part of a produce.multi through `_produce_submit`; what a
+        malformed part raises refuses that part alone."""
+        try:
+            messages = part["messages"]
+            if not isinstance(messages, list) or not messages:
+                return {"ok": False, "error": "bad_request: empty messages"}
+            refusal = self.slo.admit(req.get("producer"), len(messages))
+            if refusal is not None:
+                return {"ok": False, "error": f"overloaded: {refusal}"}
+            preq = dict(part)
+            if req.get("pid") is not None and part.get("seq") is not None:
+                preq["pid"] = req["pid"]
+            return self._produce_submit(preq, tctx=tctx, batch=batch)
+        except (KeyError, ValueError, TypeError) as e:
+            return {"ok": False,
+                    "error": f"bad_request: {type(e).__name__}: {e}"}
+        except NotCommittedError as e:
+            return {"ok": False, "error": f"not_committed: {e}"}
 
     # Fields the raw-dispatch peek materializes: the routing/admission
     # scalars (including the elastic-partition fence/routing stamps
@@ -2242,7 +2363,40 @@ class BrokerServer:
 
     def _produce_admitted(self, req: dict, raw=None, raw_count: int = 0,
                           tctx=None) -> dict:
-        """Produce semantics: at-least-once by default, EXACTLY-ONCE for
+        sub = self._produce_submit(req, raw, raw_count, tctx)
+        return sub if isinstance(sub, dict) else self._produce_collect(*sub)
+
+    def _span_refusal(self, key, span, a) -> Optional[dict]:
+        """Key-range fence of a produce.multi part: the part holds many
+        keys of one partition and names the span their hashes cover;
+        ranges are intervals, so both ends inside means every key
+        inside. A part any of whose keys has left the partition's range
+        is refused whole with the generation fence's typed refusal and
+        routing payload — its keys may belong to several owners now,
+        which only the sender can re-split."""
+        if a is None or (a.owns_key(int(span[0]))
+                         and a.owns_key(int(span[1]))):
+            return None
+        self._gen_fence_refusals += 1
+        return {
+            "ok": False,
+            "error": f"stale_partition_gen: {key[0]}/{key[1]} owns "
+                     f"[{a.range_lo}, {a.range_hi}), the part's keys span "
+                     f"[{int(span[0])}, {int(span[1])}]",
+            "generation": a.generation,
+            "routing": self._topic_routing(key[0]),
+        }
+
+    def _produce_submit(self, req: dict, raw=None, raw_count: int = 0,
+                        tctx=None, batch: "Optional[_AppendBatch]" = None):
+        """Admit one partition batch and SUBMIT its rounds without
+        waiting: a refusal dict, or (chunk sizes, waiters, routed
+        partition) for `_produce_collect`. `batch` (produce.multi)
+        gathers the appends of many parts for ONE submit
+        (`_AppendBatch`); it also keeps the part in this process (the
+        host-plane workers serve `produce` only).
+
+        Produce semantics: at-least-once by default, EXACTLY-ONCE for
         idempotent producers. A batch larger than max_batch is split into
         pipelined rounds, and some rounds can fail while others commit (a
         failed middle round leaves a gap). ALL pipelined rounds are
@@ -2267,9 +2421,18 @@ class BrokerServer:
         reproducibly (max_batch is config-static), so a full-batch replay
         re-chunks identically and every chunk dedupes."""
         key = group_key(req["topic"], req["partition"])
-        refusal = self._gen_refusal(req, key)
+        # A produce.multi part takes ONE lock-free look at its partition
+        # (PartitionManager.peek says why); a `produce` keeps the locked
+        # lookups it always made (PERF.md section 7, first item, has the
+        # runs that tried it on `peek` too and why it was left).
+        view = self.manager.peek(key) if batch is not None else None
+        refusal = self._gen_refusal(req, key, view)
         if refusal:
             return refusal
+        if view is not None and req.get("key_span") is not None:
+            refusal = self._span_refusal(key, req["key_span"], view[0])
+            if refusal:
+                return refusal
         routed = None
         khash = req.get("key_hash")
         if khash is not None:
@@ -2285,10 +2448,11 @@ class BrokerServer:
                 # attributable to the log the write actually landed in.
                 key = group_key(req["topic"], owner)
                 routed = owner
-        refusal = self._retired_refusal(key)
+                view = None if view is None else self.manager.peek(key)
+        refusal = self._retired_refusal(key, view)
         if refusal:
             return refusal
-        slot, refusal = self._check_partition(key)
+        slot, refusal = self._check_partition(key, view)
         if refusal:
             return refusal
         if raw is None:
@@ -2301,7 +2465,7 @@ class BrokerServer:
             messages = None
         B = self.config.engine.max_batch
         stamped = None
-        if self.hostplane is not None:
+        if self.hostplane is not None and batch is None:
             # Multi-core host plane: the owning worker validates, stamps
             # (its own per-(worker, generation) pid + per-slot sequence
             # counters — slices are disjoint) and packs the batch into
@@ -2381,10 +2545,15 @@ class BrokerServer:
                 self._engine_append(
                     slot, chunk, pid,
                     seq + i * B if pid > 0 else -1,
-                    tctx=tctx,
+                    tctx=tctx, batch=batch,
                 )
                 for i, chunk in enumerate(chunks)
             ]
+        return chunk_sizes, futs, routed
+
+    def _produce_collect(self, chunk_sizes: list, futs: list,
+                         routed: Optional[int]) -> dict:
+        """Wait out one submitted batch's rounds and build its answer."""
         base0 = None
         committed = 0
         first_err: Optional[Exception] = None
@@ -3096,8 +3265,9 @@ class BrokerServer:
                 )
 
     def _engine_append(self, slot: int, messages: list[bytes],
-                       pid: int = 0, seq: int = -1,
-                       tctx=None) -> Callable[[], int]:
+                       pid: int = 0, seq: int = -1, tctx=None,
+                       batch: "Optional[_AppendBatch]" = None
+                       ) -> Callable[[], int]:
         """Returns a waiter so multi-chunk produces pipeline their rounds
         (both paths submit WITHOUT blocking: local futures, or pipelined
         RPC frames when a TcpClient with call_async is underneath).
@@ -3105,6 +3275,8 @@ class BrokerServer:
         plane's pending entry — the settle release emits the six stage
         spans under it — or onto the forwarded engine.append frame for
         the controller to do the same."""
+        if batch is not None:
+            return batch.add(slot, messages, pid, seq, tctx)
         dp = self._local_engine()
         if dp is not None:
             fut = dp.submit_append(slot, messages, pid=pid, seq=seq,
@@ -3163,6 +3335,39 @@ class BrokerServer:
             return int(resp["base_offset"])
 
         return wait
+
+    def _submit_batch(self, batch: "_AppendBatch") -> None:
+        """Submit every append of one produce.multi and fill in
+        `batch.results`: futures from the local plane, or — forwarded to
+        the controller in ONE frame — base offsets. A refused or failed
+        frame fails each item as an uncommitted round (retryable): the
+        sender's (pid, seq) make the retry safe."""
+        dp = self._local_engine()
+        if dp is not None:
+            batch.results = dp.submit_appends(batch.items)
+            return
+        req = {"type": "engine.append_multi",
+               "items": [list(item[:4]) for item in batch.items]}
+        for i, item in enumerate(batch.items):
+            if item[4] is not None:  # the one sampled part's context
+                req["tctx"], req["titem"] = item[4].wire(), i
+        try:
+            resp = self.client.call(self._controller_addr(), req,
+                                    timeout=self.config.rpc_timeout_s)
+            if not resp.get("ok"):
+                raise RpcError(str(resp.get("error")))
+            results = [
+                int(r) if isinstance(r, int)
+                else NotCommittedError(str(r.get("error")))
+                for r in resp["results"]
+            ]
+            if len(results) != len(batch.items):
+                raise RpcError("engine.append_multi answered "
+                               f"{len(results)} of {len(batch.items)} items")
+        except (RpcError, KeyError, TypeError, AttributeError) as e:
+            results = [NotCommittedError(f"forward to controller: {e}")
+                       ] * len(batch.items)
+        batch.results = results
 
     def _mirror_publish(self, slot: int, base: int, payload) -> None:
         """DataPlane.mirror_fn: fan settled REC_APPEND rows out to the
@@ -3376,6 +3581,34 @@ class BrokerServer:
                     )
                 return {"ok": True, "base_offset":
                         int(fut.result(self.config.rpc_timeout_s))}
+            finally:
+                sp.end()
+        if t == "engine.append_multi":
+            # The forwarded appends of one produce.multi: submit every
+            # item, then wait for each; one answer per item (its base
+            # offset, or {"error"}), so a failed round fails its item
+            # alone.
+            items = req["items"]
+            sp = (self.spans.span("rpc.recv", ctx_from_wire(req.get("tctx")),
+                                  {"op": t, "parts": len(items)})
+                  if self.spans is not None else NULL_SPAN)
+            try:
+                titem = req.get("titem", 0)
+                futs = dp.submit_appends([
+                    (int(slot), list(messages), int(pid or 0),
+                     int(seq) if seq is not None else -1,
+                     sp.ctx if i == titem else None)
+                    for i, (slot, messages, pid, seq) in enumerate(items)
+                ])
+                results: list = []
+                for fut in futs:
+                    try:
+                        results.append(
+                            int(fut.result(self.config.rpc_timeout_s)))
+                    except Exception as e:
+                        results.append(
+                            {"error": f"{type(e).__name__}: {e}"})
+                return {"ok": True, "results": results}
             finally:
                 sp.end()
         if t == "engine.read":

@@ -10,6 +10,7 @@ addresses.
 
 from __future__ import annotations
 
+import bisect
 import dataclasses
 import random
 import threading
@@ -18,6 +19,7 @@ from ripplemq_tpu.obs.lockwitness import make_lock
 from typing import Optional
 
 from ripplemq_tpu.metadata.models import (
+    RANGE_SPACE,
     BrokerInfo,
     PartitionAssignment,
     Topic,
@@ -70,6 +72,13 @@ class MetadataManager:
         # re-checks per answer anyway, this just avoids pointless trips.
         self._follower_leases: dict[int, int] = {}
         self._controller_epoch: int = -1
+        # Key-range routing index per topic: (sorted range starts, the
+        # non-retired assignments in that order). Rebuilt lazily after
+        # anything replaced the topic (refresh, adopt_routing).
+        self._ranges: dict[str, tuple[list[int], list]] = {}
+        # The engine's max_batch as the brokers advertise it (None: a
+        # broker that does not say): the row cap of one produce part.
+        self.max_batch: Optional[int] = None
         self._stop = threading.Event()
         self._refresh_interval = refresh_interval_s
         self._thread: Optional[threading.Thread] = None
@@ -126,6 +135,9 @@ class MetadataManager:
                 }
                 with self._lock:
                     self._topics = {t.name: t for t in topics}
+                    self._ranges.clear()
+                    if resp.get("max_batch") is not None:
+                        self.max_batch = int(resp["max_batch"])
                     if brokers:
                         self._brokers = {b.broker_id: b for b in brokers}
                     self._follower_leases = leases
@@ -199,13 +211,28 @@ class MetadataManager:
     def route_key(self, topic: str, key_hash: int) -> Optional[int]:
         """The non-retired partition whose key-hash range owns
         `key_hash` (None when the topic is unknown) — the client half
-        of online split/merge routing."""
+        of online split/merge routing. One bisection over the sorted
+        range starts: this runs once per keyed message."""
+        h = int(key_hash) % RANGE_SPACE
         with self._lock:
-            t = self._topics.get(topic)
-            if t is None:
-                return None
-            for a in t.assignments:
-                if a.state != "retired" and a.owns_key(int(key_hash)):
+            index = self._ranges.get(topic)
+            if index is None:
+                t = self._topics.get(topic)
+                if t is None:
+                    return None
+                live = sorted((a for a in t.assignments
+                               if a.state != "retired"),
+                              key=lambda a: a.range_lo)
+                index = self._ranges[topic] = (
+                    [a.range_lo for a in live], live)
+            starts, live = index
+            i = bisect.bisect_right(starts, h) - 1
+            if i >= 0 and live[i].owns_key(h):
+                return live[i].partition_id
+            # Ranges that overlap or leave a hole (a snapshot taken
+            # mid-transition): fall back to the scan's answer.
+            for a in live:
+                if a.owns_key(h):
                     return a.partition_id
             return None
 
@@ -242,4 +269,5 @@ class MetadataManager:
                 t, partitions=max(t.partitions, len(assigns)),
                 assignments=assigns,
             )
+            self._ranges.pop(topic, None)
             return True

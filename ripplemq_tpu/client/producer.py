@@ -15,6 +15,16 @@ retry of an unacked batch replays the same identity; a batch abandoned
 after its sequence was put on the wire burns its range (the broker may
 hold a settled entry for it — reusing the numbers for fresh payloads
 would dedupe them away).
+
+KEYED, BATCHING produce (`send(topic, message, key)`): what a Kafka
+producer does. The key picks the partition by key-hash range; messages
+accumulate per partition (client/accumulator.py); one sender thread
+flushes, to each leader, ONE `produce.multi` request carrying every
+partition batch ("part") that is ready — `linger_s` old or full — with
+at most `max_in_flight` requests out per leader and at most ONE part in
+flight per partition. Each part is acked or refused on its own; a
+refused part is retried alone under its reserved (pid, seq), so a key's
+messages commit once and in send order. See README "Client SDK".
 """
 
 from __future__ import annotations
@@ -23,11 +33,12 @@ import itertools
 import threading
 import zlib
 
-from ripplemq_tpu.obs.lockwitness import make_lock
+from ripplemq_tpu.obs.lockwitness import make_condition, make_lock
 import time
 import uuid
 from typing import Optional
 
+from ripplemq_tpu.client.accumulator import Accumulator, Part
 from ripplemq_tpu.client.metadata import MetadataError, MetadataManager
 from ripplemq_tpu.obs.spans import (
     NULL_SPAN,
@@ -39,7 +50,12 @@ from ripplemq_tpu.obs.spans import (
 from ripplemq_tpu.metadata.models import RANGE_SPACE
 from ripplemq_tpu.client.selector import PartitionSelector, RoundRobinSelector
 from ripplemq_tpu.wire.retry import RetryPolicy, fatal_response_error
-from ripplemq_tpu.wire.transport import RpcError, TcpClient, Transport
+from ripplemq_tpu.wire.transport import (
+    RpcError,
+    RpcTimeout,
+    TcpClient,
+    Transport,
+)
 
 
 class ProduceError(Exception):
@@ -51,6 +67,80 @@ def key_hash(key: bytes) -> int:
     stable across processes and runs, so the chaos checker can replay
     a keyed workload's routing decisions exactly."""
     return zlib.crc32(bytes(key)) % RANGE_SPACE
+
+
+class SendWaiter:
+    """What `send()` returns. Calling it waits for the message's part to
+    be acked and returns the message's offset (ProduceError if the part
+    failed for good). `partition`, `base_offset`, `index` and `acked_ns`
+    describe the acked part: the partition the ack named, the part's
+    first offset, this message's place in it, `time.monotonic_ns()` at
+    the ack."""
+
+    __slots__ = ("_part", "_index", "_cond")
+
+    def __init__(self, part: Part, index: int, cond) -> None:
+        self._part, self._index, self._cond = part, index, cond
+
+    def _where(self) -> tuple[Part, int]:
+        part, idx = self._part, self._index
+        while part.moved is not None:  # rerouted: follow the message
+            part, idx = part.moved[idx]
+        self._part, self._index = part, idx
+        return part, idx
+
+    def done(self) -> bool:
+        return self._where()[0].done
+
+    def sent(self) -> bool:
+        """The message's part has left the accumulator at least once: a
+        message sent to that partition from now on rides a LATER part."""
+        return self._where()[0].sent
+
+    def __call__(self, timeout: Optional[float] = None) -> int:
+        end = None if timeout is None else time.monotonic() + timeout
+        with self._cond:
+            while True:
+                part, idx = self._where()
+                if part.done:
+                    break
+                left = None if end is None else end - time.monotonic()
+                if left is not None and left <= 0:
+                    raise ProduceError("send not acked within the timeout")
+                self._cond.wait(left)
+        if part.error is not None:
+            raise ProduceError(part.error)
+        return part.base_offset + idx
+
+    @property
+    def partition(self) -> int:
+        return self._where()[0].partition
+
+    @property
+    def base_offset(self) -> Optional[int]:
+        return self._where()[0].base_offset
+
+    @property
+    def index(self) -> int:
+        return self._where()[1]
+
+    @property
+    def acked_ns(self) -> int:
+        return self._where()[0].acked_ns
+
+
+class _Request:
+    """One produce.multi in flight."""
+
+    __slots__ = ("addr", "parts", "deadline", "fut", "finished", "rpc",
+                 "tctx")
+
+    def __init__(self, addr: str, parts: list, deadline: float) -> None:
+        self.addr, self.parts, self.deadline = addr, parts, deadline
+        self.fut = None
+        self.finished = False
+        self.rpc = NULL_SPAN   # client.rpc of the one context it carries
+        self.tctx = None       # ... and that context's client.produce
 
 
 class ProducerClient:
@@ -69,6 +159,9 @@ class ProducerClient:
         producer_name: Optional[str] = None,
         pid_refresh_s: float = 60.0,
         trace_sample_n: int = 0,
+        linger_s: float = 0.001,
+        batch_size: int = 1048576,
+        max_in_flight: int = 5,
     ) -> None:
         self._transport = transport if transport is not None else TcpClient()
         self._owns_transport = transport is None
@@ -126,6 +219,23 @@ class ProducerClient:
             rpc_timeout_s=rpc_timeout_s,
         )
         self._meta.start()
+        # The keyed path (`send`): Kafka's three knobs under Kafka's
+        # names — linger.ms (as seconds), batch.size (bytes; a part is
+        # also full at the engine's max_batch rows, which the brokers
+        # advertise), max.in.flight.requests.per.connection. The sender
+        # thread starts with the first send().
+        self._acc = Accumulator(linger_s, batch_size, max_in_flight,
+                                max_rows=lambda: self._meta.max_batch)
+        self._acc_cond = make_condition("ProducerClient._acc_cond")
+        self._done_cond = make_condition("ProducerClient._done_cond")
+        self._sender: Optional[threading.Thread] = None
+        # When the sender will next look on its own: None = only when
+        # notified, 0.0 = it is looking now.
+        self._sender_wake: Optional[float] = 0.0
+        self._requests: list[_Request] = []
+        self._need_refresh = False
+        self._next_lookup = 0.0
+        self._closing = False
 
     # ------------------------------------------------------------------ API
 
@@ -264,6 +374,310 @@ class ProducerClient:
                 raise ProduceError(err)  # terminal
         raise ProduceError(f"produce to {topic} failed: {run.summary()}")
 
+    # ------------------------------------------------- keyed, batching path
+
+    def partition_for(self, topic: str, key: bytes) -> Optional[int]:
+        """The partition `send(topic, ..., key)` would route to now
+        (None: unknown topic)."""
+        return self._meta.route_key(topic, key_hash(key))
+
+    def send(self, topic: str, message: bytes, key: bytes) -> SendWaiter:
+        """Queue one keyed message and return at once; the waiter gives
+        its offset. The key resolves to its partition by key-hash range;
+        the message joins that partition's open batch and leaves with
+        the next produce.multi request to the partition's leader (module
+        docstring). Messages of one key from one producer commit in
+        send order, once."""
+        if not message:
+            raise ValueError("empty message")
+        khash = key_hash(key)
+        partition = self._meta.route_key(topic, khash)
+        if partition is None:
+            self._refresh_quietly()
+            partition = self._meta.route_key(topic, khash)
+            if partition is None:
+                raise ProduceError(f"unknown topic {topic!r}")
+        root = NULL_SPAN
+        if self.spans is not None:
+            tid = derive_trace_id(self._pid_name,
+                                  next(self._trace_counter))
+            if sampled(tid, self._trace_sample_n):
+                root = self.spans.span("client.send", TraceContext(tid, 0),
+                                       {"topic": topic})
+        with self._acc_cond:
+            if self._closing:
+                raise ProduceError("producer is closed")
+            part, idx, look = self._acc.append(
+                topic, partition, message, khash, time.monotonic())
+            if root.ctx is not None:
+                if part.traces is None:
+                    part.traces = []
+                # [index, client.send, client.accumulate, client.produce]
+                part.traces.append([idx, root, self.spans.span(
+                    "client.accumulate", root.ctx), NULL_SPAN])
+            if self._sender is None:
+                self._sender = threading.Thread(
+                    target=self._sender_loop, daemon=True,
+                    name="producer-sender")
+                self._sender.start()
+            elif look and not self._acc.saturated():
+                # Wake the sender only if it would otherwise look too
+                # late for this part (it sleeps until the earliest
+                # linger or request deadline it knows of).
+                wake = self._sender_wake
+                if wake is None or wake > part.t_first + self._acc.linger_s:
+                    self._acc_cond.notify()
+        return SendWaiter(part, idx, self._done_cond)
+
+    def flush(self, timeout: Optional[float] = None) -> bool:
+        """Send everything queued without waiting out the linger, and
+        wait until every part is acked or failed; False on timeout."""
+        end = None if timeout is None else time.monotonic() + timeout
+        with self._acc_cond:
+            self._acc.flushing = True
+            self._acc_cond.notify()
+        try:
+            while True:
+                with self._acc_cond:
+                    if not self._acc.pending():
+                        return True
+                left = None if end is None else end - time.monotonic()
+                if left is not None and left <= 0:
+                    return False
+                with self._done_cond:  # a missed notify costs one poll
+                    self._done_cond.wait(0.05 if left is None
+                                         else min(left, 0.05))
+        finally:
+            with self._acc_cond:
+                self._acc.flushing = False
+
+    def _sender_loop(self) -> None:
+        acc, cond = self._acc, self._acc_cond
+        while True:
+            refresh = False
+            with cond:
+                now = time.monotonic()
+                expired = [r for r in self._requests
+                           if not r.finished and r.deadline <= now]
+                reqs: dict = {}
+                wake = None
+                if not expired and not acc.saturated():
+                    reqs, wake, lost = acc.drain(now, self._meta.leader_addr)
+                    if lost:
+                        # A ready part with no known leader: look the
+                        # leader up again, once per backoff.
+                        if now >= self._next_lookup:
+                            refresh = True
+                            self._next_lookup = \
+                                now + self._retry.base_backoff_s
+                        wake = self._next_lookup if wake is None \
+                            else min(wake, self._next_lookup)
+                if self._need_refresh:
+                    self._need_refresh, refresh = False, True
+                if not reqs and not expired and not refresh:
+                    if self._closing and not acc.pending():
+                        return
+                    for r in self._requests:
+                        if not r.finished:
+                            wake = r.deadline if wake is None \
+                                else min(wake, r.deadline)
+                    self._sender_wake = wake
+                    cond.wait(None if wake is None
+                              else max(0.0, wake - now))
+                    self._sender_wake = 0.0
+                    continue
+                sent = [_Request(addr, parts, now + self._timeout)
+                        for addr, parts in reqs.items()]
+                self._requests.extend(sent)
+            for r in expired:
+                self._finish(r, None, RpcTimeout(
+                    f"{r.addr}: no response after {self._timeout}s"))
+            if refresh:
+                self._refresh_quietly()
+            for r in sent:
+                self._send_request(r)
+
+    def _send_request(self, r: _Request) -> None:
+        spans = self.spans
+        pid = None
+        if self._idempotence:
+            pid = self._ensure_pid(r.addr, self._retry.begin())
+        wire_parts = []
+        traced = None
+        for i, part in enumerate(r.parts):
+            if part.run is None:
+                part.run = self._retry.begin()
+                part.run.next_delay()  # the first attempt
+            if pid is not None and part.seq is None:
+                part.seq = self._reserve_seq(part.topic, part.partition,
+                                             len(part.messages))
+            wp = {"topic": part.topic, "partition": part.partition,
+                  "messages": part.messages,
+                  "key_span": [min(part.khashes), max(part.khashes)]}
+            if pid is not None:
+                wp["seq"] = part.seq
+            gen = self._meta.generation(part.topic, part.partition)
+            if gen is not None:
+                wp["pgen"] = gen
+            wire_parts.append(wp)
+            for t in part.traces or ():
+                t[2].end()  # client.accumulate (a retry: ended already)
+                t[2] = NULL_SPAN
+                if t[3] is NULL_SPAN:
+                    t[3] = spans.span("client.produce", t[1].ctx,
+                                      {"n": len(part.messages)})
+                if traced is None:
+                    traced = (i, t[3].ctx)
+        req = {"type": "produce.multi", "producer": self._pid_name,
+               "parts": wire_parts}
+        if pid is not None:
+            req["pid"] = pid
+        if traced is not None:
+            # ONE context rides the request (its first sampled message:
+            # the broker's spans hang under it); every other sampled
+            # message of the request gets a copy of the client.rpc
+            # interval at the response.
+            r.rpc, r.tctx = spans.span("client.rpc", traced[1]), traced[1]
+            req["tctx"], req["tpart"] = r.rpc.ctx.wire(), traced[0]
+        call_async = getattr(self._transport, "call_async", None)
+        try:
+            if call_async is None:  # exotic custom transport: one at a time
+                self._finish(r, self._transport.call(
+                    r.addr, req, timeout=self._timeout), None)
+                return
+            r.fut = call_async(r.addr, req)
+        except RpcError as e:
+            self._finish(r, None, e)
+            return
+        r.fut.add_done_callback(lambda f, r=r: self._on_future(r, f))
+
+    def _on_future(self, r: _Request, fut) -> None:
+        # Runs on the transport's reader thread (or inline on an in-proc
+        # transport): bookkeeping only, never an RPC.
+        if fut.cancelled():
+            return
+        err = fut.exception()
+        if err is not None and not isinstance(err, RpcError):
+            err = RpcError(f"{type(err).__name__}: {err}")
+        self._finish(r, None if err is not None else fut.result(), err)
+
+    def _finish(self, r: _Request, resp: Optional[dict],
+                err: Optional[Exception]) -> None:
+        """Settle one request: every part is acked, failed for good,
+        rerouted, or put back at the head of its partition to retry."""
+        with self._acc_cond:
+            if r.finished:
+                return  # a timeout and a late response raced
+            r.finished = True
+            self._requests.remove(r)
+        if err is not None and r.fut is not None:
+            abandon = getattr(self._transport, "abandon", None)
+            if abandon is not None:
+                abandon(r.fut)
+        r.rpc.end(**({"error": type(err).__name__} if err else {}))
+        t_rpc = (r.rpc.t0, self.spans.clock() - r.rpc.t0) \
+            if r.rpc.ctx is not None else None
+        if err is None and not resp.get("ok"):
+            # The request itself was refused (an older broker, a
+            # malformed frame): every part shares the answer.
+            results = [resp] * len(r.parts)
+        elif err is None:
+            results = list(resp.get("parts") or ())
+            results += [{"ok": False, "error": "bad_request: no result "
+                         "for this part"}] * (len(r.parts) - len(results))
+        else:
+            results = [{"ok": False, "error": str(err)}] * len(r.parts)
+        now = time.monotonic()
+        acked_ns = time.monotonic_ns()
+        refresh = err is not None
+        outcomes = []  # (part, "ack" | "fail" | "retry" | "reroute", delay)
+        for part, res in zip(r.parts, results):
+            if res.get("ok"):
+                part.acked_ns = acked_ns
+                outcomes.append((part, "ack", int(res["base_offset"])))
+                continue
+            error = str(res.get("error", "produce failed"))
+            part.run.note(error)
+            if err is None and fatal_response_error(error):
+                outcomes.append((part, "fail", error))
+                continue
+            if error.startswith("stale_partition_gen:"):
+                # Re-resolve from the refusal's own routing payload.
+                if not self._meta.adopt_routing(
+                        part.topic, res.get("routing") or []):
+                    refresh = True
+                delay = part.run.next_delay()
+                if delay is not None:
+                    outcomes.append((part, "reroute", now + delay))
+                    continue
+            else:
+                refresh = refresh or error == "not_leader"
+                delay = part.run.next_delay()
+            if delay is None:
+                outcomes.append((part, "fail",
+                                 f"produce to {part.topic} failed: "
+                                 f"{part.run.summary()}"))
+            else:
+                outcomes.append((part, "retry", now + delay))
+        with self._acc_cond:
+            self._acc.request_done(r.addr)
+            for part, what, arg in outcomes:
+                if what == "retry":
+                    self._acc.retry(part, arg)
+                elif what == "reroute":
+                    self._reroute(part, arg)
+                else:
+                    if what == "ack":
+                        part.base_offset = arg
+                    else:
+                        part.error = arg
+                    self._acc.complete(part)
+            if refresh:
+                self._need_refresh = True
+            self._acc_cond.notify()
+        for part, what, arg in outcomes:
+            for t in part.traces or ():
+                if t_rpc is not None and t[3].ctx is not None \
+                        and t[3].ctx is not r.tctx:
+                    self.spans.span_at("client.rpc", t[3].ctx, *t_rpc)
+                if what in ("ack", "fail", "reroute"):
+                    extra = {} if what == "ack" else {"error": what}
+                    t[3].end(**extra)
+                    t[1].end(n=len(part.messages), **extra)
+            if what != "retry":
+                part.traces = None
+        with self._done_cond:
+            self._done_cond.notify_all()
+
+    def _reroute(self, part: Part, not_before: float) -> None:
+        """(under _acc_cond) the partition's key range moved: the refused
+        part and everything queued behind it are re-split by the routing
+        just adopted, oldest first, and go AHEAD of what their new
+        partitions hold. A rerouted part is a new batch of another log:
+        it takes a fresh sequence range (the old one is burnt), as
+        produce_batch's reroute does."""
+        fresh: dict[int, Part] = {}
+        order: list[Part] = []
+        for old in self._acc.take_partition(part):
+            old.moved = []
+            for msg, kh in zip(old.messages, old.khashes):
+                owner = self._meta.route_key(old.topic, kh)
+                if owner is None:
+                    owner = old.partition
+                new = fresh.get(owner)
+                if new is None:
+                    new = fresh[owner] = Part(old.topic, owner, old.t_first)
+                    # Closed (nothing younger joins it), and under the
+                    # refused part's retry budget and backoff.
+                    new.retrying, new.not_before = True, not_before
+                    new.run = part.run
+                    order.append(new)
+                old.moved.append((new, len(new.messages)))
+                new.messages.append(msg)
+                new.khashes.append(kh)
+                new.nbytes += len(msg)
+        self._acc.requeue_front(order)
+
     def _reserve_seq(self, topic: str, partition: int, n: int) -> int:
         """Reserve `n` sequence numbers for one batch (thread-safe).
         Reservation happens once per call, right before the identity
@@ -369,7 +783,15 @@ class ProducerClient:
 
         return wait
 
-    def close(self) -> None:
+    def close(self, timeout: float = 10.0) -> None:
+        """Flushes what `send()` queued (up to `timeout` seconds), stops
+        the sender thread, then closes metadata and transport."""
+        if self._sender is not None:
+            self.flush(timeout)
+            with self._acc_cond:
+                self._closing = True
+                self._acc_cond.notify()
+            self._sender.join(timeout=2.0)
         self._meta.close()
         if self._owns_transport:
             self._transport.close()

@@ -146,6 +146,13 @@ class Topic:
     assignments: tuple[PartitionAssignment, ...] = ()
 
     def assignment_for(self, partition_id: int) -> Optional[PartitionAssignment]:
+        # Configured partitions sit at their own index (split children
+        # are appended behind them): one probe instead of a scan, which
+        # at 1024 partitions is what a keyed produce part can afford.
+        if 0 <= partition_id < len(self.assignments):
+            a = self.assignments[partition_id]
+            if a.partition_id == partition_id:
+                return a
         for a in self.assignments:
             if a.partition_id == partition_id:
                 return a

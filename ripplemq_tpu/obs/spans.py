@@ -91,6 +91,13 @@ SPAN_KINDS = frozenset({
     # the pairing measures the wire round trip, not the retry loop's
     # bookkeeping; a retried call records one per attempt).
     "client.produce", "client.consume", "client.rpc",
+    # The keyed, batching producer (ProducerClient.send): client.send
+    # is the root of a sampled MESSAGE, send() to its part's ack;
+    # client.accumulate its wait in the accumulator (linger, a full
+    # request window, the one-part-per-partition rule); the part's
+    # flight is a client.produce under it, with a client.rpc per
+    # attempt as above.
+    "client.send", "client.accumulate",
     # Broker RPC surface: one span per inbound request that carried a
     # tctx (produce, consume, engine.append forward, ...). `op` field
     # names the request type. Pairs with its client/forwarder parent

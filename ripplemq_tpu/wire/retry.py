@@ -235,11 +235,27 @@ class RetryRun:
         between attempts. Returns False once max_attempts have run or the
         deadline budget is exhausted (including when the budget cannot
         fund the next backoff + attempt)."""
-        if self.attempts >= self._p.max_attempts:
+        delay = self.next_delay()
+        if delay is None:
             return False
+        if delay > 0:
+            self.sleeps.append(delay)
+            self._p._sleep(delay)
+        return True
+
+    def next_delay(self) -> Optional[float]:
+        """The non-sleeping half of `attempt()`, for callers that cannot
+        block (the keyed producer's sender thread schedules a retried
+        part instead of sleeping on it): counts the next attempt and
+        returns the jittered backoff to wait before starting it (0.0
+        for the first), or None once max_attempts have run or the
+        deadline budget cannot fund it."""
+        if self.attempts >= self._p.max_attempts:
+            return None
         rem = self.remaining_s()
         if rem is not None and rem <= 0:
-            return False
+            return None
+        delay = 0.0
         if self.attempts > 0:
             b = self._p.backoff_for(self.attempts)
             lo = b * (1.0 - self._p.jitter)
@@ -248,13 +264,10 @@ class RetryRun:
                 if delay >= rem:
                     # Sleeping would consume the whole budget: the
                     # operation is over, don't burn the wall clock.
-                    return False
+                    return None
                 delay = min(delay, rem)
-            if delay > 0:
-                self.sleeps.append(delay)
-                self._p._sleep(delay)
         self.attempts += 1
-        return True
+        return delay
 
     def note(self, error: str) -> None:
         self.last_error = str(error)

@@ -465,12 +465,19 @@ class TcpClient(Transport):
         try:
             return fut.result(timeout=timeout)
         except (TimeoutError, FuturesTimeoutError):
-            # Drop the pending entry: the connection may stay alive for a
-            # long time, and abandoned futures must not accumulate.
-            with fut._rmq_conn.pending_lock:
-                fut._rmq_conn.pending.pop(fut._rmq_req_id, None)
-            fut.cancel()
+            self.abandon(fut)
             raise RpcTimeout(f"{addr}: no response after {timeout}s") from None
+
+    @staticmethod
+    def abandon(fut: Future) -> None:
+        """Give up on a `call_async` future: drop its pending entry (the
+        connection may stay alive for a long time, and abandoned futures
+        must not accumulate) and cancel it."""
+        conn = getattr(fut, "_rmq_conn", None)
+        if conn is not None:
+            with conn.pending_lock:
+                conn.pending.pop(fut._rmq_req_id, None)
+        fut.cancel()
 
     def close(self) -> None:
         with self._lock:

@@ -139,8 +139,12 @@ def test_chain_quorum_failure_fails_all_and_preserves_retry_order():
     """Rounds of a chain that lose quorum fail their futures; restoring
     quorum lets retries commit in the original submit order."""
     cfg = small_cfg(slots=256, max_batch=8, replicas=3)
+    # The retry budget has to outlast the 0.5 s below at whatever pace the
+    # step thread fails rounds: at 50 the futures ran out of rounds inside
+    # the sleep ("no quorum after 50 rounds") in 5 of 18 runs on the
+    # parent's tree with six copies of this test running at once.
     dp = DataPlane(cfg, mode="local", store=MemoryRoundStore(),
-                   chain_depth=4, max_retry_rounds=50)
+                   chain_depth=4, max_retry_rounds=5000)
     dp.start()
     try:
         dp.set_leader(0, 0, 1)
