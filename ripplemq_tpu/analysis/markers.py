@@ -44,6 +44,7 @@ FAST_MODULES = {
     "test_dataplane",
     "test_degradation",
     "test_failover",
+    "test_fence_view",          # ~8 s: fence view units + two 3-broker drives
     "test_follower_reads",      # ~50 s: plane/lease units, 2-mode byte
                                 # identity, 3 chaos smokes (1 proc)
     "test_graft",

@@ -33,7 +33,7 @@ import threading
 
 from ripplemq_tpu.obs.lockwitness import make_rlock
 import time
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import numpy as np
 
@@ -59,6 +59,15 @@ class ConsumerTableFullError(Exception):
     (PartitionStateMachine.java:27); this framework's table is a fixed
     [P, C] device tensor, so the refusal must exist — and must surface as
     a typed, client-distinguishable error rather than `internal:`."""
+
+
+class FenceView(NamedTuple):
+    """The three fields the standby stream fences on, as ONE apply left
+    them (`PartitionManager.fence_view`)."""
+
+    controller: int
+    epoch: int
+    standbys: tuple[int, ...]
 
 
 # Metadata-plane command ops (the hostraft log's vocabulary).
@@ -199,6 +208,9 @@ class PartitionManager:
         self.controller_broker: int = config.controller
         self.controller_epoch: int = 0
         self.standbys: tuple[int, ...] = ()
+        # The same three as ONE immutable triple, for the standby stream
+        # alone (see _publish_fence_view).
+        self.fence_view = FenceView(config.controller, 0, ())
         # Stripe→member assignment (replication="striped"): derived
         # deterministically from the standby set inside every standby-
         # set apply and recorded beside it, so "who holds stripe i" is
@@ -415,6 +427,7 @@ class PartitionManager:
                 [int(b) for b in state["live"]],
                 full_surface=True,
             )
+            self._publish_fence_view()
 
     def _apply_set_controller(
         self, controller: int, epoch: int, standbys: list[int]
@@ -430,6 +443,7 @@ class PartitionManager:
         # leases — the new controller's duty re-grants to the standbys
         # it trusts, under the new epoch.
         self.follower_leases = {}
+        self._publish_fence_view()
 
     def _apply_set_standbys(self, epoch: int, standbys: list[int]) -> None:
         """Standby-set rewrite, valid only within the current epoch."""
@@ -445,6 +459,26 @@ class PartitionManager:
             b: e for b, e in self.follower_leases.items()
             if b in self.standbys
         }
+        self._publish_fence_view()
+
+    def _publish_fence_view(self) -> None:
+        """Swap in the fence view (lock held): the LAST thing every apply
+        that touches controller, epoch or standby set does, as one
+        attribute store. `fence_view` is read WITHOUT the lock, by the
+        standby stream only: `RoundReplicator.begin` / `wait`, the
+        sender's frame stamp and the standby's `repl.rounds` /
+        `repl.stripes` refusals (server `_make_replicator`). A reader
+        gets the triple one apply left, never the controller of one
+        handover with the epoch of another — which three separately
+        locked reads (`current_controller()`, `current_epoch()`,
+        `current_standbys()`) can, and three bare attribute reads would:
+        a deposed sender that read active / new epoch / active inside
+        one apply would stamp its backlog with its successor's epoch.
+        Those three accessors stay locked for every other caller, for
+        the reason `peek` gives."""
+        self.fence_view = FenceView(
+            self.controller_broker, self.controller_epoch, self.standbys
+        )
 
     def _apply_set_follower_leases(
         self, epoch: int, leases: dict[int, int]
@@ -1052,7 +1086,10 @@ class PartitionManager:
         lookup would. The locked accessors stay as they are for every
         other caller (PERF.md section 6, PR 27: making them lock-free too
         sped the subscription up and steady's ack went from 115 to 700
-        ms)."""
+        ms). The one other lock-free read is `fence_view`, for the
+        standby stream alone (`_publish_fence_view`): the same rule —
+        immutable state behind one attribute store — for the same
+        reason, a serial path that queued on this lock."""
         return self.assignment_of(key), self._slot_for(key[0], key[1])
 
     def leader_of(self, key: GroupKey) -> Optional[int]:

@@ -29,6 +29,13 @@ Protocol invariants:
   FencedError (⊂ NotCommittedError), producers retry, and the metadata
   routes them to the new controller. The sender also fences locally the
   moment its own metadata shows another controller.
+- **One fence view.** `begin`, the sender's frame stamp and `wait` each
+  take ONE `fence()` read — (this broker is the controller, the epoch,
+  the standby set) as a single metadata apply left them. The broker
+  wires it to `PartitionManager.fence_view`, which is swapped by one
+  attribute store and read without the manager's lock: the stream is
+  the one serial path every round crosses, and a lock shared with every
+  consume and commit handler cost it a convoy wait per read.
 - **Ordered per-standby stream.** Each standby has one sender thread
   with a FIFO queue, so records arrive in commit order (duplicates are
   harmless: replay is later-record-wins per slot, dataplane.replay_records).
@@ -107,10 +114,12 @@ class _Sender(threading.Thread):
         # handles) — the static graph models the alias the same way.
         self._lock = make_lock("_Sender._lock")
         self._cond = threading.Condition(self._lock)
-        # Entries are (records, fut, tctxs) — tctxs the wire-form trace
-        # contexts of the round's sampled produces (None when untraced),
-        # stamped onto the frame so standby apply spans join the trace.
-        self._queue: list[tuple[list, Future, Optional[list]]] = []
+        # Entries are (records, fut, tctxs, t_enq) — tctxs the wire-form
+        # trace contexts of the round's sampled produces (None when
+        # untraced), stamped onto the frame so standby apply spans join
+        # the trace; t_enq the replicator's clock at enqueue, where
+        # repl.send_wait_us starts.
+        self._queue: list[tuple[list, Future, Optional[list], float]] = []
         self._buffer: Optional[list] = None
         self._stopped = False
         self.unreachable = False  # consecutive send failures observed
@@ -120,14 +129,15 @@ class _Sender(threading.Thread):
     def enqueue(self, records: list, tctxs: Optional[list] = None) -> Future:
         """Live round: behind the catch-up stream while buffering."""
         fut: Future = Future()
+        entry = (records, fut, tctxs, self._rep._clock())
         with self._cond:
             if self._stopped:
                 fut.set_exception(ReplicationError("sender stopped"))
                 return fut
             if self._buffer is not None:
-                self._buffer.append((records, fut, tctxs))
+                self._buffer.append(entry)
             else:
-                self._queue.append((records, fut, tctxs))
+                self._queue.append(entry)
                 self._cond.notify()
         return fut
 
@@ -138,7 +148,7 @@ class _Sender(threading.Thread):
             if self._stopped:
                 fut.set_exception(ReplicationError("sender stopped"))
                 return fut
-            self._queue.append((records, fut, None))
+            self._queue.append((records, fut, None, self._rep._clock()))
             self._cond.notify()
         return fut
 
@@ -185,8 +195,8 @@ class _Sender(threading.Thread):
     # -- send loop --
 
     def _take_group(self) -> Optional[list]:
-        """Pop one bounded group-commit [(records, fut, tctxs), ...] off
-        the queue (caller holds self._cond)."""
+        """Pop one bounded group-commit [(records, fut, tctxs, t_enq),
+        ...] off the queue (caller holds self._cond)."""
         if not self._queue:
             return None
         group = [self._queue.pop(0)]
@@ -266,11 +276,14 @@ class _Sender(threading.Thread):
         groups requeue at the head in order and re-send under their
         ORIGINAL sseqs — a frame that did apply before the failure is
         re-applied harmlessly (duplicate records are later-record-wins
-        at replay; the gate acks `sseq < expected` after re-applying)."""
+        at replay; the gate acks `sseq < expected` after re-applying).
+        Every fence read here is one `RoundReplicator.fence()` view and
+        takes no lock of the metadata plane."""
         backoff = 0.05
         failures = 0
         next_sseq = 0
-        # In-flight window entries: [group, sseq, rpc_fut, t_frame].
+        # In-flight window entries:
+        # [group, sseq, rpc_fut, t_frame, t_sent, send_wait].
         inflight: list = []
 
         def fail_inflight(result) -> None:
@@ -313,24 +326,21 @@ class _Sender(threading.Thread):
             # -- fire new frames (top up the window) --
             fenced = False
             for group in groups:
-                # Epoch is stamped ONCE per delivery attempt from the
-                # ACTIVE view. It must never be re-read after a
-                # deposition: a deposed sender re-stamping its stale
-                # backlog with the NEW epoch would walk it straight
-                # through the standby's fence (the seeded chaos soak
-                # caught that as an acked produce the promoted
-                # controller had never seen). The double-check closes
-                # the check/stamp race.
-                if fenced or not self._rep.active():
-                    fenced = True
-                    self._settle_group(
-                        group,
-                        FencedError("controller deposed (local metadata)"),
-                    )
-                    continue
-                epoch = self._rep.epoch_fn()
-                if not self._rep.active():
-                    fenced = True
+                # ONE fence view per delivery attempt: the frame is
+                # refused unless THAT view names this broker controller,
+                # and stamped with THAT view's epoch. The epoch must
+                # never come from a later read than the check: a deposed
+                # sender stamping its stale backlog with the NEW epoch
+                # would walk it straight through the standby's fence
+                # (the seeded chaos soak caught that as an acked produce
+                # the promoted controller had never seen). One triple
+                # from one apply cannot mix the two; separate reads
+                # needed a check on both sides of the stamp and could
+                # still straddle an apply (see RoundReplicator.fence).
+                if not fenced:
+                    active, epoch, _ = self._rep.fence()
+                    fenced = not active
+                if fenced:
                     self._settle_group(
                         group,
                         FencedError("controller deposed (local metadata)"),
@@ -338,23 +348,28 @@ class _Sender(threading.Thread):
                     continue
                 t_frame = (self._rep._clock()
                            if self._rep._h_frame_us is not None else 0.0)
+                rpc_fut = self._send_frame(group, epoch, next_sseq)
+                # repl.send_wait_us: the head entry's enqueue to the
+                # frame's hand-off to the transport (queueing here +
+                # fence read + floor stamp + encode); observed at the
+                # ack, beside repl.frame_us, which it overlaps by the
+                # time _send_frame itself takes.
                 inflight.append(
-                    [group, next_sseq,
-                     self._send_frame(group, epoch, next_sseq), t_frame,
-                     time.monotonic()]
+                    [group, next_sseq, rpc_fut, t_frame, time.monotonic(),
+                     self._rep._clock() - group[0][3]]
                 )
                 next_sseq += 1
             if not inflight:
                 continue
             # -- wait on the OLDEST in-flight frame --
-            group, sseq, rpc_fut, t_frame, t_sent = inflight[0]
+            group, sseq, rpc_fut, t_frame, t_sent, send_wait = inflight[0]
             try:
                 resp = rpc_fut.result(timeout=0.1)
             except TimeoutError:
                 if self._stopped:
                     fail_inflight(ReplicationError("sender stopped"))
                     return
-                if not self._rep.active():
+                if not self._rep.fence()[0]:
                     fail_inflight(
                         FencedError("controller deposed (local metadata)")
                     )
@@ -392,12 +407,14 @@ class _Sender(threading.Thread):
                 # batching factor the PR 3 sender bought; the frame RPC
                 # time is the raw standby round trip the settle stage's
                 # standby_ack_us overlaps away (and pipelining overlaps
-                # across frames too).
+                # across frames too); send_wait is what the group spent
+                # on THIS side before the wire.
                 if self._rep._h_group is not None:
                     self._rep._h_group.observe_int(len(group))
                     self._rep._h_frame_us.observe(
                         self._rep._clock() - t_frame
                     )
+                    self._rep._h_send_wait_us.observe(send_wait)
                     self._rep._c_records.inc(len(records))
                     self._rep._c_frames.inc()
                     self._rep._c_bytes.inc(sum(len(r[3]) for r in records))
@@ -436,9 +453,12 @@ class _Sender(threading.Thread):
 class RoundReplicator:
     """Controller-side fan-out of the committed-round stream.
 
-    `members_fn` returns the CURRENT replicated standby set (acks
-    required); `epoch_fn` the current controller epoch; `active_fn`
-    whether this broker still is the controller (local fencing).
+    `fence_fn` returns ONE consistent `(active, epoch, members)`: whether
+    this broker still is the controller (local fencing), the controller
+    epoch, the CURRENT replicated standby set (acks required) — the
+    broker's is `PartitionManager.fence_view`, read without a lock.
+    Without it the view is composed from `active_fn`, `epoch_fn` and
+    `members_fn` (bare planes, tests): see `_fence_from_parts`.
     """
 
     def __init__(
@@ -454,12 +474,15 @@ class RoundReplicator:
         sender_id: int = -1,
         pipeline_depth: int = 1,
         floors_fn: Optional[Callable[[list], list]] = None,
+        fence_fn: Optional[Callable[[], tuple]] = None,
     ) -> None:
         self.client = client
         self.addr_of = addr_of
         self.epoch_fn = epoch_fn
         self.members_fn = members_fn
         self.active = active_fn
+        # The ONE fence read of begin / the sender's frame stamp / wait.
+        self.fence: Callable[[], tuple] = fence_fn or self._fence_from_parts
         self.rpc_timeout_s = rpc_timeout_s
         self.ack_timeout_s = ack_timeout_s
         # Settled-floor stamp (follower reads): called with the sorted
@@ -480,6 +503,7 @@ class RoundReplicator:
         if metrics is not None and getattr(metrics, "enabled", True):
             self._h_group = metrics.histogram("repl.group_rounds")
             self._h_frame_us = metrics.histogram("repl.frame_us")
+            self._h_send_wait_us = metrics.histogram("repl.send_wait_us")
             self._c_records = metrics.counter("repl.records")
             self._c_frames = metrics.counter("repl.frames")
             # Replication payload bytes ACKED across all standby
@@ -491,7 +515,7 @@ class RoundReplicator:
             self._c_retries = metrics.counter("repl.send_retries")
             self._clock = metrics.clock
         else:
-            self._h_group = self._h_frame_us = None
+            self._h_group = self._h_frame_us = self._h_send_wait_us = None
             self._c_records = self._c_frames = self._c_retries = None
             self._c_bytes = None
             self._clock = time.perf_counter
@@ -512,6 +536,18 @@ class RoundReplicator:
         self._had_members = False
         self._stopped = False
 
+    def _fence_from_parts(self) -> tuple:
+        """`fence()` for a plane built from three separate callables:
+        the epoch is read BETWEEN two active checks, so a deposition
+        that lands around the read yields inactive rather than an active
+        view carrying the successor's epoch. (Three separately locked
+        reads can still straddle a whole apply; the broker's one-triple
+        view cannot.)"""
+        if not self.active():
+            return False, -1, ()
+        epoch, members = self.epoch_fn(), self.members_fn()
+        return bool(self.active()), epoch, members
+
     # -- sender management --
 
     def _sender(self, bid: int) -> _Sender:
@@ -530,7 +566,7 @@ class RoundReplicator:
 
     def sync_members(self) -> None:
         """Drop senders for brokers neither in the set nor joining."""
-        members = set(self.members_fn())
+        members = set(self.fence()[2])
         with self._lock:
             drop = [
                 bid for bid in self._senders
@@ -575,10 +611,13 @@ class RoundReplicator:
         refusal — both BEFORE anything is enqueued. `tctxs` carries the
         wire-form trace contexts of the round's sampled produces (see
         obs/spans.py): stamped onto the outgoing frames and recorded as
-        sender-side repl.send spans that end when the member acks."""
-        if not self.active():
+        sender-side repl.send spans that end when the member acks.
+        Runs inside the DataPlane's dispatch-order turnstile, serial
+        across rounds: its ONE `fence()` read takes no lock."""
+        active, _, members = self.fence()
+        if not active:
             raise FencedError("controller deposed (local metadata)")
-        targets = set(self.members_fn())
+        targets = set(members)
         if targets:
             self._had_members = True
         elif self._had_members:
@@ -628,7 +667,11 @@ class RoundReplicator:
         """Second half of replicate(): block until every member acked the
         ticket's round, with the full waiver/fence discipline (see
         replicate). The ack deadline counts from begin() — queue time on
-        a stalled stream charges the suspect timer exactly as before."""
+        a stalled stream charges the suspect timer exactly as before.
+        Runs on the DataPlane's ONE settle thread: each "is the member
+        still in the set / am I still the controller" pair below is one
+        `fence()` view — the member's absence and the deposition that
+        explains it come from the same apply — and takes no lock."""
         records = ticket.records
         senders = ticket.senders
         futs = ticket.futs
@@ -638,7 +681,8 @@ class RoundReplicator:
         for bid, fut in futs.items():
             suspected = False
             while True:
-                if bid not in self.members_fn():
+                active, _, members = self.fence()
+                if bid not in members:
                     # Distinguish WHY the member left the set before
                     # waiving its ack. A same-epoch prune (suspect
                     # removal, committed through metadata raft) is safe:
@@ -650,7 +694,7 @@ class RoundReplicator:
                     # soak caught this as an acked-produce loss: probe
                     # acked 3 ms after the deposition applied, absent
                     # from the promoted plane's replay). Deposed ⇒ fence.
-                    if not self.active():
+                    if not active:
                         raise FencedError(
                             "controller deposed (local metadata)"
                         )
@@ -671,7 +715,7 @@ class RoundReplicator:
                     acked.append(bid)
                     break
                 except TimeoutError:
-                    if not self.active():
+                    if not self.fence()[0]:
                         raise FencedError("controller deposed (local metadata)")
                     if (
                         not suspected
@@ -687,7 +731,8 @@ class RoundReplicator:
                 except FencedError:
                     raise
                 except ReplicationError:
-                    if bid in self.members_fn():
+                    active, _, members = self.fence()
+                    if bid in members:
                         # Sender died (replicator stopping) while its
                         # target is still a member: without this member's
                         # ack the round may exist nowhere but here — fail
@@ -714,7 +759,7 @@ class RoundReplicator:
                     # waiver would settle a round the promoted
                     # controller never stored (chaos-soak-caught acked
                     # loss, sibling of the branch above).
-                    if not self.active():
+                    if not active:
                         raise FencedError(
                             "controller deposed (local metadata)"
                         ) from None
@@ -725,7 +770,7 @@ class RoundReplicator:
             log.debug(
                 "round settled: %d records; acked by %s, waived %s, "
                 "members now %s",
-                len(records), acked, waived, sorted(self.members_fn()),
+                len(records), acked, waived, sorted(self.fence()[2]),
             )
 
     # -- catch-up (controller duty worker thread) --

@@ -896,12 +896,23 @@ class BrokerServer:
         standbys and settles at any k stripe-acks (StripeReplicator —
         same begin/wait/catchup/suspects surface, (k+m)/k× the bytes
         instead of standby_count×)."""
+        # The stream's fence reads come from the manager's fence view
+        # (PartitionManager._publish_fence_view), WITHOUT its lock:
+        # begin, the sender's frame stamp and wait are the serial path
+        # every round crosses, and that lock is the one every consume
+        # and commit handler queues on. Nothing else reads the view:
+        # consume, commit, admission and the duties keep the locked
+        # accessors (PERF.md section 6, PR 27 and PR 28).
+        mgr, me = self.manager, self.broker_id
+
+        def fence() -> tuple:
+            v = mgr.fence_view
+            return v.controller == me, v.epoch, v.standbys
+
         kw = dict(
-            epoch_fn=self.manager.current_epoch,
-            members_fn=self.manager.current_standbys,
-            active_fn=lambda: (
-                self.manager.current_controller() == self.broker_id
-            ),
+            active_fn=lambda: fence()[0],
+            epoch_fn=lambda: fence()[1],
+            members_fn=lambda: fence()[2],
             rpc_timeout_s=min(2.0, self.config.rpc_timeout_s),
             ack_timeout_s=self.config.rpc_timeout_s,
             metrics=self.metrics,
@@ -925,8 +936,14 @@ class BrokerServer:
                 # Piggyback the per-slot settled floor (+ gap map) on
                 # every repl.rounds frame — the full-copy follower read
                 # plane's serve bound (striped frames already carry the
-                # encoder's gsn floor in their header).
-                floors_fn=self._settle_floors_stamp,
+                # encoder's gsn floor in their header). Only when the
+                # plane that reads the stamp exists on the standbys
+                # (FollowerReadPlane.ingest_rounds, built under the same
+                # knob): otherwise every frame would pay the manager's
+                # and the plane's lock for a list nothing reads.
+                floors_fn=(self._settle_floors_stamp
+                           if self.config.follower_reads else None),
+                fence_fn=fence,
                 **kw,
             )
         return self._replicator
@@ -3642,13 +3659,13 @@ class BrokerServer:
         what deposes an old controller — its resolver fails the round
         with FencedError and producers re-route."""
         epoch = int(req["epoch"])
-        cur = self.manager.current_epoch()
-        if epoch < cur:
-            return {"ok": False, "error": "stale_epoch", "epoch": cur}
-        if (
-            self.dataplane is not None
-            and self.manager.current_controller() == self.broker_id
-        ):
+        # Both refusals from ONE fence view of this broker's manager (no
+        # lock: that lock also admits the produces and serves the
+        # consumes of the partitions this broker leads).
+        view = self.manager.fence_view
+        if epoch < view.epoch:
+            return {"ok": False, "error": "stale_epoch", "epoch": view.epoch}
+        if self.dataplane is not None and view.controller == self.broker_id:
             # Our metadata lags a newer epoch (or a deposed peer streams
             # at ours): refuse non-fatally; the sender retries until the
             # fence duty on one side resolves it.
@@ -3748,13 +3765,10 @@ class BrokerServer:
         from ripplemq_tpu.stripes.codec import parse_frame
 
         epoch = int(req["epoch"])
-        cur = self.manager.current_epoch()
-        if epoch < cur:
-            return {"ok": False, "error": "stale_epoch", "epoch": cur}
-        if (
-            self.dataplane is not None
-            and self.manager.current_controller() == self.broker_id
-        ):
+        view = self.manager.fence_view  # as in _handle_repl_rounds
+        if epoch < view.epoch:
+            return {"ok": False, "error": "stale_epoch", "epoch": view.epoch}
+        if self.dataplane is not None and view.controller == self.broker_id:
             return {"ok": False, "error": "active_controller"}
         if self._store_quarantined and not self._quarantine_left_set:
             # Same stale-membership fence as repl.rounds: an emptied

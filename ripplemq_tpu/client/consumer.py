@@ -13,7 +13,10 @@ after each delivery the NEXT window's fetch is already in flight at an
 explicit offset (the broker accepts `offset` in consume requests), so a
 drain pays one round-trip of latency total instead of one per window,
 and auto-commits ride the same request-id pipeline asynchronously
-instead of blocking a quorum round per window. `long_poll_s` > 0 makes
+instead of blocking a quorum round per window — ONE in flight per
+partition, the newest offset parked behind it: the broker's worker pool
+does not keep a connection's order, and its offset table takes the last
+writer (`_auto_commit`). `long_poll_s` > 0 makes
 empty fetches park broker-side until rows settle (tail consumers cost
 one RPC per delivery, not one per poll). Both levers are opt-in and
 independently A/B-able against the legacy one-RPC-per-call behavior.
@@ -105,10 +108,13 @@ class ConsumerClient:
         self.follower_served = 0
         self.last_from_follower = False
         # Per-(topic, partition) readahead state: the in-flight fetch at
-        # an explicit offset, and the newest async auto-commit (kept so
-        # errors surface and close() can flush).
+        # an explicit offset, and the ONE async auto-commit in flight
+        # with the newest offset parked behind it (see _auto_commit):
+        # (offset in flight, its future, address, parked offset | None).
         self._pf: dict[tuple[str, int], dict] = {}
-        self._commits: dict[tuple[str, int], tuple[int, object, str]] = {}
+        self._commits: dict[
+            tuple[str, int], tuple[int, object, str, Optional[int]]
+        ] = {}
         # Causal tracing (obs/spans.py), mirroring ProducerClient: every
         # trace_sample_n-th consume opens a client.consume root span
         # whose context rides `tctx` on the sync and follower fetches
@@ -376,15 +382,26 @@ class ConsumerClient:
         if self.prefetch <= 0 or call_async is None:
             self.commit(topic, pid, offset)  # strict: ack before deliver
             return
-        # Pipelined commit: offsets are monotonically increasing per
-        # (consumer, partition) and ride ONE ordered connection, so a
-        # newer in-flight commit supersedes an older one; only the
-        # newest needs tracking. A commit that FAILED is re-driven
+        # Pipelined commit, ONE in flight per (consumer, partition). The
+        # broker runs a connection's requests on a worker pool, so two
+        # commits of one partition can reach the offset table in either
+        # order, and the table takes the last writer: an older commit
+        # overtaking a newer one moved the committed position BACK, and
+        # the next fetch without an explicit offset (after an empty
+        # window) re-delivered what lay between (seen on the chip at
+        # 130k msgs/s, PR 28: a commit took longer than the poll
+        # interval, 512 messages came twice). So while one is in flight
+        # the newest offset is PARKED behind it — offsets only grow, a
+        # parked one is superseded by the next — and goes out when the
+        # in-flight one has landed. A commit that FAILED is re-driven
         # synchronously (with retries) before anything newer is sent —
         # errors must not silently drop the committed position.
         key = (topic, pid)
         prev = self._commits.get(key)
-        if prev is not None and prev[1].done():
+        if prev is not None:
+            if not prev[1].done():
+                self._commits[key] = (prev[0], prev[1], prev[2], int(offset))
+                return
             self._commits.pop(key, None)
             if not self._commit_ok(prev[1]):
                 self.commit(topic, pid, max(int(prev[0]), int(offset)))
@@ -397,7 +414,7 @@ class ConsumerClient:
         except RpcError:
             self.commit(topic, pid, offset)  # sync fallback w/ retries
             return
-        self._commits[key] = (int(offset), fut, addr)
+        self._commits[key] = (int(offset), fut, addr, None)
 
     @staticmethod
     def _commit_ok(fut) -> bool:
@@ -410,13 +427,13 @@ class ConsumerClient:
         entry = self._commits.pop((topic, pid), None)
         if entry is None:
             return
-        off, fut, _ = entry
+        off, fut, _, parked = entry
         try:
             ok = bool(fut.result(timeout=self._timeout).get("ok"))
         except Exception:
             ok = False
-        if not ok:
-            self.commit(topic, pid, off)
+        if parked is not None or not ok:
+            self.commit(topic, pid, off if parked is None else parked)
 
     def flush_commits(self) -> None:
         """Drain every in-flight async auto-commit (prefetch mode),

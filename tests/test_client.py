@@ -255,3 +255,86 @@ def test_prefetch_round_robin_covers_all_partitions(cluster):
     finally:
         producer.close()
         consumer.close()
+
+
+class _HeldCommits:
+    """Transport proxy: `offset.commit` requests sent with call_async are
+    HELD until the test lets them reach the broker, in the order the
+    test chooses — a broker's worker pool may run two requests of one
+    connection in either order. Everything else goes straight through."""
+
+    def __init__(self, inner) -> None:
+        self._inner = inner
+        self.held: list = []  # (addr, request, future)
+
+    def call(self, addr, request, timeout=3.0):
+        return self._inner.call(addr, request, timeout=timeout)
+
+    def call_async(self, addr, request):
+        if request.get("type") != "offset.commit":
+            return self._inner.call_async(addr, request)
+        from concurrent.futures import Future
+
+        fut: Future = Future()
+        self.held.append((addr, request, fut))
+        return fut
+
+    def land(self, i: int) -> None:
+        addr, request, fut = self.held[i]
+        fut.set_result(self._inner.call(addr, request))
+
+    def close(self) -> None:
+        self._inner.close()
+
+
+def test_pipelined_commits_never_move_the_position_back(cluster):
+    """FAILING-BEFORE (seen on the chip at 130k msgs/s, PR 28): with a
+    commit slower than the poll interval the readahead consumer had
+    several auto-commits of ONE partition in flight; the broker ran them
+    out of order, the older one landed last, the committed position
+    moved back, and the next fetch without an explicit offset delivered
+    a window twice. Now one commit is in flight per partition and the
+    newest offset waits behind it."""
+    producer = make_producer(cluster)
+    transport = _HeldCommits(cluster.client("consumer-held"))
+    consumer = ConsumerClient(bootstrap(cluster), "held-commits",
+                              transport=transport, metadata_refresh_s=0.5,
+                              prefetch=1, max_messages=2)
+    try:
+        sent = [b"held-%d" % i for i in range(6)]
+        for m in sent:
+            producer.produce("topic2", m, partition=1)
+        got: list[bytes] = []
+        # The module-shared cluster may hold other tests' messages on
+        # this partition: a fresh consumer id reads them first.
+        deadline = time.time() + 30
+        while time.time() < deadline and len(
+                [m for m in got if m.startswith(b"held-")]) < len(sent):
+            got += consumer.consume("topic2", partition=1)
+        ours = [m for m in got if m.startswith(b"held-")]
+        assert ours == sent
+        # Three or more windows were delivered and not one commit has
+        # landed: exactly ONE went out, the newest offset is parked.
+        assert len(transport.held) == 1
+        # The old client had sent one per window; landing them newest
+        # first is what moved the position back. Here there is nothing
+        # to reorder: land the one, then the flush commits the parked
+        # offset behind it.
+        transport.land(0)
+        consumer.flush_commits()
+        assert len(transport.held) == 1  # the parked one went out sync
+        # A fetch without an explicit offset (what follows an empty
+        # window) starts past everything delivered: nothing comes twice.
+        more = [b"held-more-%d" % i for i in range(2)]
+        for m in more:
+            producer.produce("topic2", m, partition=1)
+        again: list[bytes] = []
+        deadline = time.time() + 30
+        while time.time() < deadline and len(again) < len(more):
+            again += consumer.consume("topic2", partition=1)
+        assert again == more
+        for i in range(1, len(transport.held)):
+            transport.land(i)  # close() flushes without waiting one out
+    finally:
+        producer.close()
+        consumer.close()
