@@ -2,9 +2,9 @@
 
 Drives produce -> quorum round -> replicate -> persist -> ack -> consume
 once, through the entry points a user would call, at the size of the
-deployment the benchmark cells use (`bench.e2e_raw_config`: 3 brokers,
-topic `bench` with 1024 partitions at RF 3, engine P=1024 R=3 slots=4608
-slot_bytes=128 max_batch=512, fused control + packed writes, full-copy
+deployment the benchmark cells use (`benchmarks/configs/omb-1024p-100b.json`,
+read, never copied: 3 brokers, topic `bench` with 1024 partitions at RF 3,
+engine P=1024 R=3 slots=4608 slot_bytes=128 max_batch=512, full-copy
 replication to 2 standbys, a durable --data-dir on every broker):
 
 - three broker processes, `python -m ripplemq_tpu.broker --id N --config F
@@ -67,6 +67,21 @@ MSG_BYTES = 100
 EXIT_FUNCTIONAL = 1
 EXIT_DEVICE = 4
 DEADLINE_S = 1100.0  # whole-run watchdog, inside the 1200 s contract
+# The one definition of the deployment, shared with the benchmark cells.
+DEPLOYMENT = os.path.join(REPO, "benchmarks", "configs", "omb-1024p-100b.json")
+
+
+def deployment_raw(ports: list[int]) -> dict:
+    """The benchmark deployment's cluster file with brokers on `ports`:
+    the config's `cluster` block, its topics, and one broker per port —
+    what benchmarks/run.py boots for the omb-1024p-100b cells."""
+    with open(DEPLOYMENT) as f:
+        config = json.load(f)
+    raw = dict(config["cluster"], engine=dict(config["cluster"]["engine"]))
+    raw["brokers"] = [{"id": i, "host": "127.0.0.1", "port": p}
+                      for i, p in enumerate(ports)]
+    raw["topics"] = config["deployment"]["topics"]
+    return raw
 
 
 def log(*a) -> None:
@@ -439,7 +454,6 @@ class Smoke:
         args = self.args
         import yaml
 
-        from bench import e2e_raw_config  # the deployment; never a copy
         from ripplemq_tpu.utils.compile_cache import (
             CHECKOUT_CACHE_DIR,
             ENV_VAR,
@@ -458,12 +472,14 @@ class Smoke:
         ports = [s.getsockname()[1] for s in socks]
         for s in socks:
             s.close()
-        raw = e2e_raw_config(ports, partitions=8 if args.tiny else 1024)
+        raw = deployment_raw(ports)
         if args.tiny:
             # Functional pass only (CPU, seconds): same topology and code
             # paths, a ring that still wraps three times, segments small
             # enough to seal. Never reported as ok.
-            raw["engine"].update(slots=256, max_batch=32, read_batch=64)
+            raw["engine"].update(partitions=8, slots=256, max_batch=32,
+                                 read_batch=64)
+            raw["topics"] = [dict(t, partitions=8) for t in raw["topics"]]
             raw.update(segment_bytes=32 << 10, metadata_election_timeout_s=1.5)
         eng = raw["engine"]
         cfg_path = os.path.join(self.work, "cluster.yaml")
@@ -480,7 +496,7 @@ class Smoke:
             "wrap_messages": 800 if args.tiny else 16384,
             "nprocs": 2 if args.tiny else 4,
             "threads": 2 if args.tiny else 8,
-            "segment_bytes": raw.get("segment_bytes", 64 << 20),
+            "segment_bytes": raw["segment_bytes"],
             "stripe_class_max": (64 << 10) if args.tiny else (4 << 20),
         }
         total = sum(stream_plan(spec).values())

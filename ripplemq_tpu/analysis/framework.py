@@ -104,7 +104,7 @@ class Repo:
 
     def py_files(self, *subdirs: str) -> list[str]:
         """Repo-relative posix paths of every .py under the subdirs
-        (files allowed too, e.g. "bench.py"), __pycache__ excluded,
+        (files allowed too), __pycache__ excluded,
         sorted for deterministic finding order."""
         out: list[str] = []
         for sub in subdirs:

@@ -47,7 +47,7 @@ LOCKED_MODULES = (
 # Where bare reads and held-lock blocking calls are hunted: the whole
 # library plus the ops-facing entry points. Tests are exempt (white-box
 # reach-ins are their job).
-SCAN_ROOTS = ("ripplemq_tpu", "profiles", "bench.py")
+SCAN_ROOTS = ("ripplemq_tpu", "profiles")
 
 _LOCK_NAME = re.compile(r"^_.*lock$")
 

@@ -2151,8 +2151,8 @@ class DataPlane:
             off_counts=off_counts,
             leader=self.leader.copy(),
             term=self.term.copy(),
-            # Rows this round's write must cover (packed_writes clips
-            # the append DMA to this; boundary-padding rounds count
+            # Rows this round's write must cover (the append DMA is
+            # clipped to this; boundary-padding rounds count
             # their padding in `counts`, so the extent covers them too).
             extents=row_extents(counts),
         )

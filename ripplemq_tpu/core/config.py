@@ -30,7 +30,7 @@ ALIGN = 8
 # the append to ONE DMA per (replica, partition) per round.
 ROW_HEADER = 8
 
-# Ring-stride aliasing hazard (PROFILE.md round-5 finding 2): when the
+# Ring-stride aliasing hazard: when the
 # per-partition ring stride (slots + max_batch) * slot_bytes lands on or
 # near a power of two >= 2^20, the append kernel's strided partition DMAs
 # alias HBM channels and the measured write rate drops 25-35% (slots 8192
@@ -58,7 +58,7 @@ def ring_stride_bytes(slots: int, max_batch: int, slot_bytes: int) -> int:
 def stride_alias_hazard(slots: int, max_batch: int, slot_bytes: int,
                         streams: int | None = None) -> str | None:
     """Non-None iff the ring stride lands on/near a >= 2^20 power of two
-    (the HBM-channel-aliasing shapes PROFILE.md r5 measured). Returns the
+    (the HBM-channel-aliasing shapes; see STRIDE_POW2_FLOOR). Returns the
     warning text so callers can warn, log, or assert on it.
 
     `streams` is the number of partition rings resident on ONE device —
@@ -86,7 +86,7 @@ def stride_alias_hazard(slots: int, max_batch: int, slot_bytes: int,
                 f"slot_bytes={slot_bytes}) is within {100 / _STRIDE_REL_TOL:.1f}% "
                 f"of 2^{pow2.bit_length() - 1}; strided append DMAs at this "
                 f"shape alias HBM channels (measured 25-35% write-rate "
-                f"penalty, PROFILE.md r5). Nudge `slots` so the stride "
+                f"penalty). Nudge `slots` so the stride "
                 f"moves off the power of two."
             )
     return None
@@ -111,21 +111,6 @@ class EngineConfig:
     read_batch: int = 32         # RB — max entries per batch read
     max_consumers: int = 64      # C — consumer-offset table width
     max_offset_updates: int = 8  # U — max offset commits per partition/step
-    # Hot-path levers (PROFILE.md r5: the sustained engine is pinned by
-    # the balanced control and write phases — both must shrink to move).
-    # Each is independently A/B-able against the legacy path and
-    # bit-identical to it (tests/test_control_fusion.py):
-    fused_control: bool = False  # bookkeeping scalars as one [K, P] ctrl
-    #                              array updated by wide fused ops instead
-    #                              of per-field element-wise ops. Honored
-    #                              by BOTH bindings: under shard_map the
-    #                              stacked leader broadcast is ONE psum on
-    #                              the replica mesh axis per round (one
-    #                              ICI collective where the legacy control
-    #                              phase issues two)
-    packed_writes: bool = False  # clip append DMA windows to the round's
-    #                              payload extent instead of always moving
-    #                              the full [B, SB] block
     # Host-path knob (NOT a device shape — no recompile): how many
     # dispatched rounds may have their standby replication in flight
     # while the device advances. Acks and the settled-read horizon are

@@ -20,9 +20,8 @@ from ripplemq_tpu.core.state import StepInput
 
 def row_extents(counts: np.ndarray) -> np.ndarray:
     """Per-partition write extents (rows, ALIGN-rounded) from payload
-    counts — what the packed write path (EngineConfig.packed_writes)
-    needs to clip each append DMA to the bytes the round actually
-    carries. Host-side analogue of core.step._padded_advance."""
+    counts — what the write phase needs to clip each append DMA to the
+    bytes the round actually carries. Host-side analogue of core.step._padded_advance."""
     counts = np.asarray(counts, np.int32)
     return ((counts + ALIGN - 1) // ALIGN * ALIGN).astype(np.int32)
 

@@ -1,10 +1,13 @@
 """Replicated data-plane state as fixed-shape arrays.
 
-One `ReplicaState` is the full data-plane state of ONE replica: the slotted
-message log, Raft bookkeeping scalars and the consumer-offset table for
-every partition hosted by the program. The reference keeps the equivalent
-state as `List<String> messages` + `Map<String, Long> consumerOffsets` per
-partition group (reference:
+`FusedReplicaState` is the full data-plane state of ONE replica as the
+device holds it: the slotted message log, the Raft bookkeeping scalars
+stacked into one [K, P] ctrl array, and the consumer-offset table for
+every partition hosted by the program. `ReplicaState` names the same
+content field by field: it is the host-side image that recovery builds
+and hands to the engine (`init_from`), never a device layout. The
+reference keeps the equivalent state as `List<String> messages` +
+`Map<String, Long> consumerOffsets` per partition group (reference:
 mq-broker/src/main/java/metadata/raft/PartitionStateMachine.java:26-27),
 purely in JVM heap; here it is a pytree of device arrays so that
 replication, quorum and apply are tensor ops.
@@ -17,7 +20,7 @@ needs, so the append write phase is ONE DMA per (replica, partition).
 Ring retention: `log_end` and `commit` are MONOTONE absolute storage
 offsets; the physical log holds the last `slots` rows as a ring (row for
 absolute offset `a` lives at physical row `a % slots`) plus a
-`max_batch`-row margin so the append DMA's fixed [B, SB] window never
+`max_batch`-row margin so the append DMA's window (at most [B, SB]) never
 wraps (rows landing in the margin are always beyond the round's advance —
 dead padding that no read ever selects). Overwriting ring rows is gated
 by a host-fed `trim` watermark (see step.replica_control): rows below
@@ -52,7 +55,10 @@ from ripplemq_tpu.core.config import EngineConfig
 
 
 class ReplicaState(NamedTuple):
-    """Per-replica data-plane state (one replica's view of P partitions)."""
+    """One replica's state with every bookkeeping vector named: the
+    host-side recovery image (`DataPlane.install`, `recover_image` /
+    `replay_records`, the lockstep follower's install, the engine
+    bindings' `init_from`). The engine never computes on this layout."""
 
     log_data: jax.Array     # uint8 [P, S+B, SB] — ring rows + margin (see module doc)
     log_end: jax.Array      # int32 [P]        — next ABSOLUTE storage offset (ALIGN-padded)
@@ -72,33 +78,31 @@ CTRL_K = len(CTRL_FIELDS)
 
 
 class FusedReplicaState(NamedTuple):
-    """ReplicaState with the four per-partition bookkeeping vectors
-    stacked into ONE [K, P] int32 array (EngineConfig.fused_control).
+    """Per-replica data-plane state as the device holds it: the four
+    per-partition bookkeeping vectors of `ReplicaState` stacked into ONE
+    [K, P] int32 array.
 
-    Rationale (PROFILE.md r5 finding 3): the control phase's cost is
-    fusion-boundary overhead across dozens of small [R, P] element-wise
-    ops, not arithmetic. Carrying the scalars as one array lets the
-    round's bookkeeping advance as a handful of wide ops on one buffer
-    (core.step.replica_control_fused) and keeps the scan carry of a
-    chained launch to three leaves instead of six.
+    Rationale: the control phase's cost is fusion-boundary overhead
+    across small [R, P] element-wise ops, not arithmetic. Carrying the
+    scalars as one array lets the round's bookkeeping advance as a
+    handful of wide ops on one buffer (core.step.replica_control) and
+    keeps the scan carry of a chained launch to three leaves.
 
-    The named accessors mirror ReplicaState so host-side readers
-    (DataPlane._fetch_state, read paths, tests) work on either
-    representation; they are views, not extra buffers. Conversion in
-    both directions is exact (`fuse_state` / `unfuse_state`).
+    The named accessors are what host-side readers use
+    (DataPlane._fetch_state, read paths, tests); they are views, not
+    extra buffers.
 
     Under the spmd binding the engine-stacked ctrl is [R, K, P] sharded
     ("replica", None, "part") — the K bookkeeping rows stay whole on
     every device while replicas and partitions shard
-    (parallel.engine._fused_state_specs), which is what lets the round's
-    two leader broadcasts ride ONE [2, local_P] psum over the replica
-    mesh axis (one ICI collective where the legacy layout issues two)
-    and keeps the named-accessor views valid on process-sharded state
-    (the slice is along the unsharded K axis)."""
+    (parallel.engine._state_specs), which is what lets the round's two
+    leader broadcasts ride ONE [2, local_P] psum over the replica mesh
+    axis and keeps the named-accessor views valid on process-sharded
+    state (the slice is along the unsharded K axis)."""
 
-    log_data: jax.Array     # uint8 [P, S+B, SB] — identical to ReplicaState
+    log_data: jax.Array     # uint8 [P, S+B, SB] — ring rows + margin (see module doc)
     ctrl: jax.Array         # int32 [K, P]       — CTRL_FIELDS, stacked
-    offsets: jax.Array      # int32 [P, C]       — identical to ReplicaState
+    offsets: jax.Array      # int32 [P, C]       — replicated consumer offsets
 
     # A leading replica axis (engine-stacked state) moves ctrl to
     # [R, K, P]; `...` keeps the accessors shape-agnostic.
@@ -120,7 +124,9 @@ class FusedReplicaState(NamedTuple):
 
 
 def fuse_state(state: ReplicaState) -> FusedReplicaState:
-    """Stack the bookkeeping scalars into the fused layout (exact)."""
+    """Stack a named image's bookkeeping scalars into the device layout
+    (exact). This is the hand-over from recovery to the engine
+    (`init_from`, `init`), and the way back in for `resync`."""
     ctrl = jnp.stack(
         [getattr(state, f) for f in CTRL_FIELDS], axis=-2
     ).astype(jnp.int32)
@@ -130,7 +136,9 @@ def fuse_state(state: ReplicaState) -> FusedReplicaState:
 
 
 def unfuse_state(state: FusedReplicaState) -> ReplicaState:
-    """Split the fused layout back into named fields (exact inverse)."""
+    """Split the device layout back into named fields (exact inverse).
+    Used by `resync`, whose per-partition masking wants [P]-leading
+    leaves, and by tests that compare field by field."""
     return ReplicaState(
         log_data=state.log_data,
         log_end=state.log_end,
@@ -165,12 +173,12 @@ class StepInput(NamedTuple):
     extents: jax.Array | None = None  # int32 [P] — rows of the [B, SB]
     #                        window the write phase must cover this round
     #                        (the host knows the payload extent at
-    #                        pack time; EngineConfig.packed_writes clips
-    #                        the append DMA to it — ops/append.py). The
-    #                        control phase clamps to [advance, B], so a
-    #                        missing/short extent can never under-write a
-    #                        committed round. None (pytree-empty) means
-    #                        "full window", the legacy write shape.
+    #                        pack time; the append DMA is clipped to it —
+    #                        ops/append.py). The control phase clamps to
+    #                        [advance, B], so a missing/short extent can
+    #                        never under-write a committed round. None
+    #                        (pytree-empty, hand-built inputs) means the
+    #                        full window.
 
 
 class StepOutput(NamedTuple):
@@ -184,7 +192,8 @@ class StepOutput(NamedTuple):
 
 
 def init_state(cfg: EngineConfig) -> ReplicaState:
-    """Zero state for one replica."""
+    """Zero image of one replica (named layout; the bindings' `init`
+    stacks it like any other image)."""
     P, S, SB, C = cfg.partitions, cfg.slots, cfg.slot_bytes, cfg.max_consumers
     return ReplicaState(
         log_data=jnp.zeros((P, S + cfg.max_batch, SB), jnp.uint8),

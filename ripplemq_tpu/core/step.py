@@ -29,10 +29,11 @@ The step is split in two phases for the hardware's sake:
 - `replica_control` — everything EXCEPT the log write: acks, ballot,
   commit bookkeeping, offset-table blend. Cheap [P]-shaped vector ops;
   runs per replica under vmap (local) or shard_map (SPMD).
-- the log write — one [B, SB] block per committed partition at a
-  variable, ALIGN-aligned offset. This is `ripplemq_tpu.ops.append`
-  (Pallas DMA kernel on TPU; XLA scatter fallback), called once on the
-  full [R, P, S, SB] log by the engine wrappers, NOT per replica.
+- the log write — the round's extent of one [B, SB] block per committed
+  partition at a variable, ALIGN-aligned offset. This is
+  `ripplemq_tpu.ops.append` (Pallas DMA kernel on TPU; XLA scatter
+  fallback), called once on the full [R, P, S, SB] log by the engine
+  wrappers, NOT per replica.
 
 Each committed round advances log_end to the next ALIGN boundary; padding
 rows carry length 0 and the round's term (core.config.ALIGN rationale).
@@ -54,7 +55,6 @@ from jax import lax
 from ripplemq_tpu.core.config import ALIGN, EngineConfig
 from ripplemq_tpu.core.state import (
     FusedReplicaState,
-    ReplicaState,
     StepInput,
     StepOutput,
     row_lens,
@@ -93,18 +93,20 @@ class ControlOut(NamedTuple):
     out: StepOutput     # per-partition round results (replica-invariant)
     do_write: jax.Array  # bool [P] — this replica writes the round's block
     extent: jax.Array    # int32 [P] — rows of the [B, SB] window the write
-    #                      phase covers (== B unless packed_writes clips
-    #                      it; replica-invariant — derived from the input)
+    #                      phase covers (the host's extent, or B where
+    #                      the input names none; replica-invariant —
+    #                      derived from the input)
 
 
 def _write_extent(cfg: EngineConfig, inp: StepInput,
                   advance: jax.Array) -> jax.Array:
     """Rows the write phase covers: the host-declared extent, ALIGN-
     rounded and clamped to [advance, B] so a committed round's rows are
-    always covered no matter what the host fed. None extents (or a
-    config without packed writes) mean the full legacy window."""
+    always covered no matter what the host fed. An input without extents
+    (hand-built, pytree-empty None) means the full B-row window — the
+    one place that is decided."""
     B = cfg.max_batch
-    if not cfg.packed_writes or inp.extents is None:
+    if inp.extents is None:
         return jnp.full_like(advance, B)
     ext = _padded_advance(jnp.clip(inp.extents, 0, B))
     return jnp.clip(ext, advance, B)
@@ -129,13 +131,13 @@ def _blend_offsets(cfg: EngineConfig, state_offsets: jax.Array,
 
 def replica_control(
     cfg: EngineConfig,
-    state: ReplicaState,
+    state: FusedReplicaState,
     inp: StepInput,
     rep_idx: jax.Array,   # int32 scalar — this replica's id on the axis
     alive: jax.Array,     # bool [R] or [P, R] — membership mask (replicated)
     quorum: jax.Array | None = None,  # int32 [P] — per-partition quorum
     trim: jax.Array | None = None,    # int32 [P] — retention watermark
-) -> tuple[ReplicaState, ControlOut]:
+) -> tuple[FusedReplicaState, ControlOut]:
     """One round's control phase from one replica's point of view: the
     ballot and all scalar-state updates. The returned state has every
     field advanced EXCEPT `log_data` (the write phase owns that).
@@ -153,6 +155,17 @@ def replica_control(
     partition, never exceeds the persisted/committed prefix, and the
     host clamps each round's batch so `advance <= S - (base % S)` (live
     rows never land in the wrap margin — see core.state ring doc).
+
+    The bookkeeping works on the stacked [K, P] ctrl buffer because the
+    control phase's cost is fusion-boundary overhead across small
+    element-wise ops, not arithmetic:
+    - the two leader broadcasts (prevLogIndex + prevLogTerm) ride ONE
+      [2, P] psum — under shard_map one collective on the replica mesh
+      axis per round, under vmap one reduction;
+    - the four bookkeeping advances are ONE [K, P] select on one buffer
+      (`maximum(x, y)` == `where(y > x, y, x)` bitwise for int32, which
+      writes current_term/commit as selects beside log_end/last_term);
+    - the scan carry of a chained launch is three leaves.
     """
     S, B, R = cfg.slots, cfg.max_batch, cfg.replicas
     # Shard-shape note: under shard_map this function sees [local_P]
@@ -166,6 +179,10 @@ def replica_control(
         quorum = jnp.full((P,), cfg.quorum, jnp.int32)
     if trim is None:
         trim = jnp.zeros((P,), jnp.int32)
+
+    ctrl = state.ctrl                                     # [K, P]
+    log_end, last_term = ctrl[0], ctrl[1]
+    current_term, commit = ctrl[2], ctrl[3]
 
     # Sanitize host-fed control values: an out-of-range index is undefined
     # behavior on TPU gathers (observed: backend InvalidArgument), and an
@@ -187,28 +204,32 @@ def replica_control(
     )
 
     # --- 1. leader's pre-append log end ("prevLogIndex" of AppendEntries)
-    # and the term of its tail row ("prevLogTerm"; cached in state).
-    base = _bcast_from_leader(state.log_end, is_leader & self_alive)  # [P]
-    leader_last_term = _bcast_from_leader(
-        state.last_term, is_leader & self_alive
-    )
+    # and the term of its tail row ("prevLogTerm"; cached in state), as
+    # ONE stacked psum: mask to the leader's contribution, sum over the
+    # replica axis.
+    lead_mask = (is_leader & self_alive)[None, :]         # [1, P]
+    led = lax.psum(
+        jnp.where(lead_mask, ctrl[0:2], jnp.zeros_like(ctrl[0:2])), AXIS
+    )                                                     # [2, P]
+    base, leader_last_term = led[0], led[1]
 
     # --- 2. ack: alive + log-matching + term current. Log matching is the
     # full Raft check — prevLogIndex (log_end == base) AND prevLogTerm:
     # a replica whose log is the same length but whose tail was written
     # under a different term has a divergent suffix and must NOT ack (it
     # re-enters via host-driven resync).
-    term_ok = inp.term >= state.current_term
-    log_match = (state.log_end == base) & (
-        (base == 0) | (state.last_term == leader_last_term)
+    term_ok = inp.term >= current_term
+    log_match = (log_end == base) & (
+        (base == 0) | (last_term == leader_last_term)
     )
-    # Capacity: the write phase always lands a full B-row window on the
-    # ring, which (previous lap) covers absolute offsets
-    # [base - S, base + B - S) — all of which must be below the trim
-    # watermark. With trim pinned at 0 this reduces to the bounded-log
-    # rule base + B <= S. Offsets-only rounds (counts == 0) consume no
-    # log space and must keep committing on a full partition: consumers
-    # still need to advance their positions through the backlog.
+    # Capacity is priced at the full B-row window whatever extent the
+    # write phase covers this round: on the ring (previous lap) the
+    # window covers absolute offsets [base - S, base + B - S) — all of
+    # which must be below the trim watermark. With trim pinned at 0 this
+    # reduces to the bounded-log rule base + B <= S. Offsets-only rounds
+    # (counts == 0) consume no log space and must keep committing on a
+    # full partition: consumers still need to advance their positions
+    # through the backlog.
     capacity_ok = (counts == 0) | (base + B - trim <= S)
     # A round is ack-worthy if it carries entries OR offset commits: offset
     # commits on idle partitions must still replicate (the reference routes
@@ -228,122 +249,10 @@ def replica_control(
     committed = votes >= quorum                            # [P]
     do_write = ack & committed                             # [P]
 
-    # --- 4. scalar state advances (atomic with the ballot). wrote_rows
-    # additionally gates the write phase: offsets-only rounds must not pay
-    # the (hottest-op) append DMA for an all-zero window.
-    wrote_rows = do_write & (advance > 0)
-    new_log_end = jnp.where(wrote_rows, base + advance, state.log_end)
-    new_last_term = jnp.where(wrote_rows, inp.term, state.last_term)
-    new_current_term = jnp.maximum(state.current_term, inp.term)
-    commit_target = jnp.where(do_write, base + advance, 0)
-    new_commit = jnp.maximum(state.commit, commit_target)
-
-    # --- 5. committed consumer-offset updates (shared with the fused
-    # path — see _blend_offsets).
-    new_offsets = _blend_offsets(cfg, state.offsets, inp, do_write)
-
-    new_state = state._replace(
-        log_end=new_log_end,
-        last_term=new_last_term,
-        current_term=new_current_term,
-        commit=new_commit,
-        offsets=new_offsets,
-    )
-    out = StepOutput(
-        base=base,
-        votes=votes,
-        committed=committed,
-        commit=lax.pmax(new_commit, AXIS),
-    )
-    return new_state, ControlOut(out, wrote_rows, _write_extent(cfg, inp, advance))
-
-
-def replica_control_fused(
-    cfg: EngineConfig,
-    state: FusedReplicaState,
-    inp: StepInput,
-    rep_idx: jax.Array,
-    alive: jax.Array,
-    quorum: jax.Array | None = None,
-    trim: jax.Array | None = None,
-) -> tuple[FusedReplicaState, ControlOut]:
-    """replica_control on the stacked-ctrl state (EngineConfig.
-    fused_control), bit-identical to the legacy path by construction
-    (asserted across scenarios in tests/test_control_fusion.py).
-
-    What actually shrinks (PROFILE.md r5 finding 3 — the control phase
-    is fusion-boundary overhead, not arithmetic):
-    - the two leader broadcasts (prevLogIndex + prevLogTerm) ride ONE
-      [2, P] psum instead of two [P] psums — under shard_map that is one
-      collective instead of two, under vmap one fused reduction;
-    - the four bookkeeping advances collapse into ONE [K, P] select on
-      one buffer instead of four where/maximum ops on four buffers
-      (each a separate XLA fusion in the scanned chain body);
-    - the scan carry of a chained launch is three leaves, not six.
-
-    Equivalence notes (each update is the exact legacy expression, just
-    restacked): `maximum(x, y)` == `where(y > x, y, x)` bitwise for
-    int32, which rewrites current_term/commit as selects; log_end and
-    last_term keep their wrote_rows selects unchanged.
-    """
-    S, B, R = cfg.slots, cfg.max_batch, cfg.replicas
-    # Same shard-shape note as replica_control: [local_P] shards under
-    # shard_map; the spmd wrappers never rely on these [P] defaults.
-    P = cfg.partitions
-    if quorum is None:
-        quorum = jnp.full((P,), cfg.quorum, jnp.int32)
-    if trim is None:
-        trim = jnp.zeros((P,), jnp.int32)
-
-    ctrl = state.ctrl                                     # [K, P]
-    log_end, last_term = ctrl[0], ctrl[1]
-    current_term, commit = ctrl[2], ctrl[3]
-
-    counts = jnp.clip(inp.counts, 0, B)
-    advance = _padded_advance(counts)                    # [P]
-
-    alive = _normalize_alive(alive, P, R)                # [P, R]
-    self_alive = alive[:, rep_idx]                       # [P]
-    leader_known = (inp.leader >= 0) & (inp.leader < R)  # [P]
-    is_leader = (inp.leader == rep_idx) & leader_known   # [P]
-    leader_alive = jnp.where(
-        leader_known,
-        jnp.take_along_axis(
-            alive, jnp.clip(inp.leader, 0, R - 1)[:, None], axis=1
-        )[:, 0],
-        False,
-    )
-
-    # --- 1. leader's pre-append log end + tail term: ONE stacked psum.
-    lead_mask = (is_leader & self_alive)[None, :]         # [1, P]
-    led = lax.psum(
-        jnp.where(lead_mask, ctrl[0:2], jnp.zeros_like(ctrl[0:2])), AXIS
-    )                                                     # [2, P]
-    base, leader_last_term = led[0], led[1]
-
-    # --- 2. ack (identical predicate to the legacy path).
-    term_ok = inp.term >= current_term
-    log_match = (log_end == base) & (
-        (base == 0) | (last_term == leader_last_term)
-    )
-    capacity_ok = (counts == 0) | (base + B - trim <= S)
-    has_work = (counts > 0) | (inp.off_counts > 0)
-    ack = (
-        self_alive
-        & leader_alive
-        & term_ok
-        & log_match
-        & capacity_ok
-        & has_work
-    )  # [P]
-
-    # --- 3. ballot before any write.
-    votes = lax.psum(ack.astype(jnp.int32), AXIS)          # [P]
-    committed = votes >= quorum                            # [P]
-    do_write = ack & committed                             # [P]
-
-    # --- 4. the four scalar advances as ONE wide select on the stacked
-    # buffer (see the docstring's equivalence notes).
+    # --- 4. scalar state advances (atomic with the ballot), as one wide
+    # select. wrote_rows additionally gates the write phase: offsets-only
+    # rounds must not pay the (hottest-op) append DMA for an all-zero
+    # window.
     wrote_rows = do_write & (advance > 0)
     adv_target = base + advance
     conds = jnp.stack([
@@ -355,7 +264,7 @@ def replica_control_fused(
     cands = jnp.stack([adv_target, inp.term, inp.term, adv_target])
     new_ctrl = jnp.where(conds, cands, ctrl)               # [K, P]
 
-    # --- 5. committed consumer-offset updates (shared helper).
+    # --- 5. committed consumer-offset updates.
     new_offsets = _blend_offsets(cfg, state.offsets, inp, do_write)
 
     new_state = state._replace(ctrl=new_ctrl, offsets=new_offsets)
@@ -370,13 +279,13 @@ def replica_control_fused(
 
 def replica_step(
     cfg: EngineConfig,
-    state: ReplicaState,
+    state: FusedReplicaState,
     inp: StepInput,
     rep_idx: jax.Array,
     alive: jax.Array,
     quorum: jax.Array | None = None,
     trim: jax.Array | None = None,
-) -> tuple[ReplicaState, StepOutput]:
+) -> tuple[FusedReplicaState, StepOutput]:
     """Complete per-replica round: control phase + per-replica XLA append.
 
     This is the portable all-in-one composition (works under plain vmap on
@@ -391,66 +300,32 @@ def replica_step(
     from ripplemq_tpu.ops.append import append_rows_xla  # local: avoid cycle
 
     log_data = append_rows_xla(
-        state.log_data, inp.entries, ctl.out.base % cfg.slots, ctl.do_write
+        state.log_data, inp.entries, ctl.out.base % cfg.slots, ctl.do_write,
+        ctl.extent,
     )
     return new_state._replace(log_data=log_data), ctl.out
 
 
 def vote_step(
     cfg: EngineConfig,
-    state: ReplicaState,
+    state: FusedReplicaState,
     cand: jax.Array,       # int32 [P] — candidate replica id per partition (-1 = no election)
     cand_term: jax.Array,  # int32 [P] — candidate's proposed term
     rep_idx: jax.Array,
     alive: jax.Array,
     quorum: jax.Array | None = None,  # int32 [P]
-) -> tuple[ReplicaState, jax.Array, jax.Array]:
+) -> tuple[FusedReplicaState, jax.Array, jax.Array]:
     """One RequestVote round: grants counted as a psum reduction.
 
-    Returns (state', elected[P] bool, votes[P] int32). The up-to-date
-    check is Raft §5.4.1: grant only to candidates whose log is at least
-    as complete. Replaces JRaft's per-group ballot
-    (NodeOptions.setElectionTimeoutMs — reference
+    Returns (state', elected[P] bool, votes[P] int32); the term grant
+    lands in ctrl row 2. The up-to-date check is Raft §5.4.1: grant only
+    to candidates whose log is at least as complete. Replaces JRaft's
+    per-group ballot (NodeOptions.setElectionTimeoutMs — reference
     PartitionRaftServer.java:85 — with timeouts host-vectorized).
     """
-    new_term, elected, votes = _vote_core(
-        cfg, state.log_end, state.last_term, state.current_term,
-        cand, cand_term, rep_idx, alive, quorum,
-    )
-    return state._replace(current_term=new_term), elected, votes
-
-
-def vote_step_fused(
-    cfg: EngineConfig,
-    state: FusedReplicaState,
-    cand: jax.Array,
-    cand_term: jax.Array,
-    rep_idx: jax.Array,
-    alive: jax.Array,
-    quorum: jax.Array | None = None,
-) -> tuple[FusedReplicaState, jax.Array, jax.Array]:
-    """vote_step on the stacked-ctrl state: same ballot core, the term
-    grant lands in ctrl row 2."""
-    new_term, elected, votes = _vote_core(
-        cfg, state.ctrl[0], state.ctrl[1], state.ctrl[2],
-        cand, cand_term, rep_idx, alive, quorum,
-    )
-    new_ctrl = state.ctrl.at[2].set(new_term)
-    return state._replace(ctrl=new_ctrl), elected, votes
-
-
-def _vote_core(
-    cfg: EngineConfig,
-    log_end: jax.Array,
-    last_term: jax.Array,
-    current_term: jax.Array,
-    cand: jax.Array,
-    cand_term: jax.Array,
-    rep_idx: jax.Array,
-    alive: jax.Array,
-    quorum: jax.Array | None,
-) -> tuple[jax.Array, jax.Array, jax.Array]:
     R = cfg.replicas
+    log_end, last_term, current_term = (
+        state.ctrl[0], state.ctrl[1], state.ctrl[2])
     alive = _normalize_alive(alive, cfg.partitions, R)  # [P, R]
     if quorum is None:
         quorum = jnp.full((cfg.partitions,), cfg.quorum, jnp.int32)
@@ -463,12 +338,11 @@ def _vote_core(
         False,
     )
 
-    my_last_term = last_term
     c_end = _bcast_from_leader(log_end, is_cand & self_alive)
-    c_last_term = _bcast_from_leader(my_last_term, is_cand & self_alive)
+    c_last_term = _bcast_from_leader(last_term, is_cand & self_alive)
 
-    up_to_date = (c_last_term > my_last_term) | (
-        (c_last_term == my_last_term) & (c_end >= log_end)
+    up_to_date = (c_last_term > last_term) | (
+        (c_last_term == last_term) & (c_end >= log_end)
     )
     grant = electing & self_alive & cand_alive & (cand_term > current_term) & up_to_date
 
@@ -476,12 +350,13 @@ def _vote_core(
     elected = votes >= quorum
 
     new_term = jnp.where(grant, cand_term, current_term)
-    return new_term, elected, votes
+    new_ctrl = state.ctrl.at[2].set(new_term)
+    return state._replace(ctrl=new_ctrl), elected, votes
 
 
 def read_batch(
     cfg: EngineConfig,
-    state: ReplicaState,
+    state: FusedReplicaState,
     partition: jax.Array,  # int32 scalar
     offset: jax.Array,     # int32 scalar — storage offset to read from
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
@@ -558,7 +433,7 @@ def read_batch_at(
 
 
 def read_offset(
-    state: ReplicaState,
+    state: FusedReplicaState,
     partition: jax.Array,
     consumer_slot: jax.Array,
 ) -> jax.Array:

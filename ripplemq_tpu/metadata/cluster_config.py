@@ -123,9 +123,8 @@ class ClusterConfig:
     # record). None = unlimited — the default, and strictly more than
     # the reference retains (its partition state is JVM-heap-bounded).
     store_retention_bytes: int | None = None
-    # Batcher operating point (see the bench's operating_curve for the
-    # measured latency/throughput tradeoff of these knobs; defaults
-    # favour ack latency):
+    # Batcher operating point (defaults favour ack latency; what they
+    # cost on the chip is PERF.md section 5):
     # - coalesce_s: how long the step thread gathers a burst before
     #   dispatching a round (each dispatch costs a host-device launch).
     # - chain_depth: complete quorum rounds per device launch for deep
@@ -253,10 +252,11 @@ class ClusterConfig:
     slo_chain_depth_min: int = 1
     slo_chain_depth_max: int = 16
     slo_settle_window_min: int = 1
-    # Measured-prior rails (bench.py operating_curve): path to a JSON
-    # file of AIMD rail overrides ({"read_coalesce_min_s": ...,
-    # "read_coalesce_max_s": ..., "chain_depth_min": ...,
-    # "chain_depth_max": ..., "settle_window_min": ...} — any subset).
+    # Measured-prior rails: path to a JSON file of AIMD rail overrides
+    # ({"read_coalesce_min_s": ..., "read_coalesce_max_s": ...,
+    # "chain_depth_min": ..., "chain_depth_max": ...,
+    # "settle_window_min": ...} — any subset). Nothing in the tree
+    # writes one since bench.py's operating_curve went in PR 29.
     # Loaded once at controller construction, the overrides replace the
     # static rails above, so the controller's FIRST tick is already
     # clamped to the measured operating envelope instead of walking in
@@ -563,6 +563,9 @@ def load_cluster_config(path: str) -> ClusterConfig:
     return parse_cluster_config(raw)
 
 
+_RETIRED_ENGINE_KEYS = ("fused_control", "packed_writes")
+
+
 def parse_cluster_config(raw: dict) -> ClusterConfig:
     brokers = tuple(
         BrokerInfo(
@@ -581,6 +584,15 @@ def parse_cluster_config(raw: dict) -> ClusterConfig:
         engine_raw["partitions"] = max(1, total_parts)
     if "replicas" not in engine_raw:
         engine_raw["replicas"] = max_rf
+    # Cluster files written before PR 29 name the two switches the engine
+    # had then; what they chose when true is the only round there is.
+    for key in _RETIRED_ENGINE_KEYS:
+        if engine_raw.pop(key, True) is not True:
+            raise ValueError(
+                f"engine.{key}: false is no longer possible: the legacy "
+                f"control phase / full-window writes were removed (PR 29); "
+                f"delete the key"
+            )
     engine = EngineConfig(**engine_raw)
     if engine.partitions < total_parts:
         raise ValueError(

@@ -10,30 +10,30 @@ XLA offers two lowerings, both bad on TPU:
   round).
 
 The Pallas kernel instead issues ONE async DMA per (replica, partition) —
-a contiguous [B, SB] window, in place via input/output aliasing, no copy
-of the untouched log. Mosaic requires window row offsets aligned to the
-uint8 sublane tile, which the engine guarantees by construction: log_end
-only ever advances in multiples of core.config.ALIGN, and both arrays are
-viewed as [..., S/ALIGN, ALIGN, SB] so the DMA offset lives in an
-untiled dimension.
+a contiguous window of at most [B, SB], in place via input/output
+aliasing, no copy of the untouched log. Mosaic requires window row
+offsets aligned to the uint8 sublane tile, which the engine guarantees
+by construction: log_end only ever advances in multiples of
+core.config.ALIGN, and both arrays are viewed as
+[..., S/ALIGN, ALIGN, SB] so the DMA offset lives in an untiled
+dimension.
 
 Semantics contract (shared with the XLA fallback, asserted in tests):
-- the FULL B-row window is written whenever do_write[r, p]; rows at index
-  >= count carry length-0 headers (alignment padding) and the next
-  committed round overwrites whatever padding trails its own base;
+- whenever do_write[r, p], the written region is the partition's extent
+  CLASS: power-of-two ALIGN-row blocks >= the ALIGN-rounded extent, up
+  to the full B rows (see the extent-classes section below). Rows at
+  index >= count carry length-0 headers (alignment padding) and the next
+  committed round overwrites whatever padding trails its own base. Rows
+  between the class and B keep their prior bytes — they are beyond the
+  round's advance, so nothing below `commit` can ever read them.
+  `extents=None` means every window is the full B rows. Both backends
+  apply the identical class rule and stay bit-identical to each other;
 - `base` is the PHYSICAL ring position (absolute log end mod cfg.slots;
   the engine wrappers compute it) — callers guarantee base[p] % ALIGN == 0
   and base[p] + B <= S_phys (the log array's row count, which is
   cfg.slots + the B-row wrap margin; see core.state) whenever
   do_write[r, p]. The control phase's trim-gated capacity rule keeps
   live rows out of the window's reclaimable tail.
-- packed mode (`extents` given — EngineConfig.packed_writes): the
-  written region shrinks from the full B rows to the partition's
-  extent CLASS (power-of-two ALIGN-row blocks >= the ALIGN-rounded
-  extent; see the packed-extents section below). Rows between the
-  class and B keep their prior bytes — they are beyond the round's
-  advance, so nothing below `commit` can ever read them. Both backends
-  apply the identical class rule and stay bit-identical to each other.
 """
 
 from __future__ import annotations
@@ -77,20 +77,19 @@ def _pick_k(P: int, target: int = 8) -> int:
     return max(1, k)
 
 
-# --------------------------------------------------------- packed extents
+# --------------------------------------------------------- extent classes
 #
-# Length-aware write packing (EngineConfig.packed_writes): instead of
-# always moving the full [B, SB] window, clip the copy to the round's
-# payload extent. Pallas DMAs need static shapes, so the dynamic extent
-# is rounded UP to a power-of-two class of ALIGN-row blocks — one
-# predicated DMA of the matching class fires per window (never more
-# issues than the legacy path; at most 2x the true extent in bytes,
-# still proportionally fewer HBM bytes for small rounds). The XLA
-# fallback applies the SAME class rule so both backends stay
-# bit-identical, packed vs packed.
+# Length-aware writes: instead of always moving the full [B, SB] window,
+# the copy is clipped to the round's payload extent. Pallas DMAs need
+# static shapes, so the dynamic extent is rounded UP to a power-of-two
+# class of ALIGN-row blocks — one predicated DMA of the matching class
+# fires per window (one issue per window whatever its class; at most 2x
+# the true extent in bytes, proportionally fewer HBM bytes for small
+# rounds). The XLA fallback applies the SAME class rule so both backends
+# stay bit-identical.
 
 
-def _packed_classes(BA: int) -> list[int]:
+def _extent_classes(BA: int) -> list[int]:
     """Ascending copy-size classes in ALIGN-row blocks: powers of two
     plus the full window (BA itself, whether or not it is a power)."""
     sizes = set()
@@ -105,15 +104,19 @@ def _packed_classes(BA: int) -> list[int]:
 def _class_roundup(eb, BA: int):
     """Smallest class >= eb (works on scalars and vectors; eb is in
     ALIGN-row blocks, already clipped to [0, BA])."""
-    classes = _packed_classes(BA)
+    classes = _extent_classes(BA)
     pb = jnp.full_like(eb, classes[-1])
     for s in reversed(classes):
         pb = jnp.where(eb <= jnp.int32(s), jnp.int32(s), pb)
     return pb
 
 
-def _extent_blocks(extents, B: int):
-    """Host row extents [P] -> ALIGN-row block counts [P], clipped."""
+def _extent_blocks(extents, P: int, B: int):
+    """Host row extents [P] -> ALIGN-row block counts [P], clipped.
+    None (a caller that names no extent) is the full window: the top
+    class, B // ALIGN blocks, for every partition."""
+    if extents is None:
+        return jnp.full((P,), B // ALIGN, jnp.int32)
     return (jnp.clip(extents.astype(jnp.int32), 0, B) + ALIGN - 1) // ALIGN
 
 
@@ -142,90 +145,23 @@ def append_rows_xla(log_data, entries, base, do_write, extents=None):
     )
 
 
-def _kernel_active(Ka: int, BA: int, ids_ref, base_ref, dw_ref, entries_ref,
-                   log_in, log_out, sems):
+def _kernel_active(Ka: int, BA: int, ids_ref, base_ref, dw_ref, eb_ref,
+                   entries_ref, log_in, log_out, sems):
+    """One grid step: the Ka active-set entries of block c, for replica
+    r. Each listed partition that writes gets ONE DMA start, its copy
+    region clipped to the partition's extent class (see the
+    extent-classes section above); the class predicates are scalar-core
+    compares."""
     r = pl.program_id(0)
     c = pl.program_id(1)
-
-    def copy(k, a):
-        p = ids_ref[a]
-        b = base_ref[p] // ALIGN  # block-row offset; exact by contract
-        return pltpu.make_async_copy(
-            entries_ref.at[k],
-            log_out.at[r, p, pl.ds(b, BA), :, :],
-            sems.at[k],
-        )
-
-    def active(a):
-        # Padding entries carry id -1; `&` evaluates both operands, so
-        # the do_write gather must use a clamped index.
-        p = jnp.maximum(ids_ref[a], 0)
-        return (ids_ref[a] >= 0) & (dw_ref[r, p] != 0)
-
-    # UNIFORM fast path: when this block's Ka partitions are CONSECUTIVE,
-    # all active, and share one base (bulk uniform ingest — every
-    # partition of a dense round advancing in lockstep), the Ka windows
-    # form one strided region and ONE DMA covers them all. The write
-    # phase is DMA-ISSUE-bound (~0.8 µs of scalar-core work per start;
-    # R x A issues per round), so collapsing Ka issues into one is a
-    # direct multiplier on uniform traffic; mixed traffic takes the
-    # per-entry path below, unchanged.
-    p0 = ids_ref[c * Ka]
-    b0 = base_ref[jnp.maximum(p0, 0)] // ALIGN
-    uniform = jnp.bool_(Ka > 1)
-    for k in range(Ka):
-        a = c * Ka + k
-        pk = ids_ref[a]
-        uniform &= (pk == p0 + k) & active(a)
-        uniform &= base_ref[jnp.maximum(pk, 0)] // ALIGN == b0
-
-    def copy_all():
-        return pltpu.make_async_copy(
-            entries_ref.at[:],
-            log_out.at[r, pl.ds(p0, Ka), pl.ds(b0, BA), :, :],
-            sems.at[0],
-        )
-
-    @pl.when(uniform)
-    def _():
-        cp = copy_all()
-        cp.start()
-        cp.wait()
-
-    @pl.when(~uniform)
-    def _():
-        for k in range(Ka):  # static unroll; Ka is small
-            a = c * Ka + k
-
-            @pl.when(active(a))
-            def _(k=k, a=a):
-                copy(k, a).start()
-
-        for k in range(Ka):
-            a = c * Ka + k
-
-            @pl.when(active(a))
-            def _(k=k, a=a):
-                copy(k, a).wait()
-
-
-def _kernel_active_packed(Ka: int, BA: int, ids_ref, base_ref, dw_ref,
-                          eb_ref, entries_ref, log_in, log_out, sems):
-    """_kernel_active with the copy region clipped to the partition's
-    extent class (see the packed-extents section above). Identical
-    structure: a uniform fast path (one strided DMA for a whole block of
-    consecutive lockstep partitions — now additionally requiring one
-    shared extent class) and a per-entry path. Every copy remains ONE
-    DMA start per window; the class predicates are scalar-core compares,
-    so packed rounds never issue more DMAs than the legacy kernel."""
-    r = pl.program_id(0)
-    c = pl.program_id(1)
-    classes = _packed_classes(BA)
+    classes = _extent_classes(BA)
 
     def pblocks(p):
         return _class_roundup(jnp.clip(eb_ref[p], 1, BA), BA)
 
     def active(a):
+        # Padding entries carry id -1; `&` evaluates both operands, so
+        # the do_write gather must use a clamped index.
         p = jnp.maximum(ids_ref[a], 0)
         return (ids_ref[a] >= 0) & (dw_ref[r, p] != 0)
 
@@ -238,6 +174,14 @@ def _kernel_active_packed(Ka: int, BA: int, ids_ref, base_ref, dw_ref,
             sems.at[k],
         )
 
+    # UNIFORM fast path: when this block's Ka partitions are CONSECUTIVE,
+    # all active, and share one base and one extent class (bulk uniform
+    # ingest — every partition of a dense round advancing in lockstep),
+    # the Ka windows form one strided region and ONE DMA covers them
+    # all. The write phase is DMA-ISSUE-bound (~0.8 µs of scalar-core
+    # work per start; R x A issues per round), so collapsing Ka issues
+    # into one is a direct multiplier on uniform traffic; mixed traffic
+    # takes the per-entry path below.
     p0 = ids_ref[c * Ka]
     b0 = base_ref[jnp.maximum(p0, 0)] // ALIGN
     pb0 = pblocks(jnp.maximum(p0, 0))
@@ -290,17 +234,11 @@ def _append_active_pallas(log_data, entries, slot_ids, base, do_write, *,
     log_v = log_data.reshape(R, P, S // ALIGN, ALIGN, SB)
     entries_v = entries.reshape(A, BA, ALIGN, SB)
     ids = jnp.where(slot_ids >= 0, jnp.clip(slot_ids, 0, P - 1), -1)
-    packed = extents is not None
-    if packed:
-        kernel = functools.partial(_kernel_active_packed, Ka, BA)
-        scalars = (ids, base, do_write.astype(jnp.int32),
-                   _extent_blocks(extents, B))
-    else:
-        kernel = functools.partial(_kernel_active, Ka, BA)
-        scalars = (ids, base, do_write.astype(jnp.int32))
+    scalars = (ids, base, do_write.astype(jnp.int32),
+               _extent_blocks(extents, P, B))
     n_scalar = len(scalars)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=n_scalar,  # ids, base, do_write[, ext blocks]
+        num_scalar_prefetch=n_scalar,  # ids, base, do_write, ext blocks
         grid=(R, A // Ka),
         in_specs=[
             pl.BlockSpec((Ka, BA, ALIGN, SB), lambda r, c, *_: (c, 0, 0, 0)),
@@ -310,7 +248,7 @@ def _append_active_pallas(log_data, entries, slot_ids, base, do_write, *,
         scratch_shapes=[pltpu.SemaphoreType.DMA((Ka,))],
     )
     out = pl.pallas_call(
-        kernel,
+        functools.partial(_kernel_active, Ka, BA),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(log_v.shape, log_v.dtype),
         # input index = scalar-prefetch args, then entries, then log.
@@ -323,9 +261,9 @@ def _append_active_pallas(log_data, entries, slot_ids, base, do_write, *,
 def append_rows_active_xla(log_data, entries, slot_ids, base, do_write,
                            extents=None):
     """XLA fallback for the active-set write: scatter entries[a]'s rows
-    into partition slot_ids[a] (per replica). `extents` (packed mode)
-    clips each window to the partition's extent class — the same rule as
-    the packed Pallas kernel, so the two stay bit-identical."""
+    into partition slot_ids[a] (per replica). `extents` clips each
+    window to the partition's extent class — the same rule as the
+    Pallas kernel, so the two stay bit-identical."""
     if log_data.ndim == 4:
         return jax.vmap(append_rows_active_xla,
                         in_axes=(0, None, None, None, 0, None))(
@@ -336,11 +274,9 @@ def append_rows_active_xla(log_data, entries, slot_ids, base, do_write,
     ids = jnp.clip(slot_ids, 0, P - 1)
     write = (slot_ids >= 0) & jnp.take(do_write, ids)          # [A]
     rows = jnp.arange(B, dtype=jnp.int32)[None, :]             # [1, B]
-    in_window = write[:, None]
-    if extents is not None:
-        eb = jnp.clip(_extent_blocks(extents, B), 1, B // ALIGN)
-        rows_lim = _class_roundup(eb, B // ALIGN) * ALIGN      # [P]
-        in_window = in_window & (rows < jnp.take(rows_lim, ids)[:, None])
+    eb = jnp.clip(_extent_blocks(extents, P, B), 1, B // ALIGN)
+    rows_lim = _class_roundup(eb, B // ALIGN) * ALIGN          # [P]
+    in_window = write[:, None] & (rows < jnp.take(rows_lim, ids)[:, None])
     ridx = jnp.where(in_window, jnp.take(base, ids)[:, None] + rows, S)
     pidx = jnp.broadcast_to(ids[:, None], (A, B))
     return log_data.at[pidx, ridx].set(entries, mode="drop")
@@ -359,8 +295,8 @@ def append_rows_active(log_data, entries, slot_ids, base, do_write, *,
     and input transfer rides every dispatch.
 
     Same contracts as append_rows (`base` physical, ALIGN-aligned;
-    full-B windows — or extent-class windows when `extents` is given;
-    do_write [R, P]); additionally each partition appears at most once
+    extent-class windows, full-B where `extents` is None; do_write
+    [R, P]); additionally each partition appears at most once
     in slot_ids per round."""
     if use_pallas is None:
         use_pallas = append_backend(log_data.shape[-1]) == "pallas"
@@ -382,8 +318,8 @@ def append_rows(log_data, entries, base, do_write, *, extents=None,
     Inputs: log_data [R, P, S, SB] (donated/aliased in place on the pallas
     path), entries [P, B, SB] packed rows, base [P] (leader log end,
     replica-invariant, ALIGN-aligned), do_write [R, P] bool, extents [P]
-    rows (packed mode: clip each window to the partition's extent class;
-    None = full legacy windows).
+    rows (each window is clipped to the partition's extent class; None =
+    full windows).
     """
     if use_pallas is None:
         use_pallas = append_backend(log_data.shape[-1]) == "pallas"

@@ -44,32 +44,32 @@ from ripplemq_tpu.ops.append import (
 
 
 class LocalEngineFns(NamedTuple):
-    init: Callable[[], ReplicaState]          # -> state with leading [R] axis
-    step: Callable[..., tuple[ReplicaState, StepOutput]]
-    step_many: Callable[..., tuple[ReplicaState, StepOutput]]  # chained rounds
-    step_sparse: Callable[..., tuple[ReplicaState, StepOutput]]  # active-set
-    step_many_sparse: Callable[..., tuple[ReplicaState, StepOutput]]
-    vote: Callable[..., tuple[ReplicaState, jax.Array, jax.Array]]
+    init: Callable[[], FusedReplicaState]     # -> state with leading [R] axis
+    step: Callable[..., tuple[FusedReplicaState, StepOutput]]
+    step_many: Callable[..., tuple[FusedReplicaState, StepOutput]]  # chained rounds
+    step_sparse: Callable[..., tuple[FusedReplicaState, StepOutput]]  # active-set
+    step_many_sparse: Callable[..., tuple[FusedReplicaState, StepOutput]]
+    vote: Callable[..., tuple[FusedReplicaState, jax.Array, jax.Array]]
     read: Callable[..., tuple[jax.Array, jax.Array, jax.Array]]
     read_many: Callable[..., tuple[jax.Array, jax.Array, jax.Array]]  # batched
     read_offset: Callable[..., jax.Array]
-    resync: Callable[..., ReplicaState]
-    init_from: Callable[[ReplicaState], ReplicaState]  # single-replica image -> [R] state
+    resync: Callable[..., FusedReplicaState]
+    init_from: Callable[[ReplicaState], FusedReplicaState]  # single-replica image -> [R] state
     append_backend: str  # "pallas" | "xla" — the write phase compiled in
 
 
 class SpmdEngineFns(NamedTuple):
-    init: Callable[[], ReplicaState]
-    step: Callable[..., tuple[ReplicaState, StepOutput]]
-    step_many: Callable[..., tuple[ReplicaState, StepOutput]]
-    step_sparse: Callable[..., tuple[ReplicaState, StepOutput]]
-    step_many_sparse: Callable[..., tuple[ReplicaState, StepOutput]]
-    vote: Callable[..., tuple[ReplicaState, jax.Array, jax.Array]]
+    init: Callable[[], FusedReplicaState]
+    step: Callable[..., tuple[FusedReplicaState, StepOutput]]
+    step_many: Callable[..., tuple[FusedReplicaState, StepOutput]]
+    step_sparse: Callable[..., tuple[FusedReplicaState, StepOutput]]
+    step_many_sparse: Callable[..., tuple[FusedReplicaState, StepOutput]]
+    vote: Callable[..., tuple[FusedReplicaState, jax.Array, jax.Array]]
     read: Callable[..., tuple[jax.Array, jax.Array, jax.Array]]
     read_many: Callable[..., tuple[jax.Array, jax.Array, jax.Array]]
     read_offset: Callable[..., jax.Array]
-    resync: Callable[..., ReplicaState]
-    init_from: Callable[[ReplicaState], ReplicaState]
+    resync: Callable[..., FusedReplicaState]
+    init_from: Callable[[ReplicaState], FusedReplicaState]
     append_backend: str
     mesh: Mesh
 
@@ -111,46 +111,31 @@ def make_local_fns(cfg: EngineConfig) -> LocalEngineFns:
     rep_idx = jnp.arange(R, dtype=jnp.int32)
     default_quorum = jnp.full((cfg.partitions,), cfg.quorum, jnp.int32)
 
-    # cfg.fused_control swaps the control phase AND the state layout: the
-    # bookkeeping scalars ride one stacked [R, K, P] ctrl array
-    # (core.state.FusedReplicaState) advanced by wide fused ops
-    # (core.step.replica_control_fused). Bit-identical semantics either
-    # way (tests/test_control_fusion.py); the read paths work on both
-    # layouts through FusedReplicaState's named accessors.
-    fused = cfg.fused_control
-    ctrl_fn = (core_step.replica_control_fused if fused
-               else core_step.replica_control)
-    vote_fn = core_step.vote_step_fused if fused else core_step.vote_step
-
+    # The state is the stacked layout (core.state.FusedReplicaState): the
+    # bookkeeping scalars ride one [R, K, P] ctrl array; the read paths
+    # go through its named accessors.
     @jax.jit
     def _init():
-        one = init_state(cfg)
-        if fused:
-            one = fuse_state(one)
+        one = fuse_state(init_state(cfg))
         return jax.tree.map(lambda x: jnp.broadcast_to(x, (R,) + x.shape).copy(), one)
 
     vctrl = jax.vmap(
-        functools.partial(ctrl_fn, cfg),
+        functools.partial(core_step.replica_control, cfg),
         in_axes=(0, None, 0, None, None, None),
         axis_name=core_step.AXIS,
     )
     default_trim = jnp.zeros((cfg.partitions,), jnp.int32)
 
-    def _ext(ctl):
-        # Packed write windows (cfg.packed_writes): the control phase
-        # derived the replica-invariant extent; None keeps the legacy
-        # full-window kernels byte-for-byte untouched.
-        return ctl.extent[0] if cfg.packed_writes else None
-
     @functools.partial(jax.jit, donate_argnums=(0,))
     def _step_j(state, inp: StepInput, alive, quorum, trim):
         # Control phase per replica (vmapped), then ONE batched write phase
         # on the full [R, P, S+B, SB] ring (Pallas DMA kernel on TPU; the
-        # window lands at the physical ring position base % slots).
+        # window lands at the physical ring position base % slots and
+        # covers the round's extent, replica-invariant like base).
         new_state, ctl = vctrl(state, inp, rep_idx, alive, quorum, trim)
         log_data = append_rows(
             state.log_data, inp.entries, ctl.out.base[0] % cfg.slots,
-            ctl.do_write, extents=_ext(ctl), use_pallas=pallas
+            ctl.do_write, extents=ctl.extent[0], use_pallas=pallas
         )
         new_state = new_state._replace(log_data=log_data)
         # outputs are replica-invariant after the psum; take replica 0's copy
@@ -177,7 +162,7 @@ def make_local_fns(cfg: EngineConfig) -> LocalEngineFns:
             new_st, ctl = vctrl(st, inp, rep_idx, alive, quorum, trim)
             log = append_rows(
                 st.log_data, inp.entries, ctl.out.base[0] % cfg.slots,
-                ctl.do_write, extents=_ext(ctl), use_pallas=pallas
+                ctl.do_write, extents=ctl.extent[0], use_pallas=pallas
             )
             return (
                 new_st._replace(log_data=log),
@@ -202,7 +187,7 @@ def make_local_fns(cfg: EngineConfig) -> LocalEngineFns:
         new_state, ctl = vctrl(state, inp, rep_idx, alive, quorum, trim)
         log_data = append_rows_active(
             state.log_data, entries_c, slot_ids,
-            ctl.out.base[0] % cfg.slots, ctl.do_write, extents=_ext(ctl),
+            ctl.out.base[0] % cfg.slots, ctl.do_write, extents=ctl.extent[0],
             use_pallas=pallas,
         )
         new_state = new_state._replace(log_data=log_data)
@@ -222,7 +207,7 @@ def make_local_fns(cfg: EngineConfig) -> LocalEngineFns:
             new_st, ctl = vctrl(st, inp, rep_idx, alive, quorum, trim)
             log = append_rows_active(
                 st.log_data, ec, ids, ctl.out.base[0] % cfg.slots,
-                ctl.do_write, extents=_ext(ctl), use_pallas=pallas
+                ctl.do_write, extents=ctl.extent[0], use_pallas=pallas
             )
             return (
                 new_st._replace(log_data=log),
@@ -239,7 +224,7 @@ def make_local_fns(cfg: EngineConfig) -> LocalEngineFns:
             default_trim if trim is None else trim)
 
     vvote = jax.vmap(
-        functools.partial(vote_fn, cfg),
+        functools.partial(core_step.vote_step, cfg),
         in_axes=(0, None, None, 0, None, None),
         axis_name=core_step.AXIS,
     )
@@ -283,15 +268,13 @@ def make_local_fns(cfg: EngineConfig) -> LocalEngineFns:
 
     @functools.partial(jax.jit, donate_argnums=(0,))
     def _resync_fn(state, src, dst, part_mask):
-        if fused:
-            # _resync's masking assumes [R, P, ...] leaves; the fused
-            # ctrl leaf is [R, K, P]. Resync is the rare recovery path,
-            # so round-trip through the named layout instead of teaching
-            # the masking about the stacked axis.
-            return fuse_state(
-                _resync(cfg, unfuse_state(state), src, dst, part_mask)
-            )
-        return _resync(cfg, state, src, dst, part_mask)
+        # _resync's masking assumes [R, P, ...] leaves; the ctrl leaf is
+        # [R, K, P]. Resync is the rare recovery path, so round-trip
+        # through the named layout instead of teaching the masking about
+        # the stacked axis.
+        return fuse_state(
+            _resync(cfg, unfuse_state(state), src, dst, part_mask)
+        )
 
     def _init_from(image: ReplicaState):
         """Install a recovered single-replica image on every replica slot
@@ -302,7 +285,7 @@ def make_local_fns(cfg: EngineConfig) -> LocalEngineFns:
             lambda x: jnp.asarray(np.broadcast_to(np.asarray(x), (R,) + np.asarray(x).shape)),
             image,
         )
-        return fuse_state(full) if fused else full
+        return fuse_state(full)
 
     return LocalEngineFns(_init, _step, _step_many, _step_sparse,
                           _step_many_sparse, _vote, _read, _read_many,
@@ -313,28 +296,15 @@ def make_local_fns(cfg: EngineConfig) -> LocalEngineFns:
 # SPMD (mesh: replica × part)
 # ---------------------------------------------------------------------------
 
-def _state_specs(cfg: EngineConfig) -> ReplicaState:
-    """PartitionSpecs for the full-cluster state [R, P, ...]: replica axis
-    over "replica", partition axis over "part"."""
-    return ReplicaState(
-        log_data=P("replica", "part", None, None),
-        log_end=P("replica", "part"),
-        last_term=P("replica", "part"),
-        current_term=P("replica", "part"),
-        commit=P("replica", "part"),
-        offsets=P("replica", "part", None),
-    )
-
-
-def _fused_state_specs(cfg: EngineConfig) -> FusedReplicaState:
-    """PartitionSpecs for the fused-control state (cfg.fused_control):
-    the stacked ctrl buffer is [R, K, P] — replica axis sharded, the K
-    bookkeeping rows replicated WITHIN a device, partition axis sharded
-    over "part". Each device then holds its shard's whole [K, local_P]
-    bookkeeping block, so a round's four scalar advances stay ONE wide
-    select on one local buffer and the two leader broadcasts ride ONE
-    [2, local_P] psum over the replica mesh axis (one ICI collective
-    where the legacy control phase issues two)."""
+def _state_specs() -> FusedReplicaState:
+    """PartitionSpecs for the full-cluster state: the log and the offset
+    table are [R, P, ...] (replica axis over "replica", partition axis
+    over "part"); the stacked ctrl buffer is [R, K, P] — replica axis
+    sharded, the K bookkeeping rows whole WITHIN a device, partition
+    axis over "part". Each device then holds its shard's whole
+    [K, local_P] bookkeeping block, so a round's four scalar advances
+    stay ONE wide select on one local buffer and the two leader
+    broadcasts ride ONE [2, local_P] psum over the replica mesh axis."""
     return FusedReplicaState(
         log_data=P("replica", "part", None, None),
         ctrl=P("replica", None, "part"),
@@ -359,33 +329,6 @@ def _input_specs() -> StepInput:
         term=P("part"),
         extents=P("part"),
     )
-
-
-
-def spmd_arg_shardings(mesh: Mesh, chain: bool = False):
-    """NamedShardings for staging step arguments on an spmd mesh:
-    ``(inp, alive, quorum, trim)`` keyed by name. Bench/profile harnesses
-    COMMIT inputs to these before a timed window — device arrays with
-    unspecified shardings make every call re-resolve shardings on the
-    python dispatch path (measured -12% on the spmd side only,
-    bench._run_spmd_parity). The broker needs no staging (it hands the
-    binding fresh host numpy arrays each round); this is for resident-
-    input measurement loops. ``chain=True`` prefixes the unsharded chain
-    axis the step_many scan inputs carry."""
-    in_specs = _input_specs()
-    if chain:
-        in_specs = jax.tree.map(
-            lambda s: P(*((None,) + tuple(s))), in_specs,
-            is_leaf=lambda s: isinstance(s, P),
-        )
-    named = lambda s: NamedSharding(mesh, s)
-    return {
-        "inp": jax.tree.map(named, in_specs,
-                            is_leaf=lambda s: isinstance(s, P)),
-        "alive": named(P("part", None)),
-        "quorum": named(P("part")),
-        "trim": named(P("part")),
-    }
 
 
 def _smap(f, mesh, in_specs, out_specs):
@@ -414,18 +357,6 @@ def make_spmd_fns(cfg: EngineConfig, mesh: Mesh) -> SpmdEngineFns:
                              mesh.devices.flat[0].platform)
     pallas = backend == "pallas"
 
-    # cfg.fused_control under shard_map: the same stacked-ctrl layout and
-    # fused ops as the local binding (core.step.replica_control_fused),
-    # with fused PartitionSpecs — the two leader broadcasts become ONE
-    # real [2, local_P] psum on the replica mesh axis (one ICI collective
-    # per round where the legacy control phase issues two). Bit-identical
-    # committed prefixes to both the legacy-spmd and fused-vmap paths
-    # (tests/test_spmd.py parity matrix).
-    fused = cfg.fused_control
-    ctrl_fn = (core_step.replica_control_fused if fused
-               else core_step.replica_control)
-    vote_fn = core_step.vote_step_fused if fused else core_step.vote_step
-
     # The ring-stride aliasing rule priced at the PER-DEVICE shape: each
     # mesh device holds ONE replica's [local_P, S+B, SB] ring block, so
     # local_P is the concurrent strided-DMA stream count — the global-P
@@ -439,7 +370,7 @@ def make_spmd_fns(cfg: EngineConfig, mesh: Mesh) -> SpmdEngineFns:
             f"rings; {hazard}", UserWarning, stacklevel=2,
         )
 
-    st_specs = _fused_state_specs(cfg) if fused else _state_specs(cfg)
+    st_specs = _state_specs()
     in_specs = _input_specs()
     rep_ids = jnp.arange(R, dtype=jnp.int32)
 
@@ -464,8 +395,9 @@ def make_spmd_fns(cfg: EngineConfig, mesh: Mesh) -> SpmdEngineFns:
     def _fill_extents(inp: StepInput) -> StepInput:
         """Hand-built inputs may leave extents=None (pytree-empty); the
         compiled specs carry a per-part extents shard, so fill with the
-        full window (== the legacy write shape). Chained inputs carry
-        the leading chain axis on every leaf, counts included."""
+        full window (what core.step._write_extent makes of None). Chained
+        inputs carry the leading chain axis on every leaf, counts
+        included."""
         if inp.extents is not None:
             return inp
         return inp._replace(
@@ -496,15 +428,13 @@ def make_spmd_fns(cfg: EngineConfig, mesh: Mesh) -> SpmdEngineFns:
     # ---- step -------------------------------------------------------------
     def step_body(state, inp, rep, alive, quorum, trim):
         st = _squeeze(state)          # strip the size-1 replica block dim
-        new_st, ctl = ctrl_fn(
+        new_st, ctl = core_step.replica_control(
             cfg, st, inp, rep[0], alive, quorum, trim
         )
         # Write phase on this device's [1, P_local, S+B, SB] ring block.
         log_data = append_rows(
             st.log_data[None], inp.entries, ctl.out.base % cfg.slots,
-            ctl.do_write[None],
-            extents=ctl.extent if cfg.packed_writes else None,
-            use_pallas=pallas,
+            ctl.do_write[None], extents=ctl.extent, use_pallas=pallas,
         )
         new_st = new_st._replace(log_data=log_data[0])
         # out is psum-replicated over "replica"; gather it over "part".
@@ -573,14 +503,13 @@ def make_spmd_fns(cfg: EngineConfig, mesh: Mesh) -> SpmdEngineFns:
     def step_sparse_body(state, inp, entries_c, slot_ids, rep, alive,
                          quorum, trim):
         st = _squeeze(state)
-        new_st, ctl = ctrl_fn(
+        new_st, ctl = core_step.replica_control(
             cfg, st, inp, rep[0], alive, quorum, trim
         )
         log_data = append_rows_active(
             st.log_data[None], entries_c, _local_ids(slot_ids),
             ctl.out.base % cfg.slots, ctl.do_write[None],
-            extents=ctl.extent if cfg.packed_writes else None,
-            use_pallas=pallas,
+            extents=ctl.extent, use_pallas=pallas,
         )
         new_st = new_st._replace(log_data=log_data[0])
         return _expand(new_st), _gather_part(ctl.out)
@@ -640,7 +569,7 @@ def make_spmd_fns(cfg: EngineConfig, mesh: Mesh) -> SpmdEngineFns:
     # ---- vote -------------------------------------------------------------
     def vote_body(state, cand, cand_term, rep, alive, quorum):
         st = _squeeze(state)
-        new_st, elected, votes = vote_fn(
+        new_st, elected, votes = core_step.vote_step(
             cfg, st, cand, cand_term, rep[0], alive, quorum
         )
         elected, votes = _gather_part((elected, votes))
@@ -754,14 +683,12 @@ def make_spmd_fns(cfg: EngineConfig, mesh: Mesh) -> SpmdEngineFns:
 
     # ---- resync -----------------------------------------------------------
     def resync_body(state, rep, src, dst, part_mask):
-        st = _squeeze(state)
-        if fused:
-            # The masking below assumes [local_P, ...] leaves; the fused
-            # ctrl leaf is [K, local_P]. Resync is the rare recovery
-            # path, so round-trip through the named layout (exact both
-            # ways) instead of teaching the masking about the stacked
-            # axis — the same trade the local binding makes.
-            st = unfuse_state(st)
+        # The masking below assumes [local_P, ...] leaves; the ctrl leaf
+        # is [K, local_P]. Resync is the rare recovery path, so
+        # round-trip through the named layout (exact both ways) instead
+        # of teaching the masking about the stacked axis — the same
+        # trade the local binding makes.
+        st = unfuse_state(_squeeze(state))
         my_rep = rep[0]
         # broadcast src replica's masked rows to everyone, then overwrite dst
         def leaf(x):
@@ -771,10 +698,7 @@ def make_spmd_fns(cfg: EngineConfig, mesh: Mesh) -> SpmdEngineFns:
             )
             return jnp.where((my_rep == dst) & m, src_rows, x)
 
-        new_st = jax.tree.map(leaf, st)
-        if fused:
-            new_st = fuse_state(new_st)
-        return _expand(new_st)
+        return _expand(fuse_state(jax.tree.map(leaf, st)))
 
     smapped_resync = _smap(
         resync_body,
@@ -789,12 +713,11 @@ def make_spmd_fns(cfg: EngineConfig, mesh: Mesh) -> SpmdEngineFns:
 
     # ---- init -------------------------------------------------------------
     def _place(one: ReplicaState):
-        """Install a single-replica image (always the NAMED layout — the
+        """Install a single-replica image (the NAMED layout — the
         recovery path hands plain ReplicaStates) on every replica slot,
-        sharded per st_specs; fused configs stack the ctrl scalars
-        first so the placed state matches the compiled layout."""
-        if fused:
-            one = fuse_state(one)
+        sharded per st_specs; the ctrl scalars are stacked first so the
+        placed state matches the compiled layout."""
+        one = fuse_state(one)
         full = jax.tree.map(
             lambda x: jnp.broadcast_to(jnp.asarray(x), (R,) + jnp.asarray(x).shape),
             one,

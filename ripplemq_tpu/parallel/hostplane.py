@@ -800,14 +800,6 @@ class HostPlane:
         with self._lock:
             return list(self._gens)
 
-    def worker_pids(self) -> list[int]:
-        """OS pids of the live worker subprocesses (bench CPU
-        accounting; dead/respawning slots are skipped)."""
-        with self._lock:
-            workers = list(self._workers)
-        return [w.proc.pid for w in workers
-                if w is not None and not w.dead and w.proc.pid is not None]
-
     def stats(self, ping_timeout_s: float = 0.5) -> dict:
         """Liveness/occupancy snapshot (admin.stats `host_plane`)."""
         with self._lock:

@@ -117,12 +117,11 @@ class LockstepController:
         # Workers build their engine from this exact shape (no local op
         # to overlap: configure launches nothing on the mesh).
         with self._lock:
-            # bools stay bools (fused_control/packed_writes) so the
-            # worker rebuilds the EXACT EngineConfig — a mesh whose
-            # processes disagree on the compiled program deadlocks.
+            # The worker rebuilds the EXACT EngineConfig (every field is
+            # an int) — a mesh whose processes disagree on the compiled
+            # program deadlocks.
             futs = self._send("configure", [
-                {k: (bool(v) if isinstance(v, bool) else int(v))
-                 for k, v in cfg.__dict__.items()},
+                {k: int(v) for k, v in cfg.__dict__.items()},
                 int(part_shards),
             ])
         self._check(futs)
@@ -285,8 +284,8 @@ class LockstepController:
         """Materialize one process-sharded state leaf on the host. The
         allgather is itself a global-mesh collective, so it must be
         broadcast like any other call — a bare np.asarray on the
-        controller would hang waiting for the workers. Fused-control
-        states serve the named scalars (log_end/current_term/commit) as
+        controller would hang waiting for the workers. The state
+        serves the named scalars (log_end/current_term/commit) as
         ctrl-buffer views (core.state.FusedReplicaState properties) —
         the slice is along the unsharded K axis, and controller and
         workers launch the identical getattr, so the mesh stays in
@@ -340,10 +339,7 @@ class LockstepWorker:
             from ripplemq_tpu.parallel.mesh import make_mesh
 
             cfg_dict, part_shards = args
-            cfg = EngineConfig(**{
-                k: (v if isinstance(v, bool) else int(v))
-                for k, v in cfg_dict.items()
-            })
+            cfg = EngineConfig(**{k: int(v) for k, v in cfg_dict.items()})
             mesh = make_mesh(cfg.replicas, int(part_shards))
             self._fns = make_spmd_fns(cfg, mesh)
             self._cfg = cfg

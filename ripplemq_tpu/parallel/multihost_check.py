@@ -62,13 +62,11 @@ def main(argv: list[str] | None = None) -> int:
     n = init_distributed(args.coordinator, args.num_hosts, args.host_index)
     replicas, part_shards = pick_axes(n)
     P = 2 * part_shards
-    # Production levers on: the DCN proof must cover the binding
-    # deployments run — fused control's stacked leader-broadcast psum is
-    # the collective that crosses the process boundary here (ISSUE 6).
+    # The control phase's stacked leader-broadcast psum is the
+    # collective that crosses the process boundary here.
     cfg = EngineConfig(
         partitions=P, replicas=replicas, slots=64, slot_bytes=32,
         max_batch=8, read_batch=8, max_consumers=8, max_offset_updates=4,
-        fused_control=True, packed_writes=True,
     )
     mesh = make_mesh(replicas, part_shards)
     fns = make_spmd_fns(cfg, mesh)

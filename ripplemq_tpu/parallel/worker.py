@@ -12,10 +12,9 @@ lockstep broadcast always lands), then joins the jax.distributed mesh
 (which blocks until every host arrives), then replays the controller's
 engine-call stream (parallel.lockstep) until terminated. The engine
 shape arrives in the controller's `configure` call — no shape flags
-needed here — including the fused_control/packed_writes levers, so the
-worker compiles the EXACT program (fused state layout included) the
-controller launches; a mesh whose processes disagree on the compiled
-program deadlocks at the first collective.
+needed here — so the worker compiles the EXACT program the controller
+launches; a mesh whose processes disagree on the compiled program
+deadlocks at the first collective.
 """
 
 from __future__ import annotations

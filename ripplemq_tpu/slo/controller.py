@@ -136,9 +136,10 @@ class SloController:
         self.cd_min = int(config.slo_chain_depth_min)
         self.cd_max = int(config.slo_chain_depth_max)
         self.sw_min = int(config.slo_settle_window_min)
-        # A measured prior (bench.py operating_curve writes one) narrows
-        # the static config rails so the AIMD law starts from this
-        # deployment's observed knee instead of the shipped defaults.
+        # A measured prior narrows the static config rails so the AIMD law
+        # starts from this deployment's observed knee instead of the
+        # shipped defaults (no script in the tree writes one since PR 29
+        # deleted bench.py's operating_curve; _load_rails has the format).
         # Best-effort: a missing or malformed file keeps the config
         # rails — a stale prior must never stop a broker from booting.
         self._load_rails(str(getattr(config, "slo_rails_file", "") or ""))
@@ -213,8 +214,10 @@ class SloController:
     # ------------------------------------------------------------ rails
 
     def _load_rails(self, path: str) -> None:
-        """Narrow the config rails from a measured prior (JSON written by
-        `python bench.py operating_curve`). Keys are optional; each one
+        """Narrow the config rails from a measured prior: a JSON object
+        with any of the keys below (tests/test_slo.py pins the format; the
+        script that wrote such files went with bench.py in PR 29). Keys
+        are optional; each one
         present replaces the matching rail, then the pairs are re-ordered
         so a prior measured under a different build can never produce an
         inverted rail. Any failure keeps the config rails."""

@@ -11,7 +11,7 @@ cache was placed from outside and JAX reads the variable itself — no
 directory is configured in code. Otherwise the cache goes to ONE fixed,
 git-ignored directory inside the checkout. Every entry point that can
 own a device calls this before its first compile (broker/__main__.py,
-parallel/worker.py, bench.py, chip_smoke.py's kernel child). A process
+parallel/worker.py, chip_smoke.py's kernel child). A process
 started with `JAX_PLATFORMS=cpu` owns no device by the process rule
 (standbys, clients, every test child): its programs build in
 milliseconds, and the brokers of one cluster would only race each other

@@ -6,8 +6,8 @@ Tests run on the CPU backend, chosen from outside the program
 through `jax.config` so a platform list inherited from the environment
 cannot win). Multi-chip behavior (replica mesh axis, partition
 sharding, psum quorum) is exercised on the virtual CPU mesh; the chip
-itself is driven only by `chip_smoke.py` (and `bench.py`), one process
-per chip.
+itself is driven only by `chip_smoke.py` and the benchmark
+(`benchmarks/run.py`), one process per chip.
 """
 
 import os

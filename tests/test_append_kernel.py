@@ -175,14 +175,14 @@ def test_pallas_uniform_fast_path_matches_xla():
     np.testing.assert_array_equal(got, want)
 
 
-# ------------------------------------------------------------ packed writes
+# ----------------------------------------------------------- extent classes
 #
-# EngineConfig.packed_writes: the copy region is clipped to the round's
-# extent, rounded UP to a power-of-two class of ALIGN-row blocks (both
-# backends apply the same rule — ops/append.py packed-extents section).
-# The packed Pallas kernel must stay bit-identical to the packed XLA
-# fallback on the FULL log; against the unpacked reference, rows below
-# the extent class must match and rows above it must be untouched.
+# The copy region is clipped to the round's extent, rounded UP to a
+# power-of-two class of ALIGN-row blocks (both backends apply the same
+# rule — ops/append.py extent-classes section). The Pallas kernel must
+# stay bit-identical to the XLA fallback on the FULL log; against a
+# full-window write (extents=None), rows below the extent class must
+# match and rows above it must be untouched.
 
 def _packed_rows_ref(extent, B):
     """Python reference of the class rule: smallest power-of-two block
@@ -213,7 +213,7 @@ def test_packed_pallas_matches_packed_xla_randomized(seed):
 
 
 @pytest.mark.parametrize("seed", range(4))
-def test_packed_writes_prefix_and_untouched_tail(seed):
+def test_extent_class_prefix_and_untouched_tail(seed):
     """Packed output == unpacked output on rows below each partition's
     extent class, and == the PRIOR log bytes above it (the packed mode's
     whole point: those bytes are never moved)."""
@@ -286,9 +286,9 @@ def test_packed_mixed_extent_classes_demote_uniform_block():
     np.testing.assert_array_equal(got, want)
 
 
-def test_packed_full_extent_equals_legacy():
-    """extents == B everywhere must reproduce the legacy full-window
-    write exactly (the packed path's identity case)."""
+def test_packed_full_extent_equals_none():
+    """extents == B everywhere must reproduce the extents=None
+    full-window write exactly (the top class is the whole window)."""
     rng = np.random.default_rng(13)
     log, entries, base, do_write = rand_case(rng)
     P, B = entries.shape[0], entries.shape[1]

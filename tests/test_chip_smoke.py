@@ -49,6 +49,28 @@ def test_tiny_smoke_passes_functionally_and_refuses_the_device():
     assert "device check REFUSED" in res.stdout
 
 
+def test_smoke_deployment_is_the_benchmark_configs_cluster_block():
+    """The smoke boots what the omb-1024p-100b cells boot: the config
+    file's `cluster` block and topics, nothing added or changed but one
+    broker per port."""
+    sys.path.insert(0, REPO)
+    try:
+        import chip_smoke
+    finally:
+        sys.path.remove(REPO)
+    with open(os.path.join(REPO, "benchmarks", "configs",
+                           "omb-1024p-100b.json")) as f:
+        config = json.load(f)
+    raw = chip_smoke.deployment_raw([7001, 7002, 7003])
+    assert [b["port"] for b in raw.pop("brokers")] == [7001, 7002, 7003]
+    assert raw.pop("topics") == config["deployment"]["topics"]
+    assert raw == config["cluster"]
+    # What the smoke's own checks lean on: a ring its 16,384 extra
+    # messages lap three times, and a segment size it can read.
+    assert 3 * raw["engine"]["slots"] <= 16384
+    assert raw["segment_bytes"] == 64 << 20
+
+
 _PROBE = (
     "import json, jax\n"
     "from ripplemq_tpu.utils.compile_cache import configure_compile_cache\n"

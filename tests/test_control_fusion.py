@@ -1,414 +1,356 @@
-"""Parity suite for the fused control phase and packed write path.
+"""The one round program against the Python model, on both bindings.
 
-EngineConfig.fused_control restructures the round's bookkeeping (stacked
-[K, P] ctrl array, wide fused ops — core.step.replica_control_fused) and
-EngineConfig.packed_writes clips append DMA windows to the round's
-payload extent (ops/append.py packed mode). Both are PERF levers: their
-contract is bit-identical behavior with the legacy path. This suite
-replays one scripted history — empty rounds, partial batches, full
-batches, quorum failures, leaderless partitions, offset-commit blends,
-capacity backpressure, a trim-gated ring wrap, chained dispatches,
-sparse (active-set) dispatches, an election and a resync — through every
-flag combination on the CPU backend and asserts:
+The device round has one control phase (bookkeeping on a stacked [K, P]
+ctrl array — core.step.replica_control), one on-device state layout
+(core.state.FusedReplicaState) and one write mode (append DMA windows
+clipped to the round's extent class — ops/append.py). This suite replays
+one scripted history — empty rounds, partial batches, full batches,
+quorum failures, leaderless partitions, offset-commit blends, capacity
+backpressure, a trim-gated ring wrap, a chained dispatch, an election, a
+resync and a sparse (active-set) dispatch — through the vmap binding and
+a 3 x 2 shard_map mesh, beside `tests/round_model.Model`, an independent
+pure-Python mirror of the rules, and asserts against the MODEL:
 
-- every StepOutput of every round is bit-identical;
+- every StepOutput of every round;
 - every scalar state field (log_end/last_term/current_term/commit) and
-  the offsets table are bit-identical after every phase;
-- the COMMITTED log prefix is byte-identical (packed mode legitimately
-  leaves bytes beyond the write extent untouched — those rows are past
-  log_end and unreadable by contract, so full-log equality is asserted
-  only for the unpacked variants).
+  the offsets table, per replica, after every phase;
+- the committed log prefix, row by row (bytes beyond a round's extent
+  class keep their prior content and are unreadable by contract, so the
+  comparison stops at each replica's commit index).
+
+Beside it: a chained launch lands where its rounds land one by one, a
+sparse round lands where the dense round lands, an input without extents
+means the full window on both bindings, and the named recovery image
+goes in and comes out unchanged.
 """
 
 from __future__ import annotations
 
+import jax
 import numpy as np
 import pytest
 
 from ripplemq_tpu.core.config import EngineConfig
-from ripplemq_tpu.core.encode import build_step_input
-from ripplemq_tpu.core.state import fuse_state, unfuse_state
-from ripplemq_tpu.parallel.engine import make_local_fns
+from ripplemq_tpu.core.encode import build_step_input, decode_entries
+from ripplemq_tpu.core.state import (
+    ReplicaState,
+    StepInput,
+    fuse_state,
+    unfuse_state,
+)
+from ripplemq_tpu.parallel.engine import make_local_fns, make_spmd_fns
+from ripplemq_tpu.parallel.mesh import make_mesh
+from tests.round_model import Model
 
-BASE = dict(
+# max_batch is two ALIGN blocks, so partial rounds write the one-block
+# extent class and full batches the two-block one.
+CFG = EngineConfig(
     partitions=4,
     replicas=3,
     slots=64,
     slot_bytes=32,
-    max_batch=8,
-    read_batch=8,
+    max_batch=16,
+    read_batch=16,
     max_consumers=8,
     max_offset_updates=4,
 )
-
-VARIANTS = {
-    "legacy": {},
-    "fused": dict(fused_control=True),
-    "packed": dict(packed_writes=True),
-    "fused+packed": dict(fused_control=True, packed_writes=True),
-}
+BINDINGS = ("vmap", "spmd")
 
 ALL = np.ones((3,), bool)
 MINORITY = np.array([True, False, False])
 MAJORITY = np.array([True, True, False])
 
-# (appends, offset_updates, leader, term, alive) per round — the
+# (appends / offset_updates, leader, term, alive) per round — the
 # scenario mix the docstring promises.
 SCRIPT = [
     # partial batch on one partition
-    (dict(appends={0: [b"a", b"b", b"c"]}), None, 0, 1, ALL),
+    (dict(appends={0: [b"a", b"b", b"c"]}), 0, 1, ALL),
     # offset blend riding an append + an offsets-only partition
     (dict(appends={1: [b"x"]}, offset_updates={0: [(1, 3)], 2: [(0, 7)]}),
-     None, 0, 1, ALL),
+     0, 1, ALL),
     # empty round (no work anywhere): nothing acks
-    (dict(), None, 0, 1, ALL),
+    (dict(), 0, 1, ALL),
     # leaderless partitions
-    (dict(appends={0: [b"noleader"]}), None, -1, 1, ALL),
+    (dict(appends={0: [b"noleader"]}), -1, 1, ALL),
     # quorum failure: minority alive
-    (dict(appends={0: [b"minority"]}), None, 0, 1, MINORITY),
+    (dict(appends={0: [b"minority"]}), 0, 1, MINORITY),
     # majority commit after the failure (retry semantics)
-    (dict(appends={0: [b"retry"]}), None, 0, 1, MAJORITY),
+    (dict(appends={0: [b"retry"]}), 0, 1, MAJORITY),
     # full batch, term bump
-    (dict(appends={2: [b"f%d" % i for i in range(8)]}), None, 1, 2, ALL),
+    (dict(appends={2: [b"f%d" % i for i in range(CFG.max_batch)]}), 1, 2, ALL),
     # offsets-only round on an idle partition
-    (dict(offset_updates={3: [(0, 5), (2, 9)]}), None, 0, 2, ALL),
+    (dict(offset_updates={3: [(0, 5), (2, 9)]}), 0, 2, ALL),
     # dead leader: no progress
-    (dict(appends={3: [b"dead"]}), None, 1, 2, np.array([True, False, True])),
+    (dict(appends={3: [b"dead"]}), 1, 2, np.array([True, False, True])),
 ]
+CHAIN = [dict(appends={0: [b"k%d" % k], 2: [b"c%d" % k] * 3}) for k in range(4)]
+SPARSE = dict(appends={2: [b"s1", b"s2"]})
 
 
-def _cfg(name):
-    return EngineConfig(**BASE, **VARIANTS[name])
+def _fns(binding):
+    if binding == "vmap":
+        return make_local_fns(CFG)
+    if len(jax.devices()) < 6:
+        pytest.skip("needs 6 virtual devices")
+    return make_spmd_fns(CFG, make_mesh(CFG.replicas, 2))  # 3 replicas x 2 shards
 
 
-def _unfused(cfg, state):
+def _snap(state):
     """Host-materialized named-field snapshot: the engine DONATES the
     state argument, so a later step invalidates device snapshots —
     every capture must copy to numpy."""
-    import jax
-
-    state = unfuse_state(state) if cfg.fused_control else state
-    return jax.tree.map(np.asarray, state)
+    return jax.tree.map(np.asarray, unfuse_state(state))
 
 
-def _run_history(name):
-    """One full scripted history; returns per-phase snapshots."""
-    cfg = _cfg(name)
-    fns = make_local_fns(cfg)
-    snaps = {}
-
-    state = fns.init()
-    outs = []
-    for appends, _, leader, term, alive in SCRIPT:
-        inp = build_step_input(cfg, leader=leader, term=term, **appends)
-        state, out = fns.step(state, inp, alive)
-        outs.append(out)
-    snaps["script_outs"] = outs
-    snaps["script_state"] = _unfused(cfg, state)
-
-    # Chained dispatch: the same four rounds through step_many must land
-    # the same place as four sequential steps.
-    chain = [
-        build_step_input(cfg, appends={0: [b"k%d" % k], 2: [b"c%d" % k] * 3},
-                         leader=0, term=2)
-        for k in range(4)
-    ]
-    stacked = jax_stack_inputs(chain)
-    state, outs_many = fns.step_many(state, stacked, ALL)
-    snaps["chain_outs"] = outs_many
-    snaps["chain_state"] = _unfused(cfg, state)
-
-    # Capacity backpressure + trim-gated ring wrap: fill the ring, see
-    # the refusal, then trim and wrap a round past the boundary.
-    fill = [b"z"] * cfg.max_batch
-    end = int(np.asarray(snaps["chain_state"].log_end)[0, 0])
-    rounds_left = (cfg.slots - end) // cfg.max_batch
-    for _ in range(rounds_left):
-        state, out = fns.step(
-            state, build_step_input(cfg, appends={0: fill}, leader=0, term=2),
-            ALL,
-        )
-    state, refused = fns.step(
-        state, build_step_input(cfg, appends={0: [b"full"]}, leader=0, term=2),
-        ALL,
-    )
-    snaps["refused"] = refused
-    trim = np.full((cfg.partitions,), cfg.max_batch, np.int32)
-    state, wrapped = fns.step(
-        state, build_step_input(cfg, appends={0: [b"wrap"]}, leader=0, term=2),
-        ALL, None, trim,
-    )
-    snaps["wrapped"] = wrapped
-    snaps["wrap_state"] = _unfused(cfg, state)
-
-    # Election + post-election round.
-    cand = np.full((cfg.partitions,), -1, np.int32)
-    cand[1] = 2
-    cand_term = np.full((cfg.partitions,), 5, np.int32)
-    state, elected, votes = fns.vote(state, cand, cand_term, ALL)
-    snaps["vote"] = (elected, votes)
-    snaps["vote_state"] = _unfused(cfg, state)
-
-    # Resync a lagging replica, then commit with the full set again.
-    state, _ = fns.step(
-        state, build_step_input(cfg, appends={1: [b"m1", b"m2"]}, leader=0,
-                                term=5),
-        MAJORITY,
-    )
-    mask = np.array([False, True, False, False])
-    state = fns.resync(state, np.int32(0), np.int32(2), mask)
-    state, out = fns.step(
-        state, build_step_input(cfg, appends={1: [b"m3"]}, leader=0, term=5),
-        ALL,
-    )
-    snaps["resync_out"] = out
-    snaps["resync_state"] = _unfused(cfg, state)
-
-    # Sparse (active-set) dispatch parity.
-    sparse_inp = build_step_input(cfg, leader=0, term=5)
-    entries = build_step_input(
-        cfg, appends={2: [b"s1", b"s2"]}, leader=0, term=5
-    )
-    ec = np.asarray(entries.entries)[2:3]
-    ids = np.array([2], np.int32)
-    sp = sparse_inp._replace(counts=np.asarray(entries.counts),
-                             extents=np.asarray(entries.extents))
-    state, out = fns.step_sparse(state, sp, ec, ids, ALL)
-    snaps["sparse_out"] = out
-    snaps["final_state"] = _unfused(cfg, state)
-
-    # Read-path parity on the final state.
-    reads = []
-    for p in range(cfg.partitions):
-        data, lens, count = fns.read(state, 0, p, 0)
-        reads.append((np.asarray(data), np.asarray(lens), int(count)))
-    snaps["reads"] = reads
-    snaps["read_offset"] = int(fns.read_offset(state, 0, 3, 0))
-    return cfg, snaps
+def _inp(work, leader, term):
+    return build_step_input(CFG, leader=leader, term=term, **work)
 
 
-def jax_stack_inputs(inputs):
-    from ripplemq_tpu.core.state import StepInput
-
+def _stack(inputs):
     return StepInput(*[
         np.stack([np.asarray(getattr(i, f)) for i in inputs])
         for f in StepInput._fields
     ])
 
 
-@pytest.fixture(scope="module")
-def histories():
-    return {name: _run_history(name) for name in VARIANTS}
+def _sparse_args(work, leader, term):
+    """The active-set form of one round: the control input without its
+    entries, plus the appending partitions' blocks and their ids."""
+    full = _inp(work, leader, term)
+    ids = np.array(sorted(work["appends"]), np.int32)
+    entries_c = np.asarray(full.entries)[ids]
+    dummy = np.zeros((CFG.partitions, 1, 1), np.uint8)
+    return full._replace(entries=dummy), entries_c, ids
+
+
+def _run_history(fns):
+    """One full scripted history through the engine and the model side
+    by side. Returns (outs, states): `outs` pairs each device StepOutput
+    (or vote result) with the model's, `states` pairs a device snapshot
+    with the model's after every phase."""
+    model = Model(CFG)
+    state = fns.init()
+    outs, states = [], []
+    trim = np.zeros((CFG.partitions,), np.int32)  # highest watermark fed
+
+    def out_pair(tag, out, want):
+        outs.append((tag, {f: np.asarray(getattr(out, f)) for f in want},
+                     want))
+
+    def state_pair(tag):
+        states.append((tag, _snap(state), model.snapshot(),
+                       [list(r) for r in model.rows], trim.copy()))
+
+    for i, (work, leader, term, alive) in enumerate(SCRIPT):
+        state, out = fns.step(state, _inp(work, leader, term), alive)
+        out_pair(f"script[{i}]", out, model.round(
+            work.get("appends"), work.get("offset_updates"), leader, term,
+            alive))
+    state_pair("script")
+
+    # Chained dispatch: four complete rounds in one launch; the model
+    # takes them one by one.
+    state, many = fns.step_many(
+        state, _stack([_inp(w, 0, 2) for w in CHAIN]), ALL)
+    for k, work in enumerate(CHAIN):
+        out_pair(f"chain[{k}]", jax.tree.map(lambda x: x[k], many),
+                 model.round(work["appends"], None, 0, 2, ALL))
+    state_pair("chain")
+
+    # Capacity backpressure + trim-gated ring wrap: fill partition 0's
+    # ring, see the refusal, then trim and wrap a round past the boundary.
+    fill = dict(appends={0: [b"z"] * CFG.max_batch})
+    while int(model.end[0, 0]) + CFG.max_batch <= CFG.slots:
+        state, out = fns.step(state, _inp(fill, 0, 2), ALL)
+        out_pair("fill", out, model.round(fill["appends"], None, 0, 2, ALL))
+    full = dict(appends={0: [b"full"]})
+    state, out = fns.step(state, _inp(full, 0, 2), ALL)
+    want = model.round(full["appends"], None, 0, 2, ALL)
+    assert not want["committed"][0], "the history must hit the capacity rule"
+    out_pair("refused", out, want)
+    trim = np.full((CFG.partitions,), CFG.max_batch, np.int32)
+    wrap = dict(appends={0: [b"wrap"]})
+    state, out = fns.step(state, _inp(wrap, 0, 2), ALL, None, trim)
+    want = model.round(wrap["appends"], None, 0, 2, ALL, trim)
+    assert want["committed"][0] and want["base"][0] + 8 > CFG.slots, (
+        "the history must wrap the ring")
+    out_pair("wrapped", out, want)
+    state_pair("wrap")
+
+    # Election (partition 1 elects replica 2 at term 5).
+    cand = np.full((CFG.partitions,), -1, np.int32)
+    cand[1] = 2
+    state, elected, votes = fns.vote(
+        state, cand, np.full((CFG.partitions,), 5, np.int32), ALL)
+    m = [model.vote(p, int(cand[p]), 5, ALL) for p in range(CFG.partitions)]
+    outs.append(("vote",
+                 {"elected": np.asarray(elected), "votes": np.asarray(votes)},
+                 {"elected": np.array([e for e, _ in m]),
+                  "votes": np.array([g for _, g in m])}))
+    state_pair("vote")
+
+    # A round replica 2 misses, a resync of it, then the full set again.
+    lag = dict(appends={1: [b"m1", b"m2"]})
+    state, out = fns.step(state, _inp(lag, 0, 5), MAJORITY)
+    out_pair("lag", out, model.round(lag["appends"], None, 0, 5, MAJORITY))
+    mask = np.array([False, True, False, False])
+    state = fns.resync(state, np.int32(0), np.int32(2), mask)
+    model.resync(1, 0, 2)
+    post = dict(appends={1: [b"m3"]})
+    state, out = fns.step(state, _inp(post, 0, 5), ALL)
+    want = model.round(post["appends"], None, 0, 5, ALL)
+    assert want["votes"][1] == 3, "the resynced replica must ack again"
+    out_pair("post-resync", out, want)
+    state_pair("resync")
+
+    # Sparse (active-set) dispatch.
+    state, out = fns.step_sparse(state, *_sparse_args(SPARSE, 0, 5), ALL)
+    out_pair("sparse", out, model.round(SPARSE["appends"], None, 0, 5, ALL))
+    state_pair("final")
+
+    # The read path on the final state, against the model's rows.
+    reads = []
+    for p in range(CFG.partitions):
+        off = CFG.max_batch if p == 0 else 0  # partition 0 wrapped: above trim
+        data, lens, count = fns.read(state, 0, p, off)
+        reads.append((p, decode_entries(data, lens, count), int(count),
+                      model.read(p, 0, off)))
+    return outs, states, reads
+
+
+@pytest.fixture(scope="module", params=BINDINGS)
+def history(request):
+    return _run_history(_fns(request.param))
+
+
+def test_outputs_match_model(history):
+    outs, _, reads = history
+    for tag, got, want in outs:
+        for f, w in want.items():
+            np.testing.assert_array_equal(got[f], w, err_msg=f"{tag}:{f}")
+    for p, msgs, count, (want_msgs, want_count) in reads:
+        assert (msgs, count) == (want_msgs, want_count), f"read p{p}"
+
+
+def test_scalar_state_matches_model(history):
+    _, states, _ = history
+    for tag, got, want, _, _ in states:
+        for f, w in want.items():
+            np.testing.assert_array_equal(
+                np.asarray(getattr(got, f)), w, err_msg=f"{tag}:{f}")
+
+
+def test_committed_log_prefix_matches_model(history):
+    """Every ring-resident committed row of every replica holds the
+    model's payload (or a length-0 padding row) at its absolute offset."""
+    from ripplemq_tpu.core.state import row_lens
+
+    _, states, _ = history
+    S = CFG.slots
+    checked = 0
+    for tag, got, want, rows, trim in states:
+        log = np.asarray(got.log_data)
+        lens = np.asarray(row_lens(log))
+        for r in range(CFG.replicas):
+            for p in range(CFG.partitions):
+                end, commit = int(want["log_end"][r, p]), int(want["commit"][r, p])
+                # Ring-resident and not reclaimable: the last lap, above
+                # the highest trim watermark the history fed.
+                for a in range(max(0, end - S, int(trim[p])), commit):
+                    n = int(lens[r, p, a % S])
+                    payload = log[r, p, a % S, 8 : 8 + n].tobytes()
+                    assert payload == rows[p][a], f"{tag}: r{r} p{p} @{a}"
+                    checked += 1
+    assert checked > 500  # the history is not vacuous
+
+
+@pytest.mark.parametrize("binding", BINDINGS)
+def test_step_many_lands_where_steps_land(binding):
+    fns = _fns(binding)
+    inputs = [_inp(w, 0, 2) for w in CHAIN]
+    one, many = fns.init(), fns.init()
+    singles = []
+    for inp in inputs:
+        one, out = fns.step(one, inp, ALL)
+        singles.append(out)
+    many, outs = fns.step_many(many, _stack(inputs), ALL)
+    for k, out in enumerate(singles):
+        _assert_tree_equal(out, jax.tree.map(lambda x: x[k], outs),
+                           f"chain[{k}]")
+    _assert_tree_equal(_snap(one), _snap(many), "state after the chain")
+
+
+@pytest.mark.parametrize("binding", BINDINGS)
+def test_step_sparse_lands_where_step_lands(binding):
+    fns = _fns(binding)
+    dense, sparse = fns.init(), fns.init()
+    for work in (SPARSE, dict(appends={0: [b"d"] * CFG.max_batch, 3: [b"e"]})):
+        dense, d_out = fns.step(dense, _inp(work, 0, 1), ALL)
+        sparse, s_out = fns.step_sparse(
+            sparse, *_sparse_args(work, 0, 1), ALL)
+        _assert_tree_equal(d_out, s_out, "sparse out")
+    _assert_tree_equal(_snap(dense), _snap(sparse), "state after sparse")
+
+
+def test_spmd_fills_missing_extents_with_the_full_window():
+    """Hand-built inputs may carry extents=None (pytree-empty): the spmd
+    wrapper must fill the full window instead of treedef-mismatching
+    against its compiled specs, the vmap binding takes None as it is,
+    and both write the whole [B, SB] block."""
+    spmd, local = _fns("spmd"), _fns("vmap")
+    ss, ls = spmd.init(), local.init()
+    inp = _inp(dict(appends={1: [b"nofill"]}), 0, 2)._replace(extents=None)
+    ss, s_out = spmd.step(ss, inp, ALL)
+    ls, l_out = local.step(ls, inp, ALL)
+    assert bool(np.asarray(l_out.committed)[1])
+    _assert_tree_equal(l_out, s_out, "extents=None out")
+    _assert_tree_equal(_snap(ls), _snap(ss), "extents=None state")
+    # One payload row, and the block's padding rows (length 0, the
+    # round's term in their header) written out to the window's end.
+    block = np.asarray(inp.entries)[1]
+    assert block[8:, 4].all()
+    for r in range(CFG.replicas):
+        np.testing.assert_array_equal(
+            np.asarray(ss.log_data)[r, 1, : CFG.max_batch], block)
 
 
 def _assert_tree_equal(a, b, msg):
-    import jax
-
     for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
         np.testing.assert_array_equal(np.asarray(x), np.asarray(y),
                                       err_msg=msg)
 
 
-STATE_KEYS = ("script_state", "chain_state", "wrap_state", "vote_state",
-              "resync_state", "final_state")
-OUT_KEYS = ("script_outs", "chain_outs", "refused", "wrapped", "vote",
-            "resync_out", "sparse_out", "reads", "read_offset")
-
-
-@pytest.mark.parametrize("name", [n for n in VARIANTS if n != "legacy"])
-def test_outputs_bit_identical(histories, name):
-    _, legacy = histories["legacy"]
-    _, variant = histories[name]
-    for key in OUT_KEYS:
-        _assert_tree_equal(legacy[key], variant[key], f"{name}:{key}")
-
-
-@pytest.mark.parametrize("name", [n for n in VARIANTS if n != "legacy"])
-def test_scalar_state_bit_identical(histories, name):
-    _, legacy = histories["legacy"]
-    _, variant = histories[name]
-    for key in STATE_KEYS:
-        for f in ("log_end", "last_term", "current_term", "commit",
-                  "offsets"):
-            np.testing.assert_array_equal(
-                np.asarray(getattr(legacy[key], f)),
-                np.asarray(getattr(variant[key], f)),
-                err_msg=f"{name}:{key}:{f}",
-            )
-
-
-@pytest.mark.parametrize("name", [n for n in VARIANTS if n != "legacy"])
-def test_committed_log_prefix_identical(histories, name):
-    cfg_l, legacy = histories["legacy"]
-    cfg_v, variant = histories[name]
-    for key in STATE_KEYS:
-        log_l = np.asarray(legacy[key].log_data)
-        log_v = np.asarray(variant[key].log_data)
-        if not cfg_v.packed_writes:
-            # Unpacked variants write the identical full windows: the
-            # whole physical ring must match byte-for-byte.
-            np.testing.assert_array_equal(log_l, log_v,
-                                          err_msg=f"{name}:{key}")
-            continue
-        ends = np.asarray(legacy[key].log_end)
-        S = cfg_l.slots
-        for r in range(cfg_l.replicas):
-            for p in range(cfg_l.partitions):
-                live = min(int(ends[r, p]), S)
-                np.testing.assert_array_equal(
-                    log_l[r, p, :live], log_v[r, p, :live],
-                    err_msg=f"{name}:{key}:r{r}p{p}",
-                )
-
-
 def test_fuse_unfuse_roundtrip():
-    cfg = _cfg("legacy")
-    fns = make_local_fns(cfg)
+    fns = make_local_fns(CFG)
     state = fns.init()
     state, _ = fns.step(
-        state, build_step_input(cfg, appends={0: [b"rt"]}, leader=0, term=1),
-        ALL,
-    )
-    rt = unfuse_state(fuse_state(state))
-    _assert_tree_equal(state, rt, "fuse/unfuse roundtrip")
+        state, _inp(dict(appends={0: [b"rt"]}), 0, 1), ALL)
+    named = _snap(state)
+    _assert_tree_equal(named, unfuse_state(fuse_state(named)),
+                       "fuse/unfuse roundtrip")
 
 
 def test_fused_accessors_match_fields():
-    cfg = _cfg("fused")
-    fns = make_local_fns(cfg)
+    fns = make_local_fns(CFG)
     state = fns.init()
     state, _ = fns.step(
-        state, build_step_input(cfg, appends={1: [b"v"]}, leader=0, term=3),
-        ALL,
-    )
+        state, _inp(dict(appends={1: [b"v"]}), 0, 3), ALL)
     plain = unfuse_state(state)
-    for f in ("log_end", "last_term", "current_term", "commit"):
+    for i, f in enumerate(("log_end", "last_term", "current_term", "commit")):
+        np.testing.assert_array_equal(
+            np.asarray(getattr(state, f)), np.asarray(state.ctrl)[:, i, :],
+            err_msg=f)
         np.testing.assert_array_equal(
             np.asarray(getattr(state, f)), np.asarray(getattr(plain, f)),
-            err_msg=f,
-        )
-
-
-def test_spmd_packed_matches_local_legacy():
-    """packed_writes is honored by the spmd binding: a shard_map mesh
-    running packed rounds must land the same scalar state and outputs
-    as the local legacy engine (same committed-prefix guarantee)."""
-    import jax
-
-    from ripplemq_tpu.parallel.engine import make_spmd_fns
-    from ripplemq_tpu.parallel.mesh import make_mesh
-
-    if len(jax.devices()) < 6:
-        pytest.skip("needs 6 virtual devices")
-    cfg = _cfg("packed")
-    mesh = make_mesh(cfg.replicas, 2)  # 3 replicas x 2 partition shards
-    spmd = make_spmd_fns(cfg, mesh)
-    local = make_local_fns(_cfg("legacy"))
-    ss, ls = spmd.init(), local.init()
-    for appends, _, leader, term, alive in SCRIPT[:6]:
-        inp = build_step_input(cfg, leader=leader, term=term, **appends)
-        ss, s_out = spmd.step(ss, inp, alive)
-        ls, l_out = local.step(ls, inp, alive)
-        _assert_tree_equal(l_out, s_out, "spmd packed out")
-    # Hand-built inputs may carry extents=None (pytree-empty): the spmd
-    # wrapper must fill the full window instead of treedef-mismatching
-    # against its compiled specs — and a full window IS the legacy
-    # write, so the local legacy engine must still agree.
-    none_inp = build_step_input(
-        cfg, appends={1: [b"nofill"]}, leader=0, term=2
-    )._replace(extents=None)
-    alive = np.ones((3,), bool)
-    ss, s_out = spmd.step(ss, none_inp, alive)
-    ls, l_out = local.step(ls, none_inp, alive)
-    _assert_tree_equal(l_out, s_out, "spmd extents=None out")
-    for f in ("log_end", "last_term", "current_term", "commit", "offsets"):
-        np.testing.assert_array_equal(
-            np.asarray(getattr(ls, f)), np.asarray(getattr(ss, f)),
-            err_msg=f,
-        )
-    ends = np.asarray(ls.log_end)
-    log_l, log_s = np.asarray(ls.log_data), np.asarray(ss.log_data)
-    for r in range(cfg.replicas):
-        for p in range(cfg.partitions):
-            live = int(ends[r, p])
-            np.testing.assert_array_equal(log_l[r, p, :live],
-                                          log_s[r, p, :live])
-
-
-def test_spmd_fused_no_fallback_warning():
-    """The NEGATION of the pre-ISSUE-6 fallback assertion: fused_control
-    under shard_map is implemented — make_spmd_fns must honor it with NO
-    fallback UserWarning and serve committed rounds through the fused
-    control phase."""
-    import warnings
-
-    import jax
-
-    from ripplemq_tpu.parallel.engine import make_spmd_fns
-    from ripplemq_tpu.parallel.mesh import make_mesh
-
-    if len(jax.devices()) < 3:
-        pytest.skip("needs 3 virtual devices")
-    cfg = _cfg("fused")
-    with warnings.catch_warnings(record=True) as rec:
-        warnings.simplefilter("always")
-        spmd = make_spmd_fns(cfg, make_mesh(cfg.replicas, 1))
-    assert not any("fused_control" in str(w.message) for w in rec), (
-        [str(w.message) for w in rec]
-    )
-    st = spmd.init()
-    inp = build_step_input(cfg, appends={0: [b"ok"]}, leader=0, term=1)
-    st, out = spmd.step(st, inp, np.ones((3,), bool))
-    assert bool(np.asarray(out.committed)[0])
-
-
-@pytest.mark.parametrize("name", ["fused", "fused+packed"])
-def test_spmd_fused_matches_local_legacy(name):
-    """The fused shard_map binding replayed against the LEGACY local
-    engine over the scripted history: same outputs, same scalar state,
-    same committed log prefix — the committed-prefix contract of the
-    ISSUE 6 tentpole, from the opposite direction of the spmd parity
-    matrix (which compares the three production bindings to each
-    other)."""
-    import jax
-
-    from ripplemq_tpu.parallel.engine import make_spmd_fns
-    from ripplemq_tpu.parallel.mesh import make_mesh
-
-    if len(jax.devices()) < 6:
-        pytest.skip("needs 6 virtual devices")
-    cfg = _cfg(name)
-    spmd = make_spmd_fns(cfg, make_mesh(cfg.replicas, 2))
-    local = make_local_fns(_cfg("legacy"))
-    ss, ls = spmd.init(), local.init()
-    for appends, _, leader, term, alive in SCRIPT:
-        inp = build_step_input(cfg, leader=leader, term=term, **appends)
-        ss, s_out = spmd.step(ss, inp, alive)
-        ls, l_out = local.step(ls, inp, alive)
-        _assert_tree_equal(l_out, s_out, f"spmd {name} out")
-    fs = unfuse_state(ss)
-    for f in ("log_end", "last_term", "current_term", "commit", "offsets"):
-        np.testing.assert_array_equal(
-            np.asarray(getattr(ls, f)), np.asarray(getattr(fs, f)),
-            err_msg=f,
-        )
-    ends = np.asarray(ls.log_end)
-    log_l, log_s = np.asarray(ls.log_data), np.asarray(fs.log_data)
-    for r in range(cfg.replicas):
-        for p in range(cfg.partitions):
-            live = min(int(ends[r, p]), cfg.slots)
-            np.testing.assert_array_equal(log_l[r, p, :live],
-                                          log_s[r, p, :live])
+            err_msg=f)
 
 
 def test_init_from_image_parity():
-    """Recovered-image install must land both layouts in the same state
-    (broker/replication.py recovery path rides init_from)."""
-    from ripplemq_tpu.core.state import ReplicaState
-
-    cfg_l, cfg_f = _cfg("legacy"), _cfg("fused")
-    P, S, B, SB, C = (cfg_l.partitions, cfg_l.slots, cfg_l.max_batch,
-                      cfg_l.slot_bytes, cfg_l.max_consumers)
+    """A recovered image installed through either binding comes back out
+    of the named accessors as it went in, on every replica
+    (broker/replication.py's recovery path rides init_from)."""
+    P, S, B, SB, C = (CFG.partitions, CFG.slots, CFG.max_batch,
+                      CFG.slot_bytes, CFG.max_consumers)
     rng = np.random.default_rng(5)
     image = ReplicaState(
         log_data=rng.integers(0, 256, size=(P, S + B, SB), dtype=np.uint8),
@@ -418,6 +360,9 @@ def test_init_from_image_parity():
         commit=np.array([8, 0, 16, 8], np.int32),
         offsets=rng.integers(0, 99, size=(P, C)).astype(np.int32),
     )
-    st_l = make_local_fns(cfg_l).init_from(image)
-    st_f = make_local_fns(cfg_f).init_from(image)
-    _assert_tree_equal(st_l, unfuse_state(st_f), "init_from parity")
+    for binding in BINDINGS:
+        got = unfuse_state(_fns(binding).init_from(image))
+        for r in range(CFG.replicas):
+            _assert_tree_equal(
+                image, jax.tree.map(lambda x: np.asarray(x)[r], got),
+                f"{binding}: replica {r}")

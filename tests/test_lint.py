@@ -262,13 +262,12 @@ def test_shard_shape_fixture_caught():
 
 
 def test_shard_shape_derivation_matches_engine():
-    # The smapped set is derived, not hand-listed: the fused/legacy
-    # control and vote fns plus the read path must all be present.
+    # The smapped set is derived, not hand-listed: the control and
+    # vote fns plus the read path must all be present.
     repo = Repo()
     smapped = shard_shapes.smapped_step_fns(
         repo.tree(shard_shapes.ENGINE_PATH))
-    assert {"replica_control", "replica_control_fused",
-            "vote_step", "vote_step_fused", "read_batch"} <= smapped
+    assert {"replica_control", "vote_step", "read_batch"} <= smapped
 
 
 # ---- stats_schema: the silently-widened-schema class -----------------

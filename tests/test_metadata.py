@@ -10,6 +10,7 @@ import random
 
 import pytest
 
+from ripplemq_tpu.core.config import EngineConfig
 from ripplemq_tpu.metadata import (
     BrokerInfo,
     PartitionAssignment,
@@ -235,6 +236,64 @@ def test_parse_cluster_config_operational_knobs():
     assert defaults.pipeline_depth == 8
     assert defaults.rpc_workers == 16
     assert defaults.linearizable_reads is False
+
+
+_ONE_BROKER = {
+    "brokers": [{"id": 0, "host": "h", "port": 1}],
+    "topics": [{"name": "t", "partitions": 1, "replication_factor": 1}],
+}
+
+
+def test_parse_drops_retired_engine_keys_when_true():
+    """Cluster files written before PR 29 carry `fused_control: true` /
+    `packed_writes: true`; what they asked for is the only round there
+    is, so they parse to the same engine as a file without them."""
+    old = parse_cluster_config({**_ONE_BROKER, "engine": {
+        "slots": 128, "fused_control": True, "packed_writes": True}})
+    new = parse_cluster_config({**_ONE_BROKER, "engine": {"slots": 128}})
+    assert old.engine == new.engine and old.engine.slots == 128
+    assert not hasattr(old.engine, "fused_control")
+    assert not hasattr(old.engine, "packed_writes")
+
+
+@pytest.mark.parametrize("key", ["fused_control", "packed_writes"])
+def test_parse_refuses_retired_engine_keys_when_false(key):
+    raw = {**_ONE_BROKER, "engine": {key: False}}
+    with pytest.raises(ValueError, match="were removed"):
+        parse_cluster_config(raw)
+    with pytest.raises(TypeError):
+        EngineConfig(**{key: True})  # the engine itself knows no such field
+
+
+def test_parse_unknown_engine_key_is_still_an_error():
+    with pytest.raises(TypeError, match="fused_writes"):
+        parse_cluster_config({**_ONE_BROKER, "engine": {"fused_writes": True}})
+
+
+def test_benchmark_cluster_blocks_parse():
+    """The three deployments the benchmark boots, as run.py builds their
+    cluster files (the `cluster` block, the deployment's topics, one
+    broker per port) — two of them still name the retired keys."""
+    import glob
+    import json
+    import os
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    paths = sorted(glob.glob(os.path.join(repo, "benchmarks", "configs",
+                                          "*.json")))
+    assert len(paths) == 3
+    for path in paths:
+        with open(path) as f:
+            config = json.load(f)
+        raw = dict(config["cluster"])
+        raw["brokers"] = [{"id": i, "host": "127.0.0.1", "port": 9000 + i}
+                          for i in range(config["deployment"]["brokers"])]
+        raw["topics"] = config["deployment"]["topics"]
+        cfg = parse_cluster_config(raw)
+        want = {k: v for k, v in config["cluster"].get("engine", {}).items()
+                if k not in ("fused_control", "packed_writes")}
+        for k, v in want.items():
+            assert getattr(cfg.engine, k) == v, (path, k)
 
 
 def test_parse_rejects_linearizable_reads_without_standbys():

@@ -1,6 +1,5 @@
-"""Tier-1 multichip smoke (ISSUE 6 satellite): one fused-spmd step +
-read on the full 8-virtual-device CPU mesh, in the DEFAULT test
-selection — so the dryrun(8) green stops being bench-only.
+"""Tier-1 multichip smoke: one spmd step + read on the full
+8-virtual-device CPU mesh, in the DEFAULT test selection.
 
 conftest.py forces `XLA_FLAGS=--xla_force_host_platform_device_count=8`
 for the whole suite, so the mesh here spans 8 real XLA devices; the
@@ -26,15 +25,12 @@ pytestmark = pytest.mark.skipif(
 
 
 def test_fused_spmd_step_and_read_on_8_device_mesh():
-    """One committed fused-spmd round + a cross-shard read + a chained
+    """One committed spmd round + a cross-shard read + a chained
     launch + an election, on the production mesh shape for 8 devices
-    (pick_axes: 2 replicas x 4 partition shards), with the production
-    levers on (fused_control + packed_writes — the binding the e2e
-    config boots)."""
+    (pick_axes: 2 replicas x 4 partition shards)."""
     replicas, part_shards = pick_axes(8)
     assert (replicas, part_shards) == (2, 4)
-    cfg = small_cfg(replicas=replicas, partitions=8, fused_control=True,
-                    packed_writes=True)
+    cfg = small_cfg(replicas=replicas, partitions=8)
     mesh = make_mesh(replicas, part_shards)
     assert len(mesh.devices.flatten()) == 8
     fns = make_spmd_fns(cfg, mesh)
@@ -77,10 +73,10 @@ def test_fused_spmd_step_and_read_on_8_device_mesh():
 
 
 def test_fused_spmd_quorum_failure_leaves_no_trace_across_shards():
-    """Atomicity under the sharded fused binding: a round refused for
+    """Atomicity under the sharded binding: a round refused for
     quorum must leave no trace on ANY shard (ballot-before-write rides
     the replica-axis psum across real device boundaries)."""
-    cfg = small_cfg(replicas=2, partitions=8, fused_control=True)
+    cfg = small_cfg(replicas=2, partitions=8)
     fns = make_spmd_fns(cfg, make_mesh(2, 4))
     state = fns.init()
     state, out = fns.step(

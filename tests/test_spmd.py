@@ -124,11 +124,10 @@ def test_spmd_read_out_of_range_matches_local():
 
 
 # ---------------------------------------------------------------------------
-# Fused-spmd parity matrix (ISSUE 6 tentpole): the fused-control binding
-# under shard_map vs the legacy-control shard_map binding vs the fused
-# vmap binding — every StepOutput and the final full state must be
-# bit-identical across empty/partial/quorum-failure/vote/resync/
-# ring-wrap/chained rounds.
+# Spmd parity matrix: the round under shard_map vs under vmap over a
+# richer history than _scenario — every StepOutput and the final full
+# state must be bit-identical across empty/partial/quorum-failure/vote/
+# resync/ring-wrap/chained rounds, at three mesh shapes.
 # ---------------------------------------------------------------------------
 
 
@@ -144,15 +143,13 @@ def _assert_trees_equal(ref, others, msg):
 def test_fused_spmd_parity_matrix(replicas, part_shards):
     from ripplemq_tpu.core.state import unfuse_state
 
-    cfg_f = small_cfg(replicas=replicas, partitions=8, fused_control=True)
-    cfg_l = small_cfg(replicas=replicas, partitions=8)
+    cfg_f = small_cfg(replicas=replicas, partitions=8)
     mesh = make_mesh(replicas, part_shards)
     engines = [
-        ("fused-spmd", make_spmd_fns(cfg_f, mesh), cfg_f),
-        ("legacy-spmd", make_spmd_fns(cfg_l, mesh), cfg_l),
-        ("fused-vmap", make_local_fns(cfg_f), cfg_f),
+        ("spmd", make_spmd_fns(cfg_f, mesh)),
+        ("vmap", make_local_fns(cfg_f)),
     ]
-    states = [fns.init() for _, fns, _ in engines]
+    states = [fns.init() for _, fns in engines]
     R = cfg_f.replicas
     alive_all = np.ones((R,), bool)
     minority = np.zeros((R,), bool)
@@ -162,7 +159,7 @@ def test_fused_spmd_parity_matrix(replicas, part_shards):
 
     def step_all(inp, alive, trim=None, tag=""):
         outs = []
-        for i, (_, fns, _) in enumerate(engines):
+        for i, (_, fns) in enumerate(engines):
             states[i], out = fns.step(states[i], inp, alive, None, trim)
             outs.append((engines[i][0], out))
         _assert_trees_equal(outs[0][1], outs[1:], tag)
@@ -187,7 +184,7 @@ def test_fused_spmd_parity_matrix(replicas, part_shards):
         make_input(cfg_f, appends={p: [b"c"] for p in range(8)}),
     )
     chain_outs = []
-    for i, (name, fns, _) in enumerate(engines):
+    for i, (name, fns) in enumerate(engines):
         states[i], outs = fns.step_many(states[i], chain, alive_all)
         chain_outs.append((name, outs))
     _assert_trees_equal(chain_outs[0][1], chain_outs[1:], "chained")
@@ -196,7 +193,7 @@ def test_fused_spmd_parity_matrix(replicas, part_shards):
     cand = np.full((8,), -1, np.int32)
     cand[5] = 0
     vres = []
-    for i, (name, fns, _) in enumerate(engines):
+    for i, (name, fns) in enumerate(engines):
         states[i], elected, votes = fns.vote(
             states[i], cand, np.full((8,), 4, np.int32), alive_all
         )
@@ -206,7 +203,7 @@ def test_fused_spmd_parity_matrix(replicas, part_shards):
     # resync (leader 0 -> last replica, masked partitions) + post round
     mask = np.zeros((8,), bool)
     mask[0] = mask[3] = True
-    for i, (_, fns, _) in enumerate(engines):
+    for i, (_, fns) in enumerate(engines):
         states[i] = fns.resync(states[i], jnp.int32(0),
                                jnp.int32(R - 1), mask)
     step_all(make_input(cfg_f, appends={0: [b"post-resync"]}, term=4),
@@ -215,10 +212,7 @@ def test_fused_spmd_parity_matrix(replicas, part_shards):
     # ring wrap behind trim: fill partition 0 to capacity, observe the
     # refusal, then trim and wrap a round past the boundary.
     fill = [b"f"] * cfg_f.max_batch
-    end = int(np.asarray(
-        unfuse_state(states[2]).log_end if cfg_f.fused_control
-        else states[2].log_end
-    )[0, 0])
+    end = int(np.asarray(states[1].log_end)[0, 0])
     for _ in range((cfg_f.slots - end) // cfg_f.max_batch):
         step_all(make_input(cfg_f, appends={0: fill}, term=4), alive_all,
                  tag="fill")
@@ -228,39 +222,18 @@ def test_fused_spmd_parity_matrix(replicas, part_shards):
     step_all(make_input(cfg_f, appends={0: [b"wrap"]}, term=4), alive_all,
              trim=trim, tag="wrap")
 
-    # Final full-state equality (named layout; unpacked variants write
-    # identical full windows, so the whole physical ring must match).
-    finals = []
-    for i, (name, _, cfg) in enumerate(engines):
-        st = unfuse_state(states[i]) if cfg.fused_control else states[i]
-        finals.append((name, st))
+    # Final full-state equality (named layout; both bindings write the
+    # same extent classes, so the whole physical ring must match).
+    finals = [(name, unfuse_state(states[i]))
+              for i, (name, _) in enumerate(engines)]
     _assert_trees_equal(finals[0][1], finals[1:], "final-state")
 
     # Read-path parity on the wrapped state.
     for part in (0, 7):
         reads = [(name, fns.read(states[i], 0, part,
                                  cfg_f.max_batch if part == 0 else 0))
-                 for i, (name, fns, _) in enumerate(engines)]
+                 for i, (name, fns) in enumerate(engines)]
         _assert_trees_equal(reads[0][1], reads[1:], f"read-p{part}")
-
-
-def test_make_spmd_fns_fused_emits_no_fallback_warning():
-    """The negation of the old fallback assertion: make_spmd_fns must
-    HONOR fused_control — no 'fused_control ... falling back' warning
-    may fire while building the binding."""
-    import warnings
-
-    cfg = small_cfg(replicas=2, partitions=8, fused_control=True)
-    with warnings.catch_warnings(record=True) as rec:
-        warnings.simplefilter("always")
-        spmd = make_spmd_fns(cfg, make_mesh(2, 4))
-    assert not any("fused_control" in str(w.message) for w in rec), (
-        [str(w.message) for w in rec]
-    )
-    st = spmd.init()
-    st, out = spmd.step(st, make_input(cfg, appends={0: [b"ok"]}),
-                        np.ones((2,), bool))
-    assert bool(np.asarray(out.committed)[0])
 
 
 def test_spmd_per_device_stride_verdict():
