@@ -11,7 +11,8 @@ while the cluster does, prints READY, and then takes its orders over stdin:
     WINDOW <t0_ns> <t1_ns>      the measured window, on time.monotonic_ns()
                                 of this machine; traffic goes on unbroken
     DRAIN <deadline_ns> <file>  consumers: read on until every partition
-                                holds the counts in <file> (- = stop now)
+                                holds the counts in <file>, at the latest
+                                until the deadline (- = no counts: stop now)
 
 and answers with one `RESULT <json>` line; bulk data goes to .npy files in
 the run's work dir. Producers also print `FIRSTACK <ns>` once.
@@ -39,21 +40,28 @@ def log(*a) -> None:
 
 class Orders:
     """The parent's lines, read on a thread so that traffic never blocks
-    on stdin."""
+    on stdin. An order is whole before its event is set: whoever sees
+    `window` sees t0 and t1, whoever sees `drain` sees the deadline and
+    the counts to wait for."""
 
-    def __init__(self) -> None:
+    def __init__(self, stdin=None) -> None:
         self.go = threading.Event()
         self.probe = threading.Event()
         self.window = threading.Event()
         self.drain = threading.Event()
         self.t0 = self.t1 = 0
         self.drain_deadline = 0
-        self.expect_path = "-"
+        # Messages a stream must hold before its consumer may leave the
+        # drain. None has one meaning: the cell's delivery is `prefix`
+        # (the order came with `-`) and a consumer leaves at DRAIN.
+        self.want = None
+        self.drain_error: str | None = None
         self.gone = False
+        self._stdin = sys.stdin if stdin is None else stdin
         threading.Thread(target=self._read, daemon=True).start()
 
     def _read(self) -> None:
-        for line in sys.stdin:
+        for line in self._stdin:
             w = line.split()
             if not w:
                 continue
@@ -65,7 +73,13 @@ class Orders:
                 self.t0, self.t1 = int(w[1]), int(w[2])
                 self.window.set()
             elif w[0] == "DRAIN":
-                self.drain_deadline, self.expect_path = int(w[1]), w[2]
+                self.drain_deadline = int(w[1])
+                if w[2] != "-":
+                    try:
+                        self.want = np.load(w[2])
+                    except Exception as e:  # counts that cannot be had
+                        self.drain_error = (f"DRAIN counts {w[2]}: "
+                                            f"{type(e).__name__}: {e}")
                 self.drain.set()
         self.gone = True  # parent went away: let every wait end
         for e in (self.go, self.probe, self.window, self.drain):
@@ -193,7 +207,7 @@ def role_consume(spec: dict, orders: Orders) -> dict:
     chunks: list[list] = [[] for _ in range(threads)]  # (stream, recv_ns, blob)
     counts = np.zeros(len(streams), np.int64)
     errors: list[str] = []
-    expect = {"counts": None}
+    short_at_deadline = [0] * threads
     fault = [spec.get("fault")]
     orders.go.wait()
 
@@ -213,12 +227,14 @@ def role_consume(spec: dict, orders: Orders) -> dict:
         try:
             while not orders.gone:
                 if orders.drain.is_set():
-                    want = expect["counts"]
-                    if want is None or time.monotonic_ns() \
-                            >= orders.drain_deadline:
+                    want = orders.want
+                    if want is None:  # delivery `prefix`: leave at DRAIN
                         break
                     short = [s for s in own if counts[s] < want[s]]
                     if not short:
+                        break
+                    if time.monotonic_ns() >= orders.drain_deadline:
+                        short_at_deadline[tid] = len(short)
                         break
                     s = short[i % len(short)]
                 elif gap > 0:
@@ -263,11 +279,10 @@ def role_consume(spec: dict, orders: Orders) -> dict:
     ts = [threading.Thread(target=run, args=(i,)) for i in range(threads)]
     for t in ts:
         t.start()
-    orders.drain.wait()
-    if orders.expect_path != "-" and not orders.gone:
-        expect["counts"] = np.load(orders.expect_path)
     for t in ts:
         t.join()
+    if orders.drain_error:
+        errors.append(orders.drain_error)
 
     t0, t1 = orders.t0, orders.t1
     per_stream: dict[int, list[bytes]] = {}
@@ -302,7 +317,8 @@ def role_consume(spec: dict, orders: Orders) -> dict:
     np.save(os.path.join(spec["work"], f"recv-{pid}.latstamp.npy"),
             np.concatenate(lat_stamps) if lat_stamps else np.zeros(0, np.int64))
     return {"received": int(counts.sum()), "received_by_t1": got_by_t1,
-            "ragged_chunks": ragged, "errors": errors[:4]}
+            "ragged_chunks": ragged, "errors": errors[:4],
+            "short_at_deadline": sum(short_at_deadline)}
 
 
 def load_records(work: str) -> np.ndarray:

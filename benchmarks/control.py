@@ -23,10 +23,11 @@ at rehearsal size. Never part of a benchmark run.
 from __future__ import annotations
 
 import argparse
+import json
 import sys
 import time
 
-from run import Run, RunFailed
+from run import Run, RunFailed, failing
 
 MUST_FAIL = ("replicas.scanned", "delivery.differ", ".missing")
 
@@ -39,10 +40,7 @@ def control_run(workload: str, seed: int, seconds: float,
               fault="flip_delivered,short_replica",
               t_start_ns=time.monotonic_ns())
     out = run.run()
-    failed = [name for name, value, limit in run.numbers
-              if (value != int(limit[3:]) if limit.startswith("==")
-                  else value != 0)]
-    return out, failed
+    return out, list(failing(run.numbers))
 
 
 def main() -> int:
@@ -61,6 +59,12 @@ def main() -> int:
             print(f"control seed {seed}: run failed outright: {e}")
             ok = False
             continue
+        # The run's result line: it names what failed.
+        if args.rehearse:
+            print(f"rehearsal (no device metric, not a result): "
+                  f"{json.dumps(out)}", file=sys.stderr)
+        else:
+            print(json.dumps(out))
         caught = all(any(m in f for f in failed) for m in MUST_FAIL)
         print(f"control seed {seed}: correct={out['correct']} failed "
               f"numbers {failed} -> "

@@ -20,10 +20,6 @@ Parameters (the cell's `generator.params`):
                     file, when a cell asks for it)
   tick_s            how often the dispatcher looks for due messages
   rpc_timeout_s     a request's deadline
-  drain_limit_s     the cell's own `drain_limit_s`, repeated because a
-                    generator sees only its params: how long after its
-                    last ack the subscription may take to commit past
-                    everything that was acked (below)
   rate_steps_msgs_per_s  (the builder's knee sweep only) as `open_loop`
 
 Records. One line per acked PART (the partition batch a produce.multi
@@ -36,31 +32,6 @@ message will open a new part when the previous message it sent to that
 partition has left the accumulator (`SendWaiter.sent()`); otherwise it
 reuses the open part's stamp. Ack and delivery latency are therefore
 timed from the due time of a part's oldest message, linger included.
-
-The wait before reporting. `child.py`'s consumer threads leave their loop
-if they look at the DRAIN order before the consumer's main thread has
-loaded the counts that came with it, and whatever their partitions still
-lacked is then never delivered. With idle consumers that window is
-microseconds; in this cell every poll returns messages, the consumer
-processes are busy, and one thread in about every second run left early
-(my chip runs, PR 27: the missing messages were one thread's partitions
-each time). `run.py` sends DRAIN when the producers have reported, so a
-producer here reports only once the subscription has read what was acked:
-its committed position, read with the program's own
-`ConsumerClient.consume_with_position` under the subscription's name and
-`auto_commit=False`, has passed the last acked offset in every partition.
-The limit on whole delivery stays the cell's: the wait ends
-`drain_limit_s` after this producer's last ack, and every partition the
-subscription has not committed by then is a FAILED CALL, which makes the
-run not `correct` - a subscription that cannot catch up inside the limit
-fails here as it would fail the drain. So that the wait adds as little as
-it can to the controller it waits for, the producer processes tell each
-other their last acked offsets through the run's work directory and each
-reads only its share of the partitions (one position read a partition a
-pass, over all processes), on `PROBES` threads. With BENCH_KEEP_TRACE set
-each process leaves `settle-<proc>.json` there: how long the wait took.
-The cure is two lines in `child.py` (PERF.md section 7); the wait and
-this parameter go when a `benchmark` PR has made it.
 
 Per-key order is checked here, at the ack: within one partition this
 producer's parts must be acked at offsets that rise with send order, a
@@ -81,7 +52,7 @@ from collections import deque
 import numpy as np
 
 from benchmarks import payload
-from ripplemq_tpu.client import ConsumerClient, ProducerClient
+from ripplemq_tpu.client import ProducerClient
 
 if not hasattr(ProducerClient, "send"):
     raise ImportError("this program's ProducerClient has no send(): the "
@@ -310,100 +281,4 @@ def run(ctx) -> dict:
                 due_msgs += n
     due_msgs += sum(n for st, n, _ in ctx.failed_calls
                     if orders.t0 <= st < orders.t1)
-    await_subscription(
-        ctx, {s: max(base + rec[1] for base, rec in by_base.items())
-              for s, by_base in parts.items()},
-        max((rec[4] for by_base in parts.values()
-             for rec in by_base.values()), default=time.monotonic_ns()),
-        float(p["drain_limit_s"]))
     return {"due_calls": due_msgs, "due_msgs": due_msgs, "late_ms": late_ms}
-
-
-PROBES = 4  # position readers a producer process
-
-
-def share_ends(ctx, ends: dict[int, int], deadline_ns: int) -> dict[int, int]:
-    """This process's `ends` (stream -> the offset after its last acked
-    message) left in the work directory for the others, theirs read, and
-    the highest of all for the streams that are this process's to watch
-    (stream mod nprocs). A process that has not left its file by the
-    deadline is a failed call: its acks are then watched by nobody."""
-    work = ctx.spec["work"]
-    path = os.path.join(work, f"keyed-ends-{ctx.proc_id}.json")
-    with open(path + ".tmp", "w") as f:
-        json.dump(ends, f)
-    os.replace(path + ".tmp", path)
-    top = dict(ends)
-    for k in range(ctx.nprocs):
-        other = os.path.join(work, f"keyed-ends-{k}.json")
-        while not os.path.exists(other):
-            if time.monotonic_ns() >= deadline_ns or ctx.orders.gone:
-                ctx.failed(0, 0, RuntimeError(
-                    f"delivery: producer {k} had no last ack to tell of by "
-                    f"this producer's limit"))
-                break
-            time.sleep(0.02)
-        else:
-            with open(other) as f:
-                for s, end in json.load(f).items():
-                    top[int(s)] = max(top.get(int(s), 0), int(end))
-    return {s: end for s, end in top.items()
-            if s % ctx.nprocs == ctx.proc_id}
-
-
-def await_subscription(ctx, ends: dict[int, int], last_ack_ns: int,
-                       limit_s: float) -> None:
-    """Until the subscription's committed position has passed every
-    producer's last acked message in this process's share of the streams,
-    or `limit_s` after `last_ack_ns`: what is not committed by then is a
-    failed call each (module docstring, "The wait before reporting").
-    Reads positions only: `auto_commit=False`."""
-    deadline_ns = last_ack_ns + int(limit_s * 1e9)
-    todo = sorted(share_ends(ctx, ends, deadline_ns).items())
-    behind: list[tuple[int, int, int]] = []  # stream, end, position seen
-    reads = [0] * PROBES
-
-    def probe(tid: int) -> None:
-        mine = [(s, end, -1) for s, end in todo[tid::PROBES]]
-        cc = ConsumerClient(ctx.spec["bootstrap"], ctx.spec["subscription"],
-                            auto_commit=False, max_messages=1,
-                            rpc_timeout_s=ctx.rpc_timeout_s)
-        try:
-            while mine and time.monotonic_ns() < deadline_ns \
-                    and not ctx.orders.gone:
-                left = []
-                for s, end, at in mine:
-                    if time.monotonic_ns() < deadline_ns:
-                        topic, part = ctx.streams[s]
-                        try:
-                            _, _, at, _ = cc.consume_with_position(topic, part)
-                            reads[tid] += 1
-                        except Exception:
-                            pass  # asked again next pass; failed if never
-                    if at < end:
-                        left.append((s, end, at))
-                mine = left
-                if mine:
-                    time.sleep(0.2)
-        finally:
-            cc.close()
-            behind.extend(mine)
-
-    threads = [threading.Thread(target=probe, args=(i,))
-               for i in range(PROBES)]
-    for t in threads:
-        t.start()
-    for t in threads:
-        t.join()
-    waited_s = (time.monotonic_ns() - last_ack_ns) / 1e9
-    for s, end, at in sorted(behind):
-        ctx.failed(0, 0, RuntimeError(
-            f"delivery: {limit_s:g}s after the last ack the subscription "
-            f"has committed {at} of stream {s}, acked to {end}"))
-    keep = os.environ.get("BENCH_KEEP_TRACE")
-    if keep:  # the builder's look at how long the wait took
-        os.makedirs(keep, exist_ok=True)
-        with open(os.path.join(keep, f"settle-{ctx.proc_id}.json"), "w") as f:
-            json.dump({"last_ack_to_report_s": waited_s, "streams": len(todo),
-                       "position_reads": sum(reads),
-                       "behind_at_limit": len(behind)}, f)

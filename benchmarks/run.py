@@ -49,6 +49,8 @@ EXIT_REHEARSAL = 4
 DEADLINE_S = 340.0       # a run ends inside the contract's 360 s ...
 DEADLINE_COLD_S = 1150.0  # ... or 1200 s where it had to compile
 PROFILE_S = 3.0
+WARM_LAST = "jit(_read_many)"  # the last program DataPlane.warm builds
+WARM_WAIT_S = 60.0
 STAT_KEYS = (("boot_failures", 0), ("duty_errors", []), ("erasure_errors", []),
              ("store_native", True), ("store_quarantined", False))
 
@@ -75,6 +77,50 @@ def merge(base: dict, over: dict) -> dict:
 
 class RunFailed(Exception):
     pass
+
+
+def failing(numbers: list) -> dict:
+    """Of the numbers compared (name, value, limit), those outside their
+    limit, by name. A limit is exact: `== N`, or 0."""
+    return {name: int(value) for name, value, limit in numbers
+            if value != (int(limit[3:]) if limit.startswith("==") else 0)}
+
+
+def compared_lines(numbers: list) -> list[str]:
+    bad = failing(numbers)
+    return [f"compared {name} = {value} (limit {limit})"
+            + ("  <-- FAILS" if name in bad else "")
+            for name, value, limit in numbers]
+
+
+def verdict(numbers: list, drain: dict) -> dict:
+    """The end of a result line. Always, and last, `compared`: every
+    number with its limit. Only where a number fails: that number again
+    as a top-level scalar under its own name, with what the drain did
+    (`drain`), because the driver's record of a refused run keeps the
+    line's top-level scalars and little else."""
+    bad = failing(numbers)
+    out = dict(bad, **drain) if bad else {}
+    out["compared"] = {name: [int(value), limit]
+                       for name, value, limit in numbers}
+    return out
+
+
+def warm_over(compile_log) -> bool:
+    """Whether the launcher's compile log (lines of compiles.jsonl) shows
+    WARM_LAST compiled or fetched from the cache."""
+    seen = False
+    for line in compile_log:
+        try:
+            msg = json.loads(line)["msg"]
+        except ValueError:  # the line being written
+            continue
+        if msg.startswith("Compiling ") and WARM_LAST in msg:
+            seen = True
+        elif seen and msg.startswith(("Finished XLA compil",
+                                      "Persistent compilation cache hit")):
+            return True
+    return False
 
 
 class Run:
@@ -297,6 +343,29 @@ class Run:
                 return st
             time.sleep(0.25)
 
+    def await_warm(self) -> int:
+        """The moment the broker's own warm-up is over, which as a rule is
+        now: it builds its round programs with the device lock held and the
+        probe is acked behind them. But `DataPlane.warm` lets go of the
+        lock between two programs, and a probe that slips in there is
+        acked with programs still to build, which then compile inside the
+        window (PERF.md section 6, PR 31). The compile log says which it
+        was: warm-up is over when its last program, WARM_LAST, has been
+        compiled or fetched. Traffic starts only then."""
+        path = os.path.join(self.work, "compiles.jsonl")
+        until = time.monotonic() + WARM_WAIT_S
+        while True:
+            if os.path.exists(path):
+                with open(path) as f:
+                    if warm_over(f):
+                        return time.monotonic_ns()
+            self.alive_or_raise()
+            if time.monotonic() > until:
+                log(f"no {WARM_LAST} in the broker's compile log {WARM_WAIT_S}s"
+                    f" after the first ack: going on without")
+                return time.monotonic_ns()
+            time.sleep(0.1)
+
     # ------------------------------------------------------------------ run
     def run(self) -> dict:
         watchdog = threading.Timer(DEADLINE_COLD_S, self._timed_out)
@@ -390,10 +459,12 @@ class Run:
                 raise RunFailed("no produce was acked\n"
                                 + self.tail("produce-0"))
         t_first = time.monotonic_ns()
+        t_warm = self.await_warm()
         self.tell(consumers + producers, "GO")
-        log(f"first ack after {(t_first - self.t_start_ns) / 1e9:.1f}s; warming "
-            f"{cell['warm_s']}s with the cell's traffic")
-        t0 = t_first + int(float(cell["warm_s"]) * 1e9)
+        log(f"first ack after {(t_first - self.t_start_ns) / 1e9:.1f}s, the "
+            f"broker's warm-up over {(t_warm - t_first) / 1e9:.1f}s later; "
+            f"warming {cell['warm_s']}s with the cell's traffic")
+        t0 = t_warm + int(float(cell["warm_s"]) * 1e9)
         t1 = t0 + int(self.seconds * 1e9)
         self.tell(producers + consumers, f"WINDOW {t0} {t1}")
         setup_s = (t0 - self.t_start_ns) / 1e9
@@ -433,14 +504,14 @@ class Run:
         records = load_records(self.work)
         ref = ReferenceLog(self.seed, self.size, records, len(self.streams))
         whole = cell["delivery"] == "whole"
+        t_drain = time.monotonic_ns()
         if whole:
             expect = os.path.join(self.work, "expect.npy")
             np.save(expect, ref.counts)
             self.tell(consumers, f"DRAIN "
-                      f"{time.monotonic_ns() + int(cell['drain_limit_s'] * 1e9)}"
-                      f" {expect}")
+                      f"{t_drain + int(cell['drain_limit_s'] * 1e9)} {expect}")
         else:
-            self.tell(consumers, f"DRAIN {time.monotonic_ns()} -")
+            self.tell(consumers, f"DRAIN {t_drain} -")
         cons = self.collect(consumers, ch, "consume")
         t_drained = time.monotonic_ns()
 
@@ -625,13 +696,9 @@ class Run:
         N.append(("window.unexpected_compiles", len(unexpected), "0"))
         N.append(("producers.failed_calls",
                   sum(r["failed_calls_total"] for r in prod), "0"))
-        correct = True
-        for name, value, limit in N:
-            ok = (value == want_replicas) if limit.startswith("==") \
-                else value == 0
-            correct = correct and ok
-            log(f"compared {name} = {value} (limit {limit})"
-                + ("" if ok else "  <-- FAILS"))
+        correct = not failing(N)
+        for line in compared_lines(N):
+            log(line)
         for e in stat_errors[:6] + [e for r in prod for e in r["errors"]][:4] \
                 + [e for r in cons for e in r["errors"]][:4]:
             log(f"  error: {e}")
@@ -724,6 +791,10 @@ class Run:
                           for k, (v, u) in metrics.items()}
         out["device"] = dev_out
         out["cold"] = self.cold
+        out.update(verdict(N, {
+            "drain_s": (t_drained - t_drain) / 1e9,
+            "drain.deadline_reached":
+                int(sum(r["short_at_deadline"] for r in cons) > 0)}))
         return out
 
     def _cut_tail(self, broker: int) -> None:
@@ -761,6 +832,8 @@ def main(argv=None) -> int:
         print(f"FAIL: {e}", file=sys.stderr, flush=True)
         return EXIT_FAILED
     line = json.dumps(out)
+    for text in compared_lines(run.numbers):  # stderr ends with them too
+        print(text, file=sys.stderr, flush=True)
     if args.rehearse:
         print(f"rehearsal (no device metric, not a result): {line}",
               file=sys.stderr, flush=True)

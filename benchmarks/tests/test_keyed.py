@@ -2,9 +2,9 @@
 seed with YCSB's zipfian shape, the arrival schedule keeps its count,
 and whole rehearsal runs (CPU backend, the files' rehearsal sizes) of
 `omb-1024p-100b-keyed.zipf` - sound, with the per-key order broken
-underneath by a patched client, and with a subscription that never
-commits, which the generator's wait must fail inside the cell's drain
-limit. About 20 s each of the three runs."""
+underneath by a patched client, and with a partition the subscription
+never delivers, which the cell's own drain must fail at its limit and
+name in the result line. About 20 s each of the three runs."""
 
 import json
 import os
@@ -51,18 +51,23 @@ def test_the_schedule_keeps_its_count_and_knows_one_arrival_law():
         schedule(0.5, 0, 10**9, 1000, "bursts")
 
 
-def test_the_generator_waits_no_longer_than_the_cell_drains():
-    with open(os.path.join(os.path.dirname(__file__), "..", "workloads",
-                           f"{CELL}.json")) as f:
-        cell = json.load(f)
-    assert cell["producers"]["params"]["drain_limit_s"] \
-        == cell["drain_limit_s"]
+def test_the_cell_drains_as_steady_does_and_its_generator_waits_for_nothing():
+    def cell(name):
+        with open(os.path.join(os.path.dirname(__file__), "..", "workloads",
+                               f"{name}.json")) as f:
+            return json.load(f)
+
+    keyed, steady = cell(CELL), cell("omb-1024p-100b.steady")
+    assert "drain_limit_s" not in keyed["producers"]["params"]
+    assert keyed["drain_limit_s"] == steady["drain_limit_s"] == 15.0
+    assert keyed["delivery"] == steady["delivery"] == "whole"
+    assert keyed["consumers"] == steady["consumers"]
 
 
 def run_cell(drain_limit_s=None):
     run = Run(CELL, 4000000011, 3.0, False, rehearse=True)
-    if drain_limit_s is not None:  # a failing wait need not take 15 s
-        run.cell["producers"]["params"]["drain_limit_s"] = drain_limit_s
+    if drain_limit_s is not None:  # a failing drain need not take 15 s
+        run.cell["drain_limit_s"] = drain_limit_s
     out = run.run()
     return out, {name: value for name, value, _ in run.numbers}
 
@@ -109,18 +114,29 @@ def test_parts_acked_out_of_order_are_failed_calls(tmp_path, monkeypatch):
     assert numbers["producers.failed_calls"] > 0
 
 
-NO_COMMIT = '''
+NOT_DELIVERED = '''
 import ripplemq_tpu.client.consumer as C
-C.ConsumerClient._auto_commit = lambda self, *a, **k: None
+_consume = C.ConsumerClient.consume
+def consume(self, topic, partition=None, max_messages=None):
+    if partition == 3:
+        return []
+    return _consume(self, topic, partition, max_messages)
+C.ConsumerClient.consume = consume
 '''
 
 
-def test_a_subscription_behind_at_the_drain_limit_is_failed_calls(
+def test_a_subscription_short_at_the_drain_limit_is_not_correct_and_says_so(
         tmp_path, monkeypatch):
-    """Consumers that receive and never commit: everything is delivered,
-    yet the subscription's position never passes the acks, and the
-    generator's wait must say so when the limit is up, not wait on."""
-    patch_children(tmp_path, monkeypatch, NO_COMMIT)
+    """Consumers that never deliver one partition: its thread reads on to
+    the cell's drain limit, not beyond, and the result line names what
+    failed, how long the drain took and that it ran into its limit."""
+    patch_children(tmp_path, monkeypatch, NOT_DELIVERED)
     out, numbers = run_cell(drain_limit_s=2.0)
     assert out["correct"] is False
-    assert numbers["producers.failed_calls"] > 0
+    assert numbers["delivery.missing"] > 0
+    assert out["delivery.missing"] == numbers["delivery.missing"]
+    assert out["drain.deadline_reached"] == 1
+    assert 2.0 <= out["drain_s"] < 10.0
+    assert list(out)[-1] == "compared"
+    assert out["compared"]["delivery.missing"] \
+        == [numbers["delivery.missing"], "0"]
