@@ -121,6 +121,12 @@ _CACHE_GAP = object()
 # base -1: present in the log, position no longer remembered).
 _PID_WINDOW = 8
 
+# The longest the step thread sleeps inside an open gather before it
+# looks at the queues again (DataPlane._gather): max_batch pendings end a
+# gather early and a slot a resolver freed may hold an older batch.
+# Also the most it gathers after a launch that outlasted coalesce_s.
+_GATHER_SLICE_S = 0.004
+
 
 class _Pending:
     __slots__ = ("payloads", "rows", "future", "rounds_left", "pid", "seq",
@@ -202,6 +208,11 @@ class DataPlane:
         from ripplemq_tpu.obs.trace import FlightRecorder
 
         self.metrics = metrics if metrics is not None else Metrics(enabled=obs)
+        # The gather's deadline is behaviour, not telemetry: it runs on
+        # the registry's clock (tests inject one) unless the registry is
+        # off, whose clock is a constant.
+        self._clock = (self.metrics.clock if self.metrics.enabled
+                       else time.perf_counter)
         self.recorder = recorder if recorder is not None else FlightRecorder()
         # Causal-tracing span ring (obs/spans.py), normally the owning
         # broker's — and only handed over when tracing is CONFIGURED
@@ -231,6 +242,11 @@ class DataPlane:
         self._m_queue_wait_us = m.histogram("produce.queue_wait_us")
         self._m_h2d_bytes = m.counter("round.h2d_bytes")
         self._m_pipeline_full = m.counter("round.pipeline_full")
+        # How often the gather engages (_gather): live rounds launched
+        # with no append in them, and loop tops that found the deadline
+        # already past and drained without a lap.
+        self._m_offsets_only = m.counter("round.offsets_only")
+        self._m_gather_expired = m.counter("round.gather_expired")
         # What a round is staged as against what it carries (_drain):
         # the appending partitions of each live round, and the rows of
         # the [A, B, SB] block stack that holds them (A the active-set
@@ -528,10 +544,11 @@ class DataPlane:
         self._offsets_shadow = np.zeros(
             (cfg.partitions, cfg.max_consumers), np.int32
         )
-        # Coalescing window: when few submissions are pending, wait this
-        # long before dispatching so a whole burst of concurrent
-        # producers lands in ONE round — every round costs a full
-        # host↔device sync to resolve. 0 disables.
+        # Coalescing window: the least time between the starts of two
+        # rounds (_gather_left), hence the longest a queued batch waits
+        # for company, counted from its submit, so a whole burst of
+        # concurrent producers lands in ONE round — every round costs a
+        # full host↔device sync to resolve. 0 disables.
         self.coalesce_s = coalesce_s
         self._inflight: "queue.Queue[tuple[StepInput, dict, object]]" = (
             queue.Queue(maxsize=self.pipeline_depth)
@@ -1129,7 +1146,7 @@ class DataPlane:
             _Pending(list(payloads), fut, self.max_retry_rounds, rows,
                      pid=pid, seq=seq,
                      tctx=tctx if self.spans is not None else None,
-                     t_submit=self.metrics.clock())
+                     t_submit=self._clock())
         )
         if pid > 0:
             # Settled batches are moved to the dedup table — and
@@ -2021,7 +2038,7 @@ class DataPlane:
         (StepInput, round_ctx) or None if nothing drainable remains."""
         cfg = self.cfg
         P, B, SB, U = cfg.partitions, cfg.max_batch, cfg.slot_bytes, cfg.max_offset_updates
-        now = self.metrics.clock()  # produce.queue_wait_us, per pending
+        now = self._clock()  # produce.queue_wait_us, per pending
         # Active-set rounds: packed [B, SB] blocks per appending slot
         # (compact device input + the bytes the resolver persists); the
         # StepInput ships only a tiny dummy in the entries field.
@@ -2160,6 +2177,63 @@ class DataPlane:
                      "bases": round_bases, "entries": blocks,
                      "counts": {s: int(counts[s]) for s in blocks}}
 
+    def _gather_left(self, t_launch: float,
+                     t_return: float) -> Optional[float]:
+        """Seconds until the open gather's deadline (zero or less once
+        it has passed), or None where none is open: nothing drainable,
+        or max_batch drainable appends already. `t_launch` is when the
+        step thread started its previous launch, `t_return` when it
+        came back from it.
+
+        A round starts coalesce_s after the previous one STARTED: the
+        launch, the hand-off to the resolvers and whatever else the
+        thread did since are time gathered, not time added to the
+        wait, and rounds come no closer than coalesce_s whatever the
+        load. Nothing queued waits for company longer than coalesce_s
+        from its own submit (a batch freed from a busy slot, a requeued
+        retry) - but for one slice after a launch's return: that is
+        when the acks a round set loose bring their producers' next
+        requests, and a round started without them is a small round
+        (PR 25's lesson; ref-compose.sync, PERF.md section 6).
+
+        Only pendings on non-busy slots count: queues behind an
+        in-flight round cannot be drained this iteration, so waiting
+        for them would delay the drainable work for nothing. Offset
+        commits wait for the same deadline and ride the round."""
+        npend, anchor = 0, t_launch
+        with self._lock:
+            for slot, q in self._appends.items():
+                if q and slot not in self._busy_a:
+                    npend += len(q)
+                    # Per-slot FIFO, retries requeued at the front: the
+                    # head is the queue's oldest.
+                    anchor = min(anchor, q[0].t_submit)
+            if not npend and not any(
+                    slot not in self._busy_o for slot in self._offsets):
+                return None
+        if npend >= self.cfg.max_batch:
+            return None
+        deadline = max(anchor + self.coalesce_s,
+                       t_return + min(self.coalesce_s, _GATHER_SLICE_S))
+        return deadline - self._clock()
+
+    def _gather(self, lap, t_launch: float, t_return: float) -> None:
+        """Wait out the open gather, one round.coalesce lap a slice:
+        nothing is launched inside it, so what is queued meanwhile
+        rides ONE round. stop() cuts a slice short."""
+        lapped = False
+        while not self._stop.is_set():
+            left = self._gather_left(t_launch, t_return)
+            if left is None:
+                return
+            if left <= 0:
+                if not lapped:
+                    self._m_gather_expired.inc()
+                return
+            lap.to(self._st_coalesce)
+            lapped = True
+            self._stop.wait(min(left, _GATHER_SLICE_S))
+
     def _run(self) -> None:
         """Step thread: drain → dispatch → hand off to the resolver.
 
@@ -2175,7 +2249,9 @@ class DataPlane:
                           hand-off to the resolvers (`_inflight.put`
                           blocks at pipeline_depth outstanding rounds;
                           round.pipeline_full counts those)
-        - round.coalesce  the coalesce sleep
+        - round.coalesce  the gather: laps of at most _GATHER_SLICE_S
+                          until coalesce_s after the previous launch
+                          started (`_gather_left`)
         - round.drain     `_drain()`: queues to device-shaped arrays
         - round.lock_wait waiting for `_device_lock`
         - round.launch    the launch call under the lock (histogram
@@ -2183,22 +2259,15 @@ class DataPlane:
         """
         lap = self.metrics.lap()
         lap.to(self._st_idle)
+        # The previous launch's start and return on the gather's clock
+        # (`lap.to` reads none where the registry is off): a plane that
+        # has launched nothing yet gathers for nothing.
+        t_launch = t_return = float("-inf")
         while not self._stop.is_set():
             ctx = None
             try:
                 if self.coalesce_s > 0:
-                    with self._lock:
-                        # Only pendings on non-busy slots count: queues
-                        # behind an in-flight round cannot be drained this
-                        # iteration, so sleeping for them delays the
-                        # drainable work (and offset commits) for nothing.
-                        npend = sum(
-                            len(q) for slot, q in self._appends.items()
-                            if slot not in self._busy_a
-                        )
-                    if 0 < npend < self.cfg.max_batch:
-                        lap.to(self._st_coalesce)
-                        time.sleep(self.coalesce_s)  # gather the burst
+                    self._gather(lap, t_launch, t_return)
                 lap.to(self._st_drain)
                 work = self._drain()
                 if work is None:
@@ -2214,6 +2283,7 @@ class DataPlane:
                 # stages (commit fetch, settle entry, acks, persist,
                 # release) measure against it, lock wait included.
                 t_dispatch = lap.to(self._st_lock_wait)
+                t_launch = self._clock()
                 with self._device_lock:
                     lap.to(self._st_launch)
                     try:
@@ -2235,6 +2305,7 @@ class DataPlane:
                 # Stage 1 of the round-lifecycle decomposition ends
                 # here: the (async) device launch call returned.
                 t_dispatched = lap.to(self._st_idle)
+                t_return = self._clock()
                 self._m_h2d_bytes.inc(ctx["h2d_bytes"])
                 self.dispatches += 1
                 live_rounds = sum(
@@ -2243,6 +2314,10 @@ class DataPlane:
                 )
                 self.rounds += live_rounds
                 self._m_chain_rounds.observe_int(live_rounds)
+                self._m_offsets_only.inc(sum(
+                    1 for rc in ctx["chain"]
+                    if rc["offsets"] and not rc["appends"]
+                ))
                 ctx["t_dispatch"] = t_dispatch
                 ctx["t_dispatched"] = t_dispatched
                 self.recorder.record(

@@ -1,7 +1,7 @@
 """Host stages: each timed where it runs, once, onto both clocks.
 
 A STAGE is a named stretch of one thread's time on the host path of a
-round (the step thread's coalesce sleep, its drain, the wait for the
+round (the step thread's gather, its drain, the wait for the
 device lock, the launch call, a resolver's blocking fetch, the settle
 thread's standby wait, a sealed segment's RS encode). Timing one does
 two things at once, from the same pair of instants:
@@ -51,8 +51,9 @@ from typing import Callable, Optional
 STAGE_NAMES = frozenset({
     # The step thread (broker/dataplane.py _run), a partition of its
     # time: waiting for work or for room in the resolver pipeline, the
-    # coalesce sleep, building the round, waiting for the device lock,
-    # the launch call (histogram: engine.dispatch_us).
+    # gather (laps up to coalesce_s past the last launch), building the
+    # round, waiting for the device lock, the launch call (histogram:
+    # engine.dispatch_us).
     "round.idle", "round.coalesce", "round.drain", "round.lock_wait",
     "round.launch",
     # A resolver's blocking fetch of the round's committed mask

@@ -786,7 +786,10 @@ def test_step_thread_round_stages_add_up_to_elapsed_time():
     """ISSUE 24: the five round.* stages of DataPlane._run partition the
     step thread's time — on the fake clock their sums equal the time
     between the thread's first and last clock read exactly — and
-    produce.queue_wait_us holds one observation per drained pending."""
+    produce.queue_wait_us holds one observation per drained pending.
+    PR 32: the gather is many short laps up to a deadline counted from
+    the previous launch's start, each its own round.coalesce
+    observation, and the closure holds across them."""
     from ripplemq_tpu.broker.dataplane import DataPlane
     from ripplemq_tpu.obs.metrics import Metrics
     from tests.helpers import small_cfg
@@ -794,15 +797,18 @@ def test_step_thread_round_stages_add_up_to_elapsed_time():
     clock, seen = _half_second_clock()
     m = Metrics(clock=clock)
     dp = DataPlane(small_cfg(), mode="local", max_retry_rounds=3,
-                   metrics=m, coalesce_s=0.001)
+                   metrics=m, coalesce_s=100.0)
     dp.start()
     try:
         dp.set_leader(0, 0, 1)
         dp.set_leader(1, 1, 1)
-        # One lone message first: a partial batch is what the step
-        # thread sleeps the coalesce window for.
+        # One lone message first: a quiet plane launches it at once,
+        # and what follows gathers until 100 s after that launch began
+        # - on a clock that gains half a second a read, some eighty
+        # laps, each a real sleep of one slice.
         dp.submit_append(0, [b"m0"]).result(timeout=30)
-        futs = [dp.submit_append(i % 2, [b"m%d" % i]) for i in range(1, 12)]
+        # (fewer than max_batch 8: that many pendings end a gather)
+        futs = [dp.submit_append(i % 2, [b"m%d" % i]) for i in range(1, 6)]
         for f in futs:
             f.result(timeout=30)
         step_ident = dp._thread.ident
@@ -811,16 +817,17 @@ def test_step_thread_round_stages_add_up_to_elapsed_time():
     first, last = seen[step_ident]
     sums = {n: m.histogram(n).total for n in ROUND_STAGE_HISTOGRAMS}
     assert sum(sums.values()) == int((last - first) * 1e6), sums
-    # Every stage ran: the plane slept the coalesce window, drained,
-    # waited for the lock, launched and idled.
+    # Every stage ran: the plane gathered in more laps than it made
+    # launches, drained, waited for the lock, launched and idled.
     counts = {n: m.histogram(n).count for n in ROUND_STAGE_HISTOGRAMS}
     assert all(counts.values()), counts
+    assert counts["round.coalesce_us"] > dp.dispatches, counts
     assert counts["round.lock_wait_us"] == counts["engine.dispatch_us"] \
         == dp.dispatches
     snap = m.snapshot()
     # No round failed, so every pending was drained exactly once.
     assert snap["counters"]["produce.round_retries"] == 0
-    assert snap["histograms"]["produce.queue_wait_us"]["count"] == 12
+    assert snap["histograms"]["produce.queue_wait_us"]["count"] == 6
     assert snap["counters"]["round.h2d_bytes"] > 0
     assert snap["counters"].get("round.pipeline_full", 0) == 0
 
