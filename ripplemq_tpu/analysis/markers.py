@@ -95,6 +95,9 @@ FAST_MODULES = {
     "test_stripes",             # ~30 s: any-k matrix + 3 striped clusters
     "test_store_migrate",
     "test_stride_rule",
+    "test_wide_rows",           # ~35 s: the served path, kernel parity and
+                                # RS at slot_bytes 1152; one v5e compile
+                                # (the VMEM rule's three edges: -m slow)
     "test_wire",
 }
 

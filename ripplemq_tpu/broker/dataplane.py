@@ -42,6 +42,7 @@ from ripplemq_tpu.core.encode import (
     stamp_term,
 )
 from ripplemq_tpu.core.state import ReplicaState, StepInput, row_lens
+from ripplemq_tpu.ops.append import active_bucket, active_buckets
 from ripplemq_tpu.parallel.engine import make_local_fns, make_spmd_fns
 from ripplemq_tpu.parallel.mesh import make_mesh
 from ripplemq_tpu.storage.segment import (
@@ -234,6 +235,7 @@ class DataPlane:
         self._m_retry_exhausted = m.counter("produce.retry_exhausted")
         self._m_read_calls = m.counter("read.calls")
         self._m_read_msgs = m.counter("read.messages")
+        self._m_read_bytes = m.counter("read.bytes")  # payload returned
         # Host stages (obs/stages.py): each a `<name>_us` histogram on
         # the registry's clock plus a profiler annotation of the same
         # name. The five step-thread stages PARTITION that thread's
@@ -253,6 +255,10 @@ class DataPlane:
         # bucket) - produce.messages over round.staged_rows is the fill.
         self._m_active_slots = m.histogram("round.active_slots")
         self._m_staged_rows = m.counter("round.staged_rows")
+        # Building that stack (zero-fill + one block copy a listed
+        # partition), the per-BYTE part of round.drain: a histogram
+        # inside the drain stage, not a sixth stage of the step thread.
+        self._m_stage_us = m.histogram("round.stage_us")
         self._st_idle = m.stage("round.idle")
         self._st_coalesce = m.stage("round.coalesce")
         self._st_drain = m.stage("round.drain")
@@ -1307,7 +1313,9 @@ class DataPlane:
             raise ValueError(f"partition slot {slot} out of range")
         self._m_read_calls.inc()
         with self._st_read.timed():  # read.serve: the whole call
-            return self._read(slot, offset, replica, max_msgs)
+            msgs, nxt = self._read(slot, offset, replica, max_msgs)
+        self._m_read_bytes.inc(sum(map(len, msgs)))
+        return msgs, nxt
 
     def _read(self, slot: int, offset: int, replica: int,
               max_msgs: Optional[int]) -> tuple[list[bytes], int]:
@@ -1947,6 +1955,7 @@ class DataPlane:
         # device input — a sparse round ships A/P of the dense bytes.
         B, SB = cfg.max_batch, cfg.slot_bytes
         A = self._active_bucket(max(len(rc["entries"]) for rc in chain))
+        t_stage = self._clock()
         ec = np.zeros((len(chain), A, B, SB), np.uint8)
         ids = np.full((len(chain), A), -1, np.int32)
         for k, rc in enumerate(chain):
@@ -1956,6 +1965,7 @@ class DataPlane:
             if rc["appends"] or rc["offsets"]:  # a live round, not padding
                 self._m_active_slots.observe_int(len(rc["entries"]))
                 self._m_staged_rows.inc(A * B)
+        self._m_stage_us.observe(self._clock() - t_stage)
         if len(rounds) == 1:
             inp = rounds[0][0]
             entries_c, slot_ids = ec[0], ids[0]
@@ -2008,28 +2018,15 @@ class DataPlane:
     def _active_bucket(self, n: int) -> int:
         """Smallest active-set capacity bucket >= n (8, 32, 128, ... up
         to P): rounds compile once per bucket, not once per active
-        count."""
-        a = 8
-        while a < n:
-            a *= 4
-        return max(1, min(a, self.cfg.partitions))
+        count. The ladder is ops.append's, beside the kernel it shapes."""
+        return active_bucket(n, self.cfg.partitions)
 
     def all_buckets(self) -> tuple[int, ...]:
         """Every active-set bucket this shape can hit — the boot-time
         warm list (a bucket first reached under traffic charges its
         multi-second XLA compile to live produces; measured as
-        multi-second dead zones in the e2e bench before full warming).
-        Derived FROM _active_bucket so the ladder geometry lives in one
-        place: sweep n over doubling active counts up to P and collect
-        the buckets they map to."""
-        P = self.cfg.partitions
-        out = []
-        n = 1
-        while n < P:
-            out.append(self._active_bucket(n))
-            n *= 2
-        out.append(self._active_bucket(P))
-        return tuple(dict.fromkeys(out))
+        multi-second dead zones in the e2e bench before full warming)."""
+        return active_buckets(self.cfg.partitions)
 
     def _build_round_locked(self, pred_end: dict[int, int]):
         """Build ONE round from the queues (caller holds self._lock).

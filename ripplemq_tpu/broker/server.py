@@ -339,6 +339,11 @@ class BrokerServer:
         # and refusal paths alike): the p99 the SLO controller's consume
         # twin steers toward slo_p99_consume_ms via read_coalesce_s.
         self._m_consume_ack_us = self.metrics.histogram("consume.ack_us")
+        # One sealed segment's shard pushed to its peer (_shard_duty):
+        # file read, frame encode, the RPC and its answer — on the duty
+        # thread, so histogram only.
+        self._st_shard_put = self.metrics.stage("seal.shard_put",
+                                                annotate=False)
         # Codec stats are process-global: set them symmetrically (last
         # constructed broker wins) rather than latching off forever —
         # a one-way disable would freeze the A/B's obs=True arm when an
@@ -1871,6 +1876,7 @@ class BrokerServer:
             if not targets:
                 break  # every peer refuses storage; nothing to do
             path = os.path.join(self._store_dir, "rs", name)
+            lap = self._st_shard_put.timed()  # closed with the answer
             try:
                 with open(path, "rb") as f:
                     blob = f.read()
@@ -1878,12 +1884,13 @@ class BrokerServer:
                 continue
             attempts += 1
             try:
-                resp = self.client.call(
-                    self._addr_of(targets[0]),
-                    {"type": "shard.put", "owner": self.broker_id,
-                     "name": name, "data": blob},
-                    timeout=self.config.rpc_timeout_s,
-                )
+                with lap:
+                    resp = self.client.call(
+                        self._addr_of(targets[0]),
+                        {"type": "shard.put", "owner": self.broker_id,
+                         "name": name, "data": blob},
+                        timeout=self.config.rpc_timeout_s,
+                    )
             except RpcError:
                 continue  # peer down; retried next pass
             if resp.get("ok"):

@@ -3,7 +3,8 @@
 A STAGE is a named stretch of one thread's time on the host path of a
 round (the step thread's gather, its drain, the wait for the
 device lock, the launch call, a resolver's blocking fetch, the settle
-thread's standby wait, a sealed segment's RS encode). Timing one does
+thread's standby wait, a sealed segment's RS encode, one shard's push
+to its peer). Timing one does
 two things at once, from the same pair of instants:
 
 - observes the registry histogram `<name>_us` on `metrics.clock()` —
@@ -69,6 +70,10 @@ STAGE_NAMES = frozenset({
     # One sealed segment's RS encode, any compile included
     # (storage/segment.py erasure worker).
     "seal.rs_encode",
+    # One shard of a sealed segment pushed to its peer by the duty loop
+    # (broker/server.py _shard_duty): file read, frame encode, the
+    # shard.put RPC and its answer.
+    "seal.shard_put",
 })
 
 

@@ -70,11 +70,87 @@ def append_backend(slot_bytes: int, platform: str | None = None) -> str:
     return "pallas"
 
 
+# Scoped VMEM a Pallas kernel gets by default, by the device's kind, as
+# the installed compiler reads it (PR 33: the append kernel compiled for
+# a described v5e:2x2, v5p:2x2x1, v4:2x2x1 and v6e:2x2 at SB 1152, A 32,
+# B 904 / 912 / 1816 / 1824 / 3640 — "Scoped allocation with size 16.03M
+# and limit 16.00M" at B 912 on the first three, 32.00M on v6e). A kind
+# that is not listed is not priced at engine build: its compiler decides
+# at warm-up.
+SCOPED_VMEM_BYTES = {
+    "TPU v4": 16 << 20,
+    "TPU v5 lite": 16 << 20,
+    "TPU v5": 16 << 20,
+    "TPU v6 lite": 32 << 20,
+}
+
+
 def _pick_k(P: int, target: int = 8) -> int:
     k = min(target, P)
     while P % k:
         k -= 1
     return max(1, k)
+
+
+def active_bucket(n: int, partitions: int) -> int:
+    """Smallest active-set capacity bucket >= n (8, 32, 128, ... up to
+    `partitions`): rounds compile once per bucket, not once per active
+    count. The ladder lives here, beside the kernel whose shapes it
+    picks; the batcher (DataPlane._active_bucket) and the VMEM price
+    below read this one copy."""
+    a = 8
+    while a < n:
+        a *= 4
+    return max(1, min(a, partitions))
+
+
+def active_buckets(partitions: int) -> tuple[int, ...]:
+    """Every bucket a `partitions`-wide engine can hit, ascending:
+    sweep n over doubling active counts up to P and collect the
+    buckets they map to."""
+    out = []
+    n = 1
+    while n < partitions:
+        out.append(active_bucket(n, partitions))
+        n *= 2
+    out.append(active_bucket(partitions, partitions))
+    return tuple(dict.fromkeys(out))
+
+
+def check_entries_block(slot_bytes: int, max_batch: int, partitions: int,
+                        device_kind: str) -> None:
+    """Refuse, at engine build and with the numbers, a `max_batch` x
+    `slot_bytes` whose `entries` block outgrows the scoped VMEM the
+    kernel gets on `device_kind` — not a compiler refusal at warm-up.
+
+    The kernel's one VMEM resident is its entries block, [Ka, B/ALIGN,
+    ALIGN, SB] uint8 with Ka = _pick_k(A) partitions a grid step; the
+    pipeline keeps two of them whenever bucket A spans more than one
+    step, one otherwise. Priced over the buckets this shape can hit.
+    The v5e compiler agrees to the row: at SB 1152, A 32 it takes B 904
+    (2 x 8 x 904 x 1152 = 16,662,528 B) and refuses 912; at A 8, one
+    step, it takes 1816 and refuses 1824 (PR 33;
+    tests/test_wide_rows.py compiles the first pair)."""
+    limit = SCOPED_VMEM_BYTES.get(device_kind)
+    if limit is None:
+        return
+    def blocks(A):  # partitions' worth of rows the bucket keeps in VMEM
+        ka = _pick_k(A)
+        return (2 if A // ka > 1 else 1) * ka
+
+    A = max(active_buckets(partitions), key=blocks)
+    ka = _pick_k(A)
+    need = blocks(A) * max_batch * slot_bytes
+    if need > limit:
+        fit = limit // (blocks(A) * slot_bytes) // ALIGN * ALIGN
+        raise ValueError(
+            f"max_batch={max_batch} x slot_bytes={slot_bytes} on "
+            f"{device_kind}: at the {A}-partition bucket the append "
+            f"kernel's entries block is {blocks(A) // ka} x {ka} x "
+            f"{max_batch} x {slot_bytes} = {need} B, over the {limit} B "
+            f"of scoped VMEM a kernel gets — at this row width and "
+            f"{partitions} partitions max_batch can be at most {fit}"
+        )
 
 
 # --------------------------------------------------------- extent classes

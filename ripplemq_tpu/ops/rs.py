@@ -213,7 +213,10 @@ def shard_bucket(n: int) -> int:
     segment of (60, 72] MiB: a segment rotates BEFORE the write that
     would cross segment_bytes, so sealed lengths fall short of it by at
     most one write (a round's records, a standby's group-commit frame).
-    A store of any other segment size meets two entries at most."""
+    Where one write is longer than that (a round of 1 KB rows), the
+    store pads the short segment up to the entry of its segment_bytes
+    (storage/erasure.encode_segment `bucket_floor`): one program a
+    store, built at open."""
     half = 1 << max((n - 1).bit_length() - 1, 0)  # 2^k < n <= 2^(k+1)
     step = max(_BLOCK_BYTES, half >> 2)
     return -(-n // step) * step

@@ -38,6 +38,7 @@ from ripplemq_tpu.core.state import (
 from ripplemq_tpu.core import step as core_step
 from ripplemq_tpu.ops.append import (
     append_backend,
+    check_entries_block,
     append_rows,
     append_rows_active,
 )
@@ -104,10 +105,14 @@ def _resync(cfg: EngineConfig, state: ReplicaState, src: jax.Array,
 def make_local_fns(cfg: EngineConfig) -> LocalEngineFns:
     R = cfg.replicas
     # The write phase is chosen ONCE, here (ops.append.append_backend):
-    # the Pallas kernel on a TPU — an unsupported slot_bytes raises —
+    # the Pallas kernel on a TPU — a slot_bytes Mosaic cannot take, or a
+    # max_batch x slot_bytes block over the kernel's VMEM, raises —
     # and the XLA scatter on the CPU test platform.
     backend = append_backend(cfg.slot_bytes)
     pallas = backend == "pallas"
+    if pallas:
+        check_entries_block(cfg.slot_bytes, cfg.max_batch, cfg.partitions,
+                            jax.devices()[0].device_kind)
     rep_idx = jnp.arange(R, dtype=jnp.int32)
     default_quorum = jnp.full((cfg.partitions,), cfg.quorum, jnp.int32)
 
@@ -356,6 +361,9 @@ def make_spmd_fns(cfg: EngineConfig, mesh: Mesh) -> SpmdEngineFns:
     backend = append_backend(cfg.slot_bytes,
                              mesh.devices.flat[0].platform)
     pallas = backend == "pallas"
+    if pallas:
+        check_entries_block(cfg.slot_bytes, cfg.max_batch, cfg.partitions,
+                            mesh.devices.flat[0].device_kind)
 
     # The ring-stride aliasing rule priced at the PER-DEVICE shape: each
     # mesh device holds ONE replica's [local_P, S+B, SB] ring block, so

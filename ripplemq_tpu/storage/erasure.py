@@ -77,25 +77,39 @@ def _shard_length(orig_len: int) -> int:
     return -(-orig_len // K)  # ceil; last data shard is zero-padded
 
 
+def store_bucket(segment_bytes: int) -> int:
+    """The shard length the RS program of a store with this segment size
+    runs at: the ladder entry (ops/rs.shard_bucket) of a full segment's
+    shard. Built at open (warm_encode); every seal of that store is
+    padded up to it (encode_segment `bucket_floor`)."""
+    return shard_bucket(_shard_length(segment_bytes))
+
+
 def warm_encode(segment_bytes: int, **kw) -> int:
-    """Encode one zeroed input of a full segment's shard length, so the
-    RS program a store of this segment size needs exists before its
-    first seal; returns that shard length. Sealed segments are a write
+    """Encode one zeroed input at a full segment's bucket, so the RS
+    program a store of this segment size needs exists before its first
+    seal; returns the full segment's shard length. Sealed segments fall
     short of segment_bytes (rotation comes before the write that would
-    cross it), which is the same ladder entry unless segment_bytes sits
-    just above one (ops/rs.shard_bucket)."""
-    n = _shard_length(segment_bytes)
-    rs_encode(np.zeros((K, shard_bucket(n)), np.uint8), k=K, m=M, **kw)
-    return n
+    cross it) by one write, which may be a ladder entry or more: the
+    store pads them up to this bucket."""
+    rs_encode(np.zeros((K, store_bucket(segment_bytes)), np.uint8),
+              k=K, m=M, **kw)
+    return _shard_length(segment_bytes)
 
 
 def encode_segment(store_dir: str, seg_name: str, stage=None,
-                   **kw) -> list[str]:
+                   bucket_floor: int = 0, **kw) -> list[str]:
     """Write the K+M shard files for one sealed segment. Atomic per shard
     (tmp + rename); returns the shard paths. `stage(shard_len)`, where
     given, returns a context manager opened around the RS encode alone
-    (the owning store's `seal.rs_encode` timer). `kw` routes to
-    ops/rs.gf_matmul (use_pallas / interpret)."""
+    (the owning store's `seal.rs_encode` timer). `bucket_floor` is the
+    least length the encoder runs at: the owning store gives the bucket
+    of its `segment_bytes`, the one program it built at open, so a
+    segment that sealed short (rotation comes BEFORE the write that
+    would cross `segment_bytes`, and one round of 1 KB rows can be many
+    MiB) is padded up to it instead of compiling a program of its own
+    beside traffic. `kw` routes to ops/rs.gf_matmul (use_pallas /
+    interpret)."""
     seg_path = os.path.join(store_dir, seg_name)
     with open(seg_path, "rb") as f:
         raw = f.read()
@@ -104,12 +118,13 @@ def encode_segment(store_dir: str, seg_name: str, stage=None,
     # Shard j is raw[j*n:(j+1)*n], the last zero-padded to n. The rows
     # are laid out at the bucket length the encoder runs at, so the
     # zeros it needs past n are these and nothing is copied again.
-    data = np.zeros((K, shard_bucket(n)), np.uint8)
+    nb = max(shard_bucket(n), bucket_floor)
+    data = np.zeros((K, nb), np.uint8)
     flat = np.frombuffer(raw, np.uint8)
     for j in range(K):
         part = flat[j * n : (j + 1) * n]
         data[j, : len(part)] = part
-    with stage(n) if stage is not None else contextlib.nullcontext():
+    with stage(nb) if stage is not None else contextlib.nullcontext():
         parity = rs_encode(data, k=K, m=M, **kw)
     shards = [*data[:, :n], *parity[:, :n]]
     os.makedirs(_rs_dir(store_dir), exist_ok=True)
@@ -218,23 +233,35 @@ def _protected_names(store_dir: str) -> set[str]:
     return set(_shard_counts(store_dir))
 
 
-def protect_store(store_dir: str, limit: Optional[int] = None,
-                  **kw) -> list[str]:
-    """Encode shards for sealed segments (every segment but the highest-
-    numbered, which is still being appended) that lack a COMPLETE shard
-    set — a crash mid-encode leaves a partial set, which must not count
-    as protected (it may tolerate fewer than M losses, or none). Empty
+def unprotected_names(store_dir: str) -> list[str]:
+    """Sealed segments (every segment but the highest-numbered, which is
+    still being appended) that hold data and lack a COMPLETE shard set —
+    a crash mid-encode leaves a partial set, which must not count as
+    protected (it may tolerate fewer than M losses, or none). Empty
     segments (a restart artifact: both store backends open a fresh index
-    on boot) carry no data and are skipped. `limit` bounds work per call
-    so callers can amortize. Returns the segment names encoded."""
-    names = _segment_names(store_dir)[:-1]
+    on boot) carry no data and are skipped. What a protect pass has to
+    encode, and what the owning store observes as `seal.pending`."""
     counts = _shard_counts(store_dir)
-    done = []
-    for name in names:
+    out = []
+    for name in _segment_names(store_dir)[:-1]:
         if counts.get(name, 0) >= K + M:
             continue
-        if os.path.getsize(os.path.join(store_dir, name)) == 0:
-            continue
+        try:
+            if os.path.getsize(os.path.join(store_dir, name)) == 0:
+                continue
+        except OSError:
+            continue  # GC'd between the listing and the stat
+        out.append(name)
+    return out
+
+
+def protect_store(store_dir: str, limit: Optional[int] = None,
+                  **kw) -> list[str]:
+    """Encode shards for the sealed segments that lack a complete shard
+    set (unprotected_names). `limit` bounds work per call so callers can
+    amortize. Returns the segment names encoded."""
+    done = []
+    for name in unprotected_names(store_dir):
         encode_segment(store_dir, name, **kw)
         done.append(name)
         if limit is not None and len(done) >= limit:

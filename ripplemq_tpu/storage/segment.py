@@ -240,11 +240,18 @@ class SegmentStore:
             # traffic.
             self._st_rs_encode = metrics.stage("seal.rs_encode")
             self._c_rs_new_shapes = metrics.counter("rs.new_shapes")
+            # Whether seals keep up, as numbers: segments sealed (an
+            # append landed in a later segment than the last one did),
+            # and at each erasure kick how many sealed segments still
+            # lack their shard set.
+            self._c_sealed = metrics.counter("seal.segments")
+            self._h_seal_pending = metrics.histogram("seal.pending")
         else:
             self._h_append = self._h_fsync = None
             self._c_append_bytes = self._c_records = None
             self._clock = None
             self._st_rs_encode = self._c_rs_new_shapes = None
+            self._c_sealed = self._h_seal_pending = None
         self._rs_shapes: set[int] = set()  # erasure thread only
         self.segment_bytes = segment_bytes
         self.erasure = erasure
@@ -338,7 +345,7 @@ class SegmentStore:
                 )
                 if rc != 0:
                     raise OSError("segstore_append failed")
-                self._active_seg = seg.value
+                self._landed_in_locked(seg.value)
                 return seg.value, off.value
             hdr = _HEADER_PREFIX.pack(
                 _MAGIC, rec_type, slot, base, len(payload)
@@ -354,7 +361,7 @@ class SegmentStore:
             locator = (self._seg_index, self._file.tell() + _HEADER.size)
             self._file.write(frame)
             self._file.flush()
-            self._active_seg = self._seg_index
+            self._landed_in_locked(self._seg_index)
             return locator
 
     def append_many(
@@ -411,7 +418,7 @@ class SegmentStore:
                 )
                 if rc != 0:
                     raise OSError("segstore_append_blob failed")
-                self._active_seg = seg.value
+                self._landed_in_locked(seg.value)
                 return [(seg.value, off.value + r) for r in rel]
             if (
                 self._file.tell() + len(blob) > self.segment_bytes
@@ -423,8 +430,16 @@ class SegmentStore:
             start = self._file.tell()
             self._file.write(blob)
             self._file.flush()
-            self._active_seg = self._seg_index
+            self._landed_in_locked(self._seg_index)
             return [(self._seg_index, start + r) for r in rel]
+
+    def _landed_in_locked(self, seg: int) -> None:
+        """An append landed in segment `seg` (caller holds _lock): the
+        segment the flusher syncs, and — once this open has written one —
+        every index passed since the last append is a segment sealed."""
+        if self._c_sealed is not None and 0 <= self._active_seg < seg:
+            self._c_sealed.inc(seg - self._active_seg)
+        self._active_seg = seg
 
     def flush(self) -> None:
         """fsync the active segment (the durability barrier)."""
@@ -514,8 +529,9 @@ class SegmentStore:
 
     def _kick_erasure(self) -> None:
         """Start (or skip, if one is running) the background shard
-        encoder; rate-limited so rotation-free flushes don't pay even a
-        listdir. Check-and-start runs under the store lock: the kick is
+        encoder, and observe `seal.pending`; rate-limited to once a
+        second so rotation-free flushes don't pay even a listdir.
+        Check-and-start runs under the store lock: the kick is
         reachable from the settle path's flush, barrier flushes, and
         the flusher thread, and the unguarded alive-check let two
         concurrent kicks both start a worker (ownership lint, PR 11;
@@ -529,21 +545,34 @@ class SegmentStore:
                 return
             self._erasure_check_t = now
             t = self._erasure_thread
-            if t is not None and t.is_alive():
-                return
-            t = threading.Thread(
-                target=self._erasure_worker, daemon=True,
-                name="segstore-erasure",
-            )
-            self._erasure_thread = t
-            t.start()
+            if t is None or not t.is_alive():
+                t = threading.Thread(
+                    target=self._erasure_worker, daemon=True,
+                    name="segstore-erasure",
+                )
+                self._erasure_thread = t
+                t.start()
+        if self._h_seal_pending is not None:
+            # Two directory listings, at most once a second, outside
+            # the lock: what the worker (just started, or still busy
+            # with an earlier seal) has in front of it.
+            from ripplemq_tpu.storage.erasure import unprotected_names
+
+            try:
+                self._h_seal_pending.observe_int(
+                    len(unprotected_names(self.directory)))
+            except OSError:
+                pass  # a directory went mid-listing: telemetry only
 
     def _erasure_worker(self) -> None:
-        from ripplemq_tpu.storage.erasure import protect_store
+        from ripplemq_tpu.storage.erasure import protect_store, store_bucket
 
         try:
+            # Every seal runs the program _erasure_warm built, however
+            # short of segment_bytes the segment rotated.
             protect_store(self.directory, stage=self._seal_stage
-                          if self._st_rs_encode is not None else None)
+                          if self._st_rs_encode is not None else None,
+                          bucket_floor=store_bucket(self.segment_bytes))
         except Exception as e:  # derived data: never take the store down
             self._erasure_failed("encode", e)
 

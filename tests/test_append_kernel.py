@@ -195,10 +195,13 @@ def _packed_rows_ref(extent, B):
     return min(s, BA) * ALIGN
 
 
-@pytest.mark.parametrize("seed", range(6))
-def test_packed_pallas_matches_packed_xla_randomized(seed):
+@pytest.mark.parametrize("seed,sb", [
+    *((seed, 128) for seed in range(6)),
+    (6, 1152), (7, 1152),  # omb-100p-1kb's row, nine lanes wide
+])
+def test_packed_pallas_matches_packed_xla_randomized(seed, sb):
     rng = np.random.default_rng(seed)
-    log, entries, base, do_write = rand_case(rng)
+    log, entries, base, do_write = rand_case(rng, SB=sb)
     P, B = entries.shape[0], entries.shape[1]
     extents = (rng.integers(0, B // ALIGN + 1, size=(P,)) * ALIGN).astype(
         np.int32

@@ -271,7 +271,7 @@ def test_parse_unknown_engine_key_is_still_an_error():
 
 
 def test_benchmark_cluster_blocks_parse():
-    """The three deployments the benchmark boots, as run.py builds their
+    """The four deployments the benchmark boots, as run.py builds their
     cluster files (the `cluster` block, the deployment's topics, one
     broker per port) — two of them still name the retired keys."""
     import glob
@@ -281,7 +281,7 @@ def test_benchmark_cluster_blocks_parse():
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     paths = sorted(glob.glob(os.path.join(repo, "benchmarks", "configs",
                                           "*.json")))
-    assert len(paths) == 3
+    assert len(paths) == 4
     for path in paths:
         with open(path) as f:
             config = json.load(f)

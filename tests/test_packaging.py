@@ -45,6 +45,25 @@ def test_local_example_config_loads():
     assert {t.name for t in config.topics} == {"topic1", "topic2"}
 
 
+def test_1kb_example_config_loads_and_is_sized_as_the_readme_says():
+    """examples/cluster-1kb.yaml: a 1,024 B record fits its slot, the
+    append kernel's VMEM block fits a TPU, and the ring is what the
+    file's comment says."""
+    from ripplemq_tpu.ops.append import append_backend, check_entries_block
+
+    config = load_cluster_config(os.path.join(REPO, "examples",
+                                              "cluster-1kb.yaml"))
+    e = config.engine
+    assert e.slot_bytes % 128 == 0 and e.payload_bytes >= 1024
+    assert e.partitions >= sum(t.partitions for t in config.topics)
+    assert append_backend(e.slot_bytes, "tpu") == "pallas"
+    check_entries_block(e.slot_bytes, e.max_batch, e.partitions,
+                        "TPU v5 lite")
+    ring = e.replicas * e.partitions * (e.slots + e.max_batch) * e.slot_bytes
+    assert ring == 9_732_096
+    assert config.standby_count == 2 and config.replication == "full"
+
+
 def test_dockerfile_entrypoint_matches_cli():
     """The ENTRYPOINT flags must be real broker CLI flags (argparse would
     exit 2 on drift) and reference files the image actually copies."""

@@ -14,6 +14,7 @@ import threading
 import time
 
 import numpy as np
+import pytest
 
 from ripplemq_tpu.broker.dataplane import DataPlane, replay_records
 from ripplemq_tpu.storage.memstore import MemoryRoundStore
@@ -91,15 +92,21 @@ def test_cache_parity_with_device_path():
             dp.stop()
 
 
-def test_ring_wrap_serves_store_below_trim_cache_above():
+@pytest.mark.parametrize("slot_bytes,size", [(32, 0), (1152, 1024)])
+def test_ring_wrap_serves_store_below_trim_cache_above(slot_bytes, size):
     """After the ring wraps, lagging consumers read the store below the
-    trim watermark and the mirror above it — still no device dispatch."""
-    cfg = small_cfg(partitions=1, slots=32, max_batch=8, read_batch=8)
+    trim watermark and the mirror above it — still no device dispatch.
+    Also at omb-100p-1kb's width: seeded random 1,024 B records in
+    1,152 B rows, five laps of the ring."""
+    cfg = small_cfg(partitions=1, slots=32, max_batch=8, read_batch=8,
+                    slot_bytes=slot_bytes)
     dp = _mk(cfg)
+    rng = np.random.default_rng(size)
     try:
         sent = []
         for i in range(20):  # 160 rows through a 32-slot ring
-            batch = [b"w-%03d-%d" % (i, j) for j in range(8)]
+            batch = [b"w-%03d-%d" % (i, j) + rng.bytes(max(0, size - 7))
+                     for j in range(8)]
             sent.extend(batch)
             dp.submit_append(0, batch).result(timeout=30)
         assert int(dp.trim[0]) > 0, "ring never wrapped"

@@ -449,10 +449,14 @@ def test_five_lengths_one_program_compiled_at_open(tmp_path):
     assert store.erasure_errors == []
 
 
-def test_a_segment_far_short_of_its_size_is_a_second_bucket(tmp_path):
-    """Not one per seal: a store whose writes are most of a segment long
-    seals far short of segment_bytes and meets the ladder entry below
-    the one built at open, once."""
+def test_a_segment_far_short_of_its_size_runs_the_program_built_at_open(
+        tmp_path):
+    """A store whose writes are most of a segment long seals far short
+    of segment_bytes - rotation comes before the write that would cross
+    it, and one round of 1 KB rows can be many MiB (PR 33). Its shard
+    length falls in the ladder entry below the one built at open; the
+    store pads it up to that one, so nothing compiles beside traffic,
+    and the shards still rebuild the segment."""
     from ripplemq_tpu.obs.metrics import Metrics
 
     m = Metrics()
@@ -463,9 +467,15 @@ def test_a_segment_far_short_of_its_size_is_a_second_bucket(tmp_path):
     store.close()
     snap = m.snapshot()
     assert snap["histograms"]["seal.rs_encode_us"]["count"] == 3
-    assert snap["counters"]["rs.new_shapes"] == 2
     assert {rs.shard_bucket(-(-(1 << 20) // 3)),
             rs.shard_bucket(-(-600_040 // 3))} == {2 * _BLOCK, _BLOCK}
+    assert snap["counters"]["rs.new_shapes"] == 1
+    name = erasure._segment_names(store.directory)[0]
+    with open(os.path.join(store.directory, name), "rb") as f:
+        raw = f.read()
+    for path in erasure.shard_paths(store.directory, name)[:2]:
+        os.remove(path)
+    assert erasure.reconstruct_segment(store.directory, name) == raw
 
 
 def _parent_shard_blobs(raw: bytes) -> list[bytes]:
