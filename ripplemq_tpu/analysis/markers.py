@@ -73,6 +73,8 @@ FAST_MODULES = {
     "test_pid_expiry",          # ~10 s: reaper units + one churn cluster
     "test_proc_chaos",          # ~2 min: 2-seed real-subprocess chaos smoke
     "test_process_cluster",     # ~20 s: real-subprocess broker boot
+    "test_program_store",       # ~30 s: store units on a tmp_path, two
+                                # bare planes' warm-ups, 7 child processes
     "test_read_batching",
     "test_read_cache",
     "test_settle_pipeline",

@@ -44,6 +44,7 @@ from ripplemq_tpu.core.encode import (
 from ripplemq_tpu.core.state import ReplicaState, StepInput, row_lens
 from ripplemq_tpu.ops.append import active_bucket, active_buckets
 from ripplemq_tpu.parallel.engine import make_local_fns, make_spmd_fns
+from ripplemq_tpu.utils.program_store import ProgramStore, default_directory
 from ripplemq_tpu.parallel.mesh import make_mesh
 from ripplemq_tpu.storage.segment import (
     REC_APPEND,
@@ -404,8 +405,12 @@ class DataPlane:
         # same in-order release, no standby-stream overlap.
         self.replicate_begin_fn = None
         self.replicate_wait_fn = None
+        # Where a boot's round programs come from and how many of them
+        # it loaded or had to build (engine.programs_loaded / _built,
+        # the `engine.device` block). The spmd bindings stay on `jit`.
+        self.programs = ProgramStore(default_directory(), self.metrics)
         if mode == "local":
-            self.fns = make_local_fns(cfg)
+            self.fns = make_local_fns(cfg, self.programs)
         elif mode == "spmd":
             if mesh is None:
                 if part_shards is None:
@@ -1624,13 +1629,16 @@ class DataPlane:
             return int(self._offsets_shadow[slot, consumer_slot])
 
     def warm(self, buckets: tuple[int, ...] = (8, 32)) -> None:
-        """Compile the hot programs before traffic needs them: the sparse
-        single and chained rounds at the given active-set buckets, and
-        the batched read. Dispatches no-op rounds of those exact shapes
-        (counts 0, all-padding ids: nothing commits, state is
-        semantically unchanged). Safe concurrently with traffic (device
-        lock); brokers kick this in the background at boot so the first
-        produce doesn't pay the multi-second XLA compile."""
+        """Have the hot programs in place before traffic needs them: the
+        sparse single and chained rounds at the given active-set buckets
+        - loaded from the program store where a boot before this one
+        built them, else traced, lowered, compiled and written there
+        (utils/program_store.py) - and the batched read. Dispatches
+        no-op rounds of those exact shapes (counts 0, all-padding ids:
+        nothing commits, state is semantically unchanged). Safe
+        concurrently with traffic (device lock); brokers kick this in
+        the background at boot so the first produce doesn't pay the
+        multi-second build."""
         cfg = self.cfg
         P, B, SB, U = (cfg.partitions, cfg.max_batch, cfg.slot_bytes,
                        cfg.max_offset_updates)
@@ -1679,6 +1687,9 @@ class DataPlane:
                         raise
         if self._stop.is_set():
             return
+        # LAST, and through plain `jit`: its compile-log line is what a
+        # harness reads as the end of warm-up (benchmarks/run.py
+        # WARM_LAST), and a program loaded from the store writes none.
         with self._device_lock:
             self.fns.read_many(
                 self._state, np.zeros((self.read_q,), np.int32),
@@ -2955,8 +2966,11 @@ class DataPlane:
         count of devices as JAX reports them, the write phase compiled
         into the engine programs ("pallas" | "xla"), the spmd mesh
         (null for the local binding), the device ids holding each
-        replica's ring, and the largest peak_bytes_in_use over those
-        devices (null where the backend keeps no memory stats — CPU)."""
+        replica's ring, the largest peak_bytes_in_use over those
+        devices (null where the backend keeps no memory stats — CPU),
+        and how many round programs this process loaded from the
+        program store and how many it built and wrote there (both 0
+        where there is no store: a process pinned to the CPU backend)."""
         import jax
 
         devices = sorted(set().union(*self._replica_devices),
@@ -2977,6 +2991,8 @@ class DataPlane:
                 sorted(d.id for d in held) for held in self._replica_devices
             ],
             "peak_bytes_in_use": max(peaks) if peaks else None,
+            "programs_loaded": self.programs.loaded,
+            "programs_built": self.programs.built,
         }
 
     def settle_stats(self) -> dict:

@@ -42,6 +42,7 @@ from ripplemq_tpu.ops.append import (
     append_rows,
     append_rows_active,
 )
+from ripplemq_tpu.utils.program_store import ProgramStore, default_directory
 
 
 class LocalEngineFns(NamedTuple):
@@ -102,8 +103,16 @@ def _resync(cfg: EngineConfig, state: ReplicaState, src: jax.Array,
 # Local (single device, replicas vmapped)
 # ---------------------------------------------------------------------------
 
-def make_local_fns(cfg: EngineConfig) -> LocalEngineFns:
+def make_local_fns(cfg: EngineConfig,
+                   programs: ProgramStore | None = None) -> LocalEngineFns:
+    """`programs` is where the round programs a boot warms (the sparse
+    single and chained rounds, the vote) are loaded from and written to
+    (utils/program_store.py); left out, the store of the compile
+    cache's directory - none in a process pinned to the CPU backend,
+    which then runs plain `jit` throughout."""
     R = cfg.replicas
+    if programs is None:
+        programs = ProgramStore(default_directory())
     # The write phase is chosen ONCE, here (ops.append.append_backend):
     # the Pallas kernel on a TPU — a slot_bytes Mosaic cannot take, or a
     # max_batch x slot_bytes block over the kernel's VMEM, raises —
@@ -198,6 +207,12 @@ def make_local_fns(cfg: EngineConfig) -> LocalEngineFns:
         new_state = new_state._replace(log_data=log_data)
         return new_state, jax.tree.map(lambda x: x[0], ctl.out)
 
+    # entries_c is [A, B, SB], chained [K, A, B, SB]: A is the bucket.
+    def _bucket(state, inp, entries_c, *rest):
+        return f"bucket {entries_c.shape[-3]}"
+
+    _step_sparse_j = programs.wrap(_step_sparse_j, cfg, backend, _bucket)
+
     def _step_sparse(state, inp, entries_c, slot_ids, alive, quorum=None,
                      trim=None):
         return _step_sparse_j(state, inp, entries_c, slot_ids, alive,
@@ -221,6 +236,9 @@ def make_local_fns(cfg: EngineConfig) -> LocalEngineFns:
 
         return jax.lax.scan(body, state, (inputs, entries_c, slot_ids))
 
+    _step_many_sparse_j = programs.wrap(_step_many_sparse_j, cfg, backend,
+                                        _bucket)
+
     def _step_many_sparse(state, inputs, entries_c, slot_ids, alive,
                           quorum=None, trim=None):
         return _step_many_sparse_j(
@@ -239,6 +257,8 @@ def make_local_fns(cfg: EngineConfig) -> LocalEngineFns:
         new_state, elected, votes = vvote(state, cand, cand_term, rep_idx,
                                           alive, quorum)
         return new_state, elected[0], votes[0]
+
+    _vote_j = programs.wrap(_vote_j, cfg, backend)
 
     def _vote(state, cand, cand_term, alive, quorum=None):
         return _vote_j(state, cand, cand_term, alive,

@@ -22,8 +22,12 @@ backend compile took under one second — and on the chip EVERY engine
 program does (0.45-1.0 s each, PERF.md): with the default the directory
 stays empty and the second boot is as cold as the first. So the
 threshold goes to zero here unless the environment set one. What the
-cache can save is that backend compile only; tracing and the
-Pallas-to-Mosaic lowering of the round programs are paid on every boot.
+cache can save is that backend compile only, a tenth of a boot; the
+tracing and the Pallas-to-Mosaic lowering in front of it (~4 s a round
+program) are what utils/program_store.py saves: the first boot writes
+each finished round program under `programs/` in the directory this
+rule resolves (`cache_dir()`), later boots load it. Programs outside
+that store (`_init`, `_read_many`, the RS encode) come through here.
 """
 
 from __future__ import annotations
@@ -42,13 +46,22 @@ CHECKOUT_CACHE_DIR = os.path.join(
 )
 
 
+def cache_dir() -> Optional[str]:
+    """The directory the rule above resolves, for whatever is kept beside
+    JAX's cache (utils/program_store.py); None for a process pinned to
+    the CPU backend."""
+    if os.environ.get("JAX_PLATFORMS") == "cpu":
+        return None
+    return os.environ.get(ENV_VAR) or CHECKOUT_CACHE_DIR
+
+
 def configure_compile_cache() -> Optional[str]:
     """Point JAX's persistent compilation cache at the in-checkout
     directory unless the environment already placed it. Returns the
     directory configured here; None when the variable is set (then no
     cache directory is touched in code) or the process is pinned to
     the CPU backend (then nothing is)."""
-    if os.environ.get("JAX_PLATFORMS") == "cpu":
+    if cache_dir() is None:
         return None
     import jax
 

@@ -255,7 +255,10 @@ def test_admin_stats_schema_lock():
         device = stats["engine"]["device"]
         assert set(device) == {"platform", "device_kind", "device_count",
                                "append_backend", "mesh", "replica_devices",
-                               "peak_bytes_in_use"}
+                               "peak_bytes_in_use", "programs_loaded",
+                               "programs_built"}
+        # No program store in a process pinned to the CPU backend.
+        assert (device["programs_loaded"], device["programs_built"]) == (0, 0)
         assert device["platform"] == "cpu"
         assert device["append_backend"] == "xla"
         assert device["mesh"] is None
