@@ -11,8 +11,9 @@ while the cluster does, prints READY, and then takes its orders over stdin:
     WINDOW <t0_ns> <t1_ns>      the measured window, on time.monotonic_ns()
                                 of this machine; traffic goes on unbroken
     DRAIN <deadline_ns> <file>  consumers: read on until every partition
-                                holds the counts in <file>, at the latest
-                                until the deadline (- = no counts: stop now)
+                                holds the counts in <file> under every
+                                subscription, at the latest until the
+                                deadline (- = no counts: stop now)
 
 and answers with one `RESULT <json>` line; bulk data goes to .npy files in
 the run's work dir. Producers also print `FIRSTACK <ns>` once.
@@ -193,33 +194,82 @@ def role_produce(spec: dict, orders: Orders) -> dict:
                 errors=[e for _, _, e in ctx.failed_calls[:4]])
 
 
+def hosted(n_subs: int, proc_id: int, nprocs: int) -> list[tuple[int, int, int]]:
+    """What consumer process `proc_id` of `nprocs` hosts of a cell's
+    `n_subs` subscriptions: (subscription index, part, of) - the process
+    covers streams `part::of` of that subscription. Fewer subscriptions
+    than processes: the processes share each subscription's streams (one
+    subscription over four processes is `(0, proc_id, 4)`, as it always
+    was). As many or more: a process hosts whole subscriptions."""
+    m = min(n_subs, nprocs)
+    out = []
+    for q in range(n_subs):
+        if q % m == proc_id % m:
+            procs = [i for i in range(nprocs) if i % m == q % m]
+            out.append((q, procs.index(proc_id), len(procs)))
+    return out
+
+
+def thread_share(entries: list, tid: int, threads: int,
+                 n_streams: int) -> tuple[int, list[int]]:
+    """Thread `tid` of a consumer process's `threads`: (position in
+    `entries` of the hosted subscription it consumes under, its streams).
+    The threads go round the hosted subscriptions, and those that share
+    one share its streams, so every (subscription, stream) a process
+    hosts has exactly one thread - given at least a thread a hosted
+    subscription, which `run.child_spec` sees to."""
+    e = tid % len(entries)
+    mates = range(e, threads, len(entries))
+    _, part, of = entries[e]
+    return e, list(range(part, n_streams, of))[tid // len(entries)::len(mates)]
+
+
+def client_kwargs(spec: dict) -> dict:
+    """What every `ConsumerClient` of this process is built with beside
+    the bootstrap and its subscription: the harness's three from `params`
+    and the cell's `consumers.client`. Bound to the client's signature, so
+    a key it does not take, or one the harness sets, is a TypeError - from
+    `main` before READY, which ends the run with no result."""
+    import inspect
+
+    from ripplemq_tpu.client import ConsumerClient
+
+    p = spec["params"]
+    kw = dict(max_messages=int(p["max_messages"]),
+              prefetch=int(p.get("prefetch", 0)),
+              rpc_timeout_s=float(p.get("rpc_timeout_s", 30.0)))
+    inspect.signature(ConsumerClient).bind(
+        spec["bootstrap"], "", **kw, **spec.get("client", {}))
+    return {**kw, **spec.get("client", {})}
+
+
 def role_consume(spec: dict, orders: Orders) -> dict:
     from ripplemq_tpu.client import ConsumerClient
 
     p = spec["params"]
+    client_kw = client_kwargs(spec)
     size = int(spec["message_bytes"])
     threads = int(p["threads"])
     streams = [tuple(s) for s in spec["streams"]]
-    mine = list(range(int(spec["proc_id"]), len(streams),
-                      int(spec["nprocs"])))
+    # [name, subscription index, part, of], as run.child_spec hands them
+    subs = spec["subscriptions"]
+    entries = [(int(q), int(part), int(of)) for _, q, part, of in subs]
     poll_s = float(p.get("poll_interval_s", 0.0))
     idle_s = float(p.get("idle_sleep_s", 0.002))
-    chunks: list[list] = [[] for _ in range(threads)]  # (stream, recv_ns, blob)
-    counts = np.zeros(len(streams), np.int64)
+    # (hosted subscription, stream, recv_ns, blob, n) per thread
+    chunks: list[list] = [[] for _ in range(threads)]
+    counts = np.zeros((len(subs), len(streams)), np.int64)
     errors: list[str] = []
     short_at_deadline = [0] * threads
     fault = [spec.get("fault")]
     orders.go.wait()
 
     def run(tid: int) -> None:
-        own = mine[tid::threads]
+        e, own = thread_share(entries, tid, threads, len(streams))
         if not own:
             return
-        cc = ConsumerClient(
-            spec["bootstrap"], spec["subscription"],
-            max_messages=int(p["max_messages"]),
-            prefetch=int(p.get("prefetch", 0)),
-            rpc_timeout_s=float(p.get("rpc_timeout_s", 30.0)))
+        cc = ConsumerClient(spec["bootstrap"], subs[e][0], **client_kw)
+        have = counts[e]
         gap = poll_s / len(own)
         nxt = time.monotonic() + gap * (tid / max(1, threads))
         i = 0
@@ -230,7 +280,7 @@ def role_consume(spec: dict, orders: Orders) -> dict:
                     want = orders.want
                     if want is None:  # delivery `prefix`: leave at DRAIN
                         break
-                    short = [s for s in own if counts[s] < want[s]]
+                    short = [s for s in own if have[s] < want[s]]
                     if not short:
                         break
                     if time.monotonic_ns() >= orders.drain_deadline:
@@ -261,8 +311,8 @@ def role_consume(spec: dict, orders: Orders) -> dict:
                     fault[0] = None
                 if msgs:
                     chunks[tid].append(
-                        (s, time.monotonic_ns(), b"".join(msgs), len(msgs)))
-                    counts[s] += len(msgs)
+                        (e, s, time.monotonic_ns(), b"".join(msgs), len(msgs)))
+                    have[s] += len(msgs)
                     empty_run = 0
                 else:
                     empty_run += 1
@@ -271,8 +321,9 @@ def role_consume(spec: dict, orders: Orders) -> dict:
                         empty_run = 0
                     elif orders.drain.is_set():
                         time.sleep(idle_s)
-        except Exception as e:  # a dead consumer fails the run
-            errors.append(f"consumer thread {tid}: {type(e).__name__}: {e}")
+        except Exception as err:  # a dead consumer fails the run
+            errors.append(
+                f"consumer thread {tid}: {type(err).__name__}: {err}")
         finally:
             cc.close()
 
@@ -285,14 +336,13 @@ def role_consume(spec: dict, orders: Orders) -> dict:
         errors.append(orders.drain_error)
 
     t0, t1 = orders.t0, orders.t1
-    per_stream: dict[int, list[bytes]] = {}
-    lats = []
-    lat_stamps = []
-    got_by_t1 = 0
+    held: dict[tuple[int, int], list[bytes]] = {}  # (subscription, stream)
+    lats, lat_stamps, lat_subs = [], [], []
+    got_by_t1 = [0] * len(subs)
     ragged = 0
     for tchunks in chunks:
-        for s, recv, blob, n in tchunks:
-            per_stream.setdefault(s, []).append(blob)
+        for e, s, recv, blob, n in tchunks:
+            held.setdefault((entries[e][0], s), []).append(blob)
             if len(blob) != n * size:
                 ragged += 1
                 continue
@@ -302,21 +352,26 @@ def role_consume(spec: dict, orders: Orders) -> dict:
             if m.any():
                 lats.append((recv - st[m]) / 1e6)
                 lat_stamps.append(st[m])
+                lat_subs.append(np.full(int(m.sum()), entries[e][0], np.int16))
             if recv <= t1:
-                got_by_t1 += n
-    order = sorted(per_stream)
-    blobs = [b"".join(per_stream[s]) for s in order]
-    flat = np.frombuffer(b"".join(blobs), np.uint8)
+                got_by_t1[e] += n
+    chunks.clear()
+    order = sorted(held)
+    blobs = [b"".join(held.pop(k)) for k in order]
     pid = int(spec["proc_id"])
-    np.save(os.path.join(spec["work"], f"recv-{pid}.bytes.npy"), flat)
-    np.save(os.path.join(spec["work"], f"recv-{pid}.index.npy"),
-            np.array([(s, len(b)) for s, b in zip(order, blobs)],
-                     np.int64).reshape(-1, 2))
-    np.save(os.path.join(spec["work"], f"recv-{pid}.lat.npy"),
-            np.concatenate(lats) if lats else np.zeros(0))
-    np.save(os.path.join(spec["work"], f"recv-{pid}.latstamp.npy"),
-            np.concatenate(lat_stamps) if lat_stamps else np.zeros(0, np.int64))
-    return {"received": int(counts.sum()), "received_by_t1": got_by_t1,
+    out = os.path.join(spec["work"], f"recv-{pid}.")
+    np.save(out + "index.npy",
+            np.array([(q, s, len(b)) for (q, s), b in zip(order, blobs)],
+                     np.int64).reshape(-1, 3))
+    np.save(out + "bytes.npy", np.frombuffer(b"".join(blobs), np.uint8))
+    for name, parts, dtype in (("lat", lats, np.float64),
+                               ("latstamp", lat_stamps, np.int64),
+                               ("latsub", lat_subs, np.int16)):
+        np.save(out + name + ".npy",
+                np.concatenate(parts) if parts else np.zeros(0, dtype))
+    return {"received": int(counts.sum()),
+            "received_by_t1": {name: n for (name, *_), n
+                               in zip(subs, got_by_t1)},
             "ragged_chunks": ragged, "errors": errors[:4],
             "short_at_deadline": sum(short_at_deadline)}
 
@@ -381,6 +436,8 @@ def main() -> int:
         spec = json.load(f)
     if args.role != "scan":  # imports off the clock, before READY
         import ripplemq_tpu.client  # noqa: F401
+    if args.role == "consume":
+        client_kwargs(spec)
     orders = Orders()
     log("READY")
     log("RESULT " + json.dumps(ROLES[args.role](spec, orders)))

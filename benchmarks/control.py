@@ -12,12 +12,23 @@ the cell's own (same cluster, same traffic, a short window):
   * one replica short of an acked message: after the clean stop, the last
     replica's newest segment loses its tail (`replica<N>.missing`).
 
-One run per seed carries all three; each number is compared on its own line
-with its own limit (0, exact), and each must fail. Run by the builder on
-the chip at the cell's own size, and by tests/test_run_broken.py on the CPU
-at rehearsal size. Never part of a benchmark run.
+One run per seed carries all three (`--breaks guarantees`); each number is
+compared on its own line with its own limit (0, exact), and each must fail.
+A fourth break has a run of its own, because its number would hide behind
+the flipped byte's (`--breaks drop`, for cells whose delivery is `whole`):
+
+  * one subscription one message short: the first batch consumer process 0
+    receives in the window loses its first message, so ONE subscription
+    (of however many the cell has) lacks one message of one partition
+    (`delivery.missing` must read exactly 1).
+
+Run by the builder on the chip at the cell's own size, and by
+tests/test_run_broken.py and tests/test_subscriptions.py on the CPU at
+rehearsal size. Never part of a benchmark run.
 
     python benchmarks/control.py --workload ref-compose.sync --seeds 1,2,3
+    python benchmarks/control.py --workload omb-100p-1kb.steady \
+        --seeds 1,2,3 --breaks guarantees,drop
 """
 
 from __future__ import annotations
@@ -29,18 +40,31 @@ import time
 
 from run import Run, RunFailed, failing
 
-MUST_FAIL = ("replicas.scanned", "delivery.differ", ".missing")
+# break -> (cluster overrides, faults, the numbers that must fail: a name
+# ends in the string, or the number reads exactly the value)
+BREAKS = {
+    "guarantees": ({"standby_count": 1}, "flip_delivered,short_replica",
+                   ("replicas.scanned", "delivery.differ", ".missing")),
+    "drop": (None, "drop_delivered", (("delivery.missing", 1),)),
+}
 
 
 def control_run(workload: str, seed: int, seconds: float,
-                rehearse: bool = False) -> tuple[dict, list[str]]:
+                rehearse: bool = False,
+                breaks: str = "guarantees") -> tuple[dict, list[str]]:
     """(result, names of the compared numbers that failed their limit)."""
+    overrides, fault, _ = BREAKS[breaks]
     run = Run(workload, seed, seconds, False, rehearse=rehearse,
-              cluster_overrides={"standby_count": 1},
-              fault="flip_delivered,short_replica",
+              cluster_overrides=overrides, fault=fault,
               t_start_ns=time.monotonic_ns())
     out = run.run()
     return out, list(failing(run.numbers))
+
+
+def caught(breaks: str, out: dict, failed: list[str]) -> bool:
+    return not out["correct"] and all(
+        any(f.endswith(m) for f in failed) if isinstance(m, str)
+        else out.get(m[0]) == m[1] for m in BREAKS[breaks][2])
 
 
 def main() -> int:
@@ -49,14 +73,17 @@ def main() -> int:
     ap.add_argument("--seeds", required=True)
     ap.add_argument("--seconds", type=float, default=6.0)
     ap.add_argument("--rehearse", action="store_true")
+    ap.add_argument("--breaks", default="guarantees",
+                    help="comma list of " + ", ".join(BREAKS))
     args = ap.parse_args()
     ok = True
-    for seed in (int(s) for s in args.seeds.split(",")):
+    for seed, breaks in ((int(s), b) for s in args.seeds.split(",")
+                         for b in args.breaks.split(",")):
         try:
             out, failed = control_run(args.workload, seed, args.seconds,
-                                      args.rehearse)
+                                      args.rehearse, breaks)
         except RunFailed as e:
-            print(f"control seed {seed}: run failed outright: {e}")
+            print(f"control seed {seed} {breaks}: run failed outright: {e}")
             ok = False
             continue
         # The run's result line: it names what failed.
@@ -65,11 +92,11 @@ def main() -> int:
                   f"{json.dumps(out)}", file=sys.stderr)
         else:
             print(json.dumps(out))
-        caught = all(any(m in f for f in failed) for m in MUST_FAIL)
-        print(f"control seed {seed}: correct={out['correct']} failed "
-              f"numbers {failed} -> "
-              f"{'caught' if caught and not out['correct'] else 'MISSED'}")
-        ok = ok and caught and not out["correct"]
+        hit = caught(breaks, out, failed)
+        print(f"control seed {seed} {breaks}: correct={out['correct']} "
+              f"failed numbers { {f: out[f] for f in failed} } -> "
+              f"{'caught' if hit else 'MISSED'}")
+        ok = ok and hit
     print("control:", "every broken run came out not correct" if ok
           else "A BROKEN RUN PASSED")
     return 0 if ok else 1

@@ -1,6 +1,7 @@
 """Seeded message bodies: the one place that says what a benchmark message is.
 
-A message is `size` bytes (100 in both deployments):
+A message is `size` bytes (the configuration's `message_bytes`: 100 or
+1,024):
 
     [0:2]   stream   u16  index of its (topic, partition) in the cell's stream list
     [2:4]   client   u16  the producer client that sent it

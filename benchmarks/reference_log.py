@@ -10,11 +10,13 @@ base offset each ack carried (the order) - the bytes are rebuilt from
 A record is one acked produce call:
     stream, client, seq0, n, stamp (ns), offset (the ack's base offset)
 
-Three comparisons, each a count with the limit 0:
-  * compare_delivery: what the subscription received per partition against
-    the reference, whole or as an exact prefix;
-  * compare_replica: what one broker's data dir holds per partition;
-  * both report messages the producers never sent (`extra`).
+Two comparisons, each a set of counts with the limit 0, both through
+`compare_all` (every partition against the reference; messages the
+producers never sent are `extra`):
+  * what a subscription received per partition, whole or as an exact
+    prefix - once for every subscription of the cell
+    (`compare_subscriptions`), since each is owed the whole log;
+  * what one broker's data dir holds per partition.
 """
 
 from __future__ import annotations
@@ -107,4 +109,26 @@ def compare_all(ref: ReferenceLog, got: dict, prefix_ok: bool) -> dict:
             out["extra"] += len(blob) // ref.size + 1
             out["bad_streams"].append(int(s))
     out["bad_streams"] = out["bad_streams"][:8]
+    return out
+
+
+def compare_subscriptions(ref: ReferenceLog, got: dict, names: list,
+                          prefix_ok: bool) -> dict:
+    """`compare_all` once for every subscription in `names` against
+    `got` (name -> stream -> bytes; a name is in `got` if any consumer of
+    that subscription reported, with nothing received or not). The counts
+    are the sums; `reported` is how many of `names` are in `got`;
+    `by_subscription` keeps each one's own result, and `bad` names those
+    with a count that is not 0. One that never reported is owed
+    everything: all `missing` (all `lag` where a prefix will do)."""
+    out = {"differ": 0, "missing": 0, "extra": 0, "lag": 0,
+           "reported": sum(n in got for n in names), "by_subscription": {},
+           "bad": []}
+    for name in names:
+        r = compare_all(ref, got.get(name, {}), prefix_ok)
+        out["by_subscription"][name] = r
+        for k in ("differ", "missing", "extra", "lag"):
+            out[k] += r[k]
+        if r["bad_streams"] or name not in got:
+            out["bad"].append(name)
     return out

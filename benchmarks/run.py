@@ -106,6 +106,18 @@ def verdict(numbers: list, drain: dict) -> dict:
     return out
 
 
+def delivery_numbers(dl: dict, whole: bool, want_subs: int) -> list:
+    """Comparison (a) as numbers beside their limits, from
+    `reference_log.compare_subscriptions`: the counts summed over the
+    cell's subscriptions, and how many subscriptions had a consumer
+    report, held to the configuration's `deployment.subscriptions`."""
+    return [("delivery.differ", dl["differ"], "0"),
+            ("delivery.extra", dl["extra"], "0"),
+            ("delivery.missing", dl["missing"],
+             "0" if whole else "0 (prefix: lag allowed)"),
+            ("delivery.subscriptions", dl["reported"], f"== {want_subs}")]
+
+
 def warm_over(compile_log) -> bool:
     """Whether the launcher's compile log (lines of compiles.jsonl) shows
     WARM_LAST compiled or fetched from the cache."""
@@ -151,6 +163,9 @@ class Run:
         self.streams = [(t["name"], p) for t in dep["topics"]
                         for p in range(t["partitions"])]
         self.n_brokers = int(dep["brokers"])
+        # One form inside: a list. `subscription` is a list of one.
+        self.subscriptions = list(self.cell["subscriptions"]) \
+            if "subscriptions" in self.cell else [self.cell["subscription"]]
         self.numbers: list[tuple[str, float, str]] = []  # name, value, limit
         self.deadline_s = DEADLINE_S
         self.stopping = False
@@ -393,24 +408,39 @@ class Run:
 
     def child_spec(self, side: str, i: int) -> dict:
         part = self.cell[side]
-        return {
+        spec = {
             "work": self.work, "seed": self.seed, "proc_id": i,
             "nprocs": int(part["processes"]), "streams": self.streams,
             "message_bytes": self.size, "bootstrap": self.bootstrap,
             "params": part["params"], "generator": part.get("generator"),
-            "subscription": self.cell["subscription"],
             "trace_sample_n": int(self.cell.get("trace_sample_n", 8))
             if self.trace else 0,
             "fault": next((f for f in self.faults if f.endswith("_delivered")),
                           None) if i == 0 else None,
         }
+        if side == "consumers":
+            from benchmarks.child import hosted
+
+            subs = hosted(len(self.subscriptions), i, spec["nprocs"])
+            if len(subs) > int(part["params"]["threads"]):
+                raise RunFailed(
+                    f"consumer process {i} hosts {len(subs)} subscriptions "
+                    f"on {part['params']['threads']} threads: one would "
+                    f"have no consumer")
+            spec["subscriptions"] = [[self.subscriptions[q], q, k, of]
+                                     for q, k, of in subs]
+            # keyword arguments for the program's ConsumerClient beside
+            # those the harness sets; the child holds them to its signature
+            spec["client"] = dict(part.get("client", {}))
+        return spec
 
     def _run(self) -> dict:
         import numpy as np
 
         from benchmarks import stats as bstats
         from benchmarks.child import load_records
-        from benchmarks.reference_log import ReferenceLog, compare_all
+        from benchmarks.reference_log import (ReferenceLog,
+                                              compare_subscriptions)
 
         cell, cfg = self.cell, self.config
         log(f"cell {cell['name']}: seed {self.seed}, window {self.seconds}s, "
@@ -600,22 +630,29 @@ class Run:
         self.tell(scans, "GO")
         sh = self.results(scans)
 
-        # ---- (a) what the subscription received, meanwhile
-        got: dict = {}
-        lat, lat_stamp = [], []
-        for i in range(len(consumers)):
-            flat = np.load(os.path.join(self.work, f"recv-{i}.bytes.npy"))
+        # ---- (a) what every subscription received, meanwhile
+        subs = self.subscriptions
+        got: dict = {}  # subscription -> stream -> bytes
+        lat, lat_stamp, lat_sub = [], [], []
+        for i, r in enumerate(cons):
+            for name in r["received_by_t1"]:  # its consumers reported
+                got.setdefault(name, {})
+            recv = os.path.join(self.work, f"recv-{i}.")
+            flat = np.load(recv + "bytes.npy")
             at = 0
-            for s, n in np.load(os.path.join(self.work,
-                                             f"recv-{i}.index.npy")):
-                got[int(s)] = flat[at:at + int(n)]
+            for q, s, n in np.load(recv + "index.npy"):
+                got[subs[int(q)]][int(s)] = flat[at:at + int(n)]
                 at += int(n)
-            lat.append(np.load(os.path.join(self.work, f"recv-{i}.lat.npy")))
-            lat_stamp.append(np.load(os.path.join(
-                self.work, f"recv-{i}.latstamp.npy")))
+            lat.append(np.load(recv + "lat.npy"))
+            lat_stamp.append(np.load(recv + "latstamp.npy"))
+            lat_sub.append(np.load(recv + "latsub.npy"))
         lat = np.concatenate(lat)
         lat_stamp = np.concatenate(lat_stamp)
-        dl = compare_all(ref, got, prefix_ok=not whole)
+        lat_sub = np.concatenate(lat_sub)
+        dl = compare_subscriptions(ref, got, subs, prefix_ok=not whole)
+        received_bytes = sum(len(b) for g in got.values() for b in g.values())
+        reported = set(got)
+        del got, flat
         scanned = self.collect(scans, sh, "scan")
 
         # ---- the window's own numbers
@@ -626,16 +663,20 @@ class Run:
         attempted = sum(r["due_msgs"] for r in prod)
         acked_due = int(records["n"][in_win].sum())
         failed = max(0, attempted - acked_due)
-        delivered_due = len(lat)
+        # a message counts once a subscription; one that any subscription
+        # lacks is a failed operation (the one furthest short says how many)
+        delivered_due = np.bincount(lat_sub, minlength=len(subs))
         if whole:
-            failed += max(0, acked_due - delivered_due)
-        recv_by_t1 = sum(r["received_by_t1"] for r in cons)
+            failed += max(0, acked_due - int(delivered_due.min()))
         acked_by_t1 = int(records["n"][records["ack"] < t1].sum())
+        lag = {name: acked_by_t1 - sum(r["received_by_t1"].get(name, 0)
+                                       for r in cons) for name in subs}
         late = [x for r in prod for x in r.get("late_ms", [])]
         log(f"window: {attempted} messages due or sent, {acked_due} of them "
             f"acked ({int(in_win.sum())} calls), {acked_msgs} messages acked "
             f"inside the window; subscription lag at window end "
-            f"{acked_by_t1 - recv_by_t1} messages; drain took "
+            f"{' '.join(f'{n}={v}' for n, v in lag.items())} messages; "
+            f"{received_bytes} bytes received in all; drain took "
             f"{(t_drained - t1) / 1e9:.1f}s")
 
         steps = cell["producers"]["params"].get("rate_steps_msgs_per_s")
@@ -680,10 +721,8 @@ class Run:
 
         # ---- correct: every number beside its limit
         N = self.numbers
-        N.append(("delivery.differ", dl["differ"], "0"))
-        N.append(("delivery.extra", dl["extra"], "0"))
-        N.append(("delivery.missing", dl["missing"],
-                  "0" if whole else "0 (prefix: lag allowed)"))
+        N.extend(delivery_numbers(dl, whole,
+                                  int(cfg["deployment"]["subscriptions"])))
         N.append(("delivery.consumer_errors",
                   sum(len(r["errors"]) + r["ragged_chunks"] for r in cons),
                   "0"))
@@ -702,8 +741,13 @@ class Run:
         for e in stat_errors[:6] + [e for r in prod for e in r["errors"]][:4] \
                 + [e for r in cons for e in r["errors"]][:4]:
             log(f"  error: {e}")
-        if dl["bad_streams"]:
-            log(f"  delivery differs on streams {dl['bad_streams']}")
+        for name in dl["bad"]:
+            r = dl["by_subscription"][name]
+            log(f"  delivery to subscription {name}: differ {r['differ']} "
+                f"extra {r['extra']} missing {r['missing']} lag {r['lag']} "
+                f"on streams {r['bad_streams']}"
+                + ("" if name in reported else
+                   " (no consumer of it reported)"))
 
         mid = (t0 + t1) // 2
         for label, xs, st in (("produce ack", ack_ms, records["stamp"][in_win]),
@@ -753,7 +797,10 @@ class Run:
                    "spans": spans, "trace": trace_summary, "config": cfg,
                    "cell": cell, "peaks": self.peaks,
                    "client": {"late_ms": late, "ack_ms": ack_ms.tolist(),
-                              "deliver_ms": lat}}
+                              "deliver_ms": lat,
+                              "deliver_ms_by_subscription": {
+                                  name: lat[lat_sub == q]
+                                  for q, name in enumerate(subs)}}}
             metrics = {}
             for fname in sorted(os.listdir(os.path.join(HERE,
                                                         "layer_metrics"))):

@@ -55,10 +55,11 @@ class FakeClient:
 class Harness:
     """One consumer child in this process: its orders through a pipe."""
 
-    def __init__(self, tmp_path, monkeypatch) -> None:
+    def __init__(self, tmp_path, monkeypatch, client_class=None,
+                 **spec) -> None:
         import ripplemq_tpu.client as client
 
-        self.client = type("ScriptedClient", (FakeClient,), {
+        self.client = type("ScriptedClient", (client_class or FakeClient,), {
             "late": threading.Event(), "served": {0: 0, 1: 0},
             "idle_polls": 0})
         monkeypatch.setattr(client, "ConsumerClient", self.client)
@@ -70,8 +71,8 @@ class Harness:
             "params": {"threads": 1, "max_messages": 64,
                        "poll_interval_s": 0.0, "idle_sleep_s": 0.001},
             "message_bytes": SIZE, "streams": [["t", 0], ["t", 1]],
-            "proc_id": 0, "nprocs": 1, "bootstrap": [], "subscription": "s",
-            "work": self.work}
+            "proc_id": 0, "nprocs": 1, "bootstrap": [],
+            "subscriptions": [["s", 0, 0, 1]], "work": self.work, **spec}
         self.result: dict = {}
         self.thread = threading.Thread(target=lambda: self.result.update(
             child.role_consume(self.spec, self.orders)))
@@ -109,11 +110,12 @@ class Harness:
         assert self.orders.gone
         self.stdin.close()
 
-    def received(self) -> dict:
+    def received(self, subscription: int = 0) -> dict:
         flat = np.load(os.path.join(self.work, "recv-0.bytes.npy"))
         out, at = {}, 0
-        for s, n in np.load(os.path.join(self.work, "recv-0.index.npy")):
-            out[int(s)] = bytes(flat[at:at + int(n)])
+        for q, s, n in np.load(os.path.join(self.work, "recv-0.index.npy")):
+            if q == subscription:
+                out[int(s)] = bytes(flat[at:at + int(n)])
             at += int(n)
         return out
 
