@@ -60,6 +60,7 @@ FAST_MODULES = {
     "test_lint",                # ripplelint fixtures + whole-repo clean run
     "test_lockwitness",         # witness units: private locks, no cluster
     "test_concurrency_triage",  # directed repros for the PR 11 race fixes
+    "test_consume_session",     # ~10 s: one 4-broker cluster, 128 partitions
     "test_log_matching",
     "test_marker_audit",
     "test_metadata",

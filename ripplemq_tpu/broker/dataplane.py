@@ -1257,32 +1257,49 @@ class DataPlane:
         replicate through the same quorum round as appends — the
         reference routes them through the same partition Raft log,
         ConsumerOffsetUpdateRequestProcessor.java:38-69)."""
-        fut: Future = Future()
-        C = self.cfg.max_consumers
+        return self.submit_offsets_many([(slot, updates)])[0]
+
+    def submit_offsets_many(self, items: list) -> list[Future]:
+        """`submit_offsets` for MANY partitions at once — the parts of
+        one offset.commit.multi request, as (slot, updates) — with the
+        same checks and the same outcome each, under ONE hold of the
+        plane's lock (the `submit_appends` of offsets: a readahead
+        consumer's commit for every partition it polls on this leader
+        must not queue behind the step thread's round build once a
+        partition)."""
+        futs: list[Future] = [Future() for _ in items]
+        ready = []
+        for (slot, updates), fut in zip(items, futs):
+            refusal = self._offsets_refusal(slot, updates)
+            if refusal is not None:
+                fut.set_exception(refusal)
+            else:
+                ready.append((slot, _PendingOffsets(
+                    [(int(s), int(o)) for s, o in updates], fut,
+                    self.max_retry_rounds)))
+        if ready:
+            self._m_offsets.inc(len(ready))
+            with self._lock:
+                for slot, pend in ready:
+                    self._offsets.setdefault(slot, []).append(pend)
+            self._work.set()
+        return futs
+
+    def _offsets_refusal(self, slot: int, updates) -> Optional[Exception]:
+        """Why one partition's offset commits cannot be queued, or None."""
         if not 0 <= slot < self.cfg.partitions:
-            fut.set_exception(ValueError(f"partition slot {slot} out of range"))
-            return fut
+            return ValueError(f"partition slot {slot} out of range")
         if len(updates) > self.cfg.max_offset_updates:
             # An oversized pending could never fit a round and would wedge
             # the slot's FIFO queue forever.
-            fut.set_exception(
-                ValueError(
-                    f"{len(updates)} offset updates exceed max_offset_updates "
-                    f"{self.cfg.max_offset_updates}"
-                )
+            return ValueError(
+                f"{len(updates)} offset updates exceed max_offset_updates "
+                f"{self.cfg.max_offset_updates}"
             )
-            return fut
+        C = self.cfg.max_consumers
         if not updates or any(not 0 <= s < C for s, _ in updates):
-            fut.set_exception(ValueError(f"bad consumer slots in {updates}"))
-            return fut
-        self._m_offsets.inc()
-        with self._lock:
-            self._offsets.setdefault(slot, []).append(
-                _PendingOffsets([(int(s), int(o)) for s, o in updates], fut,
-                                self.max_retry_rounds)
-            )
-        self._work.set()
-        return fut
+            return ValueError(f"bad consumer slots in {updates}")
+        return None
 
     # --------------------------------------------------------------- reads
 
@@ -1321,6 +1338,109 @@ class DataPlane:
             msgs, nxt = self._read(slot, offset, replica, max_msgs)
         self._m_read_bytes.inc(sum(map(len, msgs)))
         return msgs, nxt
+
+    def read_many(self, items: list) -> list:
+        """`read` for MANY partitions at once — the parts of one
+        consume.multi request, as (slot, offset, consumer_slot, replica,
+        max_msgs); an `offset` of None reads from `consumer_slot`'s
+        committed offset (`read_offset`). Per item the answer is
+        (messages, offset, next_offset) — what `read` gives, behind the
+        offset the read started at — or the exception `read` would have
+        raised. What differs is the locking. A part at a known offset on
+        a healthy partition (no settled gap, shadow not dirty) is looked
+        at WITHOUT the plane's lock: a tail poll, the common case, is
+        answered from the settled horizon alone, and a window the mirror
+        holds is copied and its trim re-checked as `_read_cache` does,
+        only unlocked. That is sound because of the order the settle
+        thread keeps (`_release_one`): a round's rows are in the mirror
+        before `_cache_end` admits them and before `_settled_end` passes
+        them, a failed round's gap is recorded before the horizon passes
+        it (so the horizon is read FIRST here, the gap table after), both
+        horizons only grow, and trim is raised before a lap's rows are
+        overwritten - a reader a moment early sees a horizon a moment
+        old, which is a poll a moment earlier. A consumer's session sends
+        a fixed number of these requests a second whatever they cost
+        (PR 40), so unlike the single-partition `read` (PERF.md section
+        6, PR 27) cheaper ones do not mean more of them; and left on the
+        lock, ten of them stood on it at any moment in the saturated
+        cell, in front of the settle thread's six takes a round. The
+        other parts are looked at under ONE hold; whatever the mirror
+        cannot answer alone (below trim, a gap, a dirty shadow, an
+        all-padding window) takes `_read`, partition by partition.
+        `read.serve` times the whole call; `read.calls` and `read.bytes`
+        count per partition, as `read` counts them."""
+        out: list = [None] * len(items)
+        windows = []  # (item, offset, row count): mirror rows to copy
+        locked = []   # items to look at under the lock
+        slow = []     # (item, offset): `_read` decides
+        cfg = self.cfg
+        with self._st_read.timed():
+            for i, (slot, offset, _, _, _) in enumerate(items):
+                if not 0 <= slot < cfg.partitions:
+                    out[i] = ValueError(
+                        f"partition slot {slot} out of range")
+                    continue
+                if offset is not None and self._host_ring is not None:
+                    end = int(self._settled_end[slot])
+                    cend = int(self._cache_end[slot])
+                    if (not self._settled_gaps.get(slot)
+                            and slot not in self._shadow_dirty):
+                        if offset >= end:
+                            out[i] = ([], offset, offset)  # caught up
+                            self.read_cache_hits += 1
+                            continue
+                        if int(self.trim[slot]) <= offset < cend:
+                            windows.append((i, offset, min(
+                                end - offset, cend - offset,
+                                cfg.read_batch)))
+                            continue
+                locked.append(i)
+            if locked:
+                with self._lock:
+                    for i in locked:
+                        slot, offset, cslot, _, _ = items[i]
+                        if offset is None:
+                            if not 0 <= cslot < cfg.max_consumers:
+                                out[i] = ValueError(
+                                    f"consumer slot {cslot} out of range")
+                                continue
+                            offset = int(self._offsets_shadow[slot, cslot])
+                        plan = None
+                        if self._host_ring is not None and not (
+                                offset < int(self.trim[slot])
+                                and self.log_index is not None):
+                            plan = self._cache_window_locked(slot, offset)
+                        if isinstance(plan, int):
+                            windows.append((i, offset, plan))
+                        elif isinstance(plan, tuple) and plan[1] == offset:
+                            out[i] = ([], offset, offset)  # caught up
+                            self.read_cache_hits += 1
+                        else:
+                            slow.append((i, offset))
+            for i, offset, k in windows:
+                slot = items[i][0]
+                rows = self._cache_rows(slot, offset, k)
+                got = None
+                if not (int(self.trim[slot]) > offset
+                        and self.log_index is not None):
+                    got = self._decode_rows(rows, offset, k, items[i][4])
+                if got is None or (not got[0] and got[1] > offset):
+                    slow.append((i, offset))  # lapped, or all padding
+                    continue
+                out[i] = (got[0], offset, got[1])
+                self.read_cache_hits += 1
+                self._m_read_msgs.inc(len(got[0]))
+            for i, offset in slow:
+                slot, _, _, replica, max_msgs = items[i]
+                try:
+                    msgs, nxt = self._read(slot, offset, replica, max_msgs)
+                    out[i] = (msgs, offset, nxt)
+                except Exception as e:
+                    out[i] = e
+        served = [r for r in out if isinstance(r, tuple)]
+        self._m_read_calls.inc(len(served))
+        self._m_read_bytes.inc(sum(len(m) for r in served for m in r[0]))
+        return out
 
     def _read(self, slot: int, offset: int, replica: int,
               max_msgs: Optional[int]) -> tuple[list[bytes], int]:
@@ -1475,15 +1595,28 @@ class DataPlane:
         return the same emptiness), so tail polls stay host-authoritative
         even while the settle pipeline holds committed-but-unsettled
         rounds in flight."""
-        S = self.cfg.slots
         with self._lock:
-            end = int(self._settled_end[slot])
-            cend = int(self._cache_end[slot])
-            dirty = slot in self._shadow_dirty
-            skip_to, gap_room = self._gap_clamp_locked(
-                slot, offset, self.cfg.read_batch
-            )
-        if dirty:
+            plan = self._cache_window_locked(slot, offset)
+        if not isinstance(plan, int):
+            return plan
+        rows = self._cache_rows(slot, offset, plan)
+        with self._lock:
+            lapped = int(self.trim[slot]) > offset
+        if lapped and self.log_index is not None:
+            return _CACHE_LAPPED  # rows may hold the next lap now
+        return self._decode_rows(rows, offset, plan, max_msgs)
+
+    def _cache_window_locked(self, slot: int, offset: int):
+        """What the mirror says of one hot read before a byte is copied
+        (caller holds self._lock; `_read_cache` has the contract): an
+        answer of its own — None, `_CACHE_GAP`, or an empty (messages,
+        next_offset) — or, as an int, the rows of the window to copy."""
+        end = int(self._settled_end[slot])
+        cend = int(self._cache_end[slot])
+        skip_to, gap_room = self._gap_clamp_locked(
+            slot, offset, self.cfg.read_batch
+        )
+        if slot in self._shadow_dirty:
             # A resolve failed with the slot's round outcome unknown:
             # the log-end shadow may TRAIL device-committed rows until
             # the next drain re-derives it, so an empty answer here
@@ -1500,19 +1633,25 @@ class DataPlane:
             return [], offset  # caught up: nothing committed past offset
         if offset >= cend:
             return _CACHE_GAP  # mirror gap: store/device is the authority
+        return int(min(end - offset, cend - offset, self.cfg.read_batch,
+                       gap_room))
+
+    def _cache_rows(self, slot: int, offset: int, k: int) -> np.ndarray:
+        """A copy of `k` mirror rows from `offset` (no lock: the caller
+        re-checks trim afterwards)."""
+        S = self.cfg.slots
         pos = offset % S
-        k = min(end - offset, cend - offset, self.cfg.read_batch, gap_room)
         if pos + k <= S:
-            rows = self._host_ring[slot, pos : pos + k].copy()
-        else:  # window spans the ring wrap, same as the device read
-            rows = np.concatenate([
-                self._host_ring[slot, pos:],
-                self._host_ring[slot, : pos + k - S],
-            ])
-        with self._lock:
-            lapped = int(self.trim[slot]) > offset
-        if lapped and self.log_index is not None:
-            return _CACHE_LAPPED  # rows may hold the next lap now
+            return self._host_ring[slot, pos : pos + k].copy()
+        # window spans the ring wrap, same as the device read
+        return np.concatenate([
+            self._host_ring[slot, pos:],
+            self._host_ring[slot, : pos + k - S],
+        ])
+
+    def _decode_rows(self, rows: np.ndarray, offset: int, k: int,
+                     max_msgs: Optional[int]) -> tuple[list[bytes], int]:
+        """(messages, next_offset) of `k` copied mirror rows."""
         # Decode on flat bytes: one tobytes() for the window, then
         # length-prefixed slices — ~3x the msgs/s of per-row numpy
         # slicing on the host-RAM-bound consume path.

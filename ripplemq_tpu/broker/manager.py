@@ -1074,9 +1074,10 @@ class PartitionManager:
     def peek(self, key: GroupKey
              ) -> tuple[Optional[PartitionAssignment], Optional[int]]:
         """(assignment, engine slot) of one partition, WITHOUT the lock:
-        the admission of a produce.multi part. A request of a keyed
-        producer holds a hundred parts, and three locked lookups apiece
-        (`generation_of`, `slot_of`, `leader_of`) queued its RPC worker
+        the admission of one part of a multi request (produce.multi,
+        and since PR 40 consume.multi and offset.commit.multi). A
+        request of a keyed producer holds a hundred parts, and three
+        locked lookups apiece (`generation_of`, `slot_of`, `leader_of`) queued its RPC worker
         behind every consume and commit on this lock a hundred times
         (first keyed sweep, PR 27: acks of 17 s at 5,000 msgs/s). What
         is read is state the applies replace and never mutate in place —
@@ -1086,7 +1087,8 @@ class PartitionManager:
         lookup would. The locked accessors stay as they are for every
         other caller (PERF.md section 6, PR 27: making them lock-free too
         sped the subscription up and steady's ack went from 115 to 700
-        ms). The one other lock-free read is `fence_view`, for the
+        ms; a readahead consumer's parts take this look because its
+        session sends FEWER requests than it sent polls, PR 40). The one other lock-free read is `fence_view`, for the
         standby stream alone (`_publish_fence_view`): the same rule —
         immutable state behind one attribute store — for the same
         reason, a serial path that queued on this lock."""

@@ -9,8 +9,13 @@ each (wire/transport dispatches by the "type" field):
   meta.propose     ← TopicsRequestProcessor write + PartitionLeaderUpdate
                      forwarding (both were metadata Raft writes)
   produce          ← MessageAppendRequestProcessor
+  produce.multi    ← (no equivalent) a keyed producer's request for
+                     many partitions of this leader, answered per part
   consume          ← MessageBatchReadRequestProcessor
+  consume.multi    ← (no equivalent) a readahead consumer's fetch for
+                     many partitions of this leader, answered per part
   offset.commit    ← ConsumerOffsetUpdateRequestProcessor
+  offset.commit.multi ← (no equivalent) that consumer's commit, likewise
   raft.*           ← JRaft's internal traffic (here: hostraft, metadata only)
   engine.*         ← controller-only: data-plane access for peer brokers
                      (the reference needs no equivalent — every JVM broker
@@ -88,6 +93,9 @@ from ripplemq_tpu.wire.transport import (
 )
 
 log = get_logger("broker")
+
+
+_RESOLVE = object()  # "look the local plane up yourself" (_quorum_refusal)
 
 
 class _UpstreamRefusal(Exception):
@@ -339,6 +347,15 @@ class BrokerServer:
         # and refusal paths alike): the p99 the SLO controller's consume
         # twin steers toward slo_p99_consume_ms via read_coalesce_s.
         self._m_consume_ack_us = self.metrics.histogram("consume.ack_us")
+        # consume.multi / offset.commit.multi (a readahead consumer's
+        # session with this leader): requests and the parts they
+        # carried - parts a request says how widely sessions engage.
+        self._m_cmulti_requests = self.metrics.counter(
+            "consume.multi_requests")
+        self._m_cmulti_parts = self.metrics.counter("consume.multi_parts")
+        self._m_omulti_requests = self.metrics.counter(
+            "commit.multi_requests")
+        self._m_omulti_parts = self.metrics.counter("commit.multi_parts")
         # One sealed segment's shard pushed to its peer (_shard_duty):
         # file read, frame encode, the RPC and its answer — on the duty
         # thread, so histogram only.
@@ -1112,8 +1129,12 @@ class BrokerServer:
                 return self._handle_produce_multi(req)
             if t == "consume":
                 return self._handle_consume(req)
+            if t == "consume.multi":
+                return self._handle_consume_multi(req)
             if t == "offset.commit":
                 return self._handle_offset_commit(req)
+            if t == "offset.commit.multi":
+                return self._handle_offset_commit_multi(req)
             if t == "producer.register":
                 return self._handle_producer_register(req)
             if t.startswith("group."):
@@ -2166,7 +2187,7 @@ class BrokerServer:
         partitions forever); non-leadership is a retryable refusal with a
         hint — unlike the reference, which answered "Not leader" and then
         appended anyway (MessageAppendRequestProcessor.java:29-33)."""
-        if view is not None:  # a produce.multi part: PartitionManager.peek
+        if view is not None:  # a multi request's part: PartitionManager.peek
             slot, leader = view[1], view[0].leader if view[0] else None
         else:
             slot = self.manager.slot_of(key)
@@ -2206,7 +2227,7 @@ class BrokerServer:
         pgen = req.get("pgen")
         if pgen is None:
             return None
-        if view is not None:  # a produce.multi part: PartitionManager.peek
+        if view is not None:  # a multi request's part: PartitionManager.peek
             gen = view[0].generation if view[0] else None
         else:
             gen = self.manager.generation_of(key)
@@ -2600,21 +2621,56 @@ class BrokerServer:
                     "routed_partition": routed}
         return {"ok": True, "base_offset": base0, "count": committed}
 
-    def _quorum_refusal(self, slot: int) -> Optional[dict]:
+    def _quorum_refusal(self, slot: int, dp=_RESOLVE) -> Optional[dict]:
         """Graceful degradation: when the partition's replica quorum is
         lost (mask says no round can commit), fail FAST with a typed,
         retryable `unavailable` refusal instead of letting the request
         hang into its RPC timeout (consume's auto-commit and offset
         commits ride quorum rounds that are doomed before dispatch).
         Only the controller can see the mask; non-controller leaders get
-        the same refusal from the controller's engine.* handlers."""
-        dp = self._local_engine()
+        the same refusal from the controller's engine.* handlers. A
+        request of many parts resolves the local plane once and hands
+        it in as `dp`."""
+        if dp is _RESOLVE:
+            dp = self._local_engine()
         if dp is not None and dp.quorum_lost(slot):
             return {"ok": False,
                     "error": f"unavailable: partition slot {slot} lost "
                              f"its replica quorum (degraded; retry after "
                              f"heal)"}
         return None
+
+    def _admit_partition(self, req: dict, key, view=None, dp=_RESOLVE
+                         ) -> tuple[Optional[int], Optional[dict]]:
+        """(engine slot, refusal): the admission a consume and an offset
+        commit share - generation fence, existence, leadership, quorum,
+        in that order. A part of a consume.multi / offset.commit.multi
+        hands in its ONE lock-free look at the partition (`view`:
+        PartitionManager.peek, as a produce.multi part does) and its
+        request's one resolution of the local plane (`dp`); the
+        single-partition requests keep the locked lookups they always
+        made (PartitionManager.peek says why)."""
+        refusal = self._gen_refusal(req, key, view)
+        if refusal:
+            return None, refusal
+        slot, refusal = self._check_partition(key, view)
+        if refusal:
+            return None, refusal
+        return slot, self._quorum_refusal(slot, dp)
+
+    @staticmethod
+    def _part_refusal(e: Exception) -> dict:
+        """The answer of ONE part of a multi request whose serving
+        raised `e`: what `_dispatch` answers a single-partition request
+        that raises it."""
+        if isinstance(e, _UpstreamRefusal):
+            return dict(e.resp)
+        if isinstance(e, NotCommittedError):
+            return {"ok": False, "error": f"not_committed: {e}"}
+        if isinstance(e, (KeyError, ValueError, TypeError)):
+            return {"ok": False,
+                    "error": f"bad_request: {type(e).__name__}: {e}"}
+        return {"ok": False, "error": f"internal: {type(e).__name__}: {e}"}
 
     def _handle_consume(self, req: dict) -> dict:
         """Ack-latency instrumentation around the consume path (the
@@ -2634,10 +2690,7 @@ class BrokerServer:
 
     def _consume_checked(self, req: dict, tctx=None) -> dict:
         key = group_key(req["topic"], req["partition"])
-        refusal = self._gen_refusal(req, key)
-        if refusal:
-            return refusal
-        slot, refusal = self._check_partition(key)
+        slot, refusal = self._admit_partition(req, key)
         if refusal:
             # Follower read path: a non-leader with a valid lease may
             # still answer an explicit-offset consume from its
@@ -2646,14 +2699,13 @@ class BrokerServer:
             # cannot prove settled refuses with the retryable
             # `not_settled_here:` and the client falls back to the
             # leader named in the ordinary hint.
-            if req.get("follower_ok") and req.get("offset") is not None:
+            if (refusal.get("error") == "not_leader"
+                    and req.get("follower_ok")
+                    and req.get("offset") is not None):
                 answer = self._follower_consume(key, req, refusal,
                                                 tctx=tctx)
                 if answer is not None:
                     return answer
-            return refusal
-        refusal = self._quorum_refusal(slot)
-        if refusal:
             return refusal
         cslot = self._resolve_consumer(req["consumer"])
         if cslot is None:
@@ -2683,6 +2735,72 @@ class BrokerServer:
         # committable position is next_offset — NOT offset + len(messages).
         return {"ok": True, "messages": msgs, "offset": offset,
                 "next_offset": next_offset}
+
+    def _handle_consume_multi(self, req: dict) -> dict:
+        """One consume request for MANY partitions of this leader (a
+        readahead consumer's session: `ConsumerClient`). `parts` is a
+        list of {topic, partition, offset, max_messages, pgen} (the last
+        three optional, as in a `consume`); `consumer` is the request's
+        and is resolved once. Every part goes through the admission a
+        `consume` does on ONE lock-free look at its partition, the reads
+        of all admitted parts are one call (`DataPlane.read_many`: one
+        hold of the plane's lock for the look at every part) - or, on a
+        leader that is not the controller, ONE engine.read_multi frame -
+        and the reply answers part by part: {"ok": true, "parts":
+        [{"ok": true, "messages", "offset", "next_offset"} | {"ok":
+        false, "error", ...}]}. A part is answered as a `consume` of it
+        would be, except that none long-polls and none is served by a
+        follower. `consume.ack_us` observes the whole request, once."""
+        parts = req.get("parts")
+        if not isinstance(parts, list) or not parts:
+            return {"ok": False, "error": "bad_request: empty parts"}
+        t0 = self.metrics.clock()
+        sp = (self.spans.span("rpc.recv", ctx_from_wire(req.get("tctx")),
+                              {"op": "consume.multi", "parts": len(parts)})
+              if self.spans is not None else NULL_SPAN)
+        self._m_cmulti_requests.inc()
+        self._m_cmulti_parts.inc(len(parts))
+        try:
+            cslot = self._resolve_consumer(req["consumer"])
+            if cslot is None:
+                return {"ok": False, "error": "consumer_registration_failed"}
+            dp = self._local_engine()
+            answers: list = [None] * len(parts)
+            items, where = [], []
+            for i, part in enumerate(parts):
+                try:
+                    key = group_key(part["topic"], part["partition"])
+                    view = self.manager.peek(key)
+                    slot, refusal = self._admit_partition(part, key, view,
+                                                          dp)
+                    if refusal:
+                        answers[i] = refusal
+                        continue
+                    offset, limit = part.get("offset"), part.get(
+                        "max_messages")
+                    if offset is not None and int(offset) < 0:
+                        answers[i] = {"ok": False, "error":
+                                      "bad_request: negative offset"}
+                        continue
+                    replicas = view[0].replicas
+                    items.append((
+                        slot, None if offset is None else int(offset), cslot,
+                        # leader not in replicas: metadata race; slot 0
+                        replicas.index(self.broker_id)
+                        if self.broker_id in replicas else 0,
+                        None if limit is None else int(limit)))
+                    where.append(i)
+                except (KeyError, ValueError, TypeError) as e:
+                    answers[i] = self._part_refusal(e)
+            for i, got in zip(where, self._engine_read_many(items, dp)):
+                answers[i] = (
+                    {"ok": True, "messages": got[0], "offset": got[1],
+                     "next_offset": got[2]}
+                    if isinstance(got, tuple) else self._part_refusal(got))
+            return {"ok": True, "parts": answers}
+        finally:
+            sp.end()
+            self._m_consume_ack_us.observe(self.metrics.clock() - t0)
 
     def _follower_consume(self, key, req: dict, not_leader: dict,
                           tctx=None) -> Optional[dict]:
@@ -2795,13 +2913,7 @@ class BrokerServer:
 
     def _handle_offset_commit(self, req: dict) -> dict:
         key = group_key(req["topic"], req["partition"])
-        refusal = self._gen_refusal(req, key)
-        if refusal:
-            return refusal
-        slot, refusal = self._check_partition(key)
-        if refusal:
-            return refusal
-        refusal = self._quorum_refusal(slot)
+        slot, refusal = self._admit_partition(req, key)
         if refusal:
             return refusal
         fenced = req.get("group") is not None
@@ -2832,6 +2944,59 @@ class BrokerServer:
             if refusal:
                 return refusal
         return {"ok": True}
+
+    def _handle_offset_commit_multi(self, req: dict) -> dict:
+        """One offset commit for MANY partitions of this leader (the
+        commit side of a readahead consumer's session). `parts` is a
+        list of {topic, partition, offset, pgen}; `consumer` and - for a
+        group member's commit - `group` / `member` / `generation` are
+        the request's (a part may carry its own). Every part goes
+        through the admission and the fences an `offset.commit` does,
+        the updates of all admitted parts are submitted under ONE hold
+        of the plane's lock (`DataPlane.submit_offsets_many`) - or ride
+        ONE engine.offsets_multi frame to the controller - this one RPC
+        worker is parked until the rounds that carry them have settled,
+        and the reply answers part by part: {"ok": true, "parts":
+        [{"ok": true} | {"ok": false, "error", ...}]}."""
+        parts = req.get("parts")
+        if not isinstance(parts, list) or not parts:
+            return {"ok": False, "error": "bad_request: empty parts"}
+        self._m_omulti_requests.inc()
+        self._m_omulti_parts.inc(len(parts))
+        cslot = self._resolve_consumer(req["consumer"])
+        if cslot is None:
+            return {"ok": False, "error": "consumer_registration_failed"}
+        dp = self._local_engine()
+        answers: list = [None] * len(parts)
+        items, fences = [], []  # fences: (part index, group fields, key)
+        for i, part in enumerate(parts):
+            try:
+                key = group_key(part["topic"], part["partition"])
+                slot, refusal = self._admit_partition(
+                    part, key, self.manager.peek(key), dp)
+                # the request's group fields, a part's own over them
+                fence = {k: src[k] for src in (req, part)
+                         for k in ("group", "member", "generation")
+                         if src.get(k) is not None}
+                if not refusal and "group" in fence:
+                    refusal = self._fence_group_commit(fence, key)
+                if refusal:
+                    answers[i] = refusal
+                    continue
+                items.append((slot, [(cslot, int(part["offset"]))]))
+                fences.append((i, fence, key))
+            except (KeyError, ValueError, TypeError) as e:
+                answers[i] = self._part_refusal(e)
+        for (i, fence, key), err in zip(fences,
+                                        self._engine_offsets_many(items, dp)):
+            if err is not None:
+                answers[i] = self._part_refusal(err)
+            else:
+                # Fenced again AFTER the offset round, as an
+                # `offset.commit` is (_handle_offset_commit says why).
+                answers[i] = ("group" in fence and self._fence_group_commit(
+                    fence, key)) or {"ok": True}
+        return {"ok": True, "parts": answers}
 
     def _fence_group_commit(self, req: dict, key) -> Optional[dict]:
         """Generation fencing: a group commit must come from a CURRENT
@@ -3539,6 +3704,38 @@ class BrokerServer:
         )
         return list(resp["messages"]), int(resp["end"])
 
+    def _engine_call_items(self, t: str, items: list, decode) -> list:
+        """ONE engine.*_multi frame to the controller for the items of a
+        multi request: per item `decode` of its result - a value, or the
+        exception that refuses it. A refused or failed frame fails every
+        item."""
+        try:
+            resp = self._engine_call({"type": t, "items": items})
+            results = [decode(r) for r in resp["results"]]
+            if len(results) != len(items):
+                raise RpcError(f"{t} answered {len(results)} of "
+                               f"{len(items)} items")
+            return results
+        except (RpcError, NotCommittedError, _UpstreamRefusal, KeyError,
+                TypeError, AttributeError) as e:
+            return [e] * len(items)
+
+    def _engine_read_many(self, items: list, dp) -> list:
+        """The reads of one consume.multi, as `DataPlane.read_many`
+        takes and answers them: from the local plane `dp`, or forwarded
+        to the controller in ONE engine.read_multi frame, never part by
+        part."""
+        if not items:
+            return []
+        if dp is not None:
+            self._read_barrier()
+            return dp.read_many(items)
+        return self._engine_call_items(
+            "engine.read_multi", [list(item) for item in items],
+            lambda r: (list(r[0]), int(r[1]), int(r[2]))
+            if isinstance(r, list)
+            else NotCommittedError(str(r.get("error"))))
+
     def _engine_log_end(self, slot: int) -> int:
         """The slot's device-committed absolute log end, from the local
         plane or the controller's (the split watermark observation —
@@ -3570,6 +3767,31 @@ class BrokerServer:
             {"type": "engine.offsets", "slot": slot,
              "updates": [[s, o] for s, o in updates]}
         )
+
+    def _engine_offsets_many(self, items: list, dp) -> list:
+        """`_engine_offsets` for the parts of one offset.commit.multi,
+        as (slot, updates): every item submitted before any is waited
+        for - under one hold of the local plane `dp`'s lock, or in ONE
+        engine.offsets_multi frame to the controller. Per item None
+        (settled) or the exception that failed it."""
+        if not items:
+            return []
+        if dp is not None:
+            results: list = []
+            for fut in dp.submit_offsets_many(items):
+                try:
+                    fut.result(timeout=self.config.rpc_timeout_s)
+                    results.append(None)
+                except Exception as e:
+                    results.append(e)
+            return results
+        return self._engine_call_items(
+            "engine.offsets_multi",
+            [[slot, [[s, o] for s, o in updates]] for slot, updates in items],
+            lambda r: None if r is True
+            else _UpstreamRefusal(r) if str(r.get("error", "")).startswith(
+                "unavailable:")
+            else NotCommittedError(str(r.get("error"))))
 
     def _handle_engine(self, t: str, req: dict) -> dict:
         dp = self._local_engine()
@@ -3643,6 +3865,20 @@ class BrokerServer:
                 wait_s=float(req.get("wait_s", 0) or 0),
             )
             return {"ok": True, "messages": msgs, "end": end}
+        if t == "engine.read_multi":
+            # The forwarded reads of one consume.multi: one answer per
+            # item ([messages, offset, next_offset], or {"error"}).
+            self._read_barrier()
+            return {"ok": True, "results": [
+                list(r) if isinstance(r, tuple)
+                else {"error": f"{type(r).__name__}: {r}"}
+                for r in dp.read_many([
+                    (int(slot), None if offset is None else int(offset),
+                     int(cslot), int(replica),
+                     None if limit is None else int(limit))
+                    for slot, offset, cslot, replica, limit in req["items"]
+                ])
+            ]}
         if t == "engine.read_offset":
             return {"ok": True, "offset": dp.read_offset(
                 int(req["slot"]), int(req["cslot"]),
@@ -3658,6 +3894,20 @@ class BrokerServer:
             )
             fut.result(self.config.rpc_timeout_s)
             return {"ok": True}
+        if t == "engine.offsets_multi":
+            # The forwarded updates of one offset.commit.multi: the
+            # quorum refusal per item, one submit for the rest, one
+            # answer per item (true, or {"error"}).
+            items = [(int(slot), [(int(s), int(o)) for s, o in updates])
+                     for slot, updates in req["items"]]
+            results: list = [self._quorum_refusal(slot, dp)
+                             for slot, _ in items]
+            live = [i for i, r in enumerate(results) if r is None]
+            for i, err in zip(live, self._engine_offsets_many(
+                    [items[i] for i in live], dp)):
+                results[i] = True if err is None else {
+                    "error": f"{type(err).__name__}: {err}"}
+            return {"ok": True, "results": results}
         return {"ok": False, "error": f"unknown engine op {t!r}"}
 
     def _handle_repl_rounds(self, req: dict) -> dict:

@@ -8,23 +8,51 @@ hardwired behavior, commit at `:103-109`) immediately commits
 offset + n — at-most-once delivery. auto_commit=False flips to
 at-least-once: process, then call commit() yourself.
 
-Pipelined readahead (`prefetch` > 0, needs a pipelining transport):
-after each delivery the NEXT window's fetch is already in flight at an
-explicit offset (the broker accepts `offset` in consume requests), so a
-drain pays one round-trip of latency total instead of one per window,
-and auto-commits ride the same request-id pipeline asynchronously
-instead of blocking a quorum round per window — ONE in flight per
-partition, the newest offset parked behind it: the broker's worker pool
-does not keep a connection's order, and its offset table takes the last
-writer (`_auto_commit`). `long_poll_s` > 0 makes
-empty fetches park broker-side until rows settle (tail consumers cost
-one RPC per delivery, not one per poll). Both levers are opt-in and
-independently A/B-able against the legacy one-RPC-per-call behavior.
-Note the contract shift when prefetch is on: commits are acknowledged
-ASYNCHRONOUSLY (flushed on close()/flush_commits()), so delivery runs
-ahead of the committed offset — a crash between delivery and commit
-flush re-delivers, i.e. prefetch trades the strict at-most-once
-auto-commit for at-least-once pipelining.
+Readahead (`prefetch` > 0, needs a pipelining transport): the client
+keeps a SESSION with each leader. It remembers the (topic, partition)s
+its caller polls, each with its position (the `next_offset` of its last
+answer; unknown until the first one, when the broker's committed offset
+decides), and when a poll finds no answer in hand it sends ONE
+`consume.multi` to that partition's leader: for the partition asked for
+and for every other partition of the session on that leader whose last
+answer has been handed out and which the caller, going by its last
+round, will ask for within `_ANSWER_MAX_AGE_S` - each at its own
+position with its own `max_messages`. The answers are kept and handed
+out as the caller comes round: an answer is handed out once, one that is
+never collected is not fetched again and moves no position, and a
+partition the caller stops polling leaves the session. A caller that
+rotates over 32 partitions of one leader 8 ms apart sends a request
+every eighth poll instead of every poll; one that drains them in a tight
+loop sends one a rotation. The age limit is what keeps that free for the
+messages: an answer waits for its hand-out no longer than a produce takes
+to be acked, where a whole rotation's answers fetched at once would be
+half a rotation old on average (PERF.md section 6, PR 40, has the
+arithmetic and the 1 KB cell that would have paid for it).
+Delivered offsets are committed the same way: ONE `offset.commit.multi`
+per leader, sent asynchronously when an answer with messages is handed
+out and with every fetch, ONE in flight per (consumer, leader) and the
+newest offsets parked behind it (per partition they only grow) - the
+broker's worker pool does not keep a connection's order and its offset
+table takes the last writer; a part that failed is re-driven
+synchronously through `commit()` before anything newer of its partition
+is sent. A part the broker refuses (`not_leader`, a stale generation, a
+lost quorum) falls to the single-partition `consume`, which re-resolves
+with the retry policy, while its siblings are served. The contract
+shift when prefetch is on: commits are acknowledged ASYNCHRONOUSLY
+(flushed on close()/flush_commits()), so delivery runs ahead of the
+committed offset - a crash between delivery and commit flush
+re-delivers, i.e. prefetch trades the strict at-most-once auto-commit
+for at-least-once pipelining. A committed offset never passes what was
+handed to the caller and never moves back.
+
+`long_poll_s` > 0 makes empty fetches park broker-side until rows settle
+(tail consumers cost one RPC per delivery, not one per poll). With
+`long_poll_s` or `follower_reads` a readahead client keeps ONE
+`consume` per partition in flight at an explicit offset instead of a
+session (a `consume.multi` neither parks nor is served by a follower);
+its commits ride the same per-leader pipeline. Every lever is opt-in
+and independently A/B-able against the legacy one-RPC-per-call
+behavior.
 
 Follower reads (`follower_reads=True`, needs a cluster running with the
 broker-side knob on): EXPLICIT-OFFSET reads route to a standby broker
@@ -44,6 +72,7 @@ server-tracked offset table.
 from __future__ import annotations
 
 import itertools
+import time
 from concurrent.futures import TimeoutError as FuturesTimeoutError
 from typing import Optional
 
@@ -61,9 +90,47 @@ from ripplemq_tpu.wire.transport import RpcError, TcpClient, Transport
 
 DEFAULT_MAX_MESSAGES = 10  # ConsumerClientImpl.java:21
 
+# How old an answer of the session may be when it is handed out: a fetch
+# carries, beside the partition asked for, those the caller will ask for
+# within this long (going by when it asked last round). A message then
+# waits in the client no longer than its produce took to be acked; with
+# no limit a rotation's answers are half a rotation old on average.
+_ANSWER_MAX_AGE_S = 0.06
+# A partition not polled for this long leaves the session.
+_SESSION_IDLE_S = 5.0
+
 
 class ConsumeError(Exception):
     pass
+
+
+class _Part:
+    """One (topic, partition) of the session."""
+
+    __slots__ = ("pos", "limit", "addr", "answer", "polled")
+
+    def __init__(self, limit: int, addr: Optional[str], now: float) -> None:
+        self.pos: Optional[int] = None  # next read position; None: the
+        #                                 broker's committed offset decides
+        self.limit = limit              # the caller's max_messages
+        self.addr = addr                # its leader; None: the single-
+        #                                 partition path re-resolves it
+        self.answer: Optional[tuple[list, int, int]] = None  # not handed
+        #                       out yet: (messages, offset, next_offset)
+        self.polled = now               # when the caller last asked
+
+
+class _LeaderCommits:
+    """The commit pipeline to one leader: the offsets of the ONE
+    offset.commit.multi in flight, and the newest offsets parked behind
+    it, both by (topic, partition)."""
+
+    __slots__ = ("owed", "sent", "fut")
+
+    def __init__(self) -> None:
+        self.owed: dict[tuple[str, int], int] = {}
+        self.sent: dict[tuple[str, int], int] = {}
+        self.fut = None
 
 
 class ConsumerClient:
@@ -107,19 +174,21 @@ class ConsumerClient:
         # verdict can say how much fan-out the follower plane absorbed.
         self.follower_served = 0
         self.last_from_follower = False
-        # Per-(topic, partition) readahead state: the in-flight fetch at
-        # an explicit offset, and the ONE async auto-commit in flight
-        # with the newest offset parked behind it (see _auto_commit):
-        # (offset in flight, its future, address, parked offset | None).
+        # Readahead state. The session (module docstring): what the
+        # caller polls, by (topic, partition). `_pf`: with long_poll_s
+        # or follower_reads instead, the one `consume` in flight per
+        # partition at an explicit offset. `_commits`: the async
+        # auto-commits of both, by leader address.
+        self._sess: dict[tuple[str, int], _Part] = {}
         self._pf: dict[tuple[str, int], dict] = {}
-        self._commits: dict[
-            tuple[str, int], tuple[int, object, str, Optional[int]]
-        ] = {}
+        self._commits: dict[str, _LeaderCommits] = {}
+        self._clock = time.monotonic
         # Causal tracing (obs/spans.py), mirroring ProducerClient: every
         # trace_sample_n-th consume opens a client.consume root span
         # whose context rides `tctx` on the sync and follower fetches
-        # (prefetched fetches were armed before this call existed, so
-        # they stay unstamped). `spans` is public for the assembler.
+        # and on the session's consume.multi (`_pf` fetches were armed
+        # before this call existed, so they stay unstamped). `spans` is
+        # public for the assembler.
         self._trace_sample_n = int(trace_sample_n)
         self._trace_counter = itertools.count()
         self.spans: Optional[SpanRing] = (
@@ -153,6 +222,12 @@ class ConsumerClient:
         msgs, _, _, _ = self.consume_with_position(topic, partition, max_messages)
         return msgs
 
+    def _session_on(self, call_async) -> bool:
+        """Whether readahead runs as a session with each leader: read
+        off the client's own input (module docstring)."""
+        return (self.prefetch > 0 and call_async is not None
+                and self.long_poll_s == 0 and not self.follower_reads)
+
     def consume_with_position(
         self,
         topic: str,
@@ -175,18 +250,21 @@ class ConsumerClient:
                                        {"topic": topic})
         self._trace_root = root
         call_async = getattr(self._transport, "call_async", None)
+        session = self._session_on(call_async)
         if self.prefetch > 0 and call_async is not None:
-            # Pin the round-robin choice ONCE per call: the prefetch
+            # Pin the round-robin choice ONCE per call: the readahead
             # probe and the sync fallback below each advancing the
-            # stateful selector would desynchronize armed readahead
-            # state from delivered partitions (with an even partition
+            # stateful selector would desynchronize readahead state
+            # from delivered partitions (with an even partition
             # count the two paths alternate in lockstep and some
             # partitions are never consumed at all).
             if partition is None:
                 t = self._meta.topic(topic)
                 if t is not None:
                     partition = self._selector.select(t)
-            got = self._consume_prefetched(topic, partition, limit, call_async)
+            got = (self._consume_session if session
+                   else self._consume_prefetched)(
+                topic, partition, limit, call_async)
             if got is not None:
                 root.end(n=len(got[0]))
                 return got
@@ -214,11 +292,18 @@ class ConsumerClient:
                 run.note(f"no leader known for {topic}[{pid}]")
                 self._refresh_quietly()
                 continue
-            # A readahead fallback must not race its own unflushed
-            # commits: the server-tracked offset lags until they apply.
-            self._flush_commit_key(topic, pid)
             req = {"type": "consume", "topic": topic, "partition": pid,
                    "consumer": self.consumer_id, "max_messages": limit}
+            part = self._sess.get((topic, pid)) if session else None
+            if part is not None and part.pos is not None:
+                # A session's partition on the single path (its part was
+                # refused, or the fetch failed): at its own position.
+                req["offset"] = part.pos
+            else:
+                # A readahead fallback must not race its own unflushed
+                # commits: the server-tracked offset lags until they
+                # apply.
+                self._flush_commit_key(topic, pid)
             # Per-ATTEMPT client.rpc span (its id rides as tctx): the
             # broker's rpc.recv pairs with the wire round trip for the
             # skew estimate, not with the retry loop (producer twin).
@@ -253,7 +338,96 @@ class ConsumerClient:
                 raise ConsumeError(err)
         raise ConsumeError(f"consume from {topic} failed: {run.summary()}")
 
-    # ------------------------------------------------- prefetch pipeline
+    # ------------------------------------------------ session with a leader
+
+    def _consume_session(self, topic: str, partition: Optional[int],
+                         limit: int, call_async):
+        """Serve one consume from the session: hand out the answer in
+        hand, or fetch first - ONE consume.multi to the partition's
+        leader (`_fetch`). Returns None to fall back to the
+        single-partition path (which re-resolves leadership with the
+        retry policy). The caller pins `partition` before calling (one
+        selector advance per consume)."""
+        if partition is None:
+            return None  # topic unknown: the sync path resolves it
+        now = self._clock()
+        key = (topic, partition)
+        part = self._sess.get(key)
+        if part is None:
+            part = self._sess[key] = _Part(
+                limit, self._meta.leader_addr(topic, partition), now)
+            before = None
+        else:
+            before, part.polled = part.polled, now
+            if part.limit != limit:
+                # In hand is an answer cut for another window: dropped,
+                # and like any answer never handed out it moved nothing.
+                part.limit, part.answer = limit, None
+        if part.answer is None and part.addr is not None:
+            self._fetch(key, part, before, now, call_async)
+        if part.answer is None:
+            return None
+        msgs, offset, next_offset = part.answer
+        return self._deliver(topic, partition, part.addr, limit, call_async,
+                             msgs, offset, next_offset)
+
+    def _fetch(self, key: tuple[str, int], part: _Part,
+               before: Optional[float], now: float, call_async) -> None:
+        """ONE consume.multi to `part`'s leader: for `part`, and for
+        every other partition of the session on that leader whose last
+        answer has been handed out, whose position is known, and which
+        the caller asked for within `_ANSWER_MAX_AGE_S` after it last
+        asked for `part` (`before`) - so will again, if it goes round as
+        it did. The answers are kept in the session; a refused part's
+        partition is left to the single-partition path. The leader's
+        parked commits go out with the fetch."""
+        addr = part.addr
+        if part.pos is None:
+            # The broker's committed offset decides where this read
+            # starts: everything handed out before must have landed.
+            self._flush_commit_key(*key)
+        parts = [(key, part)]
+        idle = []
+        for k, q in self._sess.items():
+            if q is part:
+                continue
+            if now - q.polled > _SESSION_IDLE_S:
+                idle.append(k)  # no longer polled: leaves the session
+            elif (before is not None and q.answer is None
+                    and q.addr == addr and q.pos is not None
+                    and 0 <= q.polled - before <= _ANSWER_MAX_AGE_S):
+                parts.append((k, q))
+        for k in idle:
+            del self._sess[k]
+        req = {"type": "consume.multi", "consumer": self.consumer_id,
+               "parts": [
+                   {"topic": k[0], "partition": k[1],
+                    "max_messages": q.limit,
+                    **({} if q.pos is None else {"offset": q.pos})}
+                   for k, q in parts]}
+        self._drive_commits(addr, call_async)
+        rpc = NULL_SPAN if self.spans is None else \
+            self.spans.span("client.rpc", self._trace_root.ctx)
+        if rpc.ctx is not None:
+            req["tctx"] = rpc.ctx.wire()
+        try:
+            resp = self._transport.call(addr, req, timeout=self._timeout)
+        except RpcError as e:
+            rpc.end(error=type(e).__name__)
+            return
+        rpc.end()
+        answers = resp.get("parts") if resp.get("ok") else None
+        if not isinstance(answers, list) or len(answers) != len(parts):
+            return
+        for (_, q), ans in zip(parts, answers):
+            if ans.get("ok"):
+                offset = int(ans["offset"])
+                q.answer = (list(ans["messages"]), offset,
+                            int(ans.get("next_offset", offset)))
+            else:
+                q.addr = None  # refused: its next poll goes the single path
+
+    # --------------------------------- one fetch in flight per partition
 
     def _consume_prefetched(self, topic: str, partition: Optional[int],
                             limit: int, call_async):
@@ -332,16 +506,27 @@ class ConsumerClient:
 
     def _deliver(self, topic: str, pid: int, addr: str, limit: int,
                  call_async, msgs: list, offset: int, next_offset: int):
-        """Common delivery tail: arm the next readahead fetch, run the
-        auto-commit (async when prefetching), return the position tuple.
-        With follower reads on, `addr` may be the follower that just
-        served — commits always re-resolve the LEADER (offset state is
-        a quorum-replicated fact only the leader accepts)."""
+        """Common delivery tail: move the session's position (or arm
+        the next `_pf` fetch), run the auto-commit (async when
+        prefetching), return the position tuple. With follower reads
+        on, `addr` may be the follower that just served — commits
+        always re-resolve the LEADER (offset state is a
+        quorum-replicated fact only the leader accepts)."""
         commit_addr = addr
         if self.follower_reads:
             self._pos[(topic, pid)] = int(next_offset)
             commit_addr = self._meta.leader_addr(topic, pid) or addr
-        if self.prefetch > 0 and call_async is not None:
+        if self._session_on(call_async):
+            # Handed out, once: the position moves here and nowhere
+            # else (the single-partition path delivers through here
+            # too, and hands the partition back to its leader's
+            # session).
+            part = self._sess.get((topic, pid))
+            if part is None:
+                part = self._sess[(topic, pid)] = _Part(
+                    limit, addr, self._clock())
+            part.answer, part.pos, part.addr = None, int(next_offset), addr
+        elif self.prefetch > 0 and call_async is not None:
             # Re-arm at next_offset. After an EMPTY window only a
             # long-polling fetch is worth keeping in flight (a plain one
             # would answer empty again immediately; drains break on
@@ -373,76 +558,108 @@ class ConsumerClient:
                 except RpcError:
                     pass  # connection hiccup: next call goes sync
         if msgs and self.auto_commit:
-            self._auto_commit(topic, pid, next_offset, commit_addr,
-                              call_async)
+            if self.prefetch > 0 and call_async is not None:
+                c = self._commits.setdefault(commit_addr, _LeaderCommits())
+                c.owed[(topic, pid)] = max(int(next_offset),
+                                           c.owed.get((topic, pid), 0))
+                self._drive_commits(commit_addr, call_async)
+            else:
+                # strict: ack before deliver
+                self.commit(topic, pid, next_offset)
         return msgs, pid, offset, next_offset
 
-    def _auto_commit(self, topic: str, pid: int, offset: int, addr: str,
-                     call_async) -> None:
-        if self.prefetch <= 0 or call_async is None:
-            self.commit(topic, pid, offset)  # strict: ack before deliver
-            return
-        # Pipelined commit, ONE in flight per (consumer, partition). The
-        # broker runs a connection's requests on a worker pool, so two
-        # commits of one partition can reach the offset table in either
-        # order, and the table takes the last writer: an older commit
-        # overtaking a newer one moved the committed position BACK, and
-        # the next fetch without an explicit offset (after an empty
-        # window) re-delivered what lay between (seen on the chip at
-        # 130k msgs/s, PR 28: a commit took longer than the poll
-        # interval, 512 messages came twice). So while one is in flight
-        # the newest offset is PARKED behind it — offsets only grow, a
-        # parked one is superseded by the next — and goes out when the
-        # in-flight one has landed. A commit that FAILED is re-driven
-        # synchronously (with retries) before anything newer is sent —
-        # errors must not silently drop the committed position.
-        key = (topic, pid)
-        prev = self._commits.get(key)
-        if prev is not None:
-            if not prev[1].done():
-                self._commits[key] = (prev[0], prev[1], prev[2], int(offset))
-                return
-            self._commits.pop(key, None)
-            if not self._commit_ok(prev[1]):
-                self.commit(topic, pid, max(int(prev[0]), int(offset)))
-                return
-        try:
-            fut = call_async(addr, {
-                "type": "offset.commit", "topic": topic, "partition": pid,
-                "consumer": self.consumer_id, "offset": int(offset),
-            })
-        except RpcError:
-            self.commit(topic, pid, offset)  # sync fallback w/ retries
-            return
-        self._commits[key] = (int(offset), fut, addr, None)
+    # ------------------------------------------------------------- commits
 
-    @staticmethod
-    def _commit_ok(fut) -> bool:
+    def _commit_req(self, offsets: dict) -> dict:
+        return {"type": "offset.commit.multi", "consumer": self.consumer_id,
+                "parts": [{"topic": t, "partition": p, "offset": off}
+                          for (t, p), off in offsets.items()]}
+
+    def _drive_commits(self, addr: str, call_async) -> None:
+        """Pipelined commits, ONE offset.commit.multi in flight per
+        (consumer, leader). The broker runs a connection's requests on a
+        worker pool, so two commits of one partition can reach the
+        offset table in either order, and the table takes the last
+        writer: an older commit overtaking a newer one moved the
+        committed position BACK, and the next fetch without an explicit
+        offset re-delivered what lay between (seen on the chip at 130k
+        msgs/s, PR 28: a commit took longer than the poll interval, 512
+        messages came twice). So while one request is in flight the
+        newest offsets are PARKED behind it — per partition they only
+        grow, a parked one is superseded by the next — and go out, all
+        of them in one request, when it has landed. A part that FAILED
+        is re-driven synchronously (with retries) before anything newer
+        of its partition is sent — errors must not silently drop the
+        committed position."""
+        c = self._commits.get(addr)
+        if c is None:
+            return
+        if c.fut is not None:
+            if not c.fut.done():
+                return
+            self._land(c, 0)
+        if not c.owed:
+            return
+        sent, c.owed = c.owed, {}
         try:
-            return bool(fut.result(timeout=0).get("ok"))
+            c.fut, c.sent = call_async(addr, self._commit_req(sent)), sent
+        except RpcError:
+            self._redrive(c, sent)  # sync fallback w/ retries
+
+    def _land(self, c: _LeaderCommits, timeout: float) -> None:
+        """Take the answer of the request in flight (waiting at most
+        `timeout` for it) and re-drive what it did not commit."""
+        sent, fut, c.sent, c.fut = c.sent, c.fut, {}, None
+        try:
+            resp = fut.result(timeout=timeout)
         except Exception:
-            return False
+            resp = {}
+        self._redrive(c, sent, resp)
+
+    def _redrive(self, c: _LeaderCommits, sent: dict,
+                 resp: Optional[dict] = None) -> None:
+        """Commit synchronously, through `commit()`, every offset of
+        `sent` that `resp` (an offset.commit.multi's answer) does not
+        acknowledge - the newest offset of its partition, parked or
+        sent. What is not yet re-driven when `commit()` raises stays
+        owed."""
+        answers = resp.get("parts") if resp and resp.get("ok") else None
+        if not isinstance(answers, list) or len(answers) != len(sent):
+            answers = [{}] * len(sent)
+        failed = [key for key, ans in zip(sent, answers) if not ans.get("ok")]
+        for key in failed:
+            c.owed[key] = max(sent[key], c.owed.get(key, 0))
+        for key in failed:
+            self.commit(key[0], key[1], c.owed[key])
+            del c.owed[key]
+
+    def _flush_leader(self, addr: str) -> None:
+        """Land everything owed to one leader: wait the request in
+        flight out, then send what is parked, synchronously."""
+        c = self._commits[addr]
+        if c.fut is not None:
+            self._land(c, self._timeout)
+        if c.owed:
+            sent, c.owed = c.owed, {}
+            try:
+                resp = self._transport.call(addr, self._commit_req(sent),
+                                            timeout=self._timeout)
+            except RpcError:
+                resp = None
+            self._redrive(c, sent, resp)
 
     def _flush_commit_key(self, topic: str, pid: int) -> None:
-        entry = self._commits.pop((topic, pid), None)
-        if entry is None:
-            return
-        off, fut, _, parked = entry
-        try:
-            ok = bool(fut.result(timeout=self._timeout).get("ok"))
-        except Exception:
-            ok = False
-        if parked is not None or not ok:
-            self.commit(topic, pid, off if parked is None else parked)
+        key = (topic, pid)
+        for addr, c in list(self._commits.items()):
+            if key in c.owed or key in c.sent:
+                self._flush_leader(addr)
 
     def flush_commits(self) -> None:
-        """Drain every in-flight async auto-commit (prefetch mode),
-        re-driving failures through the sync commit path. Called by
-        close(); call it directly at consumer-group checkpoints."""
-        for (topic, pid) in list(self._commits):
-            self._flush_commit_key(topic, pid)
-
-    # ------------------------------------------------------------- commits
+        """Drain every in-flight and parked async auto-commit (prefetch
+        mode), re-driving failures through the sync commit path. Called
+        by close(); call it directly at consumer-group checkpoints."""
+        for addr in list(self._commits):
+            self._flush_leader(addr)
 
     def commit(self, topic: str, partition: int, offset: int) -> None:
         """Commit an absolute offset (replicated through the partition's

@@ -258,7 +258,8 @@ def test_prefetch_round_robin_covers_all_partitions(cluster):
 
 
 class _HeldCommits:
-    """Transport proxy: `offset.commit` requests sent with call_async are
+    """Transport proxy: `offset.commit` / `offset.commit.multi` requests
+    sent with call_async are
     HELD until the test lets them reach the broker, in the order the
     test chooses — a broker's worker pool may run two requests of one
     connection in either order. Everything else goes straight through."""
@@ -271,7 +272,8 @@ class _HeldCommits:
         return self._inner.call(addr, request, timeout=timeout)
 
     def call_async(self, addr, request):
-        if request.get("type") != "offset.commit":
+        if request.get("type") not in ("offset.commit",
+                                       "offset.commit.multi"):
             return self._inner.call_async(addr, request)
         from concurrent.futures import Future
 
@@ -293,8 +295,9 @@ def test_pipelined_commits_never_move_the_position_back(cluster):
     several auto-commits of ONE partition in flight; the broker ran them
     out of order, the older one landed last, the committed position
     moved back, and the next fetch without an explicit offset delivered
-    a window twice. Now one commit is in flight per partition and the
-    newest offset waits behind it."""
+    a window twice. Now one commit request is in flight per leader (PR
+    40: an offset.commit.multi for every partition polled there) and the
+    newest offsets wait behind it."""
     producer = make_producer(cluster)
     transport = _HeldCommits(cluster.client("consumer-held"))
     consumer = ConsumerClient(bootstrap(cluster), "held-commits",
