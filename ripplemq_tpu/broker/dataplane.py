@@ -262,12 +262,21 @@ class DataPlane:
         self._m_stage_us = m.histogram("round.stage_us")
         self._st_idle = m.stage("round.idle")
         self._st_coalesce = m.stage("round.coalesce")
-        self._st_drain = m.stage("round.drain")
+        # Where the registry has waits on (a traced broker) the drain,
+        # the launch and the settle thread's release also observe their
+        # thread's CPU (`round.drain_cpu_us`, `round.launch_cpu_us`,
+        # `settle.release_cpu_us`): wall minus CPU is what the thread
+        # waited - for `_lock` (lock.wait_us.*, obs/lockwitness.py), for
+        # the interpreter, for the scheduler.
+        self._st_drain = m.stage("round.drain", cpu=True)
         self._st_lock_wait = m.stage("round.lock_wait")
-        self._st_launch = m.stage("round.launch", "engine.dispatch_us")
+        self._st_launch = m.stage("round.launch", "engine.dispatch_us",
+                                  cpu=True)
         self._st_fetch = m.stage("round.fetch", None)
         self._st_standby_wait = m.stage("settle.standby_wait", None)
         self._st_persist = m.stage("settle.persist", None)
+        self._st_release = m.stage("settle.release", None, annotate=False,
+                                   cpu=True)
         # read.serve runs on RPC threads, any number at once, beside
         # the round's own threads: histogram only, so that a device idle
         # gap is always named by a stage of the round's pipeline.
@@ -2688,6 +2697,9 @@ class DataPlane:
     def _release_one(self, ctx: dict, committed, records: list,
                      ticket, exc: Optional[Exception]) -> None:
         chain = ctx["chain"]
+        # CPU of this thread's part of settle.release_us's interval
+        # (the null lap where the registry has waits off).
+        lap = self._st_release.timed()
         try:
             if self._settle_fenced:
                 # Drain-the-window fence: once deposed, NO later round
@@ -2730,7 +2742,7 @@ class DataPlane:
             with self._st_persist.timed():
                 self._persist_round(records)
             # Stage 5: local persist (store framing + any strict-mode
-            # inline fsync; store.append_us/fsync_us decompose further).
+            # inline fsync; store.fsync_us has the fsync alone).
             t_persist = self.metrics.clock()
             self._m_persist_us.observe(t_persist - t_acked)
             # ---- DURABLY SETTLED from here: the round is persisted AND
@@ -2776,6 +2788,7 @@ class DataPlane:
             # Stage 6 (the whole-round number): dispatch → ack release.
             t0 = ctx.get("t_dispatch")
             t_rel = self.metrics.clock()
+            lap.to(None)
             if t0 is not None:
                 self._m_release_us.observe(t_rel - t0)
             self.recorder.record("settle_release", round_seq=ctx["seq"],

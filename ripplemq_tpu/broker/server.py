@@ -312,8 +312,23 @@ class BrokerServer:
         from ripplemq_tpu.obs.trace import FlightRecorder
         from ripplemq_tpu.wire import codec as _codec
 
-        self.metrics = Metrics(enabled=config.obs)
+        # A traced broker (trace_sample_n > 0, which requires obs) also
+        # names its waits: the registry reads a stage's CPU beside its
+        # wall (obs/stages.py), the three locks of
+        # lockwitness.TIMED_LOCKS built from here on are timed by role
+        # onto this registry (enabled BEFORE any of them is
+        # constructed, like the witness above), and a wake-up probe
+        # says how long a runnable thread waits for the interpreter
+        # (obs/wakeprobe.py). Untraced, none of the three exists.
+        self._waits = config.trace_sample_n > 0
+        self.metrics = Metrics(enabled=config.obs, waits=self._waits)
         self.recorder = FlightRecorder()
+        self._wake_probe = None
+        if self._waits:
+            from ripplemq_tpu.obs.wakeprobe import WakeProbe
+
+            self._time_locks()
+            self._wake_probe = WakeProbe(self.metrics, self.recorder)
         # Causal tracing plane (obs/spans.py): one span ring per broker
         # process, serving admin.spans. None when trace_sample_n=0 —
         # every emit site below gates on `self.spans is not None` (or
@@ -813,6 +828,10 @@ class BrokerServer:
                     self.config.engine, self._round_store.scan(),
                     gaps_out=gaps, pid_tab_out=pid_tab,
                 )
+            if self._waits:
+                # An in-proc sibling may have pointed the process-global
+                # timing at its own registry since __init__.
+                self._time_locks()
             dp = DataPlane(
                 self.config.engine, mode=self._engine_mode,
                 store=self._round_store,
@@ -1020,8 +1039,15 @@ class BrokerServer:
     def _addr_of(self, broker_id: int) -> str:
         return self.config.broker(broker_id).address
 
+    def _time_locks(self) -> None:
+        from ripplemq_tpu.obs import lockwitness
+
+        lockwitness.enable_timing(self.metrics, self.recorder)
+
     def start(self) -> None:
         self._started = True
+        if self._wake_probe is not None:
+            self._wake_probe.start()
         if self.hostplane is not None:
             self.hostplane.start()
         if self._net is not None:
@@ -1052,6 +1078,11 @@ class BrokerServer:
             return
         self._stopped = True
         self._stop.set()
+        if self._wake_probe is not None:
+            from ripplemq_tpu.obs import lockwitness
+
+            self._wake_probe.stop()
+            lockwitness.disable_timing(self.metrics)
         # Release handlers parked on un-proposed waves before joining
         # the duty thread (their RPC workers would otherwise hold the
         # full waiter timeout).

@@ -153,8 +153,18 @@ class Metrics:
     the name tables, never blocking writers (writers don't lock)."""
 
     def __init__(self, enabled: bool = True,
-                 clock: Optional[Callable[[], float]] = None) -> None:
+                 clock: Optional[Callable[[], float]] = None,
+                 waits: bool = False,
+                 cpu_clock: Optional[Callable[[], float]] = None) -> None:
         self.enabled = enabled
+        # Waits on (a traced broker, trace_sample_n > 0): stages that
+        # ask for it also observe their thread's CPU time
+        # (`<stage>_cpu_us`, obs/stages.py). Off, or a disabled
+        # registry: no CPU clock exists to be read.
+        self.cpu_clock: Optional[Callable[[], float]] = (
+            (cpu_clock if cpu_clock is not None else time.thread_time)
+            if waits and enabled else None
+        )
         # The stage-timing clock. perf_counter, not time.time: stage
         # deltas must not jump with wall-clock adjustments. Tests inject
         # a fake to run timing assertions with zero real sleeps. A
@@ -202,24 +212,31 @@ class Metrics:
             return h
 
     def stage(self, name: str, histogram: Optional[str] = "",
-              annotate: bool = True) -> Stage:
+              annotate: bool = True, cpu: bool = False) -> Stage:
         """A named host stage (obs/stages.py): histogram `<name>_us` on
         this registry's clock plus a profiler annotation of the same
         name. `histogram` names an older histogram that already times
         the interval (engine.dispatch_us for round.launch), or None for
-        an annotation-only stage. Resolve once, like metric handles."""
+        an annotation-only stage. `cpu` asks for `<name>_cpu_us` beside
+        it where the registry has waits on. A stage left with nothing
+        to do is the null stage. Resolve once, like metric handles."""
         if not self.enabled:
             return NULL_STAGE  # type: ignore[return-value]
         if histogram == "":
             histogram = f"{name}_us"
         hist = self.histogram(histogram) if histogram else None
-        return Stage(name, hist, self.clock, annotate)
+        cpu_hist = (self.histogram(f"{name}_cpu_us")
+                    if cpu and self.cpu_clock is not None else None)
+        if hist is None and cpu_hist is None and not annotate:
+            return NULL_STAGE  # type: ignore[return-value]
+        return Stage(name, hist, self.clock, annotate, self.cpu_clock,
+                     cpu_hist)
 
     def lap(self) -> StageLap:
         """A stage lap timer for ONE thread (obs/stages.py StageLap)."""
         if not self.enabled:
             return NULL_LAP  # type: ignore[return-value]
-        return StageLap(self.clock)
+        return StageLap(self.clock, self.cpu_clock)
 
     def snapshot(self) -> dict:
         """Wire-encodable summary: counters/gauges verbatim, histograms

@@ -32,6 +32,17 @@ Two ways to time, one vocabulary:
   That closure is what makes the split trustworthy (tested on the fake
   clock in tests/test_observability.py).
 
+A stage's CPU beside its wall: a registry built with waits on
+(`Metrics(waits=True)`, a traced broker) hands its laps a second clock,
+`time.thread_time`, read at the same boundary as the first for the
+stages that ask for it (`metrics.stage(name, cpu=True)`), observed as
+`<name>_cpu_us`. Wall minus CPU is what the thread spent NOT running:
+waiting for a lock, for the interpreter, for the scheduler. The same
+laps mark the thread as having a stage annotation open (`stage_open`),
+which is what lets a timed lock (obs/lockwitness.py) nest its
+contended-wait annotation inside the stage. With waits off no CPU
+clock is read and nothing is marked.
+
 Off paths: a disabled registry (`Metrics(enabled=False)`) hands out the
 `NULL_STAGE` / `NULL_LAP` singletons — no clock read, no allocation.
 Where JAX is absent the annotation half is skipped and the histogram
@@ -47,6 +58,7 @@ in the README "Observability" section.
 
 from __future__ import annotations
 
+import threading
 from typing import Callable, Optional
 
 STAGE_NAMES = frozenset({
@@ -74,7 +86,21 @@ STAGE_NAMES = frozenset({
     # (broker/server.py _shard_duty): file read, frame encode, the
     # shard.put RPC and its answer.
     "seal.shard_put",
+    # The settle thread's part of settle.release_us's interval (an
+    # entry leaving the settle queue to its acks' release), timed for
+    # its CPU alone: settle.release_cpu_us, and only where the registry
+    # has waits on. No histogram of its own, no annotation (it spans
+    # settle.standby_wait and settle.persist).
+    "settle.release",
 })
+
+_tls = threading.local()
+
+
+def stage_open() -> bool:
+    """Whether the calling thread has a stage annotation open, as far
+    as laps of a waits-on registry have marked it."""
+    return getattr(_tls, "open", False)
 
 
 def _annotation_cls():
@@ -90,16 +116,23 @@ def _annotation_cls():
 class Stage:
     """A named host stage: `hist` is its `<name>_us` histogram (None for
     an annotation-only stage whose interval an older histogram already
-    times), `clock` the registry's clock."""
+    times), `clock` the registry's clock. `cpu_clock` is the registry's
+    CPU clock where it has waits on (None otherwise) and `cpu_hist` the
+    stage's `<name>_cpu_us`, for the stages that asked for one."""
 
-    __slots__ = ("name", "hist", "clock", "_ann_cls")
+    __slots__ = ("name", "hist", "clock", "_ann_cls", "cpu_clock",
+                 "cpu_hist")
 
     def __init__(self, name: str, hist, clock: Callable[[], float],
-                 annotate: bool = True) -> None:
+                 annotate: bool = True,
+                 cpu_clock: Optional[Callable[[], float]] = None,
+                 cpu_hist=None) -> None:
         self.name = name
         self.hist = hist
         self.clock = clock
         self._ann_cls = _annotation_cls() if annotate else None
+        self.cpu_clock = cpu_clock
+        self.cpu_hist = cpu_hist
 
     def annotate(self):
         """Open this stage's profiler annotation on the calling thread;
@@ -111,7 +144,7 @@ class Stage:
         return ann
 
     def timed(self) -> "StageLap":
-        lap = StageLap(self.clock)
+        lap = StageLap(self.clock, self.cpu_clock)
         lap.to(self)
         return lap
 
@@ -119,13 +152,16 @@ class Stage:
 class StageLap:
     """One thread's time partitioned into stages (see module doc)."""
 
-    __slots__ = ("_clock", "_stage", "_t0", "_ann")
+    __slots__ = ("_clock", "_stage", "_t0", "_ann", "_cpu", "_c0")
 
-    def __init__(self, clock: Callable[[], float]) -> None:
+    def __init__(self, clock: Callable[[], float],
+                 cpu_clock: Optional[Callable[[], float]] = None) -> None:
         self._clock = clock
         self._stage: Optional[Stage] = None
         self._t0 = 0.0
         self._ann = None
+        self._cpu = cpu_clock  # None: the registry has waits off
+        self._c0 = 0.0
 
     def to(self, stage: Optional[Stage]) -> float:
         """Close the open stage and open `stage` (None: just close) on
@@ -139,7 +175,21 @@ class StageLap:
                 cur.hist.observe(t - self._t0)
         self._stage, self._t0 = stage, t
         self._ann = stage.annotate() if stage is not None else None
+        if self._cpu is not None:
+            self._waits(cur, stage)
         return t
+
+    def _waits(self, cur: Optional[Stage], stage: Optional[Stage]) -> None:
+        """The waits-on half of a boundary: the CPU clock, read once if
+        either side of the boundary asked for it, and the thread's
+        stage-open mark."""
+        closing = cur is not None and cur.cpu_hist is not None
+        if closing or (stage is not None and stage.cpu_hist is not None):
+            c = self._cpu()
+            if closing:
+                cur.cpu_hist.observe(c - self._c0)
+            self._c0 = c
+        _tls.open = self._ann is not None
 
     def __enter__(self) -> "StageLap":
         return self
@@ -154,6 +204,7 @@ class _NullStage:
     __slots__ = ()
     name = ""
     hist = None
+    cpu_hist = None
 
     def annotate(self):
         return None

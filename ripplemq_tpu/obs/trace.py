@@ -75,6 +75,11 @@ EVENT_TYPES = frozenset({
     # its dual-write handoff window, the reconfig duty closed it at
     # the settled watermark, a merge reabsorbed a child's range.
     "split_begin", "split_cutover", "merge_done",
+    # A traced broker's named waits (trace_sample_n > 0 only): one of
+    # the three timed locks was held past lockwitness.LONG_HOLD_S
+    # (lock, role, held_ms, the holder's site); the wake-up probe ran
+    # more than wakeprobe.STALL_S late (late_ms).
+    "lock_long_hold", "interp_stall",
 })
 
 

@@ -222,13 +222,12 @@ class SegmentStore:
                  metrics=None) -> None:
         self.directory = directory
         # Telemetry (obs.Metrics registry, usually the owning broker's):
-        # append latency/bytes and fsync latency are the disk half of the
+        # append bytes/records and fsync latency are the disk half of the
         # settle-path decomposition. None or a DISABLED registry → the
         # handles stay None and the hot paths skip even the clock reads
         # (the obs=False A/B arm must actually shed the cost).
         self.metrics = metrics
         if metrics is not None and getattr(metrics, "enabled", True):
-            self._h_append = metrics.histogram("store.append_us")
             self._h_fsync = metrics.histogram("store.fsync_us")
             self._c_append_bytes = metrics.counter("store.append_bytes")
             self._c_records = metrics.counter("store.append_records")
@@ -247,7 +246,7 @@ class SegmentStore:
             self._c_sealed = metrics.counter("seal.segments")
             self._h_seal_pending = metrics.histogram("seal.pending")
         else:
-            self._h_append = self._h_fsync = None
+            self._h_fsync = None
             self._c_append_bytes = self._c_records = None
             self._clock = None
             self._st_rs_encode = self._c_rs_new_shapes = None
@@ -324,12 +323,10 @@ class SegmentStore:
                 f"record payload of {len(payload)} bytes exceeds the "
                 f"1 GiB store record cap"
             )
-        t0 = self._clock() if self._h_append is not None else 0.0
         try:
             return self._append_locked(rec_type, slot, base, payload)
         finally:
-            if self._h_append is not None:
-                self._h_append.observe(self._clock() - t0)
+            if self._c_append_bytes is not None:
                 self._c_append_bytes.inc(len(payload))
                 self._c_records.inc()
 
@@ -397,12 +394,10 @@ class SegmentStore:
             pos += _HEADER.size + len(payload)
             payload_total += len(payload)
         blob = b"".join(frames)
-        t0 = self._clock() if self._h_append is not None else 0.0
         try:
             return self._append_blob_locked(blob, rel)
         finally:
-            if self._h_append is not None:
-                self._h_append.observe(self._clock() - t0)
+            if self._c_append_bytes is not None:
                 self._c_append_bytes.inc(payload_total)
                 self._c_records.inc(len(records))
 

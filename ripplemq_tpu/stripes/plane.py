@@ -243,8 +243,6 @@ class _StripeSender(threading.Thread):
                     fail_all(FencedError("controller deposed (local "
                                          "metadata)"))
                     break
-                t0 = (self._rep._clock()
-                      if self._rep._h_frame_us is not None else 0.0)
                 req = {"type": "repl.stripes", "epoch": epoch,
                        "frames": frames}
                 if tctxs:
@@ -265,10 +263,7 @@ class _StripeSender(threading.Thread):
                 failures = 0
                 self.unreachable = False
                 if resp.get("ok"):
-                    if self._rep._h_frame_us is not None:
-                        self._rep._h_frame_us.observe(
-                            self._rep._clock() - t0
-                        )
+                    if self._rep._c_bytes is not None:
                         self._rep._c_bytes.inc(nbytes)
                         self._rep._c_frames.inc(len(frames))
                     for entry in batch:
@@ -342,19 +337,13 @@ class StripeReplicator:
         # interpret).
         self.encode_kw = dict(encode_kw or {})
         if metrics is not None and getattr(metrics, "enabled", True):
-            self._h_encode_us = metrics.histogram("stripes.encode_us")
-            self._h_group = metrics.histogram("stripes.group_rounds")
-            self._h_frame_us = metrics.histogram("stripes.frame_us")
             self._c_bytes = metrics.counter("stripes.bytes")
             self._c_frames = metrics.counter("stripes.frames")
             self._c_groups = metrics.counter("stripes.groups")
             self._c_retries = metrics.counter("stripes.send_retries")
-            self._clock = metrics.clock
         else:
-            self._h_encode_us = self._h_group = self._h_frame_us = None
             self._c_bytes = self._c_frames = None
             self._c_groups = self._c_retries = None
-            self._clock = time.perf_counter
         # Causal-tracing hook (obs/spans.py): the owning broker sets
         # this to its SpanRing when trace sampling is configured;
         # begin() then records stripe.send spans (see its docstring).
@@ -764,13 +753,10 @@ class StripeReplicator:
                 # Tracked group: outstanding until its quorum (or its
                 # terminal failure) — blocks the settle floor meanwhile.
                 heapq.heappush(self._floor_pending, gsn)
-        t0 = self._clock() if self._h_encode_us is not None else 0.0
         frames = encode_group(records, epoch, gsn, settled_floor=floor,
                               **self.encode_kw)
-        if self._h_encode_us is not None:
-            self._h_encode_us.observe(self._clock() - t0)
+        if self._c_groups is not None:
             self._c_groups.inc()
-            self._h_group.observe_int(len(futs))
         key = (epoch, gsn)
         by_member: dict[int, list[int]] = {}
         for i, b in enumerate(held):

@@ -275,7 +275,8 @@ class TcpServer:
         self._sock = socket.create_server((host, port), reuse_port=False)
         self._sock.settimeout(0.2)
         self.host, self.port = self._sock.getsockname()[:2]
-        self._pool = ThreadPoolExecutor(max_workers=workers)
+        self._pool = ThreadPoolExecutor(max_workers=workers,
+                                        thread_name_prefix="rpc-worker")
         self._stop = threading.Event()
         self._threads: list[threading.Thread] = []
         self._conns: set[socket.socket] = set()

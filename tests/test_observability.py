@@ -907,3 +907,336 @@ def test_stages_land_in_a_profiler_trace(tmp_path):
     assert names.count("round.drain") == 2
     assert names.count("round.fetch") == 1
     assert m.histogram("round.drain_us").count == 2
+
+
+# ------------------------------------------- named waits (ISSUE 41)
+# A traced broker reads a stage's CPU beside its wall, times three
+# locks by role and runs a wake-up probe; untraced, none of it exists.
+
+
+class _CountedClock:
+    """A hand-moved clock that counts its reads."""
+
+    def __init__(self):
+        self.now = 0.0
+        self.reads = 0
+
+    def __call__(self):
+        self.reads += 1
+        return self.now
+
+
+def test_stage_lap_observes_cpu_beside_wall_for_the_stages_that_ask():
+    """On fake clocks: a stage built with cpu=True under a waits-on
+    registry observes `<stage>_cpu_us` over the same boundaries as its
+    wall histogram, the CPU sum never exceeds the wall sum, and a stage
+    without the flag observes no CPU series."""
+    from ripplemq_tpu.obs.metrics import Metrics
+
+    wall, cpu = _CountedClock(), _CountedClock()
+    m = Metrics(clock=wall, waits=True, cpu_clock=cpu)
+    drain = m.stage("round.drain", cpu=True)
+    launch = m.stage("round.launch", "engine.dispatch_us", cpu=True)
+    idle = m.stage("round.idle")
+    lap = m.lap()
+    # (stage, wall seconds in it, CPU seconds of them)
+    plan = [(idle, 0.5, 0.0), (drain, 0.25, 0.125), (launch, 0.5, 0.0625),
+            (idle, 1.0, 0.0), (drain, 0.125, 0.125), (launch, 0.25, 0.25)]
+    for st, w, c in plan:
+        lap.to(st)
+        wall.now += w
+        cpu.now += c
+    lap.to(None)
+    h = m.snapshot()["histograms"]
+    assert set(h) == {"round.drain_us", "round.drain_cpu_us",
+                      "engine.dispatch_us", "round.launch_cpu_us",
+                      "round.idle_us"}
+    total = {n: m.histogram(n).total for n in h}
+    assert total["round.drain_us"] == 375_000
+    assert total["round.drain_cpu_us"] == 250_000
+    assert total["engine.dispatch_us"] == 750_000
+    assert total["round.launch_cpu_us"] == 312_500
+    for wall_name, cpu_name in (("round.drain_us", "round.drain_cpu_us"),
+                                ("engine.dispatch_us",
+                                 "round.launch_cpu_us")):
+        assert total[cpu_name] <= total[wall_name]
+        assert h[cpu_name]["count"] == h[wall_name]["count"] == 2
+    # One CPU read a boundary that has a CPU stage on either side, none
+    # between two stages that did not ask (idle -> None at the end had
+    # launch before it, so every boundary here but none is counted).
+    assert cpu.reads == len(plan)
+    # timed(): the same pair from one region.
+    with drain.timed():
+        wall.now += 0.5
+        cpu.now += 0.25
+    assert m.histogram("round.drain_cpu_us").total == 500_000
+    # The settle thread's CPU-only stage: no histogram of its own.
+    rel = m.stage("settle.release", None, annotate=False, cpu=True)
+    with rel.timed():
+        wall.now += 1.0
+        cpu.now += 0.5
+    assert m.histogram("settle.release_cpu_us").total == 500_000
+    assert "settle.release_us" not in m.snapshot()["histograms"]
+
+
+@pytest.mark.parametrize("kwargs", [
+    {"enabled": False, "waits": True},   # a disabled registry
+    {"enabled": True, "waits": False},   # an untraced broker's
+    {"enabled": True},                   # the default
+])
+def test_no_cpu_clock_is_read_where_waits_are_off(kwargs):
+    from ripplemq_tpu.obs.metrics import Metrics
+    from ripplemq_tpu.obs.stages import NULL_STAGE, stage_open
+
+    cpu = _CountedClock()
+    m = Metrics(cpu_clock=cpu, **kwargs)
+    assert m.cpu_clock is None
+    drain = m.stage("round.drain", cpu=True)
+    launch = m.stage("round.launch", "engine.dispatch_us", cpu=True)
+    # With nothing to do the CPU-only stage is the null stage.
+    assert m.stage("settle.release", None, annotate=False,
+                   cpu=True) is NULL_STAGE
+    lap = m.lap()
+    for st in (drain, launch, drain, None):
+        lap.to(st)
+        assert not stage_open()  # nothing marks the thread either
+    with drain.timed():
+        pass
+    assert cpu.reads == 0
+    assert not any(n.endswith("_cpu_us")
+                   for n in m.snapshot()["histograms"])
+
+
+def test_waits_on_laps_mark_the_thread_while_an_annotation_is_open():
+    from ripplemq_tpu.obs.metrics import Metrics
+    from ripplemq_tpu.obs.stages import stage_open
+
+    m = Metrics(waits=True, cpu_clock=lambda: 0.0)
+    drain = m.stage("round.drain", cpu=True)
+    quiet = m.stage("read.serve", annotate=False)
+    lap = m.lap()
+    assert not stage_open()
+    lap.to(drain)
+    assert stage_open()
+    lap.to(quiet)
+    assert not stage_open()
+    lap.to(drain)
+    lap.to(None)
+    assert not stage_open()
+
+
+def test_wake_probe_observes_lateness_on_a_fake_clock():
+    """lateness = (time it ran again) - (time it asked to); past the
+    threshold, one interp_stall event."""
+    from ripplemq_tpu.obs.metrics import Metrics
+    from ripplemq_tpu.obs.trace import EVENT_TYPES, FlightRecorder
+    from ripplemq_tpu.obs.wakeprobe import PERIOD_S, STALL_S, WakeProbe
+
+    clock = _CountedClock()
+    lates = [0.0, 0.002, 0.25, 0.001]
+    stop_after = [len(lates)]
+
+    def wait(seconds):
+        assert seconds == PERIOD_S
+        if not stop_after[0]:
+            return True  # stop() was called during the sleep
+        stop_after[0] -= 1
+        clock.now += seconds + lates[len(lates) - stop_after[0] - 1]
+        return False
+
+    m, rec = Metrics(clock=clock), FlightRecorder()
+    probe = WakeProbe(m, rec, wait=wait)
+    probe._run()  # on this thread: ends when wait() reports the stop
+    h = m.histogram("interp.wake_late_us")
+    assert h.count == len(lates)
+    assert h.total == pytest.approx(sum(lates) * 1e6, abs=len(lates))
+    assert h.max == pytest.approx(250_000, abs=1)
+    events = rec.snapshot()
+    assert [e["type"] for e in events] == ["interp_stall"]
+    assert events[0]["late_ms"] == pytest.approx(250.0, abs=0.01)
+    assert STALL_S < 0.25 and "interp_stall" in EVENT_TYPES
+
+
+def test_wake_probe_thread_stops_on_stop():
+    from ripplemq_tpu.obs.metrics import Metrics
+    from ripplemq_tpu.obs.trace import FlightRecorder
+    from ripplemq_tpu.obs.wakeprobe import WakeProbe
+
+    m = Metrics()
+    probe = WakeProbe(m, FlightRecorder(), period_s=0.001)
+    assert not probe.alive()
+    probe.start()
+    assert wait_until(lambda: m.histogram("interp.wake_late_us").count >= 3,
+                      timeout=10.0)
+    assert probe.alive()
+    probe.stop()
+    assert not probe.alive()
+    probe.stop()  # idempotent
+
+
+@pytest.mark.parametrize("name", [
+    "lock.wait_us.DataPlane._lock.rpc",
+    "lock.wait_us.PartitionManager.lock.rpc",
+    "lock.hold_us.DataPlane._lock.step",
+    "lock.hold_us.DataPlane._lock.settle",
+    "lock.wait_us.DataPlane._lock.step",
+    "lock.hold_us.DataPlane._device_lock.step",
+    "round.drain_cpu_us", "round.launch_cpu_us", "settle.release_cpu_us",
+    "interp.wake_late_us",
+])
+def test_new_series_are_found_by_the_benchmarks_reader_names(name):
+    """The exposition's spelling of a new histogram is the one
+    `benchmarks/readers/_common.series_name` asks for, and no two of the
+    new names collapse into one series (`._` of a lock's name and the
+    dots survive `_prom_name` unambiguously)."""
+    from benchmarks.readers._common import parse_exposition, series_name
+    from ripplemq_tpu.obs import lockwitness as lw
+    from ripplemq_tpu.obs.metrics import Metrics, render_prometheus
+
+    m = Metrics()
+    all_names = [f"lock.{kind}_us.{lock}.{role}"
+                 for kind in ("wait", "hold")
+                 for lock in sorted(lw.TIMED_LOCKS) for role in lw.ROLES]
+    all_names += ["round.drain_cpu_us", "round.launch_cpu_us",
+                  "settle.release_cpu_us", "interp.wake_late_us",
+                  "round.drain_us", "engine.dispatch_us"]
+    assert name in all_names
+    for i, n in enumerate(all_names):
+        m.histogram(n).observe_int(i + 1)
+    values = parse_exposition(render_prometheus(m))
+    assert len({series_name(n, "_sum") for n in all_names}) \
+        == len(all_names)
+    i = all_names.index(name)
+    assert values[series_name(name, "_sum")] == i + 1
+    assert values[series_name(name, "_count")] == 1
+
+
+def test_new_layer_metric_files_read_what_the_instruments_observe():
+    """Each of the thirteen metric files of ISSUE 41, read with its
+    reader from a registry the instruments' names were observed into;
+    on a program without them (the parent) the reader finds nothing and
+    does not raise; file and BENCHMARK.json entry agree."""
+    import importlib
+    import json
+    import os
+
+    from benchmarks.readers._common import parse_exposition
+    from ripplemq_tpu.obs.metrics import Metrics, render_prometheus
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    entries = {e["name"]: e for e in bench["per_layer"]}
+    e2e = {e["name"]: e for e in bench["end_to_end"]}
+    m = Metrics()
+    before = parse_exposition(render_prometheus(m))
+    feed = {"lock.wait_us.DataPlane._lock.rpc": [0, 0, 300],
+            "lock.wait_us.PartitionManager.lock.rpc": [0, 900],
+            "lock.hold_us.DataPlane._lock.step": [2000, 4000],
+            "lock.hold_us.DataPlane._lock.settle": [500],
+            "lock.wait_us.DataPlane._lock.step": [1000],
+            "round.drain_us": [4000, 4000], "round.drain_cpu_us": [2000],
+            "engine.dispatch_us": [5000], "round.launch_cpu_us": [1000],
+            "interp.wake_late_us": [100, 300]}
+    for name, values in feed.items():
+        for v in values:
+            m.histogram(name).observe_int(v)
+    after = parse_exposition(render_prometheus(m))
+    run = {"t0_ns": 100, "t1_ns": 200,
+           "snapshots": [(101, before), (199, after)]}
+    old = {"t0_ns": 100, "t1_ns": 200,
+           "snapshots": [(101, before), (199, before)]}
+    want = {"plane_lock_wait_rpc_ms": 0.1, "manager_lock_wait_rpc_ms": 0.45,
+            "plane_lock_hold_step_ms": 3.0, "plane_lock_hold_settle_ms": 0.5,
+            "drain_lock_share": 0.125, "drain_cpu_share": 0.25,
+            "launch_cpu_share": 0.2, "interp_wake_late_ms": 0.2}
+    saturate = {"plane_lock_wait_rpc_ms", "manager_lock_wait_rpc_ms",
+                "drain_lock_share", "launch_cpu_share",
+                "interp_wake_late_ms"}
+    names = [f"host.{k}" for k in want] + [f"saturate.{k}"
+                                           for k in sorted(saturate)]
+    assert len(names) == 13
+    for name in names:
+        path = os.path.join(root, "benchmarks", "layer_metrics",
+                            f"{name}.json")
+        with open(path) as f:
+            spec = json.load(f)
+        reader = importlib.import_module(
+            f"benchmarks.readers.{spec['reader']['kind']}")
+        got = reader.read(spec["reader"]["args"], run)
+        assert got == pytest.approx(want[name.split(".", 1)[1]]), name
+        assert reader.read(spec["reader"]["args"], old) is None, name
+        entry = entries[name]
+        for key in ("name", "unit", "better", "source", "layer", "moves",
+                    "workloads"):
+            assert spec[key] == entry[key], (name, key)
+        assert set(spec["workloads"]) <= set(e2e[spec["moves"]]["workloads"])
+        assert (spec["moves"] == "acked_msgs_per_s") \
+            == name.startswith("saturate.")
+
+
+@pytest.mark.parametrize("sample_n", [0, 1])
+def test_waits_hang_off_trace_sample_n_alone(sample_n):
+    """Untraced: the three locks of a booted controller are raw
+    `threading` locks, its registry has no CPU clock and no probe thread
+    exists. Traced: timing wrappers, a CPU clock, a probe - and the
+    series reach admin.metrics_text; stop() takes all of it down."""
+    import threading
+
+    from ripplemq_tpu.obs import lockwitness as lw
+
+    def probes():
+        return [t for t in threading.enumerate() if t.name == "wake-probe"]
+
+    assert not lw.timing_enabled() and not probes()
+    cfg = make_config(3, obs=True, trace_sample_n=sample_n)
+    with InProcCluster(cfg) as c:
+        c.wait_for_leaders()
+        ctrl = next(b for b in c.brokers.values() if b.is_controller)
+        dp = ctrl.dataplane
+        three = (dp._lock, dp._device_lock, ctrl.manager.lock)
+        if not sample_n:
+            assert type(dp._lock) is type(threading.Lock())
+            assert type(dp._device_lock) is type(threading.Lock())
+            assert type(ctrl.manager.lock) is type(threading.RLock())
+            assert ctrl.metrics.cpu_clock is None
+            assert not lw.timing_enabled() and not probes()
+        else:
+            assert [type(x) for x in three] == [
+                lw.TimedLock, lw.TimedLock, lw.TimedRLock]
+            # Each broker's locks observe into its OWN registry.
+            assert all(x._sink.metrics is ctrl.metrics for x in three)
+            assert ctrl.metrics.cpu_clock is time.thread_time
+            assert len(probes()) == len(c.brokers)
+            client = c.client()
+            resp = client.call(
+                ctrl.addr, {"type": "produce", "topic": "topic1",
+                            "partition": 0, "messages": [b"m1"]},
+                timeout=10.0)
+            if not resp.get("ok"):
+                resp = client.call(
+                    resp["leader_addr"],
+                    {"type": "produce", "topic": "topic1", "partition": 0,
+                     "messages": [b"m1"]}, timeout=10.0)
+            assert resp["ok"], resp
+            text = client.call(ctrl.addr, {"type": "admin.metrics_text"},
+                               timeout=5.0)["text"]
+            for series in (
+                    "ripplemq_lock_hold_us_DataPlane__lock_step_count",
+                    "ripplemq_lock_wait_us_PartitionManager_lock_other_sum",
+                    "ripplemq_lock_hold_us_DataPlane__device_lock_step_sum",
+                    "ripplemq_round_drain_cpu_us_sum",
+                    "ripplemq_round_launch_cpu_us_count",
+                    "ripplemq_settle_release_cpu_us_count",
+                    "ripplemq_interp_wake_late_us_count"):
+                assert series in text, series
+            h = ctrl.metrics.histogram
+            assert h("lock.hold_us.DataPlane._lock.step").count > 0
+            assert h("round.launch_cpu_us").count \
+                == h("engine.dispatch_us").count > 0
+            assert h("round.launch_cpu_us").total \
+                <= h("engine.dispatch_us").total
+            assert h("round.drain_cpu_us").total \
+                <= h("round.drain_us").total
+    assert not lw.timing_enabled()
+    assert wait_until(lambda: not probes(), timeout=5.0)
