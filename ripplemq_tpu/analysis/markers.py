@@ -93,6 +93,7 @@ FAST_MODULES = {
     "test_shmring",             # ~5 s: in-process ring framing units
     "test_soak",                # ~15 s: the bounded hand-written soak
     "test_spmd",
+    "test_staging",             # ~25 s: bare planes, not started; five launches
     "test_storage",
     "test_store_gc",            # ~17 s: GC/retention store churn
     "test_stripes",             # ~30 s: any-k matrix + 3 striped clusters

@@ -39,10 +39,13 @@ from ripplemq_tpu.core.encode import (
     decode_entries_with_pos,
     pack_payload_rows,
     row_extents,
-    stamp_term,
 )
 from ripplemq_tpu.core.state import ReplicaState, StepInput, row_lens
-from ripplemq_tpu.ops.append import active_bucket, active_buckets
+from ripplemq_tpu.ops.append import (
+    active_bucket,
+    active_buckets,
+    class_rows,
+)
 from ripplemq_tpu.parallel.engine import make_local_fns, make_spmd_fns
 from ripplemq_tpu.utils.program_store import ProgramStore, default_directory
 from ripplemq_tpu.parallel.mesh import make_mesh
@@ -130,6 +133,16 @@ _PID_WINDOW = 8
 _GATHER_SLICE_S = 0.004
 
 
+def _row_index(starts: list[int], counts: list[int]) -> np.ndarray:
+    """Where every row of a packed array goes, run after run: run j is
+    `counts[j]` consecutive rows from `starts[j]`, and row i of the
+    packed array is the i-th of all of them."""
+    n = np.asarray(counts, np.int64)
+    first = np.cumsum(n) - n  # a run's first row in the packed array
+    return (np.repeat(np.asarray(starts, np.int64) - first, n)
+            + np.arange(int(n.sum())))
+
+
 class _Pending:
     __slots__ = ("payloads", "rows", "future", "rounds_left", "pid", "seq",
                  "tctx", "t_submit")
@@ -139,9 +152,10 @@ class _Pending:
                  tctx=None, t_submit: float = 0.0):
         self.payloads = payloads
         # Appends carry their rows PRE-PACKED (pack_payload_rows on the
-        # submitting thread); the drain only memcpys blocks and stamps
-        # the round term — per-message packing inside the batcher lock
-        # serialized the whole plane under deep backlogs.
+        # submitting thread); the drain only joins them and stamps the
+        # round term (`_stage`, never writing to them) — per-message
+        # packing inside the batcher lock serialized the whole plane
+        # under deep backlogs.
         self.rows = rows
         self.future = future
         self.rounds_left = rounds_left
@@ -256,10 +270,13 @@ class DataPlane:
         # bucket) - produce.messages over round.staged_rows is the fill.
         self._m_active_slots = m.histogram("round.active_slots")
         self._m_staged_rows = m.counter("round.staged_rows")
-        # Building that stack (zero-fill + one block copy a listed
-        # partition), the per-BYTE part of round.drain: a histogram
-        # inside the drain stage, not a sixth stage of the step thread.
+        # Building that stack outside the lock (`_stage`), the per-BYTE
+        # part of round.drain: a histogram inside the drain stage, not a
+        # sixth stage of the step thread. round.stage_copies is the
+        # number of array allocations, copies and assignments one
+        # dispatch's staging made: it must not go with the listed slots.
         self._m_stage_us = m.histogram("round.stage_us")
+        self._m_stage_copies = m.histogram("round.stage_copies")
         self._st_idle = m.stage("round.idle")
         self._st_coalesce = m.stage("round.coalesce")
         # Where the registry has waits on (a traced broker) the drain,
@@ -536,6 +553,13 @@ class DataPlane:
         # first whole-tree run flagged it; benign-idempotent, but a
         # pre-spawn constant costs P bytes and zero reasoning).
         self._dummy = np.zeros((cfg.partitions, 1, 1), np.uint8)
+        # What `_stage` pads with and up to: a shared block of zero rows
+        # (read-only) and, by a slot's row count, the rows its write
+        # moves (the extent class of ops.append, looked up without numpy).
+        self._zero_rows = np.zeros((cfg.max_batch, cfg.slot_bytes), np.uint8)
+        self._zero_rows.setflags(write=False)
+        self._class_of = class_rows(
+            np.arange(cfg.max_batch + 1), cfg.max_batch).tolist()
         # Read coalescer: device reads queue here and drain as ONE
         # read_many dispatch of up to read_q queries — the consume-side
         # mirror of append batching. No artificial wait: while one batch
@@ -2061,7 +2085,6 @@ class DataPlane:
         are chain-constant, so once a slot's round fails every later one
         does too) makes the predicted bases exact for every committed
         round."""
-        cfg = self.cfg
         with self._lock:
             if not self._appends and not self._offsets:
                 return None
@@ -2105,25 +2128,11 @@ class DataPlane:
                     rounds.append((
                         pad_inp,
                         {"appends": {}, "offsets": {}, "bases": {},
-                         "entries": {}, "counts": {}},
+                         "counts": {}, "terms": {}},
                     ))
         chain = [r[1] for r in rounds]
-        # Compact active-set arrays: one [A, B, SB] block stack + global
-        # slot ids per round (A = shared bucket over the chain so the
-        # stacked shape is uniform; -1 pads). This is the ONLY bulk
-        # device input — a sparse round ships A/P of the dense bytes.
-        B, SB = cfg.max_batch, cfg.slot_bytes
-        A = self._active_bucket(max(len(rc["entries"]) for rc in chain))
         t_stage = self._clock()
-        ec = np.zeros((len(chain), A, B, SB), np.uint8)
-        ids = np.full((len(chain), A), -1, np.int32)
-        for k, rc in enumerate(chain):
-            for a, (slot, block) in enumerate(sorted(rc["entries"].items())):
-                ec[k, a] = block
-                ids[k, a] = slot
-            if rc["appends"] or rc["offsets"]:  # a live round, not padding
-                self._m_active_slots.observe_int(len(rc["entries"]))
-                self._m_staged_rows.inc(A * B)
+        ec, ids = self._stage(chain)
         self._m_stage_us.observe(self._clock() - t_stage)
         if len(rounds) == 1:
             inp = rounds[0][0]
@@ -2149,6 +2158,84 @@ class DataPlane:
                      "entries_c": entries_c, "slot_ids": slot_ids,
                      "alive": alive, "quorum": quorum, "trim": trim,
                      "h2d_bytes": h2d}
+
+    def _stage(self, chain: list[dict]) -> tuple[np.ndarray, np.ndarray]:
+        """The device input of one dispatch from the lists its rounds
+        hold (`_build_round_locked`): the block stack `[K, A, B, SB]`
+        and the slot ids `[K, A]` (A the shared active-set bucket, -1
+        pads), built OUTSIDE `_lock` in a number of copies that does not
+        go with the listed partitions (round.stage_copies counts them).
+
+        What it costs is not bytes but hand-overs of the interpreter: a
+        numpy call over more than a few hundred elements releases it,
+        and beside hundreds of RPC workers the step thread then waits
+        milliseconds to get it back (`np.concatenate` releases it once a
+        PIECE: 94 ms a dispatch of 400 slots on the chip's host, for
+        7 ms of copying). So the rows travel through one packed buffer
+        made WITHOUT letting go: per listed slot (rounds in order, slots
+        sorted) its pendings' rows, then zero rows up to the write's
+        extent class (ops.append.class_rows: the DMA moves that many,
+        so the stamp covers them) - one `bytearray.join` of the
+        `pend.rows` (never written to: a retry stages them again under
+        another term); the slot's term into bytes 4:8 of every row - four
+        strided writes through a memoryview; then one indexed assignment
+        into the stack, the one call that does let go. Rows past the
+        class stay zero and are never moved. The packed buffer is also
+        the round's host copy (`rc["rows"]`, `rc["rows_at"]`: what
+        `_round_records` persists and streams), so the stack can go once
+        it is launched."""
+        cfg = self.cfg
+        B, SB = cfg.max_batch, cfg.slot_bytes
+        K = len(chain)
+        A = self._active_bucket(max(len(rc["counts"]) for rc in chain))
+        ec = np.zeros((K, A, B, SB), np.uint8)
+        ids = np.full((K, A), -1, np.int32)
+        copies = 2
+        # Plain Python over what the rounds list: ints and references.
+        class_of, zero = self._class_of, self._zero_rows
+        at: list[int] = []     # a listed slot's block, of the K * A
+        slots: list[int] = []
+        spans: list[int] = []  # its rows in the packed buffer
+        terms: list[int] = []  # its term, once a row
+        parts: list[np.ndarray] = []
+        total = 0
+        for k, rc in enumerate(chain):
+            listed = sorted(rc["counts"])
+            rc["rows_at"] = rows_at = {}
+            for a, slot in enumerate(listed):
+                n = rc["counts"][slot]
+                span = class_of[n]
+                at.append(k * A + a)
+                slots.append(slot)
+                spans.append(span)
+                terms += [rc["terms"][slot]] * span
+                rows_at[slot] = total
+                total += span
+                # A boundary-padding round takes nothing: all zero rows.
+                taken = rc["appends"][slot]
+                parts.extend([pend.rows for pend, _, _ in taken])
+                fill = n if taken else 0
+                if span > fill:
+                    parts.append(zero[: span - fill])
+            if rc["appends"] or rc["offsets"]:  # a live round, not padding
+                self._m_active_slots.observe_int(len(listed))
+                self._m_staged_rows.inc(A * B)
+        packed = None
+        if at:
+            buf = bytearray().join(parts)
+            stamp = struct.pack("<%di" % total, *terms)
+            rows = memoryview(buf)
+            for byte in range(4):
+                rows[4 + byte :: SB] = stamp[byte::4]
+            packed = np.frombuffer(buf, np.uint8).reshape(total, SB)
+            ec.reshape(K * A * B, SB)[
+                _row_index([j * B for j in at], spans)] = packed
+            ids.reshape(K * A)[at] = slots
+            copies += 7  # the join, four stamps, two assignments
+        for rc in chain:
+            rc["rows"] = packed
+        self._m_stage_copies.observe_int(copies)
+        return ec, ids
 
     def _zero_round_template(self):
         """Shared all-zero (counts, off_slots, off_vals, off_counts)
@@ -2193,12 +2280,14 @@ class DataPlane:
         exact for committed rounds by the chain prefix property. Returns
         (StepInput, round_ctx) or None if nothing drainable remains."""
         cfg = self.cfg
-        P, B, SB, U = cfg.partitions, cfg.max_batch, cfg.slot_bytes, cfg.max_offset_updates
+        P, B, U = cfg.partitions, cfg.max_batch, cfg.max_offset_updates
         now = self._clock()  # produce.queue_wait_us, per pending
-        # Active-set rounds: packed [B, SB] blocks per appending slot
-        # (compact device input + the bytes the resolver persists); the
-        # StepInput ships only a tiny dummy in the entries field.
-        blocks: dict[int, np.ndarray] = {}
+        # This only DECIDES: which pendings a round takes, at which rows,
+        # from which base, under which term. No row is copied under the
+        # lock; `_stage` builds the device input from these lists (the
+        # StepInput ships only a tiny dummy in the entries field).
+        listed: dict[int, int] = {}  # slot -> rows counted (pads too)
+        terms: dict[int, int] = {}   # slot -> its term at this moment
         counts = np.zeros((P,), np.int32)
         off_slots = np.zeros((P, U), np.int32)
         off_vals = np.zeros((P, U), np.int32)
@@ -2267,14 +2356,8 @@ class DataPlane:
                 taken.append((pend, fill, n))
                 fill += n
             if taken:
-                # Assemble pre-packed row blocks (C memcpys), then stamp
-                # the round term over every row — padding included — in
-                # one vectorized write. No per-message work here.
-                block = np.zeros((B, SB), np.uint8)
-                for pend, start, n in taken:
-                    block[start : start + n] = pend.rows
-                stamp_term(block, int(self.term[slot]))
-                blocks[slot] = block
+                listed[slot] = fill
+                terms[slot] = int(self.term[slot])
                 counts[slot] = fill
                 round_appends[slot] = taken
                 round_bases[slot] = end
@@ -2286,9 +2369,8 @@ class DataPlane:
                 # the term; decode skips them) so the next round
                 # starts the lap at ring position 0.
                 pad = S - end % S  # < B here (head <= B did not fit)
-                block = np.zeros((B, SB), np.uint8)
-                stamp_term(block, int(self.term[slot]))
-                blocks[slot] = block
+                listed[slot] = pad
+                terms[slot] = int(self.term[slot])
                 counts[slot] = pad
                 round_appends[slot] = []
                 round_bases[slot] = end
@@ -2330,8 +2412,7 @@ class DataPlane:
             extents=row_extents(counts),
         )
         return inp, {"appends": round_appends, "offsets": round_offsets,
-                     "bases": round_bases, "entries": blocks,
-                     "counts": {s: int(counts[s]) for s in blocks}}
+                     "bases": round_bases, "counts": listed, "terms": terms}
 
     def _gather_left(self, t_launch: float,
                      t_return: float) -> Optional[float]:
@@ -2462,6 +2543,10 @@ class DataPlane:
                 # here: the (async) device launch call returned.
                 t_dispatched = lap.to(self._st_idle)
                 t_return = self._clock()
+                # The launch holds what it needs of the stack: what the
+                # round keeps on the host until it settles is the packed
+                # rows alone.
+                del ctx["entries_c"]
                 self._m_h2d_bytes.inc(ctx["h2d_bytes"])
                 self.dispatches += 1
                 live_rounds = sum(
@@ -2876,15 +2961,29 @@ class DataPlane:
             return
         S, SB = self.cfg.slots, self.cfg.slot_bytes
         written: list[tuple[int, int, int]] = []
+        payloads: list[bytes] = []
+        slots: list[int] = []
+        starts: list[int] = []  # a record's first row in its slot's ring
+        counts: list[int] = []
         for rec_type, slot, base, payload in records:
             if rec_type != REC_APPEND:
                 continue
-            rows = np.frombuffer(payload, np.uint8).reshape(-1, SB)
-            pos = base % S
-            self._host_ring[slot, pos : pos + rows.shape[0]] = rows
-            written.append((slot, base, base + rows.shape[0]))
+            n = len(payload) // SB
+            payloads.append(payload)
+            slots.append(slot)
+            starts.append(base % S)
+            counts.append(n)
+            written.append((slot, base, base + n))
         if not written:
             return
+        # The round's rows in ONE indexed assignment, as `_stage` puts
+        # them into the device input and for its reason: a slice
+        # assignment a record releases the interpreter a record, and the
+        # settle thread then waits for it hundreds of times a round. A
+        # round never laps the ring boundary, so a record's rows are
+        # consecutive in the ring.
+        self._host_ring[np.repeat(slots, counts), _row_index(starts, counts)] = (
+            np.frombuffer(b"".join(payloads), np.uint8).reshape(-1, SB))
         # ONE hold of the lock for the round's watermarks, after all its
         # rows are in place (a round of a keyed producer has hundreds of
         # records; a hold apiece queued the settle thread behind the
@@ -2921,15 +3020,16 @@ class DataPlane:
     def _round_records(self, rc: dict, committed
                        ) -> list[tuple[int, int, int, bytes]]:
         """One round's committed writes as store/replication records —
-        built from the round ctx's host-side copies (the packed blocks
-        the drain shipped to the device, plus counts and bases)."""
+        built from the round ctx's host-side copy (the packed rows
+        `_stage` put into the device input, plus counts and bases)."""
         records: list[tuple[int, int, int, bytes]] = []
         for slot in rc["appends"]:
             n = rc["counts"].get(slot, 0)
             if not committed[slot] or n == 0:
                 continue
             adv = int(-(-n // ALIGN) * ALIGN)
-            payload = rc["entries"][slot][:adv].tobytes()
+            at = rc["rows_at"][slot]
+            payload = rc["rows"][at : at + adv].tobytes()
             records.append(
                 (REC_APPEND, int(slot), int(rc["bases"][slot]), payload)
             )

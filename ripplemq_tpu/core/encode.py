@@ -61,8 +61,8 @@ def pack_rows(
 def pack_payload_rows(cfg: EngineConfig, payloads: list[bytes]) -> np.ndarray:
     """Pack payloads into a [len(payloads), SB] block of header-prefixed
     rows with a ZERO term field — the batcher stamps the round term over
-    the whole assembled block at drain time (the term is a round
-    property, unknown at submit). Splitting the packing from the term
+    the rows a round carries at drain time, in its own copy (the term is
+    a round property, unknown at submit). Splitting the packing from the term
     stamp lets the per-message work run on the submitting thread (RPC
     workers, in parallel) instead of inside the batcher's lock, where it
     serialized the whole data plane under deep backlogs. Callers
@@ -90,13 +90,6 @@ def pack_payload_rows(cfg: EngineConfig, payloads: list[bytes]) -> np.ndarray:
         rows[i, 0:4] = np.frombuffer(np.int32(n).tobytes(), np.uint8)
         rows[i, ROW_HEADER : ROW_HEADER + n] = np.frombuffer(m, np.uint8)
     return rows
-
-
-def stamp_term(block: np.ndarray, term: int) -> None:
-    """Write `term` into every row's term field of an assembled [B, SB]
-    block (padding rows included — log-matching reads the tail row's
-    term whether or not it holds a payload)."""
-    block[:, 4:8] = np.frombuffer(np.int32(term).tobytes(), np.uint8)
 
 
 def build_step_input(

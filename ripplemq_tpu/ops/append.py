@@ -42,6 +42,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -185,6 +186,17 @@ def _class_roundup(eb, BA: int):
     for s in reversed(classes):
         pb = jnp.where(eb <= jnp.int32(s), jnp.int32(s), pb)
     return pb
+
+
+def class_rows(extents, B: int):
+    """Host (numpy) form of the class rule: the rows a write of
+    `extents` rows moves - what the batcher's staging has to fill and
+    stamp (DataPlane._stage). Held to `_class_roundup` by
+    tests/test_staging.py."""
+    BA = B // ALIGN
+    classes = np.asarray(_extent_classes(BA))
+    eb = np.clip((np.asarray(extents) + ALIGN - 1) // ALIGN, 1, BA)
+    return classes[np.searchsorted(classes, eb)] * ALIGN
 
 
 def _extent_blocks(extents, P: int, B: int):

@@ -1234,9 +1234,14 @@ def test_waits_hang_off_trace_sample_n_alone(sample_n):
             assert h("lock.hold_us.DataPlane._lock.step").count > 0
             assert h("round.launch_cpu_us").count \
                 == h("engine.dispatch_us").count > 0
-            assert h("round.launch_cpu_us").total \
-                <= h("engine.dispatch_us").total
-            assert h("round.drain_cpu_us").total \
-                <= h("round.drain_us").total
+            # CPU and wall come from two clocks (thread_time, the
+            # registry's), read a few instructions apart and each cut
+            # to a whole microsecond: a stage that was all CPU (a first
+            # launch on a quiet machine) reads a few us OVER its wall
+            # (2849 against 2846 in the driver's run of PR 41's tree).
+            # What the pair must not do is drift: 20 us a stage.
+            for cpu, wall in (("round.launch_cpu_us", "engine.dispatch_us"),
+                              ("round.drain_cpu_us", "round.drain_us")):
+                assert h(cpu).total <= h(wall).total + 20 * h(wall).count
     assert not lw.timing_enabled()
     assert wait_until(lambda: not probes(), timeout=5.0)
