@@ -61,6 +61,8 @@ FAST_MODULES = {
     "test_lockwitness",         # witness units: private locks, no cluster
     "test_concurrency_triage",  # directed repros for the PR 11 race fixes
     "test_consume_session",     # ~10 s: one 4-broker cluster, 128 partitions
+    "test_parked_fetch",        # ~30 s: in-proc clusters of 16 partitions,
+                                # the parked fetch against a plain model
     "test_log_matching",
     "test_marker_audit",
     "test_metadata",

@@ -178,6 +178,22 @@ class _PendingOffsets(_Pending):
     pass
 
 
+class _Park:
+    """One parked fetch (`DataPlane.park`): per slot the offset rows must
+    settle past to end it, the event its RPC worker stands on, and how
+    it ended - `t_wake` is the registry's clock at the settle release
+    that passed one of its offsets, `stopped` says the plane went away
+    under it."""
+
+    __slots__ = ("offs", "event", "t_wake", "stopped")
+
+    def __init__(self, offs: dict[int, int]) -> None:
+        self.offs = offs
+        self.event = threading.Event()
+        self.t_wake: Optional[float] = None
+        self.stopped = False
+
+
 class DataPlane:
     """See module docstring.
 
@@ -298,6 +314,16 @@ class DataPlane:
         # the round's own threads: histogram only, so that a device idle
         # gap is always named by a stage of the round's pipeline.
         self._st_read = m.stage("read.serve", annotate=False)
+        # Parked fetches (`park`): a long-polling consume or consume.multi
+        # whose every part was empty stands on an event the settle
+        # thread's release sets. fetch.park is the stand itself,
+        # registration to wake (RPC threads: histogram only, as
+        # read.serve); fetch.parked_now and fetch.woken_per_release are
+        # observed at each release - how many parks stood, how many of
+        # them that release ended.
+        self._st_park = m.stage("fetch.park", annotate=False)
+        self._m_parked_now = m.histogram("fetch.parked_now")
+        self._m_woken_per_release = m.histogram("fetch.woken_per_release")
         # Durability mode for the settle-path persist: "async" defers
         # fsync to the store's flusher thread at flush_interval_s cadence
         # (disk lags acks by at most one interval — the PR 3 contract);
@@ -520,6 +546,13 @@ class DataPlane:
             "DataPlane._device_lock")          # every touch of self._state
         self._work = threading.Event()
         self._stop = threading.Event()
+        # The parked fetches, by slot (a park of many slots is in each of
+        # their lists), and their number. A lock of their own: a park
+        # registers, stands and leaves without `_lock`, and the settle
+        # thread takes this one only at a release that finds parks.
+        self._park_lock = make_lock("DataPlane._park_lock")
+        self._parks: dict[int, list[_Park]] = {}
+        self._n_parks = 0
         self._thread = threading.Thread(
             target=self._run, daemon=True, name="dataplane-step"
         )
@@ -687,6 +720,7 @@ class DataPlane:
         self._stop.set()
         self._work.set()
         self._read_work.set()
+        self.release_parks()  # before the joins: nothing will settle now
         # A never-started plane (boot failed between construction and
         # start — server._boot_dataplane's cleanup path) must still run
         # the rest of stop (fail queued futures, flush): joining an
@@ -796,6 +830,95 @@ class DataPlane:
         the array bare)."""
         with self._lock:
             return int(self._settled_end[slot])
+
+    def horizons(self, slots) -> list[int]:
+        """The settled-read horizons of `slots` as `read_many` looks at
+        them: without the plane's lock (they only grow; a look a moment
+        early is a poll a moment earlier). For a fetch about to park:
+        read BEFORE its read, it is the least its park must wait past."""
+        P = self.cfg.partitions
+        return [int(self._settled_end[s]) if 0 <= s < P else 0
+                for s in slots]
+
+    def park(self, pairs, timeout: float) -> Optional[float]:
+        """Stand until rows settle past the offset of ANY (slot, offset)
+        of `pairs`, at most `timeout` seconds: a long-polling fetch
+        whose every part read empty. Returns the registry's clock at the
+        settle release that ended the stand (the caller reads again and
+        observes how late its rows came to hand), or None at the
+        deadline. Raises NotCommittedError when the plane stops under
+        it (`release_parks`).
+
+        No tick and no take of `_lock`: the park registers under
+        `_park_lock` and THEN looks at the horizons, the settle thread
+        advances a horizon and THEN looks at the registry
+        (`_wake_parks`), so one of the two sees the other - a park that
+        finds a horizon already past ends at once, one the release finds
+        is woken by it, and between two settles nothing runs."""
+        offs: dict[int, int] = {}
+        for slot, off in pairs:
+            offs[slot] = min(off, offs.get(slot, off))
+        p = _Park(offs)
+        with self._st_park.timed():
+            with self._park_lock:
+                if self._stop.is_set():
+                    raise NotCommittedError("data plane stopped")
+                for slot in offs:
+                    self._parks.setdefault(slot, []).append(p)
+                self._n_parks += 1
+            try:
+                if any(int(self._settled_end[s]) > off
+                       for s, off in offs.items()):
+                    p.t_wake = self.metrics.clock()  # settled meanwhile
+                else:
+                    p.event.wait(timeout)
+            finally:
+                with self._park_lock:
+                    for slot in offs:
+                        q = self._parks.get(slot)
+                        if q is not None and p in q:
+                            q.remove(p)
+                            if not q:
+                                del self._parks[slot]
+                    self._n_parks -= 1
+        if p.stopped:
+            raise NotCommittedError("data plane stopped")
+        return p.t_wake
+
+    def _wake_parks(self, advanced: list[tuple[int, int]]) -> None:
+        """The settle thread, after a release moved the horizons of
+        `advanced` (slot, new end): end the parks one of whose offsets
+        it passed - those and no others, a wake is an interpreter
+        hand-over - and observe how many stood and how many it ended.
+        With nothing parked: two observations, no lock."""
+        woken = 0
+        if self._n_parks:
+            t = self.metrics.clock()
+            hit = []
+            with self._park_lock:
+                standing = self._n_parks
+                for slot, end in advanced:
+                    for p in self._parks.get(slot, ()):
+                        if p.t_wake is None and p.offs[slot] < end:
+                            p.t_wake = t  # under two slots: woken once
+                            hit.append(p)
+            for p in hit:
+                p.event.set()
+            woken = len(hit)
+            self._m_parked_now.observe_int(standing)
+        else:
+            self._m_parked_now.observe_int(0)
+        self._m_woken_per_release.observe_int(woken)
+
+    def release_parks(self) -> None:
+        """End every park with a refusal (NotCommittedError in its
+        `park`): the plane stops, or the broker that serves it does."""
+        with self._park_lock:
+            parks = {p for q in self._parks.values() for p in q}
+            for p in parks:
+                p.stopped = True
+        for p in parks:
+            p.event.set()
 
     def settle_floors(self, slots) -> list[list]:
         """Per-slot settled-floor stamp for the replication sender
@@ -2853,6 +2976,7 @@ class DataPlane:
                 for rec_type, slot, base, payload in records:
                     if rec_type == REC_APPEND:
                         mirror_fn(slot, base, payload)
+            advanced: list[tuple[int, int]] = []
             with self._lock:
                 for k, rc in enumerate(chain):
                     for slot in rc["appends"]:
@@ -2862,6 +2986,7 @@ class DataPlane:
                             end = rc["bases"][slot] + adv
                             if end > self._settled_end[slot]:
                                 self._settled_end[slot] = end
+                                advanced.append((slot, end))
                     for slot, taken_off in rc["offsets"].items():
                         if committed[k, slot]:
                             for pend in taken_off:
@@ -2870,6 +2995,9 @@ class DataPlane:
             for k in range(len(chain) - 1, -1, -1):
                 self._settle_round(chain[k], chain[k]["bases"],
                                    committed[k], ack=True)
+            # The producers' acks first, then the parked fetches the
+            # round's rows end (`park`).
+            self._wake_parks(advanced)
             # Stage 6 (the whole-round number): dispatch → ack release.
             t0 = ctx.get("t_dispatch")
             t_rel = self.metrics.clock()

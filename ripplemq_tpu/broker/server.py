@@ -368,6 +368,19 @@ class BrokerServer:
         self._m_cmulti_requests = self.metrics.counter(
             "consume.multi_requests")
         self._m_cmulti_parts = self.metrics.counter("consume.multi_parts")
+        # Long-polling fetches (`_fetch`), counted by the broker that
+        # took the client's request: requests that parked, how the park
+        # ended, and the consume / consume.multi requests whose reply
+        # held a row (parked or not). fetch.wake_late_us is the plane's
+        # side: the settle release that ended a park to its rows in hand.
+        self._m_fetch_parked = self.metrics.counter("fetch.parked")
+        self._m_fetch_expired = self.metrics.counter("fetch.expired")
+        self._m_fetch_woken = self.metrics.counter("fetch.woken")
+        self._m_fetch_answered = self.metrics.counter("fetch.answered")
+        self._m_wake_late_us = self.metrics.histogram("fetch.wake_late_us")
+        # Seconds the request a thread is serving stood parked, so that
+        # consume.ack_us times the handler's work and not the stand.
+        self._parked_tls = threading.local()
         self._m_omulti_requests = self.metrics.counter(
             "commit.multi_requests")
         self._m_omulti_parts = self.metrics.counter("commit.multi_parts")
@@ -1078,6 +1091,10 @@ class BrokerServer:
             return
         self._stopped = True
         self._stop.set()
+        if self.dataplane is not None:
+            # Fetches parked on the plane hold RPC workers of this
+            # broker: refused now, not at their deadlines.
+            self.dataplane.release_parks()
         if self._wake_probe is not None:
             from ripplemq_tpu.obs import lockwitness
 
@@ -2706,18 +2723,25 @@ class BrokerServer:
     def _handle_consume(self, req: dict) -> dict:
         """Ack-latency instrumentation around the consume path (the
         produce.ack_us twin): every answer — leader serve, follower
-        serve, refusal — observes its full wall time into
-        `consume.ack_us`, the p99 the SLO controller's consume twin
-        steers toward slo_p99_consume_ms (via read_coalesce_s)."""
+        serve, refusal — observes its full wall time, less what a long
+        poll stood parked, into `consume.ack_us`, the p99 the SLO
+        controller's consume twin steers toward slo_p99_consume_ms (via
+        read_coalesce_s)."""
         t0 = self.metrics.clock()
+        self._parked_tls.s = 0.0
         sp = (self.spans.span("rpc.recv", ctx_from_wire(req.get("tctx")),
                               {"op": "consume"})
               if self.spans is not None else NULL_SPAN)
         try:
-            return self._consume_checked(req, tctx=sp.ctx)
+            resp = self._consume_checked(req, tctx=sp.ctx)
+            if resp.get("messages"):
+                self._m_fetch_answered.inc()
+            return resp
         finally:
             sp.end()
-            self._m_consume_ack_us.observe(self.metrics.clock() - t0)
+            # the wall time less what a long poll stood parked
+            self._m_consume_ack_us.observe(
+                self.metrics.clock() - t0 - self._parked_tls.s)
 
     def _consume_checked(self, req: dict, tctx=None) -> dict:
         key = group_key(req["topic"], req["partition"])
@@ -2760,7 +2784,7 @@ class BrokerServer:
         limit = req.get("max_messages")
         msgs, next_offset = self._engine_read(
             slot, offset, replica, None if limit is None else int(limit),
-            wait_s=float(req.get("wait_s", 0) or 0),
+            wait_s=float(req.get("wait_s", 0) or 0), tctx=tctx,
         )
         # Offsets are storage offsets (rounds are alignment-padded), so the
         # committable position is next_offset — NOT offset + len(messages).
@@ -2780,12 +2804,19 @@ class BrokerServer:
         and the reply answers part by part: {"ok": true, "parts":
         [{"ok": true, "messages", "offset", "next_offset"} | {"ok":
         false, "error", ...}]}. A part is answered as a `consume` of it
-        would be, except that none long-polls and none is served by a
-        follower. `consume.ack_us` observes the whole request, once."""
+        would be, except that none is served by a follower. With `wait_s`
+        the REQUEST long-polls: if every part was admitted and every
+        read came back empty it parks once, for all its parts, and is
+        answered when rows settle past the position of ANY of them, or
+        at the deadline, empty (`_fetch`); a request with a refused part
+        is answered at once, so that the client can take that part
+        elsewhere. `consume.ack_us` observes the whole request, once,
+        less what it stood parked (fetch.park_us has that)."""
         parts = req.get("parts")
         if not isinstance(parts, list) or not parts:
             return {"ok": False, "error": "bad_request: empty parts"}
         t0 = self.metrics.clock()
+        self._parked_tls.s = 0.0
         sp = (self.spans.span("rpc.recv", ctx_from_wire(req.get("tctx")),
                               {"op": "consume.multi", "parts": len(parts)})
               if self.spans is not None else NULL_SPAN)
@@ -2823,15 +2854,24 @@ class BrokerServer:
                     where.append(i)
                 except (KeyError, ValueError, TypeError) as e:
                     answers[i] = self._part_refusal(e)
-            for i, got in zip(where, self._engine_read_many(items, dp)):
-                answers[i] = (
-                    {"ok": True, "messages": got[0], "offset": got[1],
-                     "next_offset": got[2]}
-                    if isinstance(got, tuple) else self._part_refusal(got))
+            wait_s = (float(req.get("wait_s", 0) or 0)
+                      if len(items) == len(parts) else 0.0)
+            rows = False
+            for i, got in zip(where, self._engine_read_many(
+                    items, dp, wait_s, tctx=sp.ctx)):
+                if isinstance(got, tuple):
+                    answers[i] = {"ok": True, "messages": got[0],
+                                  "offset": got[1], "next_offset": got[2]}
+                    rows = rows or bool(got[0])
+                else:
+                    answers[i] = self._part_refusal(got)
+            if rows:
+                self._m_fetch_answered.inc()
             return {"ok": True, "parts": answers}
         finally:
             sp.end()
-            self._m_consume_ack_us.observe(self.metrics.clock() - t0)
+            self._m_consume_ack_us.observe(
+                self.metrics.clock() - t0 - self._parked_tls.s)
 
     def _follower_consume(self, key, req: dict, not_leader: dict,
                           tctx=None) -> Optional[dict]:
@@ -3670,78 +3710,151 @@ class BrokerServer:
             return
         rep.replicate([], timeout_s=min(2.0, self.config.rpc_timeout_s))
 
-    # Long-poll ceiling: a waiting consume parks one RPC worker, so the
+    # Long-poll ceiling: a parked fetch keeps its RPC worker, so the
     # server-side wait is clipped well below any client RPC timeout (and
-    # the worker pool size bounds how many can park at once).
+    # the worker pool size bounds how many can park at once: one per
+    # consumer and leader with a session, one per partition without).
     _LONG_POLL_CAP_S = 10.0
 
-    def _engine_read(self, slot: int, offset: int, replica: int,
-                     max_msgs: Optional[int] = None,
-                     wait_s: float = 0.0):
-        dp = self._local_engine()
-        if dp is not None:
-            self._read_barrier()
+    def _fetch(self, dp, items: list, wait_s: float, read, tctx=None,
+               count: bool = True) -> tuple[list, Optional[list]]:
+        """The reads of one consume or consume.multi against the local
+        plane `dp`, long-polling for `wait_s`: `read(items)` answers as
+        `DataPlane.read_many` does ((messages, offset, next_offset) or
+        an exception per (slot, offset, consumer slot, replica, limit)).
+        While EVERY answer is empty the request parks on the plane
+        (`DataPlane.park`: one stand for all its parts, on an event the
+        settle thread's release sets - no tick, no lock take while
+        nothing settles) and reads again, from where each part's last
+        read ended, when rows settle past ANY of those positions, so it
+        answers only what a consume at that moment would: settled,
+        commit-bounded rows. An empty-but-advanced answer (below a
+        settled gap or an all-padding tail) parks BEHIND its advance and
+        still hands the advance back; each stand waits past the horizon
+        seen before the read it follows, so a read that came back empty
+        under a horizon already past its position cannot spin. The read
+        barrier was the caller's: rows arriving during the wait are
+        NEWER than its proof, never staler. A plane that stops or a
+        controller deposed under a park refuses it (NotCommittedError).
+        Returns the answers and how the parking went: None, or
+        [outcome ("woken", "expired"), seconds parked] - counted here
+        unless the leader that forwarded the request does (`count`)."""
+        if wait_s <= 0:
+            return read(items), None
+        slots = [item[0] for item in items]
+        ends = dp.horizons(slots)
+        out = read(items)
+        deadline = time.monotonic() + min(wait_s, self._LONG_POLL_CAP_S)
+        clock = self.metrics.clock
+        outcome, parked_s = None, 0.0
+        while out and all(isinstance(r, tuple) and not r[0] for r in out):
+            left = deadline - time.monotonic()
+            if left <= 0:
+                outcome = "expired"
+                break
+            t0 = clock()
+            t_wake = dp.park(
+                [(slot, max(r[2], end))
+                 for slot, r, end in zip(slots, out, ends)], left)
+            t1 = clock()
+            parked_s += t1 - t0
+            if tctx is not None and self.spans is not None:
+                self.spans.span_at("fetch.park", tctx, t0, t1 - t0)
+            if t_wake is None:
+                outcome = "expired"
+                break
+            outcome = "woken"
+            if self._stop.is_set() or self._local_engine() is not dp:
+                raise NotCommittedError(
+                    "controller deposed under a parked fetch")
+            ends = dp.horizons(slots)
+            again = read([(item[0], r[2], *item[2:])
+                          for item, r in zip(items, out)])
+            out = [(g[0], r[1], g[2]) if isinstance(g, tuple) else g
+                   for r, g in zip(out, again)]
+            self._m_wake_late_us.observe(clock() - t_wake)
+        park = None if outcome is None else [outcome, parked_s]
+        if count:
+            self._note_park(park)  # its span is `_fetch`'s own, above
+        return out, park
+
+    def _note_park(self, park, tctx=None) -> None:
+        """Count how one request's parking went (`_fetch`'s second
+        answer, or the `park` of a forwarded read's reply). With `tctx`
+        the park ran on the controller: its length becomes the
+        fetch.park span under this broker's rpc.recv, ending now."""
+        if not park:
+            return
+        self._m_fetch_parked.inc()
+        (self._m_fetch_woken if park[0] == "woken"
+         else self._m_fetch_expired).inc()
+        dur = float(park[1])
+        self._parked_tls.s = getattr(self._parked_tls, "s", 0.0) + dur
+        if tctx is not None and self.spans is not None:
+            self.spans.span_at("fetch.park", tctx,
+                               self.metrics.clock() - dur, dur)
+
+    def _forward_wait(self, wait_s: float) -> float:
+        """A forwarded wait must finish inside the engine-call RPC
+        timeout or the long poll would read as a dead controller."""
+        return min(wait_s, max(0.0, self.config.rpc_timeout_s - 1))
+
+    def _read_local(self, dp, slot: int, offset: int, replica: int,
+                    max_msgs: Optional[int], wait_s: float, tctx=None,
+                    count: bool = True):
+        """One partition's read against the local plane, long-polling
+        for `wait_s` (`_fetch`): (messages, next_offset, how the parking
+        went)."""
+        self._read_barrier()
+
+        def read(items: list) -> list:
+            (_, offset, _, _, _), = items
             if self.hostplane is not None:
                 # Settled-mirror fast path: the owning worker serves the
                 # hot window off this process's GIL. Only a NON-EMPTY
                 # answer short-circuits — empty/behind/unavailable all
                 # fall through to the plane, which stays the authority
-                # (and owns the long-poll park below).
+                # (and owns the park).
                 got = self.hostplane.read(slot, offset, max_msgs)
                 if got is not None and got[0]:
-                    return got
+                    return [(got[0], offset, got[1])]
             msgs, end = dp.read(slot, offset, replica, max_msgs)
-            if msgs or wait_s <= 0:
-                return msgs, end
-            # Long-poll: an empty fetch parks here until rows settle
-            # past `offset` or the window lapses, so a tail consumer
-            # costs one RPC per DELIVERY instead of one per poll. The
-            # re-read fires off the settled-horizon watermark — a
-            # host-RAM check per tick, no device dispatch (the barrier
-            # above stays valid: rows arriving during the wait are
-            # NEWER than the proof, never staler).
-            deadline = time.monotonic() + min(wait_s, self._LONG_POLL_CAP_S)
-            # Park RELATIVE to the read's advance: an empty-but-advanced
-            # answer (offset below a settled gap or an all-padding tail)
-            # moves the wake watermark to its end, so the wait arms on
-            # rows settling PAST the dead range instead of re-reading
-            # the same advance every tick for the whole window — and the
-            # window still parks (one RPC per delivery, not one per
-            # client poll) when the tail past the advance is idle. The
-            # advance itself reaches the client in `end` either way.
-            wait_from = max(offset, end)
-            while time.monotonic() < deadline:
-                if self._stop.wait(timeout=0.01):
-                    break
-                if self._local_engine() is not dp:
-                    break  # deposed mid-wait: refuse via the normal path
-                # Locked accessor (the mirror_gap_slots advisor
-                # pattern): the settle thread mutates the horizon and
-                # the gap table together, and a bare array reach-in
-                # here was the one read-side consumer of plane
-                # internals outside the plane's own lock discipline.
-                if dp.settled_end(slot) > wait_from:
-                    msgs, end = dp.read(slot, wait_from, replica, max_msgs)
-                    if msgs:
-                        break
-                    wait_from = max(wait_from, end)
-            return msgs, end
+            return [(msgs, offset, end)]
+
+        # Long-poll: an empty fetch parks in `_fetch` until rows settle
+        # past `offset` or the window lapses, so a tail consumer costs
+        # one RPC per DELIVERY instead of one per poll.
+        (got,), park = self._fetch(
+            dp, [(slot, offset, 0, replica, max_msgs)], wait_s, read,
+            tctx=tctx, count=count)
+        return got[0], got[2], park
+
+    def _engine_read(self, slot: int, offset: int, replica: int,
+                     max_msgs: Optional[int] = None,
+                     wait_s: float = 0.0, tctx=None):
+        dp = self._local_engine()
+        if dp is not None:
+            return self._read_local(dp, slot, offset, replica, max_msgs,
+                                    wait_s, tctx)[:2]
         resp = self._engine_call(
             {"type": "engine.read", "slot": slot, "offset": offset,
              "replica": replica, "max_msgs": max_msgs,
-             # The forwarded wait must finish inside the engine-call RPC
-             # timeout or the long poll would read as a dead controller.
-             "wait_s": min(wait_s, max(0.0, self.config.rpc_timeout_s - 1))}
-        )
+             "wait_s": self._forward_wait(wait_s)})
+        self._note_park(resp.get("park"), tctx)
         return list(resp["messages"]), int(resp["end"])
 
-    def _engine_call_items(self, t: str, items: list, decode) -> list:
+    def _engine_call_items(self, t: str, items: list, decode,
+                           wait_s: float = 0.0, tctx=None) -> list:
         """ONE engine.*_multi frame to the controller for the items of a
         multi request: per item `decode` of its result - a value, or the
         exception that refuses it. A refused or failed frame fails every
-        item."""
+        item. `wait_s` rides a read's frame (`_forward_wait`)."""
         try:
-            resp = self._engine_call({"type": t, "items": items})
+            req = {"type": t, "items": items}
+            if wait_s > 0:
+                req["wait_s"] = self._forward_wait(wait_s)
+            resp = self._engine_call(req)
+            self._note_park(resp.get("park"), tctx)
             results = [decode(r) for r in resp["results"]]
             if len(results) != len(items):
                 raise RpcError(f"{t} answered {len(results)} of "
@@ -3751,21 +3864,27 @@ class BrokerServer:
                 TypeError, AttributeError) as e:
             return [e] * len(items)
 
-    def _engine_read_many(self, items: list, dp) -> list:
+    def _engine_read_many(self, items: list, dp, wait_s: float = 0.0,
+                          tctx=None) -> list:
         """The reads of one consume.multi, as `DataPlane.read_many`
         takes and answers them: from the local plane `dp`, or forwarded
         to the controller in ONE engine.read_multi frame, never part by
-        part."""
+        part; long-polling for `wait_s`, here or there (`_fetch`)."""
         if not items:
             return []
         if dp is not None:
             self._read_barrier()
-            return dp.read_many(items)
+            try:
+                return self._fetch(dp, items, wait_s, dp.read_many,
+                                   tctx=tctx)[0]
+            except NotCommittedError as e:  # released under its park
+                return [e] * len(items)
         return self._engine_call_items(
             "engine.read_multi", [list(item) for item in items],
             lambda r: (list(r[0]), int(r[1]), int(r[2]))
             if isinstance(r, list)
-            else NotCommittedError(str(r.get("error"))))
+            else NotCommittedError(str(r.get("error"))),
+            wait_s, tctx)
 
     def _engine_log_end(self, slot: int) -> int:
         """The slot's device-committed absolute log end, from the local
@@ -3890,26 +4009,29 @@ class BrokerServer:
                 sp.end()
         if t == "engine.read":
             limit = req.get("max_msgs")
-            msgs, end = self._engine_read(
-                int(req["slot"]), int(req["offset"]), int(req["replica"]),
-                None if limit is None else int(limit),
-                wait_s=float(req.get("wait_s", 0) or 0),
-            )
-            return {"ok": True, "messages": msgs, "end": end}
+            # A forwarded long poll parks here, on the plane; the
+            # leader that took the client's request counts how it went.
+            msgs, end, park = self._read_local(
+                dp, int(req["slot"]), int(req["offset"]),
+                int(req["replica"]), None if limit is None else int(limit),
+                float(req.get("wait_s", 0) or 0), count=False)
+            return {"ok": True, "messages": msgs, "end": end,
+                    **({"park": park} if park else {})}
         if t == "engine.read_multi":
             # The forwarded reads of one consume.multi: one answer per
             # item ([messages, offset, next_offset], or {"error"}).
             self._read_barrier()
+            out, park = self._fetch(dp, [
+                (int(slot), None if offset is None else int(offset),
+                 int(cslot), int(replica),
+                 None if limit is None else int(limit))
+                for slot, offset, cslot, replica, limit in req["items"]
+            ], float(req.get("wait_s", 0) or 0), dp.read_many, count=False)
             return {"ok": True, "results": [
                 list(r) if isinstance(r, tuple)
                 else {"error": f"{type(r).__name__}: {r}"}
-                for r in dp.read_many([
-                    (int(slot), None if offset is None else int(offset),
-                     int(cslot), int(replica),
-                     None if limit is None else int(limit))
-                    for slot, offset, cslot, replica, limit in req["items"]
-                ])
-            ]}
+                for r in out
+            ], **({"park": park} if park else {})}
         if t == "engine.read_offset":
             return {"ok": True, "offset": dp.read_offset(
                 int(req["slot"]), int(req["cslot"]),

@@ -45,14 +45,30 @@ re-delivers, i.e. prefetch trades the strict at-most-once auto-commit
 for at-least-once pipelining. A committed offset never passes what was
 handed to the caller and never moves back.
 
-`long_poll_s` > 0 makes empty fetches park broker-side until rows settle
-(tail consumers cost one RPC per delivery, not one per poll). With
-`long_poll_s` or `follower_reads` a readahead client keeps ONE
-`consume` per partition in flight at an explicit offset instead of a
-session (a `consume.multi` neither parks nor is served by a follower);
-its commits ride the same per-leader pipeline. Every lever is opt-in
-and independently A/B-able against the legacy one-RPC-per-call
-behavior.
+`long_poll_s` > 0 makes the session TAIL (a Kafka consumer's
+`fetch.min.bytes=1` / `fetch.max.wait.ms`): ONE long-polling
+`consume.multi` per leader is kept in flight, asynchronously, listing
+every partition of the session there that has no answer in hand, each at
+its position, with `wait_s`; the broker parks it once, for all its
+parts, and answers when rows settle past the position of ANY of them, or
+after `long_poll_s`, empty (broker/server.py `_fetch`). `consume(topic,
+p)` hands out what is in hand for `p` or returns empty AT ONCE - a poll
+never stands on a park, its own partition's or a sibling's - and the
+fetch goes out again as soon as its answer has been handed out
+(`_arm`), so a delivery costs one RPC, not one per poll. The first poll
+of a partition is the plain session's synchronous fetch (no `wait_s`:
+the broker's committed offset decides its position); an
+empty-but-advanced answer moves the position; a refused part, and every
+part of a failed request, takes the single-partition `consume`. With
+`long_poll_s` 0 no request carries a `wait_s` key. Commits ride the same
+per-leader pipeline either way.
+
+With `follower_reads` a readahead client keeps ONE `consume` per
+partition in flight at an explicit offset instead of a session (`_pf`;
+a `consume.multi` is not served by a follower), long-polling it after
+an empty window if `long_poll_s` is set; its commits ride the same
+per-leader pipeline. Every lever is opt-in and independently A/B-able
+against the legacy one-RPC-per-call behavior.
 
 Follower reads (`follower_reads=True`, needs a cluster running with the
 broker-side knob on): EXPLICIT-OFFSET reads route to a standby broker
@@ -107,7 +123,8 @@ class ConsumeError(Exception):
 class _Part:
     """One (topic, partition) of the session."""
 
-    __slots__ = ("pos", "limit", "addr", "answer", "polled")
+    __slots__ = ("pos", "limit", "addr", "answer", "polled", "fetching",
+                 "joined")
 
     def __init__(self, limit: int, addr: Optional[str], now: float) -> None:
         self.pos: Optional[int] = None  # next read position; None: the
@@ -118,6 +135,20 @@ class _Part:
         self.answer: Optional[tuple[list, int, int]] = None  # not handed
         #                       out yet: (messages, offset, next_offset)
         self.polled = now               # when the caller last asked
+        self.fetching = False           # listed in the leader's parked
+        #                                 fetch in flight (long_poll_s)
+        self.joined = now               # when it entered the session
+
+
+class _Parked:
+    """The ONE long-polling consume.multi in flight to a leader: its
+    future, the parts it lists - each with the position and window it
+    was sent for - when it went out, and its client.rpc span."""
+
+    __slots__ = ("fut", "parts", "sent", "rpc")
+
+    def __init__(self, fut, parts: list, sent: float, rpc) -> None:
+        self.fut, self.parts, self.sent, self.rpc = fut, parts, sent, rpc
 
 
 class _LeaderCommits:
@@ -175,20 +206,24 @@ class ConsumerClient:
         self.follower_served = 0
         self.last_from_follower = False
         # Readahead state. The session (module docstring): what the
-        # caller polls, by (topic, partition). `_pf`: with long_poll_s
-        # or follower_reads instead, the one `consume` in flight per
-        # partition at an explicit offset. `_commits`: the async
-        # auto-commits of both, by leader address.
+        # caller polls, by (topic, partition); `_parked`: with
+        # long_poll_s, its one long-polling consume.multi in flight per
+        # leader. `_pf`: with follower_reads instead, the one `consume`
+        # in flight per partition at an explicit offset. `_commits`:
+        # the async auto-commits of both, by leader address.
         self._sess: dict[tuple[str, int], _Part] = {}
+        self._parked: dict[str, _Parked] = {}  # by leader (long_poll_s)
         self._pf: dict[tuple[str, int], dict] = {}
         self._commits: dict[str, _LeaderCommits] = {}
         self._clock = time.monotonic
         # Causal tracing (obs/spans.py), mirroring ProducerClient: every
         # trace_sample_n-th consume opens a client.consume root span
         # whose context rides `tctx` on the sync and follower fetches
-        # and on the session's consume.multi (`_pf` fetches were armed
-        # before this call existed, so they stay unstamped). `spans` is
-        # public for the assembler.
+        # and on the session's consume.multi - a parked one carries the
+        # context of the call that sent it and its client.rpc ends when
+        # the answer is taken (`_pf` fetches were armed before this
+        # call existed, so they stay unstamped). `spans` is public for
+        # the assembler.
         self._trace_sample_n = int(trace_sample_n)
         self._trace_counter = itertools.count()
         self.spans: Optional[SpanRing] = (
@@ -226,7 +261,7 @@ class ConsumerClient:
         """Whether readahead runs as a session with each leader: read
         off the client's own input (module docstring)."""
         return (self.prefetch > 0 and call_async is not None
-                and self.long_poll_s == 0 and not self.follower_reads)
+                and not self.follower_reads)
 
     def consume_with_position(
         self,
@@ -363,7 +398,16 @@ class ConsumerClient:
                 # In hand is an answer cut for another window: dropped,
                 # and like any answer never handed out it moved nothing.
                 part.limit, part.answer = limit, None
-        if part.answer is None and part.addr is not None:
+        if self.long_poll_s > 0 and part.pos is not None:
+            # Tailing: the leader's parked fetch brings the answers. A
+            # partition with none in hand reads empty AT ONCE - it never
+            # stands on a park, its own or a sibling's.
+            if part.addr is not None:
+                self._absorb(part.addr, now)
+            if part.answer is None and part.addr is not None:
+                self._arm(part.addr, now, call_async)
+                return [], partition, part.pos, part.pos
+        elif part.answer is None and part.addr is not None:
             self._fetch(key, part, before, now, call_async)
         if part.answer is None:
             return None
@@ -394,6 +438,7 @@ class ConsumerClient:
             if now - q.polled > _SESSION_IDLE_S:
                 idle.append(k)  # no longer polled: leaves the session
             elif (before is not None and q.answer is None
+                    and not q.fetching
                     and q.addr == addr and q.pos is not None
                     and 0 <= q.polled - before <= _ANSWER_MAX_AGE_S):
                 parts.append((k, q))
@@ -426,6 +471,116 @@ class ConsumerClient:
                             int(ans.get("next_offset", offset)))
             else:
                 q.addr = None  # refused: its next poll goes the single path
+
+    # ------------------------------------- the parked fetch (long_poll_s)
+
+    def _arm(self, addr: str, now: float, call_async) -> None:
+        """Send the leader at `addr` its ONE long-polling consume.multi,
+        unless one is in flight: for every partition of the session
+        there whose position is known and which has no answer in hand,
+        each at its position, with `wait_s` - the broker answers when
+        rows settle past ANY of them, or after `long_poll_s`, empty. A
+        partition with an answer still to be handed out cannot be listed
+        (its position moves at the hand-out), and a fetch parked without
+        it would not see its next rows: while the caller is coming round
+        for such an answer (it asked within `_ANSWER_MAX_AGE_S`) the
+        fetch waits for the hand-out, which sends it (`_deliver`). While
+        the session is still learning what its caller polls (a partition
+        joined it within `_ANSWER_MAX_AGE_S`) a fetch waits no longer
+        than that: the first ones list a partition or two, and one of
+        them parked for the whole `long_poll_s` would keep the leader's
+        one fetch away from every partition that joined after it. The
+        leader's parked commits go out with the fetch."""
+        if addr in self._parked:
+            return
+        parts, idle = [], []
+        wait_s = self.long_poll_s
+        for k, q in self._sess.items():
+            if q.addr != addr:
+                continue
+            if now - q.joined <= _ANSWER_MAX_AGE_S:
+                wait_s = min(wait_s, _ANSWER_MAX_AGE_S)
+            if q.pos is None:
+                continue
+            if now - q.polled > _SESSION_IDLE_S:
+                idle.append(k)  # no longer polled: leaves the session
+            elif q.answer is None:
+                parts.append((k, q, q.pos, q.limit))
+            elif now - q.polled <= _ANSWER_MAX_AGE_S:
+                return
+        for k in idle:
+            del self._sess[k]
+        if not parts:
+            return
+        req = {"type": "consume.multi", "consumer": self.consumer_id,
+               "wait_s": wait_s,
+               "parts": [{"topic": k[0], "partition": k[1],
+                          "max_messages": limit, "offset": pos}
+                         for k, _, pos, limit in parts]}
+        self._drive_commits(addr, call_async)
+        rpc = NULL_SPAN if self.spans is None else \
+            self.spans.span("client.rpc", self._trace_root.ctx)
+        if rpc.ctx is not None:
+            req["tctx"] = rpc.ctx.wire()
+        try:
+            fut = call_async(addr, req)
+        except RpcError as e:
+            rpc.end(error=type(e).__name__)
+            for _, q, _, _ in parts:
+                q.addr = None  # the single-partition path re-resolves
+            return
+        for _, q, _, _ in parts:
+            q.fetching = True
+        self._parked[addr] = _Parked(fut, parts, now, rpc)
+
+    def _absorb(self, addr: str, now: float) -> None:
+        """Take the answer of the leader's parked fetch, if it has come:
+        rows are kept to be handed out, an empty-but-advanced answer
+        moves the position, a refused part (or every part of a failed
+        request, or of one that outlived its wait and the RPC timeout)
+        is left to the single-partition path. An answer counts only for
+        a part still where it was when the fetch went out."""
+        f = self._parked.get(addr)
+        if f is None:
+            return
+        if f.fut.done():
+            try:
+                resp = f.fut.result(timeout=0)
+            except Exception:
+                resp = {}
+        elif now - f.sent > self.long_poll_s + self._timeout:
+            # never answered: the wait it carried and an RPC's time over
+            self._abandon(f.fut)
+            resp = {}
+        else:
+            return
+        del self._parked[addr]
+        f.rpc.end()
+        answers = resp.get("parts") if resp.get("ok") else None
+        if not isinstance(answers, list) or len(answers) != len(f.parts):
+            answers = [{}] * len(f.parts)
+        for (k, q, pos, limit), ans in zip(f.parts, answers):
+            q.fetching = False
+            if (self._sess.get(k) is not q or q.pos != pos
+                    or q.limit != limit or q.answer is not None):
+                continue
+            if not ans.get("ok"):
+                q.addr = None
+                continue
+            offset = int(ans["offset"])
+            next_offset = int(ans.get("next_offset", offset))
+            msgs = list(ans["messages"])
+            if msgs:
+                q.answer = (msgs, offset, next_offset)
+            else:
+                q.pos = max(pos, next_offset)
+
+    def _abandon(self, fut) -> None:
+        """Give up on a fetch in flight, where the transport keeps a
+        pending entry for it (`TcpClient.abandon`)."""
+        abandon = getattr(self._transport, "abandon", None)
+        if abandon is not None:
+            abandon(fut)
 
     # --------------------------------- one fetch in flight per partition
 
@@ -513,6 +668,7 @@ class ConsumerClient:
         always re-resolve the LEADER (offset state is a
         quorum-replicated fact only the leader accepts)."""
         commit_addr = addr
+        session = False
         if self.follower_reads:
             self._pos[(topic, pid)] = int(next_offset)
             commit_addr = self._meta.leader_addr(topic, pid) or addr
@@ -526,6 +682,7 @@ class ConsumerClient:
                 part = self._sess[(topic, pid)] = _Part(
                     limit, addr, self._clock())
             part.answer, part.pos, part.addr = None, int(next_offset), addr
+            session = True
         elif self.prefetch > 0 and call_async is not None:
             # Re-arm at next_offset. After an EMPTY window only a
             # long-polling fetch is worth keeping in flight (a plain one
@@ -566,6 +723,10 @@ class ConsumerClient:
             else:
                 # strict: ack before deliver
                 self.commit(topic, pid, next_offset)
+        if session and self.long_poll_s > 0:
+            # Handed out: the leader's next parked fetch can list this
+            # partition at its new position.
+            self._arm(addr, self._clock(), call_async)
         return msgs, pid, offset, next_offset
 
     # ------------------------------------------------------------- commits
@@ -698,6 +859,12 @@ class ConsumerClient:
         )
 
     def close(self) -> None:
+        for f in self._parked.values():
+            # An answer that comes now is dropped: it was never handed
+            # out, so it moved no position.
+            self._abandon(f.fut)
+            f.rpc.end()
+        self._parked.clear()
         try:
             self.flush_commits()
         except Exception:

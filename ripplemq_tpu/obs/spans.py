@@ -122,6 +122,11 @@ SPAN_KINDS = frozenset({
     # Follower reads: serve from replicated bytes, including a
     # stripe-reconstruct-on-read when the local copy is a stripe set.
     "follower.serve", "stripe.reconstruct",
+    # A long-polling consume / consume.multi parked on the plane until
+    # rows settle or its wait lapses (broker/server.py _fetch): a child
+    # of the request's rpc.recv, so that span's self time is the
+    # handler's work and not the stand.
+    "fetch.park",
     # Metadata plane: one coalesced control-plane wave, and an elastic
     # split/merge cutover.
     "meta.wave", "meta.cutover",

@@ -79,6 +79,10 @@ STAGE_NAMES = frozenset({
     # One DataPlane.read call (mirror, ring or store), on whichever RPC
     # thread serves it: histogram only, no annotation.
     "read.serve",
+    # One stand of a long-polling fetch on the plane (DataPlane.park):
+    # registration to the settle release that ends it, or to its
+    # deadline. On RPC threads: histogram only, no annotation.
+    "fetch.park",
     # One sealed segment's RS encode, any compile included
     # (storage/segment.py erasure worker).
     "seal.rs_encode",

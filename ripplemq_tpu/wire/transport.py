@@ -225,10 +225,23 @@ class InProcClient(Transport):
         consumer readahead — then run unchanged on in-proc clusters
         without anyone burning a pool thread around a sync call."""
         fut: Future = Future()
-        try:
-            fut.set_result(self.call(addr, request))
-        except Exception as e:
-            fut.set_exception(e)
+
+        def run() -> None:
+            try:
+                fut.set_result(self.call(addr, request))
+            except Exception as e:
+                fut.set_exception(e)
+
+        if request.get("wait_s"):
+            # The one exception: a long-polling request parks in its
+            # handler until rows settle, and inline it would hold the
+            # caller for the whole wait - the opposite of what it is
+            # sent for. It runs on a thread of its own, as it holds a
+            # pool worker of a TCP server.
+            threading.Thread(target=run, daemon=True,
+                             name="inproc-parked").start()
+        else:
+            run()
         return fut
 
 

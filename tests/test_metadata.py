@@ -270,30 +270,40 @@ def test_parse_unknown_engine_key_is_still_an_error():
         parse_cluster_config({**_ONE_BROKER, "engine": {"fused_writes": True}})
 
 
-def test_benchmark_cluster_blocks_parse():
-    """The four deployments the benchmark boots, as run.py builds their
-    cluster files (the `cluster` block, the deployment's topics, one
-    broker per port) — two of them still name the retired keys."""
+def _benchmark_configs() -> list[str]:
     import glob
-    import json
     import os
 
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    paths = sorted(glob.glob(os.path.join(repo, "benchmarks", "configs",
-                                          "*.json")))
-    assert len(paths) == 4
-    for path in paths:
-        with open(path) as f:
-            config = json.load(f)
-        raw = dict(config["cluster"])
-        raw["brokers"] = [{"id": i, "host": "127.0.0.1", "port": 9000 + i}
-                          for i in range(config["deployment"]["brokers"])]
-        raw["topics"] = config["deployment"]["topics"]
-        cfg = parse_cluster_config(raw)
-        want = {k: v for k, v in config["cluster"].get("engine", {}).items()
-                if k not in ("fused_control", "packed_writes")}
-        for k, v in want.items():
-            assert getattr(cfg.engine, k) == v, (path, k)
+    return sorted(glob.glob(
+        os.path.join(repo, "benchmarks", "configs", "*.json")))
+
+
+def test_benchmark_has_configurations():
+    assert _benchmark_configs()
+
+
+@pytest.mark.parametrize(
+    "path", _benchmark_configs(),
+    ids=lambda p: p.rsplit("/", 1)[-1])
+def test_benchmark_cluster_blocks_parse(path):
+    """Every deployment the benchmark boots - whatever files are there -
+    as run.py builds its cluster file (the `cluster` block, the
+    deployment's topics, one broker per port); two of them still name
+    the retired keys."""
+    import json
+
+    with open(path) as f:
+        config = json.load(f)
+    raw = dict(config["cluster"])
+    raw["brokers"] = [{"id": i, "host": "127.0.0.1", "port": 9000 + i}
+                      for i in range(config["deployment"]["brokers"])]
+    raw["topics"] = config["deployment"]["topics"]
+    cfg = parse_cluster_config(raw)
+    want = {k: v for k, v in config["cluster"].get("engine", {}).items()
+            if k not in ("fused_control", "packed_writes")}
+    for k, v in want.items():
+        assert getattr(cfg.engine, k) == v, (path, k)
 
 
 def test_parse_rejects_linearizable_reads_without_standbys():
