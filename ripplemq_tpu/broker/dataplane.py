@@ -377,6 +377,11 @@ class DataPlane:
             np.zeros((P0, cfg.slots, cfg.slot_bytes), np.uint8)
             if host_read_cache else None
         )
+        # The same bytes, flat: what `_cache_rows` copies a window from.
+        self._host_ring_bytes = (
+            None if self._host_ring is None
+            else memoryview(self._host_ring).cast("B")
+        )
         self._cache_end = np.zeros((P0,), np.int64)
         # Post-gap mirrored run per slot: after a resolve failure leaves
         # a mirror gap, later rounds still write their rows physically —
@@ -1792,34 +1797,39 @@ class DataPlane:
         return int(min(end - offset, cend - offset, self.cfg.read_batch,
                        gap_room))
 
-    def _cache_rows(self, slot: int, offset: int, k: int) -> np.ndarray:
-        """A copy of `k` mirror rows from `offset` (no lock: the caller
-        re-checks trim afterwards)."""
-        S = self.cfg.slots
-        pos = offset % S
+    def _cache_rows(self, slot: int, offset: int, k: int) -> bytes:
+        """A copy of `k` mirror rows from `offset`, as bytes (no lock:
+        the caller re-checks trim afterwards). Sliced off the ring's
+        flat memoryview: a numpy copy of a window this size lets go of
+        the interpreter, and a reader of many partitions then stands in
+        line for it once a part (PERF.md section 6, PR 45)."""
+        S, SB = self.cfg.slots, self.cfg.slot_bytes
+        ring = self._host_ring_bytes
+        base, pos = slot * S, offset % S
         if pos + k <= S:
-            return self._host_ring[slot, pos : pos + k].copy()
+            return bytes(ring[(base + pos) * SB : (base + pos + k) * SB])
         # window spans the ring wrap, same as the device read
-        return np.concatenate([
-            self._host_ring[slot, pos:],
-            self._host_ring[slot, : pos + k - S],
-        ])
+        return b"".join((ring[(base + pos) * SB : (base + S) * SB],
+                         ring[base * SB : (base + pos + k - S) * SB]))
 
-    def _decode_rows(self, rows: np.ndarray, offset: int, k: int,
+    def _decode_rows(self, flat: bytes, offset: int, k: int,
                      max_msgs: Optional[int]) -> tuple[list[bytes], int]:
         """(messages, next_offset) of `k` copied mirror rows."""
-        # Decode on flat bytes: one tobytes() for the window, then
-        # length-prefixed slices — ~3x the msgs/s of per-row numpy
-        # slicing on the host-RAM-bound consume path.
+        # Decode on flat bytes: the lengths from the row heads (one
+        # strided little-endian int32 view, `row_lens`' reading of bytes
+        # 0:4, in one numpy call where the array form takes a dozen: a
+        # part of a consume.multi is a few rows), then length-prefixed
+        # slices — ~3x the msgs/s of per-row numpy slicing on the
+        # host-RAM-bound consume path.
         SB = self.cfg.slot_bytes
-        lens = np.minimum(np.asarray(row_lens(rows)), SB - _HDR)
-        flat = rows.tobytes()
-        # Lengths are clamped to the row capacity above — a corrupt
-        # length header must not bleed the next row's bytes into a
-        # message (the device/store decode paths clamp per row too).
+        cap = SB - _HDR
+        lens = np.ndarray((k,), "<i4", flat, 0, (SB,)).tolist()
+        # Lengths are clamped to the row capacity — a corrupt length
+        # header must not bleed the next row's bytes into a message (the
+        # device/store decode paths clamp per row too).
         with_pos = [
-            (i, flat[i * SB + _HDR : i * SB + _HDR + n])
-            for i, n in enumerate(lens.tolist())
+            (i, flat[i * SB + _HDR : i * SB + _HDR + min(n, cap)])
+            for i, n in enumerate(lens)
             if n > 0
         ]
         if max_msgs is not None and len(with_pos) > max(0, max_msgs):

@@ -28,6 +28,20 @@ messages: an answer waits for its hand-out no longer than a produce takes
 to be acked, where a whole rotation's answers fetched at once would be
 half a rotation old on average (PERF.md section 6, PR 40, has the
 arithmetic and the 1 KB cell that would have paid for it).
+"Within" is counted on the CALLER's clock: the wall clock less the
+seconds the caller has spent inside `consume_with_position` - in the
+session's own synchronous fetch, a commit flush, the single-partition
+path with its retries and back-off. That clock runs while the caller
+sleeps, works through its messages or walks to its next call, and stands
+while the client holds it, so the session's width follows how the CALLER
+goes round and not how long a request took. On the wall clock a request
+slower than `_ANSWER_MAX_AGE_S` made every partition of a tight loop
+read as "polled more than that apart": every fetch then carried one
+part, and a rotation of 32 partitions was 32 blocking requests of that
+length with no way back (PERF.md section 6, PR 45: the keyed cell). The
+price is stated there too: an answer in hand can be older than
+`_ANSWER_MAX_AGE_S` of wall time when a sibling's fetch (another
+leader's) blocks between the fetch that brought it and its hand-out.
 Delivered offsets are committed the same way: ONE `offset.commit.multi`
 per leader, sent asynchronously when an answer with messages is handed
 out and with every fetch, ONE in flight per (consumer, leader) and the
@@ -111,8 +125,14 @@ DEFAULT_MAX_MESSAGES = 10  # ConsumerClientImpl.java:21
 # within this long (going by when it asked last round). A message then
 # waits in the client no longer than its produce took to be acked; with
 # no limit a rotation's answers are half a rotation old on average.
+# Counted on the caller's clock (`_Part.polled`; module docstring): the
+# time the client's own requests held the caller is not the caller's
+# cadence, and counting it made a request slower than this a cliff - one
+# part a request from then on. The parked fetch (`_arm`), which never
+# holds its caller, reads it on the wall clock.
 _ANSWER_MAX_AGE_S = 0.06
-# A partition not polled for this long leaves the session.
+# A partition not polled for this long leaves the session: wall time
+# (`_Part.seen`), since it is about a caller that went away.
 _SESSION_IDLE_S = 5.0
 
 
@@ -123,10 +143,11 @@ class ConsumeError(Exception):
 class _Part:
     """One (topic, partition) of the session."""
 
-    __slots__ = ("pos", "limit", "addr", "answer", "polled", "fetching",
-                 "joined")
+    __slots__ = ("pos", "limit", "addr", "answer", "polled", "seen",
+                 "fetching", "joined")
 
-    def __init__(self, limit: int, addr: Optional[str], now: float) -> None:
+    def __init__(self, limit: int, addr: Optional[str], now: float,
+                 asked: float) -> None:
         self.pos: Optional[int] = None  # next read position; None: the
         #                                 broker's committed offset decides
         self.limit = limit              # the caller's max_messages
@@ -134,7 +155,9 @@ class _Part:
         #                                 partition path re-resolves it
         self.answer: Optional[tuple[list, int, int]] = None  # not handed
         #                       out yet: (messages, offset, next_offset)
-        self.polled = now               # when the caller last asked
+        self.polled = asked             # when the caller last asked, on
+        #                                 the caller's clock
+        self.seen = now                 # ... and on the wall clock
         self.fetching = False           # listed in the leader's parked
         #                                 fetch in flight (long_poll_s)
         self.joined = now               # when it entered the session
@@ -216,6 +239,12 @@ class ConsumerClient:
         self._pf: dict[tuple[str, int], dict] = {}
         self._commits: dict[str, _LeaderCommits] = {}
         self._clock = time.monotonic
+        # The caller's clock (module docstring): `_held_s` is the wall
+        # time the caller has spent inside consume_with_position,
+        # `_asked` the current call's entry on the clock that leaves it
+        # out.
+        self._held_s = 0.0
+        self._asked = 0.0
         # Causal tracing (obs/spans.py), mirroring ProducerClient: every
         # trace_sample_n-th consume opens a client.consume root span
         # whose context rides `tctx` on the sync and follower fetches
@@ -273,6 +302,16 @@ class ConsumerClient:
         next_offset). Manual committers commit `next_offset` — offsets are
         STORAGE offsets (the broker pads replication rounds for the TPU's
         alignment), so `offset + len(messages)` is NOT a valid position."""
+        entered = self._clock()
+        self._asked = entered - self._held_s
+        try:
+            return self._consume(topic, partition, max_messages)
+        finally:
+            # However the call ends, the caller was held this long.
+            self._held_s += self._clock() - entered
+
+    def _consume(self, topic: str, partition: Optional[int],
+                 max_messages: Optional[int]):
         limit = self.max_messages if max_messages is None else max_messages
         self.last_from_follower = False
         root = NULL_SPAN
@@ -385,15 +424,15 @@ class ConsumerClient:
         selector advance per consume)."""
         if partition is None:
             return None  # topic unknown: the sync path resolves it
-        now = self._clock()
+        now, asked = self._clock(), self._asked
         key = (topic, partition)
         part = self._sess.get(key)
         if part is None:
             part = self._sess[key] = _Part(
-                limit, self._meta.leader_addr(topic, partition), now)
+                limit, self._meta.leader_addr(topic, partition), now, asked)
             before = None
         else:
-            before, part.polled = part.polled, now
+            before, part.polled, part.seen = part.polled, asked, now
             if part.limit != limit:
                 # In hand is an answer cut for another window: dropped,
                 # and like any answer never handed out it moved nothing.
@@ -422,9 +461,14 @@ class ConsumerClient:
         answer has been handed out, whose position is known, and which
         the caller asked for within `_ANSWER_MAX_AGE_S` after it last
         asked for `part` (`before`) - so will again, if it goes round as
-        it did. The answers are kept in the session; a refused part's
-        partition is left to the single-partition path. The leader's
-        parked commits go out with the fetch."""
+        it did. `before` and `polled` are stamps of the caller's clock
+        (module docstring): the time this very request holds the caller
+        is not part of how the caller goes round, so a late caller
+        polling back to back gets its whole rotation on this leader in
+        one request however long a request takes. `now` is wall time,
+        for the idle rule. The answers are kept in the session; a
+        refused part's partition is left to the single-partition path.
+        The leader's parked commits go out with the fetch."""
         addr = part.addr
         if part.pos is None:
             # The broker's committed offset decides where this read
@@ -435,7 +479,7 @@ class ConsumerClient:
         for k, q in self._sess.items():
             if q is part:
                 continue
-            if now - q.polled > _SESSION_IDLE_S:
+            if now - q.seen > _SESSION_IDLE_S:
                 idle.append(k)  # no longer polled: leaves the session
             elif (before is not None and q.answer is None
                     and not q.fetching
@@ -484,7 +528,9 @@ class ConsumerClient:
         (its position moves at the hand-out), and a fetch parked without
         it would not see its next rows: while the caller is coming round
         for such an answer (it asked within `_ANSWER_MAX_AGE_S`) the
-        fetch waits for the hand-out, which sends it (`_deliver`). While
+        fetch waits for the hand-out, which sends it (`_deliver`). (Wall
+        time, here and below: a parked fetch never holds its caller, and
+        `now` is also when the fetch went out, for its timeout.) While
         the session is still learning what its caller polls (a partition
         joined it within `_ANSWER_MAX_AGE_S`) a fetch waits no longer
         than that: the first ones list a partition or two, and one of
@@ -502,11 +548,11 @@ class ConsumerClient:
                 wait_s = min(wait_s, _ANSWER_MAX_AGE_S)
             if q.pos is None:
                 continue
-            if now - q.polled > _SESSION_IDLE_S:
+            if now - q.seen > _SESSION_IDLE_S:
                 idle.append(k)  # no longer polled: leaves the session
             elif q.answer is None:
                 parts.append((k, q, q.pos, q.limit))
-            elif now - q.polled <= _ANSWER_MAX_AGE_S:
+            elif now - q.seen <= _ANSWER_MAX_AGE_S:
                 return
         for k in idle:
             del self._sess[k]
@@ -680,7 +726,7 @@ class ConsumerClient:
             part = self._sess.get((topic, pid))
             if part is None:
                 part = self._sess[(topic, pid)] = _Part(
-                    limit, addr, self._clock())
+                    limit, addr, self._clock(), self._asked)
             part.answer, part.pos, part.addr = None, int(next_offset), addr
             session = True
         elif self.prefetch > 0 and call_async is not None:

@@ -16,6 +16,8 @@ from ripplemq_tpu.chaos.cluster import small_engine
 from ripplemq_tpu.client import ConsumerClient, ProducerClient
 from ripplemq_tpu.client import consumer as consumer_mod
 from ripplemq_tpu.metadata.models import Topic
+from ripplemq_tpu.wire.retry import RetryPolicy
+from ripplemq_tpu.wire.transport import RpcError
 from tests.broker_harness import InProcCluster, make_config
 
 WIDE = "wide"  # 128 partitions, RF 3, over four brokers: leaders sit on
@@ -187,6 +189,163 @@ def test_a_fetch_carries_only_what_the_caller_asks_for_soon(cluster):
             == 2 * delta(after, before, "consume.multi_requests") - 3
         assert delta(after, before, "consume.multi_requests") < 12
     finally:
+        ra.close()
+
+
+# -- the caller's cadence is counted on the caller's clock (PR 45) --------
+
+
+class Slow:
+    """Transport proxy: a synchronous request HOLDS its caller - the
+    consumer's clock moves by `cost(request)` seconds while it is out -
+    and `held` is the sum (a retry policy's back-off sleeps through
+    `hold` too), so the test keeps the caller's clock by its own count. Every consume.multi is noted as (partition asked for
+    first, all partitions listed). `refuse` names a partition whose part
+    the next consume.multi listing it answers `not_leader` (once);
+    `fail` makes the next single-partition consume raise (once)."""
+
+    def __init__(self, inner, clock, cost) -> None:
+        self._inner, self._clock, self._cost = inner, clock, cost
+        self.held = 0.0
+        self.fetches: list[list[int]] = []
+        self.singles = 0
+        self.refuse = None
+        self.fail = False
+
+    def hold(self, dt: float) -> None:
+        self._clock.tick(dt)
+        self.held += dt
+
+    def call(self, addr, request, timeout=3.0):
+        self.hold(self._cost(request))
+        kind = request.get("type")
+        if kind == "consume":
+            self.singles += 1
+            if self.fail:
+                self.fail = False
+                raise RpcError("injected: peer down")
+        resp = self._inner.call(addr, request, timeout=timeout)
+        if kind == "consume.multi":
+            listed = [x["partition"] for x in request["parts"]]
+            self.fetches.append(listed)
+            if self.refuse in listed and resp.get("ok"):
+                resp["parts"][listed.index(self.refuse)] = {
+                    "ok": False, "error": "not_leader"}
+                self.refuse = None
+        return resp
+
+    def call_async(self, addr, request):
+        return self._inner.call_async(addr, request)
+
+    def close(self) -> None:
+        self._inner.close()
+
+
+def _cost(multi: float, single: float = 0.0):
+    return lambda request: {"consume.multi": multi,
+                            "consume": single}.get(request.get("type"), 0.0)
+
+
+# name: partitions (all on one leader; led by the controller or not), the
+# caller's schedule (`gap`: seconds of WALL time from poll to poll, a
+# late caller polling at once, as benchmarks/child.py goes round; `own`:
+# seconds the caller itself spends before every poll), what a request
+# costs, and the width every fetch must have from rotation `settled` on
+# (1: all but the learning rotation).
+CADENCES = {
+    # (a) late and back to back behind a 100 ms request: the whole
+    # rotation in ONE request (on the wall clock: eight of one part)
+    "back_to_back_slow_request": dict(
+        n=8, ctl=True, gap=0.0, own=0.0001, cost=_cost(0.1), width=(8, 8)),
+    # (b) on steady's schedule, 7.8 ms apart with a 2.5 ms request:
+    # what the caller asks for within 60 ms - the one asked for and the
+    # eight behind it (5.3 + 7 x 7.8 ms on the caller's clock), once the
+    # learning rotation's stamps (a request each: 5.3 ms apart) are gone
+    "scheduled_fast_request": dict(
+        n=12, ctl=True, gap=0.0078, own=0.0, cost=_cost(0.0025),
+        width=(9, 9), settled=2),
+    # (c) 70 ms apart, a fast request: sessions of one, as ever
+    "apart_fast_request": dict(
+        n=3, ctl=False, gap=0.07, own=0.0, cost=_cost(0.001), width=(1, 1)),
+    # (d) 70 ms apart of which 65 are the caller's OWN sleep and 5 the
+    # request: still one part - the caller's own time counts
+    "apart_by_its_own_sleep": dict(
+        n=3, ctl=False, gap=0.0, own=0.065, cost=_cost(0.005),
+        width=(1, 1)),
+    # (e) the single path's failed attempt and back-off (>= 100 ms on
+    # the policy's clock) are time the client held the caller
+    "fallback_retries_and_backoff": dict(
+        n=4, ctl=True, gap=0.0, own=0.0001, cost=_cost(0.001, 0.005),
+        width=(3, 4), trouble=2),
+}
+
+
+@pytest.mark.parametrize("name", list(CADENCES))
+def test_session_width_follows_the_callers_clock(cluster, name):
+    case = CADENCES[name]
+    parts = take(cluster, case["n"], controller=case["ctl"])
+    sent = fill(cluster, parts, name.encode()[:6])
+    strict = make_consumer(cluster, f"strict-{name}", max_messages=2)
+    clock = Clock()
+    slow = Slow(cluster.client(f"slow-{name}"), clock, case["cost"])
+    ra = make_consumer(
+        cluster, f"ra-{name}", transport=slow, prefetch=1, max_messages=2,
+        retry_policy=RetryPolicy(max_attempts=3, base_backoff_s=0.2,
+                                 clock=clock, sleep=slow.hold))
+    ra._clock = clock
+    try:
+        want, _ = rotate(strict, parts)
+        got = {p: [] for p in parts}
+        stamp: dict[int, float] = {}  # the caller's clock at its polls
+        per_rotation = []
+        nxt = clock.t
+        for r in range(6):
+            mark = len(slow.fetches)
+            if r == case.get("trouble"):
+                slow.refuse, slow.fail = parts[1], True
+            for p in parts:
+                clock.tick(case["own"])
+                nxt = max(nxt + case["gap"], clock.t)
+                clock.t = nxt  # sleeps to its schedule; late: at once
+                before, stamp[p] = stamp.get(p), clock.t - slow.held
+                at = len(slow.fetches)
+                msgs = ra.consume(WIDE, partition=p)
+                if msgs:
+                    got[p].append(msgs)
+                for listed in slow.fetches[at:]:
+                    # whoever rides along was asked for within 60 ms
+                    # after `p` last was, on the caller's clock
+                    assert listed[0] == p
+                    for q in listed[1:]:
+                        assert -1e-9 <= stamp[q] - before \
+                            <= consumer_mod._ANSWER_MAX_AGE_S + 1e-9
+            per_rotation.append(slow.fetches[mark:])
+        lo, hi = case["width"]
+        for fetches in per_rotation[case.get("settled", 1):]:
+            assert fetches and all(lo <= len(listed) <= hi for listed in fetches), \
+                per_rotation
+        if name == "back_to_back_slow_request":
+            # from the second rotation on ONE request a rotation
+            assert [len(f) for f in per_rotation] == [8, 1, 1, 1, 1, 1]
+        if name == "fallback_retries_and_backoff":
+            r = case["trouble"]
+            # the refused part went the single path: one failed attempt,
+            # a back-off of 100-200 ms on the wall, one that served ...
+            assert slow.singles == 2 and clock.t - 1000.0 > 0.1
+            # ... and the rotation after it is still ONE request of all
+            # four: the polls behind the trouble were not "150 ms later"
+            assert per_rotation[r + 1] == [parts]
+        # what the strict client reads: same messages, same order, same
+        # windows, same committed offsets
+        for p in parts:
+            assert flat(got[p]) == flat(want[p]) == sent[p]
+            assert [len(w) for w in got[p]] == [2, 2, 1]
+        ra.flush_commits()
+        for p in parts:
+            assert committed(cluster, f"ra-{name}", p) \
+                == committed(cluster, f"strict-{name}", p)
+    finally:
+        strict.close()
         ra.close()
 
 

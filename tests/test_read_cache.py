@@ -123,6 +123,37 @@ def test_ring_wrap_serves_store_below_trim_cache_above(slot_bytes, size):
         dp.stop()
 
 
+@pytest.mark.parametrize("slot,offset,k", [
+    (0, 0, 8),       # a window at the ring's start
+    (2, 5, 11),      # inside the ring, not the first partition
+    (3, 27, 9),      # across the ring's wrap: rows 27-31, then 0-3
+    (1, 32 + 30, 2), # a later lap: position = offset mod slots
+    (3, 31, 1),      # the ring's last row alone
+])
+def test_cache_rows_are_the_ring_bytes(slot, offset, k):
+    """`_cache_rows` slices a window off the ring's FLAT bytes (a numpy
+    copy of a window lets go of the interpreter, PR 45): the same bytes
+    as the [partition, slot, byte] view's rows, wrap included, and
+    `_decode_rows` reads them as it read the array."""
+    cfg = small_cfg(partitions=4, slots=32, max_batch=8, read_batch=8,
+                    slot_bytes=32)
+    dp = DataPlane(cfg, mode="local", store=MemoryRoundStore())
+    rng = np.random.default_rng(7)
+    dp._host_ring[:] = rng.integers(0, 256, dp._host_ring.shape, np.uint8)
+    S = cfg.slots
+    want = np.concatenate([dp._host_ring[slot, (offset + i) % S]
+                           for i in range(k)]).tobytes()
+    got = dp._cache_rows(slot, offset, k)
+    assert isinstance(got, bytes) and got == want
+    # a written row: 4 B length, 4 B term, payload
+    row = np.zeros(cfg.slot_bytes, np.uint8)
+    row[0], row[8:11] = 3, list(b"abc")
+    dp._host_ring[slot, offset % S] = row
+    msgs, nxt = dp._decode_rows(dp._cache_rows(slot, offset, 1), offset, 1,
+                                None)
+    assert msgs == [b"abc"] and nxt == offset + 1
+
+
 def test_mirror_gap_falls_back_to_device():
     """A resolve failure leaves a mirror gap; reads in it must come from
     the device ring (the authority), not serve stale mirror bytes."""
