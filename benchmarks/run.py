@@ -79,6 +79,48 @@ class RunFailed(Exception):
     pass
 
 
+def metrics_for(cell: str, here: str = HERE) -> list[dict]:
+    """The per-layer metrics a `--trace 1` run of `cell` reports, in the
+    order of its line. The tie is written once, by whichever of the two
+    came later, so that neither ever needs an edit of a file that is
+    there: a cell's `workloads/<cell>.json` names under `layer_metrics`
+    the definitions that were there before it (their files say what is
+    read, not where); a metric that came after a cell names that cell
+    under `workloads` in its own file. First the cell's list in its order,
+    then the later files by name. A listed name with no file, a file under
+    another name, a `moves` the cell does not report, a name listed twice
+    or nothing to report at all is RunFailed, naming cell and metric."""
+    def load(*parts: str) -> dict:
+        with open(os.path.join(here, *parts)) as f:
+            return json.load(f)
+
+    spec = load("workloads", f"{cell}.json")
+    names = list(spec.get("layer_metrics", []))
+    files = {f[:-5]: load("layer_metrics", f) for f in sorted(os.listdir(
+        os.path.join(here, "layer_metrics"))) if f.endswith(".json")}
+    later = [name for name, m in files.items() if name not in names
+             and cell in m.get("workloads", ())]
+    if not names + later:
+        raise RunFailed(f"cell {cell}: no per-layer metric: its file lists "
+                        f"none and no metric's file names it")
+    for name in names + later:
+        m = files.get(name)
+        if names.count(name) > 1:
+            raise RunFailed(f"cell {cell}: lists per-layer metric {name} "
+                            f"{names.count(name)} times")
+        if m is None:
+            raise RunFailed(f"cell {cell}: lists per-layer metric {name}, "
+                            f"and layer_metrics/{name}.json is not there")
+        if m["name"] != name:
+            raise RunFailed(f"cell {cell}: layer_metrics/{name}.json holds "
+                            f"the metric {m['name']}")
+        if m["moves"] not in spec["end_to_end"]:
+            raise RunFailed(f"cell {cell}: per-layer metric {name} moves "
+                            f"{m['moves']}, which the cell does not report "
+                            f"({', '.join(spec['end_to_end'])})")
+    return [files[name] for name in names + later]
+
+
 def failing(numbers: list) -> dict:
     """Of the numbers compared (name, value, limit), those outside their
     limit, by name. A limit is exact: `== N`, or 0."""
@@ -144,6 +186,7 @@ class Run:
                  t_start_ns: int = T_START_NS) -> None:
         self.t_start_ns = t_start_ns  # the process's start, unless a tool
         # such as control.py makes several runs in one process
+        self.layer_metrics = metrics_for(workload)  # or RunFailed, at once
         self.cell = load_json("workloads", f"{workload}.json")
         self.config = load_json("configs", f"{self.cell['config']}.json")
         if rehearse:
@@ -802,11 +845,7 @@ class Run:
                                   name: lat[lat_sub == q]
                                   for q, name in enumerate(subs)}}}
             metrics = {}
-            for fname in sorted(os.listdir(os.path.join(HERE,
-                                                        "layer_metrics"))):
-                m = load_json("layer_metrics", fname)
-                if cell["name"] not in m.get("workloads", [cell["name"]]):
-                    continue
+            for m in self.layer_metrics:
                 reader = importlib.import_module(
                     f"benchmarks.readers.{m['reader']['kind']}")
                 v = reader.read(m["reader"]["args"], run)
@@ -871,9 +910,9 @@ def main(argv=None) -> int:
         print("the program under test is not in this directory",
               file=sys.stderr)
         return EXIT_NO_PROGRAM
-    run = Run(args.workload, args.seed, args.seconds, bool(args.trace),
-              rehearse=args.rehearse)
     try:
+        run = Run(args.workload, args.seed, args.seconds, bool(args.trace),
+                  rehearse=args.rehearse)
         out = run.run()
     except RunFailed as e:
         print(f"FAIL: {e}", file=sys.stderr, flush=True)

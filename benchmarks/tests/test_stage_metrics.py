@@ -13,6 +13,7 @@ import os
 import pytest
 
 from benchmarks.readers._common import parse_exposition
+from run import metrics_for
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 LAYER_METRICS = os.path.join(os.path.dirname(HERE), "layer_metrics")
@@ -95,20 +96,24 @@ def test_new_metric_is_left_out_where_the_program_lacks_it(metric):
 
 
 def test_new_metric_files_match_their_benchmark_entries():
-    with open(os.path.join(os.path.dirname(os.path.dirname(HERE)),
-                           "BENCHMARK.json")) as f:
+    root = os.path.dirname(os.path.dirname(HERE))
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
         bench = json.load(f)
     entries = {m["name"]: m for m in bench["per_layer"]}
-    cells = {w["name"] for w in bench["workloads"]}
     e2e = {m["name"]: m for m in bench["end_to_end"]}
+    # cell -> the metrics it reports (since PR 46 the cell's own list)
+    lists = {w["name"]: [m["name"] for m in metrics_for(w["name"])]
+             for w in bench["workloads"]}
     for metric in NEW_METRICS:
         m, e = load(metric), entries[metric]
-        for key in ("name", "unit", "better", "source", "layer", "moves",
-                    "workloads"):
+        for key in ("name", "unit", "better", "source", "layer", "moves"):
             assert m[key] == e[key], (metric, key)
-        assert set(m["workloads"]) <= cells
-        # every cell a metric lists reports the end-to-end metric it moves
-        assert set(m["workloads"]) <= set(e2e[m["moves"]]["workloads"])
+        assert "workloads" not in m  # a file says what is read, not where
+        assert e["workloads"] == [c for c in lists if metric in lists[c]]
+        # every cell that reports a metric reports the end-to-end metric
+        # it moves
+        assert e["workloads"] and set(e["workloads"]) \
+            <= set(e2e[m["moves"]]["workloads"])
 
 
 def test_round_stage_sums_close_over_the_recorded_pair():

@@ -104,18 +104,28 @@ def test_benchmark_json_lists_the_cell_and_its_metrics():
     for m in bench["end_to_end"]:
         if m["name"] in ("produce_ack_p50_ms", "deliver_p50_ms"):
             assert m["workloads"][-1] == CELL
-    files = sorted(f[:-5] for f in os.listdir(
-        os.path.join(HERE, "..", "layer_metrics")) if f.startswith("tail."))
-    listed = {m["name"]: m for m in bench["per_layer"]
-              if m["name"].startswith("tail.")}
-    assert sorted(listed) == files and len(files) == 24
-    for name in files:
+    # what the cell reads per layer is its own list (PR 46): the six of
+    # the parked fetch under the cell's prefix, the shared path under
+    # the names every cell reads it by, and the three the cap of 128 had
+    # taken in PR 43's check back as list entries
+    names = cell["layer_metrics"]
+    assert len(names) == len(set(names)) == 27
+    assert sorted(n for n in names if n.startswith("tail.")) == [
+        "tail.expired_share", "tail.fetch_parts_per_request", "tail.park_ms",
+        "tail.parked_share", "tail.requests_per_delivery",
+        "tail.wake_late_ms"]
+    assert {"dataplane.drain_ms", "dataplane.stage_fill", "store.fsync_ms",
+            "host.interp_wake_late_ms", "host.plane_lock_wait_rpc_ms",
+            "lat.append_roofline_pct", "client.deliver_p99_ms"} <= set(names)
+    listed = {m["name"]: m for m in bench["per_layer"]}
+    for name in names:
         m = load("layer_metrics", name)
-        assert m["workloads"] == [CELL] == listed[name]["workloads"]
-        assert {k: m[k] for k in listed[name]} == listed[name]
-    assert {"tail.wake_late_ms", "tail.park_ms", "tail.expired_share",
-            "tail.parked_share", "tail.requests_per_delivery",
-            "tail.fetch_parts_per_request"} <= set(files)
+        assert CELL in listed[name]["workloads"], name
+        assert {k: m[k] for k in listed[name] if k != "workloads"} \
+            == {k: v for k, v in listed[name].items() if k != "workloads"}
+        assert m["moves"] in cell["end_to_end"], name
+    assert sorted(n for n, m in listed.items() if CELL in m["workloads"]) \
+        == sorted(names)
 
 
 def run_cell(**kw):
