@@ -63,6 +63,9 @@ FAST_MODULES = {
     "test_consume_session",     # ~10 s: one 4-broker cluster, 128 partitions
     "test_parked_fetch",        # ~30 s: in-proc clusters of 16 partitions,
                                 # the parked fetch against a plain model
+    "test_follower_fetch",      # ~12 s: the follower's fetch against a
+                                # plain model (no cluster), one 3-broker
+                                # cluster across two deaths (~8 s)
     "test_log_matching",
     "test_marker_audit",
     "test_metadata",

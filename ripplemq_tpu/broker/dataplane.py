@@ -462,6 +462,12 @@ class DataPlane:
         # same in-order release, no standby-stream overlap.
         self.replicate_begin_fn = None
         self.replicate_wait_fn = None
+        # Follower reads: called by the settle release with the slots
+        # whose settled floor it moved, after the round's acks and the
+        # parked fetches here - the replicator owes every standby their
+        # stamp (RoundReplicator.push_floor). None (follower reads off):
+        # the release ends as it always did.
+        self.floor_push_fn = None
         # Where a boot's round programs come from and how many of them
         # it loaded or had to build (engine.programs_loaded / _built,
         # the `engine.device` block). The spmd bindings stay on `jit`.
@@ -3008,6 +3014,9 @@ class DataPlane:
             # The producers' acks first, then the parked fetches the
             # round's rows end (`park`).
             self._wake_parks(advanced)
+            push = self.floor_push_fn
+            if push is not None and advanced:
+                push([slot for slot, _ in advanced])
             # Stage 6 (the whole-round number): dispatch → ack release.
             t0 = ctx.get("t_dispatch")
             t_rel = self.metrics.clock()

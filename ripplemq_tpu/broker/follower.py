@@ -45,6 +45,16 @@ one decode. The cache is bounded by `follower_page_cache_bytes`
 (plane-wide, oldest-page eviction); an evicted page re-decodes on the
 next miss in striped mode and refuses to the leader in full-copy mode.
 
+A tailing reader parks here as it does on the leader (`park`, the twin
+of `DataPlane.park`): a fetch whose every part read empty AT the floor
+stands on the plane until a floor stamp passes the position of any of
+its parts - the stamp that passes it and no other - or its wait lapses;
+a newer controller epoch, a lost lease or a stop (`release_parks`)
+refuses it. The leader's settle release pushes the floor of a round it
+settled without waiting for the next round's records
+(replication.py `_Sender.push_floor`), so a follower's horizon follows
+the leader's by a frame, not by a round.
+
 Row framing is the engine's own: each cached page is the REC_APPEND
 payload verbatim — packed `slot_bytes`-wide rows whose first 4 bytes
 are the little-endian payload length (length-0 rows are alignment
@@ -54,6 +64,8 @@ mirror serves.
 
 from __future__ import annotations
 
+import threading
+import time
 from collections import OrderedDict, deque
 from typing import Callable, Optional
 
@@ -81,6 +93,28 @@ _SIBLING_FRAME_CAP = 4096
 _MAX_DECODE_PER_READ = 64
 _MAX_FETCH_ROUNDS_PER_READ = 8
 _MAX_GAPS_PER_SLOT = 128
+
+
+class ParkRefused(Exception):
+    """A parked fetch ended without an answer: the plane moved to a
+    newer controller epoch, the broker lost its lease, or it stops. The
+    caller refuses the request to the leader."""
+
+
+class _Park:
+    """One parked fetch (`FollowerReadPlane.park`): per slot the offset
+    the floor must pass to end it, the event its RPC worker stands on,
+    and how it ended - `t_wake` is the plane's clock at the floor stamp
+    that passed one of its offsets, `refused` says why it was released
+    instead."""
+
+    __slots__ = ("offs", "event", "t_wake", "refused")
+
+    def __init__(self, offs: dict[int, int]) -> None:
+        self.offs = offs
+        self.event = threading.Event()
+        self.t_wake: Optional[float] = None
+        self.refused: Optional[str] = None
 
 
 class _SlotRun:
@@ -176,9 +210,13 @@ class FollowerReadPlane:
         cache_bytes: int,
         fetch_fn: Optional[Callable[[int], list[StripeFrame]]] = None,
         decode_kw: Optional[dict] = None,
+        clock: Callable[[], float] = time.perf_counter,
     ) -> None:
         self._slot_bytes = int(slot_bytes)
         self._cache_bytes = int(cache_bytes)
+        # The owning broker's registry clock: a park's `t_wake` is read
+        # on it, beside the handler's own stamps.
+        self._clock = clock
         # Sibling-stripe pager (server closure over stripe.fetch): one
         # call = one page round across the live holders, returning
         # parsed frames with gsn >= the argument. None = full-copy-only
@@ -209,7 +247,12 @@ class FollowerReadPlane:
         self._sibling_n = 0
         self._decode_next = -1
         self._floor_gsn = 0
+        # Parked fetches by slot (`park`), under `_lock` like the
+        # floors that end them.
+        self._parks: dict[int, list[_Park]] = {}
         # Counters (persist across generations; stats()).
+        self._tails = 0
+        self._floor_stamps = 0
         self._served = 0
         self._refused = 0
         self._rows = 0
@@ -244,6 +287,9 @@ class FollowerReadPlane:
             self._sibling_n = 0
             self._decode_next = -1
             self._floor_gsn = 0
+            # A fetch parked under the old generation owes its reader a
+            # refusal: the new one's floors owe nothing to its position.
+            self._release_parks_locked("epoch")
         return True
 
     def note_epoch(self, epoch: int) -> None:
@@ -273,16 +319,27 @@ class FollowerReadPlane:
                 if int(rec[0]) != REC_APPEND:
                     continue
                 self._publish_locked(int(rec[1]), int(rec[2]), bytes(rec[3]))
+            hit: list[_Park] = []
+            t = self._clock() if self._parks else 0.0
             for ent in floors or ():
                 slot, end = int(ent[0]), int(ent[1])
                 if end > self._floor.get(slot, -1):
                     self._floor[slot] = end
+                    # The parks this stamp passes, and no others.
+                    for p in self._parks.get(slot, ()):
+                        if p.t_wake is None and p.offs[slot] < end:
+                            p.t_wake = t  # under two slots: woken once
+                            hit.append(p)
                 # The leader's gap list is authoritative and already
                 # pruned below its trim: replace, don't merge.
                 self._gaps[slot] = [
                     [int(a), int(b)] for a, b in ent[2]
                 ][-_MAX_GAPS_PER_SLOT:]
+            if floors:
+                self._floor_stamps += 1
             self._evict_locked()
+        for p in hit:
+            p.event.set()
 
     def ingest_stripe(self, epoch: int, frame: StripeFrame) -> None:
         """Striped path: stash THIS standby's stripe of a group and
@@ -328,32 +385,42 @@ class FollowerReadPlane:
 
     # ----------------------------------------------------------- serve
 
-    def read(self, slot: int, offset: int, max_msgs: Optional[int]
+    def read(self, slot: int, offset: int, max_msgs: Optional[int],
+             tail_ok: bool = False
              ) -> Optional[tuple[list[bytes], int]]:
         """Answer a consume from replicated bytes, strictly below the
         slot's settled floor. Returns (messages, next_offset) — empty
-        messages always advance (a replicated-gap skip or padding walk)
-        — or None: REFUSE, the caller sends `not_settled_here:` and the
-        client falls back to the leader."""
+        messages advance (a replicated-gap skip or padding walk) — or
+        None: REFUSE, the caller sends `not_settled_here:` and the
+        client falls back to the leader. A position at or past a KNOWN
+        floor is refused too, unless the caller can wait there
+        (`tail_ok`: a consume.multi, which answers its reader empty or
+        parks it, `park`): then it reads ([], offset) - nothing is
+        settled here past the position yet, and nothing is handed out."""
         slot, offset = int(slot), int(offset)
-        res = self._read_cached(slot, offset, max_msgs)
+        res = self._read_cached(slot, offset, max_msgs, tail_ok)
         if res is None and self._mode == "striped":
             self._advance_striped(slot, offset)
-            res = self._read_cached(slot, offset, max_msgs)
+            res = self._read_cached(slot, offset, max_msgs, tail_ok)
         with self._lock:
             if res is None:
                 self._refused += 1
+            elif res[1] == offset:
+                self._tails += 1
             else:
                 self._served += 1
                 self._rows += len(res[0])
         return res
 
-    def _read_cached(self, slot: int, offset: int, max_msgs: Optional[int]
+    def _read_cached(self, slot: int, offset: int, max_msgs: Optional[int],
+                     tail_ok: bool = False
                      ) -> Optional[tuple[list[bytes], int]]:
         with self._lock:
             floor = self._floor.get(slot)
-            if floor is None or offset >= floor:
+            if floor is None:
                 return None
+            if offset >= floor:
+                return ([], offset) if tail_ok else None
             for s, e in self._gaps.get(slot, ()):
                 if s <= offset < e:
                     # Same skip answer the leader's gap clamp serves.
@@ -368,6 +435,67 @@ class FollowerReadPlane:
             else:
                 self._hits += 1
             return got
+
+    # ------------------------------------------------------------ park
+
+    def park(self, pairs, timeout: float, epoch: int) -> Optional[float]:
+        """Stand until a floor stamp passes the offset of ANY (slot,
+        offset) of `pairs`, at most `timeout` seconds: a long-polling
+        consume.multi whose every part read empty at the floor (the
+        twin of `DataPlane.park`; there the settle release ends a park,
+        here `ingest_rounds`). Returns the plane's clock at the stamp
+        that ended the stand (the caller reads again and observes how
+        late its rows came to hand), or None at the deadline. Raises
+        ParkRefused when the plane is not at `epoch`, moves past it
+        under the park, or `release_parks` ends it (a lost lease, a
+        stop). Registered and looked at under the plane's lock, which
+        every stamp takes: a floor that passed between the caller's
+        read and this call ends the park at once, and no tick runs
+        while nothing settles."""
+        offs: dict[int, int] = {}
+        for slot, off in pairs:
+            offs[slot] = min(off, offs.get(slot, off))
+        p = _Park(offs)
+        with self._lock:
+            if self._epoch != int(epoch):
+                raise ParkRefused("epoch")
+            if any(self._floor.get(s, -1) > off for s, off in offs.items()):
+                return self._clock()  # a stamp passed it meanwhile
+            for slot in offs:
+                self._parks.setdefault(slot, []).append(p)
+        try:
+            p.event.wait(timeout)
+        finally:
+            with self._lock:
+                for slot in offs:
+                    q = self._parks.get(slot)
+                    if q is not None and p in q:
+                        q.remove(p)
+                        if not q:
+                            del self._parks[slot]
+        if p.refused is not None:
+            raise ParkRefused(p.refused)
+        return p.t_wake
+
+    def _standing_locked(self) -> set:
+        """The parks standing now (one under every slot it lists)."""
+        return {p for q in self._parks.values() for p in q}
+
+    def _release_parks_locked(self, why: str) -> None:
+        for p in self._standing_locked():
+            if p.t_wake is None and p.refused is None:
+                p.refused = why
+                p.event.set()
+
+    def release_parks(self, why: str) -> None:
+        """End every park with a refusal (ParkRefused in its `park`):
+        the broker lost its follower lease, or it stops."""
+        with self._lock:
+            self._release_parks_locked(why)
+
+    def parked(self) -> int:
+        with self._lock:
+            return len(self._standing_locked())
 
     def audit_answer(self, slot: int, offset: int, next_offset: int
                      ) -> bool:
@@ -575,6 +703,9 @@ class FollowerReadPlane:
                 "floor_lag_rows": int(lag),
                 "reads_served": self._served,
                 "reads_refused": self._refused,
+                "reads_at_tail": self._tails,
+                "floor_stamps": self._floor_stamps,
+                "parked": len(self._standing_locked()),
                 "rows_served": self._rows,
                 "answers_past_floor": self._past_floor,
                 "cache": {

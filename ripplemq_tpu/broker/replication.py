@@ -121,6 +121,10 @@ class _Sender(threading.Thread):
         # repl.send_wait_us starts.
         self._queue: list[tuple[list, Future, Optional[list], float]] = []
         self._buffer: Optional[list] = None
+        # Settled floors owed to the standby (`push_floor`): the slots,
+        # and the oldest release stamp among them (0: none owed).
+        self._floor_slots: set[int] = set()
+        self._floor_t_ns = 0
         self._stopped = False
         self.unreachable = False  # consecutive send failures observed
 
@@ -151,6 +155,19 @@ class _Sender(threading.Thread):
             self._queue.append((records, fut, None, self._rep._clock()))
             self._cond.notify()
         return fut
+
+    def push_floor(self, slots, t_ns: int) -> None:
+        """The settle release moved the floors of `slots` at `t_ns`
+        (time.monotonic_ns): owe the standby their stamp. It rides the
+        next frame this sender fires - one with records if any is
+        queued, else a records-less frame of its own (`_send_frame`)."""
+        with self._cond:
+            if self._stopped:
+                return
+            self._floor_slots.update(slots)
+            if not self._floor_t_ns:
+                self._floor_t_ns = int(t_ns)
+            self._cond.notify()
 
     def cancel(self, fut: Future) -> bool:
         """Remove a still-queued entry by its future (a timed-out read
@@ -218,11 +235,32 @@ class _Sender(threading.Thread):
                 else:
                     f.set_result(result)
 
-    def _send_frame(self, group: list, epoch: int, sseq: int):
+    def _send_frame(self, group: list, epoch: int, sseq: int,
+                    pushed: Optional[tuple] = None):
         """Fire one epoch-stamped, stream-sequenced repl.rounds frame;
         returns a Future of the response dict (pipelined when the
         transport supports call_async, an already-resolved future
-        otherwise — the in-proc network is synchronous by design)."""
+        otherwise — the in-proc network is synchronous by design).
+
+        Which frames carry a floor stamp (`floors`; only with a
+        `floors_fn`, i.e. follower reads on): a frame with records
+        carries the floors of the slots its records touch, as it
+        always did; a frame that takes a pushed floor with it
+        (`pushed`: (slots, release stamp) from `push_floor`) carries
+        those slots' floors too, and the stamp as `floor_t_ns` - with
+        nothing queued it is a records-less frame sent for the floor
+        alone, so the floor of round N reaches the standby a frame
+        after N settled and not with round N+1's records. A
+        records-less frame with nothing pushed (the read barrier's
+        `replicate([])`) carries none. Why a floor can never pass its
+        gap map: the stamp is not what the release saw but what
+        `floors_fn` (`DataPlane.settle_floors`) reads NOW, floor and
+        gaps of every slot in one pass under the plane's lock, and the
+        standby applies frames in `sseq` order - so each stamp is a
+        consistent (floor, gaps) pair no older than the last one it
+        replaced. It can only name rounds whose acks already landed
+        from every member, this standby included: their rows went out
+        in frames before this one."""
         records = [r for entry in group for r in entry[0]]
         req = {
             "type": "repl.rounds",
@@ -238,16 +276,20 @@ class _Sender(threading.Thread):
             # _handle_repl_rounds), closing the cross-process edge the
             # assembler's skew estimate keys on.
             req["tctx"] = tctxs
-        if self._rep.floors_fn is not None and records:
+        slots = {r[1] for r in records}
+        if pushed is not None:
+            slots |= pushed[0]
+        if self._rep.floors_fn is not None and slots:
             # Piggyback the per-slot settled floor (+ gap map) for the
-            # slots this frame touches: the standby publishes it as its
-            # follower-read horizon. Stamped at send time, so it is
-            # conservative — it can only name rounds whose acks already
-            # landed cluster-wide, never this frame's own rows.
+            # slots this frame touches or was pushed for: the standby
+            # publishes it as its follower-read horizon. Stamped at
+            # send time, so it is conservative — it can only name
+            # rounds whose acks already landed cluster-wide, never this
+            # frame's own rows.
             try:
-                req["floors"] = self._rep.floors_fn(
-                    sorted({r[1] for r in records})
-                )
+                req["floors"] = self._rep.floors_fn(sorted(slots))
+                if pushed is not None:
+                    req["floor_t_ns"] = pushed[1]
             except Exception:
                 pass  # floor stamp is best-effort; the frame still ships
         call_async = getattr(self._rep.client, "call_async", None)
@@ -283,7 +325,7 @@ class _Sender(threading.Thread):
         failures = 0
         next_sseq = 0
         # In-flight window entries:
-        # [group, sseq, rpc_fut, t_frame, t_sent, send_wait].
+        # [group, sseq, rpc_fut, t_frame, t_sent, send_wait, pushed].
         inflight: list = []
 
         def fail_inflight(result) -> None:
@@ -307,13 +349,18 @@ class _Sender(threading.Thread):
                 self._queue[0:0] = [
                     pair for entry in inflight for pair in entry[0]
                 ]
+                for entry in inflight:  # floors they carried: owed again
+                    if entry[6] is not None:
+                        self._floor_slots |= entry[6][0]
+                        self._floor_t_ns = min(
+                            self._floor_t_ns or entry[6][1], entry[6][1])
             inflight.clear()
 
         while True:
             depth = max(1, int(self._rep.pipeline_depth))
             with self._cond:
                 while (not self._queue and not inflight
-                       and not self._stopped):
+                       and not self._floor_slots and not self._stopped):
                     self._cond.wait(timeout=0.2)
                 if self._stopped:
                     break
@@ -323,6 +370,15 @@ class _Sender(threading.Thread):
                     if g is None:
                         break
                     groups.append(g)
+                pushed = None
+                if self._floor_slots and (
+                        groups or len(inflight) < depth):
+                    # An owed floor goes with the first frame fired
+                    # now; with nothing queued, on a frame of its own.
+                    pushed = (self._floor_slots, self._floor_t_ns)
+                    self._floor_slots, self._floor_t_ns = set(), 0
+                    if not groups:
+                        groups.append([])
             # -- fire new frames (top up the window) --
             fenced = False
             for group in groups:
@@ -348,7 +404,7 @@ class _Sender(threading.Thread):
                     continue
                 t_frame = (self._rep._clock()
                            if self._rep._h_frame_us is not None else 0.0)
-                rpc_fut = self._send_frame(group, epoch, next_sseq)
+                rpc_fut = self._send_frame(group, epoch, next_sseq, pushed)
                 # repl.send_wait_us: the head entry's enqueue to the
                 # frame's hand-off to the transport (queueing here +
                 # fence read + floor stamp + encode); observed at the
@@ -356,13 +412,15 @@ class _Sender(threading.Thread):
                 # time _send_frame itself takes.
                 inflight.append(
                     [group, next_sseq, rpc_fut, t_frame, time.monotonic(),
-                     self._rep._clock() - group[0][3]]
+                     self._rep._clock() - group[0][3] if group else 0.0,
+                     pushed]
                 )
+                pushed = None
                 next_sseq += 1
             if not inflight:
                 continue
             # -- wait on the OLDEST in-flight frame --
-            group, sseq, rpc_fut, t_frame, t_sent, send_wait = inflight[0]
+            group, sseq, rpc_fut, t_frame, t_sent, send_wait, _ = inflight[0]
             try:
                 resp = rpc_fut.result(timeout=0.1)
             except TimeoutError:
@@ -409,7 +467,11 @@ class _Sender(threading.Thread):
                 # standby_ack_us overlaps away (and pipelining overlaps
                 # across frames too); send_wait is what the group spent
                 # on THIS side before the wire.
-                if self._rep._h_group is not None:
+                if not group:
+                    # A frame sent for a floor alone: not a group commit.
+                    if self._rep._c_floor_frames is not None:
+                        self._rep._c_floor_frames.inc()
+                elif self._rep._h_group is not None:
                     self._rep._h_group.observe_int(len(group))
                     self._rep._h_frame_us.observe(
                         self._rep._clock() - t_frame
@@ -513,11 +575,16 @@ class RoundReplicator:
             # stripe frame bytes under stripes.bytes).
             self._c_bytes = metrics.counter("repl.bytes")
             self._c_retries = metrics.counter("repl.send_retries")
+            # Records-less frames sent for a pushed floor alone
+            # (`push_floor`): only a replicator that stamps floors has
+            # the series.
+            self._c_floor_frames = (metrics.counter("repl.floor_frames")
+                                    if floors_fn is not None else None)
             self._clock = metrics.clock
         else:
             self._h_group = self._h_frame_us = self._h_send_wait_us = None
             self._c_records = self._c_frames = self._c_retries = None
-            self._c_bytes = None
+            self._c_bytes = self._c_floor_frames = None
             self._clock = time.perf_counter
         # Causal-tracing hook (obs/spans.py): the owning broker sets
         # this to its SpanRing when trace sampling is configured; begin()
@@ -597,6 +664,25 @@ class RoundReplicator:
             s.stop()
 
     # -- hot path (DataPlane resolver/settle threads) --
+
+    def push_floor(self, slots) -> None:
+        """The settle release moved the settled floors of `slots`
+        (DataPlane._release_one, after the round's acks went out): owe
+        every set member their stamp now, so a follower's horizon
+        follows the settle by a frame instead of waiting for the next
+        round's records (`_Sender.push_floor`, `_send_frame`). Without
+        a `floors_fn` (follower reads off) nothing is owed and nothing
+        is sent. A joiner gets none: it is not a set member, and the
+        floors name rounds only the members acked."""
+        if self.floors_fn is None or not slots:
+            return
+        t_ns = time.monotonic_ns()
+        members = self.fence()[2]
+        with self._lock:
+            senders = [self._senders.get(b) for b in members]
+        for s in senders:
+            if s is not None:
+                s.push_floor(slots, t_ns)
 
     def begin(self, records: list,
               tctxs: Optional[list] = None) -> "ReplicationTicket":

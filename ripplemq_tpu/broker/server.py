@@ -43,6 +43,7 @@ Broker duties, each a small periodic loop:
 
 from __future__ import annotations
 
+import itertools
 import threading
 import time
 from concurrent.futures import Future
@@ -621,14 +622,7 @@ class BrokerServer:
         self.follower_plane = None
         self._follower_cursors: dict[int, list] = {}
         if config.follower_reads:
-            from ripplemq_tpu.broker.follower import FollowerReadPlane
-
-            self.follower_plane = FollowerReadPlane(
-                config.engine.slot_bytes,
-                config.follower_page_cache_bytes,
-                fetch_fn=(self._fetch_sibling_stripes
-                          if config.replication == "striped" else None),
-            )
+            self._make_follower_plane()
         persist_fn = None
         if data_dir is not None:
             import os
@@ -942,6 +936,20 @@ class BrokerServer:
         dp.replicate_fn = rep.replicate
         dp.replicate_begin_fn = rep.begin
         dp.replicate_wait_fn = rep.wait
+        push = getattr(rep, "push_floor", None)
+        if self.config.follower_reads and push is not None:
+            # The floor of a settled round goes to the standbys from
+            # the release itself (replication.py `push_floor`); what it
+            # costs the settle thread is settle.floor_push_us.
+            hist = self.metrics.histogram("settle.floor_push_us")
+            clock = self.metrics.clock
+
+            def push_timed(slots: list) -> None:
+                t0 = clock()
+                push(slots)
+                hist.observe(clock() - t0)
+
+            dp.floor_push_fn = push_timed
 
     def _make_replicator(self):
         """Replication-plane factory: `replication="full"` streams full
@@ -1095,6 +1103,8 @@ class BrokerServer:
             # Fetches parked on the plane hold RPC workers of this
             # broker: refused now, not at their deadlines.
             self.dataplane.release_parks()
+        if self.follower_plane is not None:
+            self.follower_plane.release_parks("stopped")
         if self._wake_probe is not None:
             from ripplemq_tpu.obs import lockwitness
 
@@ -1168,6 +1178,13 @@ class BrokerServer:
                     # The row cap of one round per partition: what a
                     # batching producer fills a produce.multi part to.
                     "max_batch": self.config.engine.max_batch,
+                    # Broker id -> rack, where the cluster names racks
+                    # (Kafka's broker.rack): a consumer with a
+                    # `client_rack` keeps its session with the leased
+                    # follower of its own.
+                    **({"broker_racks": {str(b): r for b, r in
+                                         self.config.broker_racks}}
+                       if self.config.broker_racks else {}),
                 }
             if t == "meta.propose":
                 return self._handle_meta_propose(req)
@@ -2804,7 +2821,10 @@ class BrokerServer:
         and the reply answers part by part: {"ok": true, "parts":
         [{"ok": true, "messages", "offset", "next_offset"} | {"ok":
         false, "error", ...}]}. A part is answered as a `consume` of it
-        would be, except that none is served by a follower. With `wait_s`
+        would be. A request marked `follower_ok` that reaches a standby
+        holding a current-epoch lease is served whole from its follower
+        read plane instead (`_follower_fetch`: a rack-aware consumer's
+        session, client/consumer.py `client_rack`). With `wait_s`
         the REQUEST long-polls: if every part was admitted and every
         read came back empty it parks once, for all its parts, and is
         answered when rows settle past the position of ANY of them, or
@@ -2815,6 +2835,10 @@ class BrokerServer:
         parts = req.get("parts")
         if not isinstance(parts, list) or not parts:
             return {"ok": False, "error": "bad_request: empty parts"}
+        if req.get("follower_ok") and self.follower_plane is not None:
+            resp = self._follower_fetch(req, parts)
+            if resp is not None:
+                return resp
         t0 = self.metrics.clock()
         self._parked_tls.s = 0.0
         sp = (self.spans.span("rpc.recv", ctx_from_wire(req.get("tctx")),
@@ -2885,14 +2909,8 @@ class BrokerServer:
         if fp is None:
             return None
         slot = self.manager.slot_of(key)
-        if slot is None:
+        if slot is None or self._follower_epoch() is None:
             return None
-        epoch = self.manager.current_epoch()
-        if self.manager.follower_lease(self.broker_id) != epoch:
-            return None
-        fp.note_epoch(epoch)  # fence the plane even before new frames
-        if fp.epoch() != epoch:
-            return None  # cached bytes are another generation's
         offset = int(req["offset"])
         if offset < 0:
             return {"ok": False, "error": "bad_request: negative offset"}
@@ -2943,6 +2961,232 @@ class BrokerServer:
         fsp.end(rows=len(msgs))
         return {"ok": True, "messages": msgs, "offset": offset,
                 "next_offset": next_offset, "follower": True}
+
+    def _make_follower_plane(self) -> None:
+        """The follower read plane of this broker and the series of
+        the requests it serves (`follower_reads` on)."""
+        from ripplemq_tpu.broker.follower import FollowerReadPlane
+
+        self.follower_plane = FollowerReadPlane(
+            self.config.engine.slot_bytes,
+            self.config.follower_page_cache_bytes,
+            fetch_fn=(self._fetch_sibling_stripes
+                      if self.config.replication == "striped" else None),
+            clock=self.metrics.clock,
+        )
+        # A consume.multi served HERE, from the plane
+        # (`_follower_fetch`): the twins of the leader's fetch.*
+        # series - requests, how their parks went, requests whose
+        # reply held a row, parts refused to the leader - and the
+        # floor's own: stamps against frames with records, and how
+        # long after the controller's settle release a pushed floor
+        # reached the plane (both stamps time.monotonic_ns, so one
+        # machine's). follower.serve_us is the request less its park.
+        m = self.metrics
+        self._m_ff_requests = m.counter("follower.fetch_requests")
+        self._m_ff_parked = m.counter("follower.fetch_parked")
+        self._m_ff_woken = m.counter("follower.fetch_woken")
+        self._m_ff_expired = m.counter("follower.fetch_expired")
+        self._m_ff_answered = m.counter("follower.fetch_answered")
+        self._m_ff_refused = m.counter("follower.fetch_refused_parts")
+        self._m_ff_park_us = m.histogram("follower.park_us")
+        self._m_ff_wake_late_us = m.histogram("follower.wake_late_us")
+        self._m_ff_serve_us = m.histogram("follower.serve_us")
+        self._m_ff_floors = m.counter("follower.floors")
+        self._m_ff_rounds = m.counter("follower.rounds")
+        self._m_ff_floor_lag_us = m.histogram("follower.floor_lag_us")
+        # Ordinals of the requests served and the pushed floors
+        # received here: what a traced broker samples its own
+        # follower.fetch / follower.floor roots by (a consumer that
+        # does not trace sends no context to hang them on).
+        self._ff_ordinal = itertools.count()
+        self._floor_ordinal = itertools.count()
+
+    def _follower_epoch(self) -> Optional[int]:
+        """The controller epoch this broker may answer follower reads
+        under, or None: no plane, no lease of the current epoch, or a
+        plane whose bytes are another generation's. Asked per answer
+        (and again after a park): a deposed standby stops serving the
+        instant the handover applies, before its plane even resets."""
+        fp = self.follower_plane
+        if fp is None:
+            return None
+        epoch = self.manager.current_epoch()
+        if self.manager.follower_lease(self.broker_id) != epoch:
+            return None
+        fp.note_epoch(epoch)  # fence the plane even before new frames
+        if fp.epoch() != epoch:
+            return None  # cached bytes are another generation's
+        return epoch
+
+    def _follower_park_duty(self) -> None:
+        """A fetch parked on the follower plane stands on this broker's
+        lease: gone (or the epoch moved, which `_follower_epoch` tells
+        the plane), its reader is refused to the leader now and not at
+        its deadline."""
+        fp = self.follower_plane
+        if (fp is not None and fp.parked()
+                and self._follower_epoch() is None):
+            fp.release_parks("lease")
+
+    def _follower_fetch(self, req: dict, parts: list) -> Optional[dict]:
+        """A consume.multi served by THIS standby from its follower read
+        plane (Kafka's KIP-392 fetch from the in-rack replica), or None
+        when it is in no position to (no lease of the current epoch;
+        `replication: striped`, which decodes on read and keeps the
+        single `consume`): the ordinary path then answers part by part,
+        `not_leader` with the hint for every partition another broker
+        leads. Every part is at an explicit offset (one without is
+        refused to the leader, whose committed offset decides it) and
+        takes ONE look at its partition; all reads are the plane's,
+        strictly below its replicated settled floor, every answer that
+        hands out rows or an advance through `audit_answer`; a part the
+        plane cannot prove settled is refused with `not_settled_here:`
+        and the leader's hint, its siblings served; the reply is marked
+        `follower`. With `wait_s`, every part admitted and every read
+        empty at the floor, the request parks ONCE for all its parts on
+        the plane's waiter (`FollowerReadPlane.park`) and is answered
+        when a floor stamp passes the position of ANY part - lease and
+        epoch checked again before the rows go out - or at the deadline
+        (clipped by `_LONG_POLL_CAP_S`), empty; an epoch change, a lost
+        lease or a stop refuses the whole request. A parked request
+        keeps its RPC worker, here and not on the controller."""
+        from ripplemq_tpu.broker.follower import ParkRefused
+
+        if self.config.replication == "striped":
+            return None
+        epoch = self._follower_epoch()
+        if epoch is None:
+            return None
+        fp, clock = self.follower_plane, self.metrics.clock
+        t0 = clock()
+        ctx = ctx_from_wire(req.get("tctx"))
+        if ctx is None and self.spans is not None:
+            # A consumer that does not trace sent no context: every
+            # trace_sample_n-th request served here roots its own.
+            tid = derive_trace_id(f"follower.fetch/broker{self.broker_id}",
+                                  next(self._ff_ordinal))
+            if sampled(tid, self.config.trace_sample_n):
+                ctx = TraceContext(tid, 0)
+        sp = (self.spans.span("follower.fetch", ctx, {"parts": len(parts)})
+              if self.spans is not None else NULL_SPAN)
+        self._m_ff_requests.inc()
+        answers: list = [None] * len(parts)
+        items: list = []  # [index, slot, first offset, limit, its look]
+        for i, part in enumerate(parts):
+            try:
+                key = group_key(part["topic"], part["partition"])
+                view = self.manager.peek(key)
+                offset, limit = part.get("offset"), part.get("max_messages")
+                refusal = self._gen_refusal(part, key, view)
+                if refusal is None and view[1] is None:
+                    refusal = {"ok": False,
+                               "error": f"unknown_partition: {key}"}
+                if refusal is None and offset is None:
+                    refusal = {"ok": False, "error": "not_leader",
+                               **self._leader_hint(view)}
+                if refusal is None and int(offset) < 0:
+                    refusal = {"ok": False,
+                               "error": "bad_request: negative offset"}
+                if refusal is not None:
+                    answers[i] = refusal
+                    continue
+                items.append([i, view[1], int(offset),
+                              None if limit is None else int(limit), view])
+            except (KeyError, ValueError, TypeError) as e:
+                answers[i] = self._part_refusal(e)
+
+        def read(positions: list) -> list:
+            out = []
+            for (_, slot, _, limit, _), pos in zip(items, positions):
+                got = fp.read(slot, pos, limit, tail_ok=True)
+                if (got is not None and got[1] != pos
+                        and not fp.audit_answer(slot, pos, got[1])):
+                    got = None  # the last-line witness (see audit_answer)
+                out.append(got)
+            return out
+
+        wait_s = (float(req.get("wait_s", 0) or 0)
+                  if len(items) == len(parts) else 0.0)
+        deadline = time.monotonic() + min(wait_s, self._LONG_POLL_CAP_S)
+        got = read([item[2] for item in items])
+        outcome, parked_s, why = None, 0.0, None
+        while (wait_s > 0 and got
+               and all(g is not None and not g[0] for g in got)):
+            left = deadline - time.monotonic()
+            if left <= 0:
+                outcome = "expired"
+                break
+            tp = clock()
+            try:
+                t_wake = fp.park([(item[1], g[1])
+                                  for item, g in zip(items, got)],
+                                 left, epoch)
+            except ParkRefused as e:
+                t_wake, why = None, str(e)
+            tw = clock()
+            parked_s += tw - tp
+            if sp.ctx is not None:
+                self.spans.span_at("follower.park", sp.ctx, tp, tw - tp)
+            if why is None and t_wake is not None and (
+                    self._stop.is_set() or self._follower_epoch() != epoch):
+                why = "lease"
+            if why is not None:
+                break
+            if t_wake is None:
+                outcome = "expired"
+                break
+            outcome = "woken"
+            # Read again, each part from where its last read ended; an
+            # empty-but-advanced answer keeps its advance.
+            got = read([g[1] for g in got])
+            late = clock() - t_wake
+            self._m_ff_wake_late_us.observe(late)
+            if sp.ctx is not None:
+                self.spans.span_at("follower.wake", sp.ctx, t_wake, late)
+        if parked_s:
+            self._m_ff_parked.inc()
+            self._m_ff_park_us.observe(parked_s)
+            if outcome is not None and why is None:
+                (self._m_ff_woken if outcome == "woken"
+                 else self._m_ff_expired).inc()
+        if why is not None:
+            sp.end(error=f"released: {why}")
+            return {"ok": False,
+                    "error": f"not_settled_here: the parked fetch was "
+                             f"released ({why}); read from the leader"}
+        served = refused = rows = 0
+        for (i, slot, offset, _, view), g in zip(items, got):
+            if g is None:
+                refused += 1
+                answers[i] = {
+                    "ok": False,
+                    "error": f"not_settled_here: slot {slot} offset "
+                             f"{offset} cannot be proved settled by this "
+                             f"standby",
+                    **self._leader_hint(view),
+                }
+            else:
+                answers[i] = {"ok": True, "messages": g[0],
+                              "offset": offset, "next_offset": g[1]}
+                if g[0]:
+                    served += 1
+                    rows += len(g[0])
+        if served:
+            self._m_ff_answered.inc()
+        if refused:
+            self._m_ff_refused.inc(refused)
+        self._m_ff_serve_us.observe(clock() - t0 - parked_s)
+        sp.end(served=served, refused=refused, rows=rows)
+        return {"ok": True, "parts": answers, "follower": True}
+
+    def _leader_hint(self, view) -> dict:
+        """The `leader` / `leader_addr` of a refusal that sends its
+        reader to the partition's leader (`view`: PartitionManager.peek)."""
+        leader = view[0].leader if view[0] else None
+        return {"leader": leader,
+                "leader_addr": (self._addr_of(leader)
+                                if leader is not None else None)}
 
     def _fetch_sibling_stripes(self, min_gsn: int) -> list:
         """FollowerReadPlane.fetch_fn (striped reconstruct-on-read):
@@ -4133,6 +4377,12 @@ class BrokerServer:
             # pre-floor senders — the plane then holds rows it cannot
             # yet serve, which is the safe direction).
             fp.ingest_rounds(epoch, recs, req.get("floors"))
+            if recs:
+                self._m_ff_rounds.inc()
+            if req.get("floors"):
+                self._m_ff_floors.inc()
+            if req.get("floor_t_ns") is not None:
+                self._note_floor_lag(int(req["floor_t_ns"]))
             if self.hostplane is not None:
                 # Worker-plane fan-out: mirror the replicated rows into
                 # the owning worker so follower reads ride the same
@@ -4163,6 +4413,23 @@ class BrokerServer:
             flush()
             self._repl_last_flush = now
         return {"ok": True}
+
+    def _note_floor_lag(self, t_push_ns: int) -> None:
+        """A pushed floor has passed on the plane: observe how long
+        after the controller's settle release (its time.monotonic_ns,
+        this one's now - one machine's clock, as the benchmark's
+        delivery stamp is; across machines the number means nothing and
+        judges nothing). Every trace_sample_n-th is a follower.floor
+        span of that length, ending now."""
+        lag_us = max(0, (time.monotonic_ns() - t_push_ns) // 1000)
+        self._m_ff_floor_lag_us.observe_int(lag_us)
+        if self.spans is not None:
+            tid = derive_trace_id(f"follower.floor/broker{self.broker_id}",
+                                  next(self._floor_ordinal))
+            if sampled(tid, self.config.trace_sample_n):
+                self.spans.span_at("follower.floor", TraceContext(tid, 0),
+                                   self.metrics.clock() - lag_us / 1e6,
+                                   lag_us / 1e6)
 
     def _handle_repl_stripes(self, req: dict) -> dict:
         """Standby side of STRIPED replication (stripes/plane.py): the
@@ -4342,6 +4609,7 @@ class BrokerServer:
                 self._standby_duty()
                 self._quota_share_duty()
                 self._follower_lease_duty()
+                self._follower_park_duty()
                 self._reconfig_duty()
                 self._autosplit_duty()
                 self._shard_duty()

@@ -140,6 +140,7 @@ def _config_yaml_dict(config: ClusterConfig) -> dict:
         "pid_retention_s": config.pid_retention_s,
         "follower_reads": config.follower_reads,
         "follower_page_cache_bytes": config.follower_page_cache_bytes,
+        "broker_racks": {b: r for b, r in config.broker_racks},
         # The batcher operating point and worker sizing used to be
         # dropped here: an in-proc soak and its subprocess twin ran
         # DIFFERENT coalesce/chain/pipeline shapes whenever a test

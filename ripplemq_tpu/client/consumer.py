@@ -84,8 +84,31 @@ an empty window if `long_poll_s` is set; its commits ride the same
 per-leader pipeline. Every lever is opt-in and independently A/B-able
 against the legacy one-RPC-per-call behavior.
 
+Rack-aware reads (`client_rack="<rack>"`, Kafka's KIP-392
+`client.rack`; needs a cluster with `follower_reads` and `broker_racks`,
+and readahead): the SESSION above - and with `long_poll_s` its parked
+fetch - is kept with the leased follower of the client's own rack
+instead of each partition's leader, while one exists
+(`MetadataManager.rack_follower`: sticky, not a draw a call): every
+partition of the session in ONE `consume.multi` marked `follower_ok`,
+which that standby serves whole from its follower read plane and parks
+on its own settled floor (broker/server.py `_follower_fetch`). This is
+the mode for a consumer that TAILS from the in-rack replica. The first
+read of a partition (the broker's committed offset decides its
+position), a part the follower refuses (`not_settled_here:`) and every
+commit go to the leader. A follower that stops answering, loses its
+lease or changes epoch costs one metadata refresh and a fall back to the
+leaders for `_RACK_RETRY_S`, never an error to the caller; positions are
+the client's own and an answer counts only for a part still where the
+fetch was sent for, so what `consume` hands out has no gap and no repeat
+whoever served it. `client_rack` takes the session and leaves
+`follower_reads` (below) without effect; a client without it behaves as
+before.
+
 Follower reads (`follower_reads=True`, needs a cluster running with the
-broker-side knob on): EXPLICIT-OFFSET reads route to a standby broker
+broker-side knob on; the BACKLOG fan-out mode, for many cursors catching
+up - a tailing consumer wants `client_rack` above): EXPLICIT-OFFSET
+reads route to a standby broker
 holding a current-epoch follower-read lease (meta.topics advertises the
 lease table), spreading a backlog fan-out over the standby set instead
 of funneling every cursor through the leader. Safety lives broker-side
@@ -134,6 +157,10 @@ _ANSWER_MAX_AGE_S = 0.06
 # A partition not polled for this long leaves the session: wall time
 # (`_Part.seen`), since it is about a caller that went away.
 _SESSION_IDLE_S = 5.0
+# How long a rack-aware client stays with the leaders after its rack's
+# follower failed it (`_rack_failed`) before it looks at the lease table
+# again.
+_RACK_RETRY_S = 2.0
 
 
 class ConsumeError(Exception):
@@ -144,15 +171,18 @@ class _Part:
     """One (topic, partition) of the session."""
 
     __slots__ = ("pos", "limit", "addr", "answer", "polled", "seen",
-                 "fetching", "joined")
+                 "fetching", "joined", "via_follower")
 
     def __init__(self, limit: int, addr: Optional[str], now: float,
                  asked: float) -> None:
         self.pos: Optional[int] = None  # next read position; None: the
         #                                 broker's committed offset decides
         self.limit = limit              # the caller's max_messages
-        self.addr = addr                # its leader; None: the single-
+        self.addr = addr                # where its session is: its
+        #                                 leader, or the client's in-rack
+        #                                 follower; None: the single-
         #                                 partition path re-resolves it
+        self.via_follower = False       # `answer` came from a follower
         self.answer: Optional[tuple[list, int, int]] = None  # not handed
         #                       out yet: (messages, offset, next_offset)
         self.polled = asked             # when the caller last asked, on
@@ -168,10 +198,12 @@ class _Parked:
     future, the parts it lists - each with the position and window it
     was sent for - when it went out, and its client.rpc span."""
 
-    __slots__ = ("fut", "parts", "sent", "rpc")
+    __slots__ = ("fut", "parts", "sent", "rpc", "follower")
 
-    def __init__(self, fut, parts: list, sent: float, rpc) -> None:
+    def __init__(self, fut, parts: list, sent: float, rpc,
+                 follower: bool = False) -> None:
         self.fut, self.parts, self.sent, self.rpc = fut, parts, sent, rpc
+        self.follower = follower  # sent to the in-rack follower
 
 
 class _LeaderCommits:
@@ -206,6 +238,7 @@ class ConsumerClient:
         long_poll_s: float = 0.0,
         follower_reads: bool = False,
         trace_sample_n: int = 0,
+        client_rack: Optional[str] = None,
     ) -> None:
         self._transport = transport if transport is not None else TcpClient()
         self._owns_transport = transport is None
@@ -215,7 +248,11 @@ class ConsumerClient:
         self.max_messages = max_messages
         self.prefetch = max(0, int(prefetch))
         self.long_poll_s = max(0.0, float(long_poll_s))
-        self.follower_reads = bool(follower_reads)
+        # Rack-aware session (module docstring); it takes the session,
+        # so the per-partition `_pf` mode of follower_reads is off.
+        self.client_rack = str(client_rack) if client_rack else None
+        self._rack_down_until = 0.0
+        self.follower_reads = bool(follower_reads) and not self.client_rack
         self._timeout = rpc_timeout_s
         # Follower routing's position hint: last delivered next_offset
         # per (topic, partition). Only a HINT — the leader's
@@ -451,8 +488,35 @@ class ConsumerClient:
         if part.answer is None:
             return None
         msgs, offset, next_offset = part.answer
+        if part.via_follower:
+            part.via_follower = False
+            if msgs:
+                self.follower_served += 1
+                self.last_from_follower = True
         return self._deliver(topic, partition, part.addr, limit, call_async,
                              msgs, offset, next_offset)
+
+    # ------------------------------------------- the in-rack follower
+
+    def _rack_addr(self, now: float) -> Optional[str]:
+        """Where a rack-aware client's session is kept: the leased
+        follower of its rack, None without one (or without a rack, or
+        while the follower is held to have failed)."""
+        if self.client_rack is None or now < self._rack_down_until:
+            return None
+        return self._meta.rack_follower(self.client_rack)
+
+    def _rack_failed(self, addr: str) -> None:
+        """The in-rack follower did not answer, or answered as a broker
+        without a lease does: its partitions go back through the
+        single-partition path to their leaders, the session stays with
+        the leaders for `_RACK_RETRY_S`, and one refresh fetches the
+        lease table that says why."""
+        self._rack_down_until = self._clock() + _RACK_RETRY_S
+        for q in self._sess.values():
+            if q.addr == addr:
+                q.addr = None
+        self._refresh_quietly()
 
     def _fetch(self, key: tuple[str, int], part: _Part,
                before: Optional[float], now: float, call_async) -> None:
@@ -494,6 +558,12 @@ class ConsumerClient:
                     "max_messages": q.limit,
                     **({} if q.pos is None else {"offset": q.pos})}
                    for k, q in parts]}
+        # To the in-rack follower only at explicit offsets: a first
+        # read is its leader's, which `addr` then is.
+        follower = (addr == self._rack_addr(now)
+                    and all(q.pos is not None for _, q in parts))
+        if follower:
+            req["follower_ok"] = True
         self._drive_commits(addr, call_async)
         rpc = NULL_SPAN if self.spans is None else \
             self.spans.span("client.rpc", self._trace_root.ctx)
@@ -503,16 +573,23 @@ class ConsumerClient:
             resp = self._transport.call(addr, req, timeout=self._timeout)
         except RpcError as e:
             rpc.end(error=type(e).__name__)
+            if follower:
+                self._rack_failed(addr)
             return
         rpc.end()
         answers = resp.get("parts") if resp.get("ok") else None
         if not isinstance(answers, list) or len(answers) != len(parts):
+            answers = None
+        if follower and (answers is None or not resp.get("follower")):
+            self._rack_failed(addr)
+        if answers is None:
             return
         for (_, q), ans in zip(parts, answers):
             if ans.get("ok"):
                 offset = int(ans["offset"])
                 q.answer = (list(ans["messages"]), offset,
                             int(ans.get("next_offset", offset)))
+                q.via_follower = bool(resp.get("follower"))
             else:
                 q.addr = None  # refused: its next poll goes the single path
 
@@ -563,7 +640,14 @@ class ConsumerClient:
                "parts": [{"topic": k[0], "partition": k[1],
                           "max_messages": limit, "offset": pos}
                          for k, _, pos, limit in parts]}
-        self._drive_commits(addr, call_async)
+        follower = addr == self._rack_addr(now)
+        if follower:
+            # The in-rack follower serves it whole from its own plane
+            # and parks it on its own settled floor; every leader's
+            # parked commits go out with it, since none has a fetch.
+            req["follower_ok"] = True
+        for a in list(self._commits) if follower else (addr,):
+            self._drive_commits(a, call_async)
         rpc = NULL_SPAN if self.spans is None else \
             self.spans.span("client.rpc", self._trace_root.ctx)
         if rpc.ctx is not None:
@@ -574,10 +658,12 @@ class ConsumerClient:
             rpc.end(error=type(e).__name__)
             for _, q, _, _ in parts:
                 q.addr = None  # the single-partition path re-resolves
+            if follower:
+                self._rack_failed(addr)
             return
         for _, q, _, _ in parts:
             q.fetching = True
-        self._parked[addr] = _Parked(fut, parts, now, rpc)
+        self._parked[addr] = _Parked(fut, parts, now, rpc, follower)
 
     def _absorb(self, addr: str, now: float) -> None:
         """Take the answer of the leader's parked fetch, if it has come:
@@ -605,6 +691,12 @@ class ConsumerClient:
         answers = resp.get("parts") if resp.get("ok") else None
         if not isinstance(answers, list) or len(answers) != len(f.parts):
             answers = [{}] * len(f.parts)
+            resp = {}
+        if f.follower and not resp.get("follower"):
+            # No answer, a refusal of the whole request (its park was
+            # released: the lease went, the epoch moved, the broker
+            # stops), or the answer of a broker without a lease.
+            self._rack_failed(addr)
         for (k, q, pos, limit), ans in zip(f.parts, answers):
             q.fetching = False
             if (self._sess.get(k) is not q or q.pos != pos
@@ -618,6 +710,7 @@ class ConsumerClient:
             msgs = list(ans["messages"])
             if msgs:
                 q.answer = (msgs, offset, next_offset)
+                q.via_follower = bool(resp.get("follower"))
             else:
                 q.pos = max(pos, next_offset)
 
@@ -715,6 +808,17 @@ class ConsumerClient:
         quorum-replicated fact only the leader accepts)."""
         commit_addr = addr
         session = False
+        if self.client_rack is not None:
+            # Commits to the leader always. Rows handed out: the
+            # session goes on with the in-rack follower while there is
+            # one, else the leader. An empty answer leaves the
+            # partition where it was answered: one the follower refused
+            # and the leader has nothing for either (the follower has
+            # no floor for it yet) waits in the LEADER's fetch for its
+            # first rows, instead of going round between the two.
+            commit_addr = self._meta.leader_addr(topic, pid) or addr
+            if msgs:
+                addr = self._rack_addr(self._clock()) or commit_addr
         if self.follower_reads:
             self._pos[(topic, pid)] = int(next_offset)
             commit_addr = self._meta.leader_addr(topic, pid) or addr

@@ -72,6 +72,9 @@ class MetadataManager:
         # re-checks per answer anyway, this just avoids pointless trips.
         self._follower_leases: dict[int, int] = {}
         self._controller_epoch: int = -1
+        # Broker id -> rack, where the cluster names racks (meta.topics
+        # `broker_racks`): what `rack_follower` chooses by.
+        self._broker_racks: dict[int, str] = {}
         # Key-range routing index per topic: (sorted range starts, the
         # non-retired assignments in that order). Rebuilt lazily after
         # anything replaced the topic (refresh, adopt_routing).
@@ -143,6 +146,9 @@ class MetadataManager:
                     self._follower_leases = leases
                     self._controller_epoch = int(
                         resp.get("controller_epoch", -1))
+                    self._broker_racks = {
+                        int(b): str(r) for b, r in
+                        dict(resp.get("broker_racks") or {}).items()}
                 return
             except (RpcError, MetadataError, KeyError, ValueError) as e:
                 run.note(f"{type(e).__name__}: {e}")
@@ -183,6 +189,21 @@ class MetadataManager:
         if not addrs:
             return None
         return self._rng.choice(addrs)
+
+    def rack_follower(self, rack: str) -> Optional[str]:
+        """Address of the broker of `rack` that
+        holds a current-epoch follower-read lease - the lowest id where
+        a rack has several, so that every look gives the same answer
+        while the table stands (a rack-aware consumer's session stays
+        where it is) - or None: no such rack, or no leased follower in
+        it (the rack is the controller's, or its standby is gone)."""
+        with self._lock:
+            for b in sorted(self._follower_leases):
+                if (self._follower_leases[b] == self._controller_epoch
+                        and self._broker_racks.get(b) == rack
+                        and b in self._brokers):
+                    return self._brokers[b].address
+        return None
 
     def leader_addr(self, topic: str, partition_id: int) -> Optional[str]:
         with self._lock:

@@ -291,6 +291,15 @@ class ClusterConfig:
     # pages are re-fetched/re-decoded on demand (striped) or refused to
     # the leader (full).
     follower_page_cache_bytes: int = 32 << 20
+    # Racks (Kafka's `broker.rack`, KIP-392): ((broker id, rack), ...),
+    # sorted by id; in a cluster file `broker_racks: {id: rack, ...}`,
+    # a key of its own beside `brokers`. `meta.topics` advertises the
+    # map, and a consumer built with `client_rack` keeps its session
+    # with the leased follower of its own rack (client/consumer.py);
+    # needs `follower_reads` to have a follower to go to. A broker the
+    # map leaves out has no rack and is no consumer's in-rack replica.
+    # Empty (default): no rack anywhere, as before.
+    broker_racks: tuple = ()
     # Consume-side SLO twin of slo_p99_ack_ms: the consume-ack p99
     # target in MILLISECONDS. > 0 makes the SLO controller AIMD-steer
     # read_coalesce_s against this target alongside the produce loop
@@ -507,6 +516,15 @@ class ClusterConfig:
                 "reads are served from the standbys' replicated copies "
                 "(with no standbys there is nobody to lease)"
             )
+        ids = set(self.broker_ids())
+        for entry in self.broker_racks:
+            if (not isinstance(entry, tuple) or len(entry) != 2
+                    or entry[0] not in ids
+                    or not isinstance(entry[1], str) or not entry[1]):
+                raise ValueError(
+                    f"broker_racks entry {entry!r}: want (id of a "
+                    f"configured broker, non-empty rack name)"
+                )
         if self.slo_p99_consume_ms < 0:
             raise ValueError("slo_p99_consume_ms must be >= 0 (0 disables)")
         if self.slo_p99_consume_ms > 0 and not self.obs:
@@ -656,6 +674,10 @@ def parse_cluster_config(raw: dict) -> ClusterConfig:
     if "follower_page_cache_bytes" in raw:
         extra["follower_page_cache_bytes"] = int(
             raw["follower_page_cache_bytes"])
+    if "broker_racks" in raw:
+        extra["broker_racks"] = tuple(sorted(
+            (int(b), str(r))
+            for b, r in dict(raw["broker_racks"] or {}).items()))
     # SLO autopilot knobs (float rails + the int chain/window rails +
     # the tenant-quota mapping, normalized to a sorted tuple so the
     # frozen config stays hashable-by-structure and round-trips the

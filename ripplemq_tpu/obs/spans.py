@@ -122,6 +122,16 @@ SPAN_KINDS = frozenset({
     # Follower reads: serve from replicated bytes, including a
     # stripe-reconstruct-on-read when the local copy is a stripe set.
     "follower.serve", "stripe.reconstruct",
+    # A consume.multi served whole by a leased standby (broker/server.py
+    # _follower_fetch; a rack-aware consumer's session): follower.fetch
+    # is the request - under the client's context, or a root of the
+    # broker's own for every trace_sample_n-th request when the client
+    # sent none - follower.park its stand on the plane's waiter,
+    # follower.wake the floor stamp that ended a park to the rows in
+    # hand. follower.floor is a root of its own: the controller's
+    # settle release to the pushed floor passing on this standby's
+    # plane (one machine's monotonic clock; every trace_sample_n-th).
+    "follower.fetch", "follower.park", "follower.wake", "follower.floor",
     # A long-polling consume / consume.multi parked on the plane until
     # rows settle or its wait lapses (broker/server.py _fetch): a child
     # of the request's rpc.recv, so that span's self time is the
