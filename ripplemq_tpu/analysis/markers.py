@@ -47,7 +47,7 @@ FAST_MODULES = {
     "test_fence_view",          # ~8 s: fence view units + two 3-broker drives
     "test_follower_reads",      # ~50 s: plane/lease units, 2-mode byte
                                 # identity, 3 chaos smokes (1 proc)
-    "test_gather",              # ~8 s: bare planes on a hand-moved clock
+    "test_gather",              # ~12 s: bare planes on a hand-moved clock
     "test_graft",
     "test_group_waves",         # ~5 s: wave-apply units + one cluster run
     "test_groups",              # ~30 s: coordinator units + one cluster run

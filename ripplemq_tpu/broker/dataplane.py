@@ -128,8 +128,10 @@ _PID_WINDOW = 8
 
 # The longest the step thread sleeps inside an open gather before it
 # looks at the queues again (DataPlane._gather): max_batch pendings end a
-# gather early and a slot a resolver freed may hold an older batch.
-# Also the most it gathers after a launch that outlasted coalesce_s.
+# gather early and a slot a resolver freed may hold an older batch (the
+# release of the last round out does not wait for a slice: it wakes the
+# gather). Also the least a gather lasts after a launch's return,
+# whether the release ends it or the launch outlasted coalesce_s.
 _GATHER_SLICE_S = 0.004
 
 
@@ -276,10 +278,12 @@ class DataPlane:
         self._m_h2d_bytes = m.counter("round.h2d_bytes")
         self._m_pipeline_full = m.counter("round.pipeline_full")
         # How often the gather engages (_gather): live rounds launched
-        # with no append in them, and loop tops that found the deadline
-        # already past and drained without a lap.
+        # with no append in them, loop tops that found the deadline
+        # already past and drained without a lap, and gathers that the
+        # release of the last round out ended before their deadline.
         self._m_offsets_only = m.counter("round.offsets_only")
         self._m_gather_expired = m.counter("round.gather_expired")
+        self._m_gather_early = m.counter("round.gather_early")
         # What a round is staged as against what it carries (_drain):
         # the appending partitions of each live round, and the rows of
         # the [A, B, SB] block stack that holds them (A the active-set
@@ -557,6 +561,9 @@ class DataPlane:
             "DataPlane._device_lock")          # every touch of self._state
         self._work = threading.Event()
         self._stop = threading.Event()
+        # What an open gather sleeps on (_gather): set by the release
+        # that leaves no round out (_round_back) and by stop().
+        self._gather_wake = threading.Event()
         # The parked fetches, by slot (a park of many slots is in each of
         # their lists), and their number. A lock of their own: a park
         # registers, stands and leaves without `_lock`, and the settle
@@ -632,11 +639,13 @@ class DataPlane:
         self._offsets_shadow = np.zeros(
             (cfg.partitions, cfg.max_consumers), np.int32
         )
-        # Coalescing window: the least time between the starts of two
-        # rounds (_gather_left), hence the longest a queued batch waits
-        # for company, counted from its submit, so a whole burst of
-        # concurrent producers lands in ONE round — every round costs a
-        # full host↔device sync to resolve. 0 disables.
+        # Coalescing window: the longest a queued batch waits for
+        # company, counted from its submit or from the previous round's
+        # start (_gather_left). A round starts when the one before it
+        # has been released, so a burst of concurrent producers lands in
+        # ONE round while the pipeline is busy — every round costs a
+        # full host↔device sync to resolve — and at the latest
+        # coalesce_s after the previous one started. 0 disables.
         self.coalesce_s = coalesce_s
         self._inflight: "queue.Queue[tuple[StepInput, dict, object]]" = (
             queue.Queue(maxsize=self.pipeline_depth)
@@ -686,6 +695,15 @@ class DataPlane:
         # its log end). Seqs are assigned by the step thread.
         self._dispatch_seq = 0
         self._next_turn = 0
+        # Rounds BACK: dispatches that have left the pipeline, released
+        # by the settle thread or failed on the way (_round_back, under
+        # self._lock). The step thread counts rounds out by
+        # `_dispatch_seq`; where the two are equal no round is between
+        # launch and release, and an open gather ends (_gather_left).
+        self._rounds_back = 0
+        # The step thread's own: whether the gather end `_gather_left`
+        # last reported is the release's and not the deadline's.
+        self._ends_early = False
         self._turnstile = make_condition("DataPlane._turnstile")
         # Occupancy counters (bench/admin surface): depth is sampled at
         # each settle enqueue; backpressure counts enqueues that found
@@ -730,6 +748,7 @@ class DataPlane:
     def stop(self) -> None:
         self._stop.set()
         self._work.set()
+        self._gather_wake.set()
         self._read_work.set()
         self.release_parks()  # before the joins: nothing will settle now
         # A never-started plane (boot failed between construction and
@@ -2555,27 +2574,37 @@ class DataPlane:
 
     def _gather_left(self, t_launch: float,
                      t_return: float) -> Optional[float]:
-        """Seconds until the open gather's deadline (zero or less once
-        it has passed), or None where none is open: nothing drainable,
-        or max_batch drainable appends already. `t_launch` is when the
+        """Seconds until the open gather ends (zero or less once it
+        has), or None where none is open: nothing drainable, or
+        max_batch drainable appends already. `t_launch` is when the
         step thread started its previous launch, `t_return` when it
         came back from it.
 
-        A round starts coalesce_s after the previous one STARTED: the
+        A round starts when the one before it has been RELEASED: while
+        a round is between launch and release (`_dispatch_seq` ahead of
+        `_rounds_back`) another would only queue behind it on the one
+        settle thread, so what comes meanwhile is gathered and rides
+        ONE round; once none is out, drainable appends go. Rounds so
+        come no faster than the settle thread retires them, whatever
+        the load.
+
+        coalesce_s is the longest a batch waits for company: the
+        DEADLINE is coalesce_s after the previous round STARTED - the
         launch, the hand-off to the resolvers and whatever else the
-        thread did since are time gathered, not time added to the
-        wait, and rounds come no closer than coalesce_s whatever the
-        load. Nothing queued waits for company longer than coalesce_s
-        from its own submit (a batch freed from a busy slot, a requeued
-        retry) - but for one slice after a launch's return: that is
-        when the acks a round set loose bring their producers' next
-        requests, and a round started without them is a small round
-        (PR 25's lesson; ref-compose.sync, PERF.md section 6).
+        thread did since are time gathered, not time added to the wait
+        - or after the oldest drainable submit where that is older (a
+        batch freed from a busy slot, a requeued retry), and it ends
+        the gather whatever is still out. Neither end comes sooner than
+        one slice after a launch's return: that is when the acks a
+        round set loose bring their producers' next requests, and a
+        round started without them is a small round (PR 25's lesson;
+        ref-compose.sync, PERF.md section 6).
 
         Only pendings on non-busy slots count: queues behind an
         in-flight round cannot be drained this iteration, so waiting
         for them would delay the drainable work for nothing. Offset
-        commits wait for the same deadline and ride the round."""
+        commits alone end no gather early: they wait for the deadline
+        and ride whichever round goes first."""
         npend, anchor = 0, t_launch
         with self._lock:
             for slot, q in self._appends.items():
@@ -2587,28 +2616,37 @@ class DataPlane:
             if not npend and not any(
                     slot not in self._busy_o for slot in self._offsets):
                 return None
+            released = self._rounds_back == self._dispatch_seq
         if npend >= self.cfg.max_batch:
             return None
-        deadline = max(anchor + self.coalesce_s,
-                       t_return + min(self.coalesce_s, _GATHER_SLICE_S))
-        return deadline - self._clock()
+        after_return = t_return + min(self.coalesce_s, _GATHER_SLICE_S)
+        deadline = max(anchor + self.coalesce_s, after_return)
+        now = self._clock()
+        early = released and npend > 0
+        # Whose end a return of zero or less reports (_gather).
+        self._ends_early = early and now < deadline
+        return (after_return if early else deadline) - now
 
     def _gather(self, lap, t_launch: float, t_return: float) -> None:
         """Wait out the open gather, one round.coalesce lap a slice:
         nothing is launched inside it, so what is queued meanwhile
-        rides ONE round. stop() cuts a slice short."""
+        rides ONE round. The release of the last round out and stop()
+        cut a slice short."""
         lapped = False
         while not self._stop.is_set():
+            self._gather_wake.clear()
             left = self._gather_left(t_launch, t_return)
             if left is None:
                 return
             if left <= 0:
-                if not lapped:
+                if self._ends_early:
+                    self._m_gather_early.inc()
+                elif not lapped:
                     self._m_gather_expired.inc()
                 return
             lap.to(self._st_coalesce)
             lapped = True
-            self._stop.wait(min(left, _GATHER_SLICE_S))
+            self._gather_wake.wait(min(left, _GATHER_SLICE_S))
 
     def _run(self) -> None:
         """Step thread: drain → dispatch → hand off to the resolver.
@@ -2626,8 +2664,9 @@ class DataPlane:
                           blocks at pipeline_depth outstanding rounds;
                           round.pipeline_full counts those)
         - round.coalesce  the gather: laps of at most _GATHER_SLICE_S
-                          until coalesce_s after the previous launch
-                          started (`_gather_left`)
+                          until the round before is released, at most
+                          until coalesce_s after it started
+                          (`_gather_left`)
         - round.drain     `_drain()`: queues to device-shaped arrays
         - round.lock_wait waiting for `_device_lock`
         - round.launch    the launch call under the lock (histogram
@@ -2845,6 +2884,8 @@ class DataPlane:
             with self._lock:
                 self._busy_a -= ctx["appends"].keys()
                 self._busy_o -= ctx["offsets"].keys()
+            if entry is None:
+                self._round_back(windowed=False)  # failed: no release
 
     def _enqueue_settle(self, entry: tuple) -> None:
         """Start the entry's standby replication (non-blocking when the
@@ -3057,9 +3098,23 @@ class DataPlane:
                                  fenced=self._settle_fenced)
             self._fail_committed(ctx, committed, e)
         finally:
-            with self._lock:
-                self._settle_inflight -= 1
+            self._round_back(windowed=True)
             self._settle_sem.release()
+
+    def _round_back(self, windowed: bool) -> None:
+        """One dispatch has left the pipeline: released (or failed) by
+        the settle thread, `windowed`, or failed by its resolver before
+        it entered the settle window. Where that leaves no round out,
+        an open gather is woken to end (`_gather_left`)."""
+        with self._lock:
+            if windowed:
+                self._settle_inflight -= 1
+            self._rounds_back += 1
+            # `_dispatch_seq` is the step thread's: a stale read here
+            # wakes a gather for nothing, it misses no wake.
+            none_out = self._rounds_back == self._dispatch_seq
+        if none_out:
+            self._gather_wake.set()
 
     def _emit_stage_spans(self, ctx: dict, t_wait: float, t_acked: float,
                           t_persist: float, t_rel: float) -> None:

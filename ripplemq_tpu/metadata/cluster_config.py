@@ -125,11 +125,13 @@ class ClusterConfig:
     store_retention_bytes: int | None = None
     # Batcher operating point (defaults favour ack latency; what they
     # cost on the chip is PERF.md section 5):
-    # - coalesce_s: the least time between the starts of two rounds,
-    #   hence the longest a queued batch waits for company, counted
-    #   from its submit (each dispatch costs a host-device launch; a
-    #   round's own launch is time gathered for the next); 0 = no
-    #   gather.
+    # - coalesce_s: the longest a queued batch waits for company,
+    #   counted from its submit or from the previous round's start (a
+    #   round's own launch is time gathered for the next). A round
+    #   starts when the one before it has been released, no sooner
+    #   than one slice after its launch returned, and at the latest
+    #   then (each dispatch costs a host-device launch and a turn of
+    #   the one settle thread); 0 = no gather.
     # - chain_depth: complete quorum rounds per device launch for deep
     #   backlogs (lax.scan; amortizes the launch).
     # - pipeline_depth: outstanding launches before dispatch

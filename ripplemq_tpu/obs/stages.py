@@ -64,7 +64,8 @@ from typing import Callable, Optional
 STAGE_NAMES = frozenset({
     # The step thread (broker/dataplane.py _run), a partition of its
     # time: waiting for work or for room in the resolver pipeline, the
-    # gather (laps up to coalesce_s past the last launch), building the
+    # gather (laps until the round before is released, at most until
+    # coalesce_s after it started), building the
     # round, waiting for the device lock, the launch call (histogram:
     # engine.dispatch_us).
     "round.idle", "round.coalesce", "round.drain", "round.lock_wait",

@@ -1,12 +1,16 @@
-"""The step thread's gather (DataPlane._run, PR 32): a round starts
-coalesce_s after the previous one STARTED, waited for in slices - not
-a sleep that begins when the thread happens to look, so a launch, an
+"""The step thread's gather (DataPlane._run; PR 32, PR 51): a round
+starts when the one before it has been RELEASED, and at the latest
+coalesce_s after that one STARTED, waited for in slices - not a sleep
+that begins when the thread happens to look, so a launch, an
 offsets-only round or a late wake-up is time gathered, not time added.
 
 Every case runs on a clock the test moves by hand (the registry's
 injectable clock), so an open gather stays open until the test says
 otherwise and nothing here sleeps for a coalesce window: the plane's
-own laps are one slice (4 ms) each."""
+own laps are one slice (4 ms) each. The deadline's cases (PR 32's) run
+behind a round that is kept out (`hold_rounds_out`): with none out a
+drainable append goes one slice after the launch's return, which is
+what the last test of this file is about."""
 
 import threading
 import time
@@ -39,13 +43,16 @@ class HandClock:
 class Rig:
     """A bare local plane on a hand clock, leaders set, NOT started."""
 
-    def __init__(self, coalesce_s: float = COALESCE_S) -> None:
+    def __init__(self, coalesce_s: float = COALESCE_S,
+                 held: bool = False) -> None:
         self.clock = HandClock()
         self.metrics = Metrics(clock=self.clock)
         self.dp = DataPlane(small_cfg(), mode="local", max_retry_rounds=3,
                             metrics=self.metrics, coalesce_s=coalesce_s)
         for slot in range(4):
             self.dp.set_leader(slot, 0, 1)
+        # `held`: every round stays out until the test calls `release`.
+        self.release = hold_rounds_out(self.dp) if held else None
 
     def prime(self) -> None:
         """Start the plane and put one round behind it, launched at the
@@ -67,6 +74,12 @@ class Rig:
         time.sleep(SETTLE_S)
         return self.dp.dispatches == dispatches
 
+    def back(self, rounds: int) -> bool:
+        """`rounds` dispatches have left the pipeline: an ack goes out
+        a moment BEFORE its round is counted back."""
+        return wait_until(lambda: self.dp._rounds_back == rounds,
+                          timeout=30, interval=0.002)
+
 
 def hold_first_launch(dp):
     """Stub the launch: the FIRST one signals `inside` and stays there
@@ -84,9 +97,36 @@ def hold_first_launch(dp):
     return inside, leave
 
 
+def hold_rounds_out(dp):
+    """Stub the rounds' way back: they settle and ack as ever, but none
+    is counted back (and no gather is woken) before the test calls the
+    `release` this returns - the state of a round between its launch
+    and its release, for as long as a test needs it."""
+    real, guard = dp._round_back, threading.Lock()
+    owed: list[bool] = []
+    released = []
+
+    def held_back(windowed):
+        with guard:
+            if not released:
+                owed.append(windowed)
+                return
+        real(windowed)
+
+    def release():
+        with guard:
+            released.append(True)
+        while owed:
+            real(owed.pop())
+
+    dp._round_back = held_back
+    return release
+
+
 @pytest.fixture()
 def rig():
-    r = Rig()
+    """A rig whose rounds stay out: the deadline ends every gather."""
+    r = Rig(held=True)
     yield r
     r.dp.stop()
 
@@ -228,8 +268,7 @@ def test_stop_cuts_a_gather_short(rig, monkeypatch):
     time.sleep(0.1)  # a lap is booked when it ends: this one has not
     assert rig.laps() == 0 and rig.dp.dispatches == 1
     t0 = time.perf_counter()
-    rig.dp._stop.set()
-    rig.dp._thread.join(timeout=30)
+    rig.dp.stop()
     assert not rig.dp._thread.is_alive()
     assert time.perf_counter() - t0 < 5.0  # a quarter of ONE slice
     assert rig.laps() == 1
@@ -266,6 +305,7 @@ def test_gather_runs_on_a_real_clock_when_the_registry_is_off():
                    obs=False, coalesce_s=0.5)
     for slot in range(4):
         dp.set_leader(slot, 0, 1)
+    hold_rounds_out(dp)  # the deadline, not the first round's release
     dp.start()
     try:
         assert dp.submit_append(0, [b"m0"]).result(timeout=30) == 0
@@ -275,3 +315,165 @@ def test_gather_runs_on_a_real_clock_when_the_registry_is_off():
         assert dp.rounds == 2  # the five gathered half a second, together
     finally:
         dp.stop()
+
+
+# ---- a round starts when the one before it has been released (PR 51)
+
+
+def _released_round_costs_one_slice(r, monkeypatch):
+    """(i) behind a RELEASED round an append goes one slice after the
+    launch's return, with no coalesce_s waited."""
+    r.prime()
+    assert r.back(1)
+    fut = r.dp.submit_append(0, [b"m0"])     # 100: launch and return
+    assert r.holds() and not fut.done() and r.laps() > 0
+    r.clock.advance(dataplane_mod._GATHER_SLICE_S)
+    assert fut.result(timeout=30) == 0
+    assert r.counter("round.gather_early") == 1
+    assert r.counter("round.gather_expired") == 1  # the priming round's
+    wait = r.metrics.histogram("produce.queue_wait_us")
+    assert (wait.count, wait.total) == (2, 4000)
+
+
+def _release_wakes_the_gather(r, monkeypatch):
+    """(ii) behind an unreleased round the append waits, slice and all;
+    the release ends the gather there and then - with a slice of 20 s
+    real time, in a fraction of one: the settle thread wakes it."""
+    monkeypatch.setattr(dataplane_mod, "_GATHER_SLICE_S", 20.0)
+    r.prime()
+    r.clock.advance(30.0)     # a slice past the return, 70 to go
+    fut = r.dp.submit_append(0, [b"m0"])
+    time.sleep(0.1)  # a lap is booked when it ends: this one has not
+    assert r.laps() == 0 and r.dp.dispatches == 1 and not fut.done()
+    t0 = time.perf_counter()
+    r.release()
+    assert fut.result(timeout=30) == 0
+    assert time.perf_counter() - t0 < 5.0  # a quarter of ONE slice
+    assert r.laps() == 1
+    assert r.counter("round.gather_early") == 1
+
+
+def _never_released_goes_at_the_deadline(r, monkeypatch):
+    """(iii) a count of rounds back that never moves leaves the
+    deadline to end the gather: the parent's timing."""
+    r.prime()
+    fut = r.dp.submit_append(0, [b"m0"])
+    r.clock.advance(COALESCE_S - 0.5)
+    assert r.holds() and not fut.done()
+    r.clock.advance(0.5)
+    assert fut.result(timeout=30) == 0
+    assert r.counter("round.gather_early") == 0
+    assert r.counter("round.gather_expired") == 1  # lapped: no more
+
+
+def _a_commit_alone_ends_no_gather_early(r, monkeypatch):
+    """(iv) behind a released round a commit still waits for the
+    deadline (f'); an append that comes meanwhile goes early and takes
+    it along."""
+    r.prime()
+    assert r.back(1)
+    r.clock.advance(1.0)                     # 101: the slice is over
+    off = r.dp.submit_offsets(1, [(3, 1)])
+    assert r.holds() and not off.done() and r.laps() > 0
+    app = r.dp.submit_append(0, [b"m0"])
+    assert app.result(timeout=30) == 0 and off.result(timeout=30) is True
+    assert r.dp.rounds == 2
+    assert r.counter("round.offsets_only") == 0
+    assert r.counter("round.gather_early") == 1
+    assert r.back(2)
+    r.clock.advance(1.0)                     # 102, round two from 101
+    off = r.dp.submit_offsets(1, [(3, 2)])
+    r.clock.advance(COALESCE_S - 1.5)
+    assert r.holds(2) and not off.done()
+    r.clock.advance(0.5)                     # 111 = 101 + coalesce_s
+    assert off.result(timeout=30) is True
+    assert r.counter("round.offsets_only") == 1
+    assert r.counter("round.gather_early") == 1
+
+
+def _a_window_within_a_slice_is_never_early(r, monkeypatch):
+    """(v) coalesce_s no longer than a slice: one window after the
+    launch's return IS the deadline, released round or not (the launch
+    of a second: test_one_slice_more_after_a_launch_longer_than_the_
+    window, which runs with nothing held)."""
+    r.prime()
+    assert r.back(1)
+    fut = r.dp.submit_append(0, [b"m0"])
+    assert r.holds() and not fut.done() and r.laps() > 0
+    r.clock.advance(0.002)
+    assert fut.result(timeout=30) == 0
+    assert r.counter("round.gather_early") == 0
+    assert r.counter("round.gather_expired") == 1
+
+
+class _Unfetchable:
+    """A `committed` output whose host fetch fails."""
+
+    def __array__(self, *args, **kwargs):
+        raise RuntimeError("fetch failed")
+
+
+def _fail_the_launch(dp):
+    def no_launch(*args):
+        raise RuntimeError("launch failed")
+
+    dp.fns = dp.fns._replace(step_sparse=no_launch)
+
+
+def _fail_the_fetch(dp):
+    real = dp.fns.step_sparse
+
+    def launch(*args):
+        state, out = real(*args)
+        return state, out._replace(committed=_Unfetchable())
+
+    dp.fns = dp.fns._replace(step_sparse=launch)
+
+
+def _fail_the_settle(dp):
+    def no_standby(records):
+        raise RuntimeError("standby lost")
+
+    dp.replicate_fn = no_standby
+
+
+def _a_failed_round_counts_as_back(fail, out):
+    """(vi) a round that ends in a step error (its launch: never handed
+    on, `out` 0), a resolve error (its fetch) or a settle failure
+    leaves nothing out: the next gather ends one slice after the failed
+    launch's return, if it came to one."""
+    def case(r, monkeypatch):
+        real_fns, real_replicate = r.dp.fns, r.dp.replicate_fn
+        fail(r.dp)
+        r.dp.start()
+        with pytest.raises(Exception, match="failed|standby lost"):
+            r.dp.submit_append(3, [b"lost"]).result(timeout=30)
+        assert r.back(out) and r.dp._dispatch_seq == out
+        r.dp.fns, r.dp.replicate_fn = real_fns, real_replicate
+        r.clock.advance(1.0)
+        assert r.dp.submit_append(0, [b"m0"]).result(timeout=30) == 0
+        assert r.laps() == 0
+        assert r.counter("round.gather_early") == 1
+
+    return case
+
+
+@pytest.mark.parametrize("case,rig_kw", [
+    (_released_round_costs_one_slice, {}),
+    (_release_wakes_the_gather, {"coalesce_s": 100.0, "held": True}),
+    (_never_released_goes_at_the_deadline, {"held": True}),
+    (_a_commit_alone_ends_no_gather_early, {}),
+    (_a_window_within_a_slice_is_never_early, {"coalesce_s": 0.002}),
+    (_a_failed_round_counts_as_back(_fail_the_launch, 0), {}),
+    (_a_failed_round_counts_as_back(_fail_the_fetch, 1), {}),
+    (_a_failed_round_counts_as_back(_fail_the_settle, 1), {}),
+], ids=["released_one_slice", "release_wakes", "never_released_deadline",
+        "commit_alone_waits", "window_within_a_slice", "failed_launch",
+        "failed_fetch", "failed_settle"])
+def test_a_round_starts_when_the_one_before_is_released(case, rig_kw,
+                                                         monkeypatch):
+    r = Rig(**rig_kw)
+    try:
+        case(r, monkeypatch)
+    finally:
+        r.dp.stop()

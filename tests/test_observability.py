@@ -792,15 +792,18 @@ def test_step_thread_round_stages_add_up_to_elapsed_time():
     produce.queue_wait_us holds one observation per drained pending.
     PR 32: the gather is many short laps up to a deadline counted from
     the previous launch's start, each its own round.coalesce
-    observation, and the closure holds across them."""
+    observation, and the closure holds across them. (PR 51: behind a
+    round that is out; a released one ends the gather in a lap.)"""
     from ripplemq_tpu.broker.dataplane import DataPlane
     from ripplemq_tpu.obs.metrics import Metrics
     from tests.helpers import small_cfg
+    from tests.test_gather import hold_rounds_out
 
     clock, seen = _half_second_clock()
     m = Metrics(clock=clock)
     dp = DataPlane(small_cfg(), mode="local", max_retry_rounds=3,
                    metrics=m, coalesce_s=100.0)
+    hold_rounds_out(dp)
     dp.start()
     try:
         dp.set_leader(0, 0, 1)
