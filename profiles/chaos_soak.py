@@ -75,12 +75,6 @@ def main() -> int:
                          "(stripe_kill / stripe_partition, sized to m); "
                          "the checker holds the run to the k-of-k+m "
                          "durability contract")
-    ap.add_argument("--host-workers", type=int, default=1,
-                    help="run every broker with N host-plane worker "
-                         "subprocesses (parallel/hostplane.py): "
-                         "produces stamp/pack through the shared-memory "
-                         "rings, controller consumes serve off the "
-                         "settled mirror; works on both backends")
     ap.add_argument("--timeline", action="store_true",
                     help="attach the merged fault-vs-lifecycle timeline "
                          "(nemesis fault ops + every broker's flight-"
@@ -183,7 +177,6 @@ def main() -> int:
             include_timeline=args.timeline,
             include_postmortems=args.postmortems,
             lock_witness=args.witness,
-            host_workers=args.host_workers,
             slo=args.slo,
             follower_reads=args.follower_reads,
             splits=args.splits,

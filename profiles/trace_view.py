@@ -14,10 +14,8 @@ and tree join). Two modes:
            +0.115ms rpc.recv        ...  [broker0]
            ...
 
-   `--host-workers N` boots the multi-core host plane so the trees
-   include the shm-ring worker hop (worker.serve/validate/stamp/pack in
-   the worker subprocess's own clock domain); `--striped` switches
-   replication to the striped plane (stripe.send/stripe.apply spans).
+   `--striped` switches replication to the striped plane
+   (stripe.send/stripe.apply spans).
 
 2. Offline (`--from-json FILE`): render traces from records on disk —
    either a bare JSON list of span records, or a chaos verdict (the
@@ -28,7 +26,7 @@ No wall clocks anywhere: every placement is in the root span's
 monotonic domain via the assembler's NTP-style per-process offsets.
 
 Run: python profiles/trace_view.py
-     python profiles/trace_view.py --host-workers 2 --striped
+     python profiles/trace_view.py --striped
      python profiles/trace_view.py --from-json verdict.json
 """
 
@@ -73,8 +71,6 @@ def _live(args) -> list[dict]:
     from ripplemq_tpu.client.producer import ProducerClient
 
     kw = dict(obs=True, trace_sample_n=1)
-    if args.host_workers > 1:
-        kw["host_workers"] = args.host_workers
     if args.striped:
         kw["replication"] = "striped"
     cfg = make_cluster_config(n_brokers=3, **kw)
@@ -113,9 +109,6 @@ def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--messages", type=int, default=5,
                     help="sampled produces to run in live mode")
-    ap.add_argument("--host-workers", type=int, default=1,
-                    help="boot the multi-core host plane (worker hop "
-                         "spans cross the shm ring)")
     ap.add_argument("--striped", action="store_true",
                     help="striped replication (stripe.send/apply spans)")
     ap.add_argument("--from-json", default=None, metavar="FILE",

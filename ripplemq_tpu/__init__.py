@@ -46,11 +46,9 @@ __all__ = [
 
 def __getattr__(name):
     # Lazy re-exports (PEP 562): importing the package must not pull
-    # jax. The multi-core host plane SPAWNS worker subprocesses whose
-    # import chain runs through this module — an eager `from
-    # ripplemq_tpu.core import ...` charged every worker boot (and
-    # every client-only import) the full ~4 s jax initialization for
-    # symbols the worker never touches.
+    # jax — an eager `from ripplemq_tpu.core import ...` charged every
+    # client-only import the full ~4 s jax initialization for symbols
+    # it never touches.
     if name in __all__:
         from ripplemq_tpu import core
 

@@ -43,6 +43,7 @@ FAST_MODULES = {
     "test_core_step",
     "test_dataplane",
     "test_degradation",
+    "test_deployment_files",    # ~1 s: every benchmark config, parsed
     "test_failover",
     "test_fence_view",          # ~8 s: fence view units + two 3-broker drives
     "test_follower_reads",      # ~50 s: plane/lease units, 2-mode byte
@@ -51,8 +52,6 @@ FAST_MODULES = {
     "test_graft",
     "test_group_waves",         # ~5 s: wave-apply units + one cluster run
     "test_groups",              # ~30 s: coordinator units + one cluster run
-    "test_hostplane",           # ~15 s: worker spawns are jax-free (~100 ms)
-    "test_hostplane_chaos",     # ~35 s: one seeded run + prefix parity
     "test_hostraft",
     "test_idempotence",         # ~25 s: dedup units + failover replay
     "test_keyed_producer",      # ~10 s: send()/produce.multi vs the reference
@@ -72,7 +71,7 @@ FAST_MODULES = {
     "test_model_check",
     "test_multichip_smoke",     # tier-1 fused-spmd canary on the 8-dev mesh
     "test_spans",               # ~25 s: span units + one proc-backend
-                                # acceptance tree (2 workers, striped)
+                                # acceptance tree (striped)
     "test_observability",
     "test_op_split",
     "test_packaging",
@@ -95,7 +94,6 @@ FAST_MODULES = {
     "test_retry_policy",
     "test_rs",
     "test_shard_distribution",
-    "test_shmring",             # ~5 s: in-process ring framing units
     "test_soak",                # ~15 s: the bounded hand-written soak
     "test_spmd",
     "test_staging",             # ~25 s: bare planes, not started; five launches

@@ -1,11 +1,10 @@
 """Thread inventory: every thread in the repo, derived from its spawn
 site, closed over the repo call graph, documented in the README.
 
-The multi-core split (ROADMAP "Multi-core host plane") is a refactor of
-the most lock-dense code in the repo — ~20 `threading.Thread` spawn
-sites across the dataplane pipeline, the replication senders, the
-stripes encoder, the segment-store flusher, hostraft, transports, and
-duty loops. Before moving any of them into worker subprocesses, the
+The host path is the most lock-dense code in the repo — ~20
+`threading.Thread` spawn sites across the dataplane pipeline, the
+replication senders, the stripes encoder, the segment-store flusher,
+hostraft, transports, and duty loops. Before any of them moves, the
 repo needs a MECHANICAL answer to "which code runs on which thread":
 
 - Spawn sites are DERIVED, not hand-listed: `threading.Thread(target=

@@ -382,7 +382,7 @@ class DataPlane:
             if host_read_cache else None
         )
         # The same bytes, flat: what `_cache_rows` copies a window from.
-        self._host_ring_bytes = (
+        self._host_ring_flat = (
             None if self._host_ring is None
             else memoryview(self._host_ring).cast("B")
         )
@@ -446,15 +446,6 @@ class DataPlane:
         # (FencedError ⊂ NotCommittedError → producers retry at the new
         # controller).
         self.replicate_fn = replicate_fn
-        # Host-plane settled-mirror hook (parallel/hostplane.py): when
-        # the broker runs worker subprocesses, the settle thread
-        # publishes each durably-settled round's REC_APPEND rows to the
-        # owning worker so consume reads for that slice are served off
-        # this process's GIL. Fire-and-forget BY CONTRACT — the hook
-        # must never block settle (HostPlane.publish drops on a full
-        # ring; the worker's contiguity check turns drops into clean
-        # engine-read fallbacks).
-        self.mirror_fn = None
         # Pipelined-settle split of replicate_fn (RoundReplicator.begin/
         # wait): `begin` enqueues a round's records on every standby
         # stream without blocking; `wait` blocks until all member acks.
@@ -1190,8 +1181,11 @@ class DataPlane:
         rows = self._check_and_pack(slot, payloads, fut)
         if rows is None:
             return fut
-        return self._submit_rows(slot, list(payloads), rows, pid, seq, fut,
-                                 tctx)
+        with self._lock:
+            fut = self._enqueue_locked(slot, list(payloads), rows, int(pid),
+                                       int(seq), fut, tctx)
+        self._work.set()
+        return fut
 
     def submit_appends(self, items: list) -> list[Future]:
         """`submit_append` for MANY batches at once — the parts of one
@@ -1262,55 +1256,6 @@ class DataPlane:
                 TypeError(f"payloads must be bytes: {e}")
             )
             return None
-
-    def submit_packed(self, slot: int, packed, lens: list[int],
-                      pid: int = 0, seq: int = -1, tctx=None) -> Future:
-        """Queue a PRE-PACKED append batch: `packed` is the
-        `[len(lens), slot_bytes]` row block a host-plane worker already
-        validated and packed (parallel/hostplane.py `_pack_rows`, the
-        byte-identical twin of pack_payload_rows) — the payload bytes
-        cross this boundary once and are never re-encoded. Semantics
-        are submit_append's exactly; validation here is only the cheap
-        structural re-check (the block shape), since the worker ran the
-        per-message checks where packing ran."""
-        fut: Future = Future()
-        cfg = self.cfg
-        SB = cfg.slot_bytes
-        k = len(lens)
-        if not 0 <= slot < cfg.partitions:
-            fut.set_exception(ValueError(f"partition slot {slot} out of range"))
-            return fut
-        if k == 0 or k > cfg.max_batch or len(packed) != k * SB:
-            fut.set_exception(ValueError(
-                f"packed block of {len(packed)} bytes does not hold "
-                f"{k} rows of {SB} (max_batch {cfg.max_batch})"
-            ))
-            return fut
-        if k and (min(lens) <= 0 or max(lens) > cfg.payload_bytes):
-            fut.set_exception(ValueError(
-                f"packed row lengths out of (0, {cfg.payload_bytes}]"
-            ))
-            return fut
-        rows = np.frombuffer(packed, np.uint8).reshape(k, SB)
-        # Zero-copy payload views into the block (the drain only ever
-        # len()s and persists them; the block itself is what rides the
-        # round).
-        mv = memoryview(packed)
-        payloads = [
-            mv[i * SB + _HDR : i * SB + _HDR + lens[i]] for i in range(k)
-        ]
-        return self._submit_rows(slot, payloads, rows, pid, seq, fut, tctx)
-
-    def _submit_rows(self, slot: int, payloads: list, rows,
-                     pid: int, seq: int, fut: Future,
-                     tctx=None) -> Future:
-        """Shared enqueue tail of submit_append / submit_packed (the
-        caller validated and packed)."""
-        with self._lock:
-            fut = self._enqueue_locked(slot, payloads, rows, int(pid),
-                                       int(seq), fut, tctx)
-        self._work.set()
-        return fut
 
     def _enqueue_locked(self, slot: int, payloads: list, rows, pid: int,
                         seq: int, fut: Future, tctx=None) -> Future:
@@ -1829,7 +1774,7 @@ class DataPlane:
         the interpreter, and a reader of many partitions then stands in
         line for it once a part (PERF.md section 6, PR 45)."""
         S, SB = self.cfg.slots, self.cfg.slot_bytes
-        ring = self._host_ring_bytes
+        ring = self._host_ring_flat
         base, pos = slot * S, offset % S
         if pos + k <= S:
             return bytes(ring[(base + pos) * SB : (base + pos + k) * SB])
@@ -3028,11 +2973,6 @@ class DataPlane:
             # settled-gap structure remains the full fix if soaks flag
             # it.)
             self._mirror_records(records)
-            mirror_fn = self.mirror_fn
-            if mirror_fn is not None:
-                for rec_type, slot, base, payload in records:
-                    if rec_type == REC_APPEND:
-                        mirror_fn(slot, base, payload)
             advanced: list[tuple[int, int]] = []
             with self._lock:
                 for k, rc in enumerate(chain):
@@ -3124,7 +3064,7 @@ class DataPlane:
         untraced PLANE (spans is None) never reaches here. All
         timestamps are metrics.clock() = perf_counter, the span ring's
         own domain. Stage spans are siblings under the produce path's
-        span (rpc.recv / worker.hop) that submitted the batch."""
+        span (rpc.recv) that submitted the batch."""
         t0 = ctx.get("t_dispatch")
         if t0 is None:
             return
@@ -3588,9 +3528,13 @@ class DataPlane:
                             ents = self._pid_tab.setdefault(
                                 (pend.pid, slot), []
                             )
-                            ents.append(
-                                (pend.seq, pend.seq + n,
-                                 int(base[slot]) + start)
+                            # By sequence, not by arrival: a chain's
+                            # rounds are released newest first, and
+                            # the probe reads the table's end off
+                            # its last entry.
+                            bisect.insort(
+                                ents, (pend.seq, pend.seq + n,
+                                       int(base[slot]) + start)
                             )
                             del ents[:-_PID_WINDOW]
                             self._pid_inflight.pop(

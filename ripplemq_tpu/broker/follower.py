@@ -118,8 +118,7 @@ class _Park:
 
 
 class _SlotRun:
-    """One slot's newest contiguous run of replicated settled rows —
-    the same window discipline as the host plane's `_SlotMirror`: a
+    """One slot's newest contiguous run of replicated settled rows: a
     publish landing past the end restarts the run (correctness lives in
     the refusal upstream), eviction raises the start."""
 
@@ -502,12 +501,12 @@ class FollowerReadPlane:
         """Last-line safety witness at the answer boundary: True iff
         the window ABOUT TO BE SERVED lies at-or-below the slot's
         settled floor. Every follower answer passes through here
-        regardless of which path produced it (own cache, gap skip, or
-        the worker-plane mirror) — a False means some serving path's
-        own fence failed; the caller must refuse, and the miss is
-        counted (`answers_past_floor` in stats()) so the chaos harness
-        can hold the run to follower-answers-≤-floor as a first-class
-        violation rather than trusting the fences it is testing."""
+        regardless of which path produced it (own cache or gap skip) —
+        a False means some serving path's own fence failed; the caller
+        must refuse, and the miss is counted (`answers_past_floor` in
+        stats()) so the chaos harness can hold the run to
+        follower-answers-≤-floor as a first-class violation rather than
+        trusting the fences it is testing."""
         with self._lock:
             floor = self._floor.get(int(slot))
             ok = (floor is not None and int(offset) < floor
@@ -515,21 +514,6 @@ class FollowerReadPlane:
             if not ok:
                 self._past_floor += 1
             return ok
-
-    def validate_window(self, slot: int, offset: int, next_offset: int
-                        ) -> bool:
-        """True iff [offset, next_offset) lies strictly below the
-        slot's floor and outside every known gap — the fence applied to
-        answers served from the shared worker-plane mirror instead of
-        this plane's own cache."""
-        with self._lock:
-            floor = self._floor.get(int(slot))
-            if floor is None or offset >= floor or next_offset > floor:
-                return False
-            for s, e in self._gaps.get(int(slot), ()):
-                if s < next_offset and offset < e:
-                    return False
-            return True
 
     # --------------------------------------- striped reconstruct-on-read
 

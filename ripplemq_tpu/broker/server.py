@@ -77,13 +77,11 @@ from ripplemq_tpu.broker.manager import (
     ConsumerTableFullError,
     PartitionManager,
 )
-from ripplemq_tpu.storage.segment import REC_APPEND
 from ripplemq_tpu.groups.coordinator import GroupLiveness
 from ripplemq_tpu.groups.state import group_consumer_name
 from ripplemq_tpu.metadata.cluster_config import ClusterConfig
 from ripplemq_tpu.metadata.models import group_key, topics_to_wire
 from ripplemq_tpu.utils.logs import get_logger
-from ripplemq_tpu.wire import codec
 from ripplemq_tpu.wire.retry import RetryPolicy
 from ripplemq_tpu.wire.transport import (
     InProcNetwork,
@@ -97,6 +95,18 @@ log = get_logger("broker")
 
 
 _RESOLVE = object()  # "look the local plane up yourself" (_quorum_refusal)
+
+# Control-plane wave batching (_batch_duty): the membership/pid commands
+# a broker receives ride ONE OP_BATCH proposal per window, or as soon as
+# _META_BATCH_MAX are queued (bounds the proposal's payload and what a
+# full queue adds to its oldest waiter's latency).
+_META_BATCH_S = 0.05
+_META_BATCH_MAX = 256
+# Heartbeat relay (_beats_relay_duty): ONE group.beats frame per interval
+# carries a broker's buffered member beats to the metadata leader: this
+# long, or a quarter of group_session_timeout_s where that is shorter
+# (a beat relayed late in a short session keeps nobody alive).
+_HEARTBEAT_RELAY_S = 0.5
 
 
 class _UpstreamRefusal(Exception):
@@ -340,7 +350,6 @@ class BrokerServer:
         from ripplemq_tpu.obs.spans import SpanRing
         self.spans = (
             SpanRing(f"broker{broker_id}",
-                     capacity=config.span_ring_slots,
                      clock=self.metrics.clock, metrics=self.metrics)
             if config.trace_sample_n > 0 else None
         )
@@ -426,7 +435,6 @@ class BrokerServer:
             self._tcp_server = TcpServer(
                 self.info.host, self.info.port, self.dispatch,
                 workers=config.rpc_workers,
-                raw_handler=self._raw_produce,
                 metrics=self.metrics,
             )
 
@@ -521,8 +529,8 @@ class BrokerServer:
         self._group_empty_since: dict[str, float] = {}
         # --- control-plane wave batching (_batch_duty) ---
         # Membership/pid commands received by THIS broker queue here and
-        # ride ONE OP_BATCH proposal per wave (meta_batch_s cadence, or
-        # early at meta_batch_max) instead of one raft proposal each.
+        # ride ONE OP_BATCH proposal per wave (_META_BATCH_S cadence, or
+        # early at _META_BATCH_MAX) instead of one raft proposal each.
         # Each entry carries the waiter its RPC handler blocks on until
         # the wave is proposed. Both locks are leaves: never held across
         # a propose/RPC, so they stay out of every existing lock order.
@@ -530,7 +538,7 @@ class BrokerServer:
         self._intake: list[tuple[dict, _WaveWaiter]] = []
         # Serializes wave formation: waves must reach the metadata
         # leader in FIFO intake order (an enqueue that hits
-        # meta_batch_max drains inline, racing the duty tick).
+        # _META_BATCH_MAX drains inline, racing the duty tick).
         self._intake_drain_lock = make_lock(
             "BrokerServer._intake_drain_lock"
         )
@@ -542,7 +550,7 @@ class BrokerServer:
         # --- heartbeat relay plane (_beats_relay_duty) ---
         # Member heartbeats are ANSWERED locally from the replicated
         # group view and the per-member stamps buffered here; one
-        # group.beats frame per heartbeat_relay_s carries them to the
+        # group.beats frame per relay interval carries them to the
         # metadata leader's liveness ledger — leader heartbeat RPC load
         # is O(brokers), not O(members).
         self._beat_lock = make_lock("BrokerServer._beat_lock")
@@ -572,10 +580,8 @@ class BrokerServer:
         import uuid as _uuid
 
         self._broker_pid: Optional[int] = None
-        # Per-boot nonce shared by the broker stamping pid AND the
-        # host-plane workers' per-(worker, generation) pids: restarts
-        # and worker respawns must never reuse a pid whose sequence
-        # counters they lost (_worker_pid_duty).
+        # Per-boot nonce: a restart must never reuse a pid whose
+        # sequence counters it lost.
         self._pid_nonce = _uuid.uuid4().hex[:12]
         self._broker_pid_name = (
             f"_broker/{broker_id}/{self._pid_nonce}"
@@ -584,29 +590,6 @@ class BrokerServer:
         self._broker_pid_refreshed = 0.0
         self._stamp_lock = make_lock("BrokerServer._stamp_lock")
         self._stamp_seqs: dict[int, int] = {}
-        # --- multi-core host plane (parallel/hostplane.py) ---
-        # host_workers > 1 boots worker subprocesses owning disjoint
-        # partition-group slices of the host path: produce validation +
-        # pid/seq stamping + payload packing, and settled-mirror consume
-        # serving on the controller. Built here, started in start() —
-        # worker boots are async (~100 ms spawn of a jax-free module),
-        # so construction never blocks on them.
-        self.hostplane = None
-        self._worker_pid_names: dict[int, tuple[int, str]] = {}
-        self._worker_pids: dict[int, int] = {}
-        self._worker_pid_proposed: dict[int, float] = {}
-        if config.host_workers > 1:
-            from ripplemq_tpu.parallel.hostplane import HostPlane
-
-            self.hostplane = HostPlane(
-                config.host_workers,
-                slot_bytes=config.engine.slot_bytes,
-                payload_bytes=config.engine.payload_bytes,
-                max_batch=config.engine.max_batch,
-                ring_bytes=config.host_ring_bytes,
-                recorder=self.recorder,
-                spans=self.spans,
-            )
         # Pipelined replication stream gate (see _ReplStreamGate): the
         # standby side of repl.rounds applies frames in per-stream
         # sequence order while the sender keeps a window in flight.
@@ -687,8 +670,6 @@ class BrokerServer:
         if dataplane is not None:
             self.dataplane = dataplane
             self.manager.attach_dataplane(dataplane)
-            if self.hostplane is not None:
-                dataplane.mirror_fn = self._mirror_publish
             if dataplane.replicate_fn is None and self._round_store is not None:
                 self._wire_replicator(dataplane)
         # No construction-time boot when this broker's (possibly
@@ -855,8 +836,6 @@ class BrokerServer:
             )
             if image is not None:
                 dp.install(image, settled_gaps=gaps, pid_table=pid_tab)
-            if self.hostplane is not None:
-                dp.mirror_fn = self._mirror_publish
             if self._round_store is not None:
                 self._wire_replicator(dp)
             self._owns_dataplane = True
@@ -1069,8 +1048,6 @@ class BrokerServer:
         self._started = True
         if self._wake_probe is not None:
             self._wake_probe.start()
-        if self.hostplane is not None:
-            self.hostplane.start()
         if self._net is not None:
             self._net.register(self.addr, self.dispatch)
         else:
@@ -1131,8 +1108,6 @@ class BrokerServer:
             self.dataplane.stop()
         if self._owns_store and self._round_store is not None:
             self._round_store.close()
-        if self.hostplane is not None:
-            self.hostplane.stop()
         self.client.close()
         self._raft_client.close()
 
@@ -1412,23 +1387,15 @@ class BrokerServer:
             ],
             "stripe_rebuilds": self._stripe_rebuilds,
         }
-        # Multi-core host plane liveness/occupancy (null when
-        # host_workers == 1 — no subprocess plane).
-        if self.hostplane is None:
-            stats["host_plane"] = None
-        else:
-            stats["host_plane"] = self.hostplane.stats()
         # Control-plane wave batching + heartbeat relay: how many
         # OP_BATCH waves this broker formed, the sub-commands they
         # carried (proposals_saved = events - waves: raft proposals the
         # coalescing avoided), the wave-size histogram (pow2 buckets),
         # and the relay plane's counters — beats answered locally,
-        # frames delivered, stamps ingested while leading. `enabled:
-        # false` shape (counters intact) when meta_batch_s is 0.
+        # frames delivered, stamps ingested while leading.
         with self._intake_lock:
             intake_depth = len(self._intake)
         stats["control_plane"] = {
-            "enabled": self.config.meta_batch_s > 0,
             "waves": self._wave_count,
             "wave_events": self._wave_events,
             "wave_failures": self._wave_failures,
@@ -2063,7 +2030,7 @@ class BrokerServer:
     # -- control-plane wave batching ---------------------------------------
     # Membership/pid commands coalesce into OP_BATCH waves: each broker
     # queues the commands its own RPC handlers receive and proposes ONE
-    # wave per meta_batch_s (early at meta_batch_max), so the metadata
+    # wave per _META_BATCH_S (early at _META_BATCH_MAX), so the metadata
     # leader's raft proposal load under a churn storm is O(brokers) per
     # wave interval instead of O(membership events). The wave apply
     # (PartitionManager.apply) defers each touched group's rebalance to
@@ -2072,41 +2039,35 @@ class BrokerServer:
     # straddling a failover) a no-op.
 
     def _submit_meta(self, cmd: dict) -> bool:
-        """Route one metadata command onto the wave intake (meta_batch_s
-        > 0) or propose it directly (batching disabled — the pre-wave
-        shape, also the bench's 'before' arm). Returns whether the
-        command was proposed; the caller still polls its own local
-        apply for commitment, unchanged."""
-        if self.config.meta_batch_s <= 0:
-            return self.propose_cmd(cmd)
+        """Route one metadata command onto the wave intake. Returns
+        whether the command was proposed; the caller still polls its
+        own local apply for commitment, unchanged."""
         waiter = _WaveWaiter()
-        cap = 4 * self.config.meta_batch_max
+        cap = 4 * _META_BATCH_MAX
         with self._intake_lock:
             if len(self._intake) >= cap:
                 # Bounded intake: refuse retryably instead of queueing
                 # unboundedly — the client's backoff is the ladder.
                 return False
             self._intake.append((cmd, waiter))
-            full = len(self._intake) >= self.config.meta_batch_max
+            full = len(self._intake) >= _META_BATCH_MAX
         if full:
             # A full wave needn't wait for the duty tick: the enqueuing
             # handler thread forms it inline (it would only block on the
             # waiter otherwise).
             self._drain_intake()
-        waiter.event.wait(
-            self.config.meta_batch_s + self.config.rpc_timeout_s * 3
-        )
+        waiter.event.wait(_META_BATCH_S + self.config.rpc_timeout_s * 3)
         return waiter.ok
 
     def _drain_intake(self) -> None:
         """Form and propose waves until the intake is empty (FIFO; at
-        most meta_batch_max commands per wave). Serialized by the drain
+        most _META_BATCH_MAX commands per wave). Serialized by the drain
         lock — concurrent triggers (duty tick vs a full-queue enqueue)
         must not reorder waves."""
         with self._intake_drain_lock:
             while True:
                 with self._intake_lock:
-                    batch = self._intake[: self.config.meta_batch_max]
+                    batch = self._intake[:_META_BATCH_MAX]
                     del self._intake[: len(batch)]
                 if not batch:
                     return
@@ -2141,17 +2102,14 @@ class BrokerServer:
                     w.event.set()
 
     def _batch_duty(self) -> None:
-        """Wave cadence: propose the queued commands once meta_batch_s
+        """Wave cadence: propose the queued commands once _META_BATCH_S
         has passed since the last wave (size-triggered waves drain
         inline from the enqueuing thread, see _submit_meta)."""
-        if self.config.meta_batch_s <= 0:
-            return
         with self._intake_lock:
             pending = len(self._intake)
         if not pending:
             return
-        if (time.monotonic() - self._last_wave
-                < self.config.meta_batch_s):
+        if time.monotonic() - self._last_wave < _META_BATCH_S:
             return
         self._drain_intake()
 
@@ -2168,15 +2126,16 @@ class BrokerServer:
 
     def _beats_relay_duty(self) -> None:
         """Forward the locally-buffered member beats to the metadata
-        leader's liveness ledger as ONE group.beats frame per
-        heartbeat_relay_s. A frame that cannot be delivered (no leader,
-        leader moved, wire error) re-merges into the buffer and retries
-        next tick — the stamps are idempotent monotonic refreshes, and
-        the leader-change grace window (GroupLiveness first-sighting
-        seeding) absorbs delivery gaps exactly as it absorbs leader
-        churn."""
+        leader's liveness ledger as ONE group.beats frame per relay
+        interval (_HEARTBEAT_RELAY_S says how long). A frame that
+        cannot be delivered (no leader, leader moved, wire error)
+        re-merges into the buffer and retries next tick — the stamps
+        are idempotent monotonic refreshes, and the leader-change grace
+        window (GroupLiveness first-sighting seeding) absorbs delivery
+        gaps exactly as it absorbs leader churn."""
         now = time.monotonic()
-        if now - self._last_beat_relay < self.config.heartbeat_relay_s:
+        if now - self._last_beat_relay < min(
+                _HEARTBEAT_RELAY_S, self.config.group_session_timeout_s / 4):
             return
         with self._beat_lock:
             if not self._beat_buffer:
@@ -2328,10 +2287,10 @@ class BrokerServer:
     def _handle_produce(self, req: dict) -> dict:
         """Admission + ack-latency instrumentation around the produce
         path. Admission runs FIRST — before partition resolution,
-        validation, pid stamping, payload packing, or a worker-ring hop
-        — so a shed/quota refusal under overload costs one dict lookup
-        (slo/admission.py; typed retryable `overloaded:`, so clients
-        jitter-backoff instead of hammering the refusal). Admitted
+        validation or pid stamping — so a shed/quota refusal under
+        overload costs one dict lookup (slo/admission.py; typed
+        retryable `overloaded:`, so clients jitter-backoff instead of
+        hammering the refusal). Admitted
         requests observe their full wall time (success AND failure —
         timeouts are exactly the overload signal) into `produce.ack_us`,
         the p99 the SLO controller steers against."""
@@ -2353,7 +2312,9 @@ class BrokerServer:
             return {"ok": False, "error": f"overloaded: {refusal}"}
         t0 = self.metrics.clock()
         try:
-            return self._produce_admitted(req, tctx=sp.ctx)
+            sub = self._produce_submit(req, tctx=sp.ctx)
+            return (sub if isinstance(sub, dict)
+                    else self._produce_collect(*sub))
         finally:
             self._m_ack_us.observe(self.metrics.clock() - t0)
             sp.end()
@@ -2373,9 +2334,7 @@ class BrokerServer:
 
         On a leader that is not the controller the admitted parts ride
         ONE engine.append_multi frame to the controller instead of one
-        engine.append each. The raw-frame peek (`_raw_produce`) does not
-        know this type and leaves it to the canonical decode; with the
-        host plane on, parts are served in this process."""
+        engine.append each."""
         parts = req.get("parts")
         if not isinstance(parts, list) or not parts:
             return {"ok": False, "error": "bad_request: empty parts"}
@@ -2429,53 +2388,6 @@ class BrokerServer:
         except NotCommittedError as e:
             return {"ok": False, "error": f"not_committed: {e}"}
 
-    # Fields the raw-dispatch peek materializes: the routing/admission
-    # scalars (including the elastic-partition fence/routing stamps
-    # pgen + key_hash) plus the message VECTOR's element count (never
-    # its bytes). `tctx` is peeked only to DETECT a sampled produce
-    # (lists peek as element counts, not values): sampled frames take
-    # the canonical decode path below, where the full trace context is
-    # materialized — at trace_sample_n-th cadence the one extra decode
-    # is exactly the kind of overhead sampling exists to amortize.
-    _RAW_PEEK = ("type", "topic", "partition", "producer", "pid", "seq",
-                 "pgen", "key_hash", "messages", "tctx")
-
-    def _raw_produce(self, body) -> Optional[dict]:
-        """Raw-frame produce dispatch (TcpServer accept path, host-plane
-        brokers only): peek the routing scalars off the UNDECODED frame
-        and hand the bytes to the owning worker, which performs the
-        frame's single full decode — deleting the per-batch broker
-        decode → ring re-encode → worker decode hop. Returns None for
-        anything that is not a clean host-plane produce; the ordinary
-        decode path then produces the canonical behavior (byte parity
-        between both paths is pinned in tests/test_hostplane.py)."""
-        if self.hostplane is None:
-            return None
-        peek = codec.peek_fields(body, self._RAW_PEEK)
-        if peek is None or peek.get("type") != "produce":
-            return None
-        if peek.get("tctx") is not None:
-            return None  # sampled: canonical path records the spans
-        n = peek.get("messages")
-        if not isinstance(n, int) or n <= 0:
-            return None  # empty/odd batch: canonical path refuses it
-        if not isinstance(peek.get("topic"), str) \
-                or not isinstance(peek.get("partition"), int):
-            return None
-        refusal = self.slo.admit(peek.get("producer"), n)
-        if refusal is not None:
-            return {"ok": False, "error": f"overloaded: {refusal}"}
-        t0 = self.metrics.clock()
-        try:
-            return self._produce_admitted(peek, raw=body, raw_count=n)
-        finally:
-            self._m_ack_us.observe(self.metrics.clock() - t0)
-
-    def _produce_admitted(self, req: dict, raw=None, raw_count: int = 0,
-                          tctx=None) -> dict:
-        sub = self._produce_submit(req, raw, raw_count, tctx)
-        return sub if isinstance(sub, dict) else self._produce_collect(*sub)
-
     def _span_refusal(self, key, span, a) -> Optional[dict]:
         """Key-range fence of a produce.multi part: the part holds many
         keys of one partition and names the span their hashes cover;
@@ -2497,14 +2409,13 @@ class BrokerServer:
             "routing": self._topic_routing(key[0]),
         }
 
-    def _produce_submit(self, req: dict, raw=None, raw_count: int = 0,
-                        tctx=None, batch: "Optional[_AppendBatch]" = None):
+    def _produce_submit(self, req: dict, tctx=None,
+                        batch: "Optional[_AppendBatch]" = None):
         """Admit one partition batch and SUBMIT its rounds without
         waiting: a refusal dict, or (chunk sizes, waiters, routed
         partition) for `_produce_collect`. `batch` (produce.multi)
         gathers the appends of many parts for ONE submit
-        (`_AppendBatch`); it also keeps the part in this process (the
-        host-plane workers serve `produce` only).
+        (`_AppendBatch`).
 
         Produce semantics: at-least-once by default, EXACTLY-ONCE for
         idempotent producers. A batch larger than max_batch is split into
@@ -2565,100 +2476,24 @@ class BrokerServer:
         slot, refusal = self._check_partition(key, view)
         if refusal:
             return refusal
-        if raw is None:
-            messages = req["messages"]
-            if not isinstance(messages, list) or not messages:
-                return {"ok": False, "error": "bad_request: empty messages"}
-        else:
-            # Raw dispatch: the batch is still undecoded wire bytes;
-            # only its element count is known (the peek).
-            messages = None
+        messages = req["messages"]
+        if not isinstance(messages, list) or not messages:
+            return {"ok": False, "error": "bad_request: empty messages"}
         B = self.config.engine.max_batch
-        stamped = None
-        if self.hostplane is not None and batch is None:
-            # Multi-core host plane: the owning worker validates, stamps
-            # (its own per-(worker, generation) pid + per-slot sequence
-            # counters — slices are disjoint) and packs the batch into
-            # max_batch-sized row blocks that ride to the engine
-            # pre-packed (DataPlane.submit_packed / engine.append_packed
-            # — the payload bytes are never re-encoded past the worker).
-            from ripplemq_tpu.parallel.hostplane import (
-                OversizeBatchError,
-                WorkerUnavailableError,
-            )
-
-            # worker.hop: the broker-side shm-ring round trip; the
-            # worker's serve/validate/stamp/pack spans parent under it
-            # (hop.ctx rides the ring frame) and ship back inside the
-            # response for the broker ring to adopt.
-            hop = (self.spans.span("worker.hop", tctx)
-                   if self.spans is not None else NULL_SPAN)
-            try:
-                if raw is not None:
-                    stamped = self.hostplane.submit_raw(
-                        slot, raw, raw_count,
-                        pid=req.get("pid"), seq=req.get("seq"),
-                        timeout_s=self.config.rpc_timeout_s,
-                    )
-                else:
-                    stamped = self.hostplane.submit(
-                        slot, messages,
-                        pid=req.get("pid"), seq=req.get("seq"),
-                        timeout_s=self.config.rpc_timeout_s,
-                        tctx=None if hop.ctx is None else hop.ctx.wire(),
-                    )
-                hop.end()
-            except WorkerUnavailableError as e:
-                hop.end(error="worker_unavailable")
-                # Typed RETRYABLE refusal — never a silent hang: the
-                # dispatcher already detected the dead worker and is
-                # respawning it; the client's retry lands.
-                return {"ok": False, "error": f"worker_unavailable: {e}"}
-            except OversizeBatchError:
-                # The batch would not fit a ring frame: serve it on the
-                # in-process path below (no size bound there) instead
-                # of refusing — the single-process semantics are the
-                # fallback contract for every worker-plane miss.
-                stamped = None
-            except ValueError as e:
-                return {"ok": False, "error": f"bad_request: {e}"}
-        if stamped is None and messages is None:
-            # The raw fast path missed (oversize batch, no worker):
-            # materialize the frame ONCE and run the canonical path —
-            # the fallback contract, identical semantics to the dict
-            # route.
-            full = codec.decode(raw)
-            messages = (full.get("messages")
-                        if isinstance(full, dict) else None)
-            if not isinstance(messages, list) or not messages:
-                return {"ok": False, "error": "bad_request: empty messages"}
-        if stamped is not None:
-            pid, seq = int(stamped["pid"]), int(stamped["seq"])
-            chunk_sizes = [len(lens) for lens, _ in stamped["chunks"]]
-            futs = [
-                self._engine_append_packed(
-                    slot, lens, packed, pid,
-                    seq + i * B if pid > 0 else -1,
-                    tctx=tctx,
-                )
-                for i, (lens, packed) in enumerate(stamped["chunks"])
-            ]
+        if req.get("pid") is not None:
+            pid, seq = int(req["pid"]), int(req.get("seq", -1))
         else:
-            if req.get("pid") is not None:
-                pid, seq = int(req["pid"]), int(req.get("seq", -1))
-            else:
-                pid, seq = self._stamp_pid_seq(slot, len(messages))
-            chunks = [messages[i : i + B]
-                      for i in range(0, len(messages), B)]
-            chunk_sizes = [len(c) for c in chunks]
-            futs = [
-                self._engine_append(
-                    slot, chunk, pid,
-                    seq + i * B if pid > 0 else -1,
-                    tctx=tctx, batch=batch,
-                )
-                for i, chunk in enumerate(chunks)
-            ]
+            pid, seq = self._stamp_pid_seq(slot, len(messages))
+        chunks = [messages[i : i + B] for i in range(0, len(messages), B)]
+        chunk_sizes = [len(c) for c in chunks]
+        futs = [
+            self._engine_append(
+                slot, chunk, pid,
+                seq + i * B if pid > 0 else -1,
+                tctx=tctx, batch=batch,
+            )
+            for i, chunk in enumerate(chunks)
+        ]
         return chunk_sizes, futs, routed
 
     def _produce_collect(self, chunk_sizes: list, futs: list,
@@ -2918,32 +2753,19 @@ class BrokerServer:
         limit = None if limit is None else int(limit)
         fsp = (self.spans.span("follower.serve", tctx, {"slot": slot})
                if self.spans is not None else NULL_SPAN)
-        got = None
-        if self.hostplane is not None:
-            # Shared fan-out on the worker plane: the owning worker's
-            # settled mirror (fed by the repl ingest below) serves the
-            # hot window off this process's GIL — one mirror read feeds
-            # many cursors. Every mirror answer is re-fenced against
-            # the floor/gap map before it leaves (the mirror itself
-            # holds rows ahead of the floor).
-            mirror = self.hostplane.read(slot, offset, limit)
-            if (mirror is not None and mirror[0]
-                    and fp.validate_window(slot, offset, mirror[1])):
-                got = mirror
-        if got is None:
-            # A cold striped page pays a reconstruct inside fp.read —
-            # attribute it (decoded-counter delta detects one) as a
-            # child of follower.serve.
-            dec0 = fp._decoded
-            t0r = self.metrics.clock()
-            got = fp.read(slot, offset, limit)
-            if fsp.ctx is not None and fp._decoded > dec0:
-                self.spans.span_at(
-                    "stripe.reconstruct", fsp.ctx, t0r,
-                    self.metrics.clock() - t0r,
-                    {"groups": fp._decoded - dec0})
-        # Last-line witness: EVERY answer (mirror or cache) re-checks
-        # against the floor at the boundary, independent of the serving
+        # A cold striped page pays a reconstruct inside fp.read —
+        # attribute it (decoded-counter delta detects one) as a
+        # child of follower.serve.
+        dec0 = fp._decoded
+        t0r = self.metrics.clock()
+        got = fp.read(slot, offset, limit)
+        if fsp.ctx is not None and fp._decoded > dec0:
+            self.spans.span_at(
+                "stripe.reconstruct", fsp.ctx, t0r,
+                self.metrics.clock() - t0r,
+                {"groups": fp._decoded - dec0})
+        # Last-line witness: EVERY answer re-checks against the floor
+        # at the boundary, independent of the serving
         # path's own fence — a failed audit refuses and is counted as
         # a first-class chaos violation (answers_past_floor).
         if got is not None and not fp.audit_answer(slot, offset, got[1]):
@@ -3806,40 +3628,6 @@ class BrokerServer:
 
         return wait
 
-    def _engine_append_packed(self, slot: int, lens: list[int], packed,
-                              pid: int = 0, seq: int = -1,
-                              tctx=None) -> Callable[[], int]:
-        """The pre-packed twin of _engine_append: the host-plane worker
-        already validated + packed the rows, so the local path hands the
-        block to DataPlane.submit_packed and the forwarded path ships it
-        as ONE engine.append_packed frame — the payload bytes cross the
-        leader→controller hop exactly once, in engine row format."""
-        dp = self._local_engine()
-        if dp is not None:
-            fut = dp.submit_packed(slot, packed, lens, pid=pid, seq=seq,
-                                   tctx=tctx)
-            return lambda: int(fut.result(timeout=self.config.rpc_timeout_s))
-        req = {"type": "engine.append_packed", "slot": slot,
-               "lens": list(lens), "packed": packed,
-               "pid": pid, "seq": seq}
-        if tctx is not None:
-            req["tctx"] = tctx.wire()
-        call_async = getattr(self.client, "call_async", None)
-        if call_async is None:  # in-proc transport: synchronous by design
-            resp = self._engine_call(req)
-            return lambda: int(resp["base_offset"])
-        rpc_fut = call_async(self._controller_addr(), req)
-
-        def wait() -> int:
-            resp = rpc_fut.result(timeout=self.config.rpc_timeout_s)
-            if not resp.get("ok"):
-                if "not_committed" in str(resp.get("error", "")):
-                    raise NotCommittedError(resp["error"])
-                raise RpcError(f"engine call failed: {resp.get('error')}")
-            return int(resp["base_offset"])
-
-        return wait
-
     def _submit_batch(self, batch: "_AppendBatch") -> None:
         """Submit every append of one produce.multi and fill in
         `batch.results`: futures from the local plane, or — forwarded to
@@ -3872,67 +3660,6 @@ class BrokerServer:
             results = [NotCommittedError(f"forward to controller: {e}")
                        ] * len(batch.items)
         batch.results = results
-
-    def _mirror_publish(self, slot: int, base: int, payload) -> None:
-        """DataPlane.mirror_fn: fan settled REC_APPEND rows out to the
-        owning host worker (settle thread; HostPlane.publish never
-        blocks — drops degrade to engine-read fallbacks)."""
-        hp = self.hostplane
-        if hp is not None:
-            hp.publish(slot, base, payload)
-
-    def _worker_pid_duty(self) -> None:
-        """Host-plane stamping pids: register one metadata pid per
-        (worker, generation) and install it in the worker. A RESPAWNED
-        worker restarts its sequence counters at zero, so it must stamp
-        under a FRESH pid (gen is in the name) — riding the old pid
-        would collapse fresh batches as replays in the cluster dedup
-        table. Until its pid applies, a fresh worker stamps (0, -1)
-        and produces flow unstamped (at-least-once, the pre-stamping
-        behavior). Registered pids re-register at a third of
-        pid_retention_s, the same session-refresh rule as the broker's
-        own stamping pid."""
-        hp = self.hostplane
-        if hp is None:
-            return
-        now = time.monotonic()
-        retention = self.config.pid_retention_s
-        for idx, gen in enumerate(hp.generations()):
-            known = self._worker_pid_names.get(idx)
-            if known is None or known[0] != gen:
-                self._worker_pid_names[idx] = (gen, (
-                    f"_broker/{self.broker_id}/{self._pid_nonce}"
-                    f"/w{idx}g{gen}"
-                ))
-                self._worker_pids.pop(idx, None)
-                self._worker_pid_proposed.pop(idx, None)
-            _, name = self._worker_pid_names[idx]
-            pid = self.manager.producer_id(name)
-            if pid is None:
-                if now - self._worker_pid_proposed.get(idx, 0.0) >= 1.0:
-                    self._worker_pid_proposed[idx] = now
-                    self.propose_cmd(
-                        {"op": OP_REGISTER_PRODUCER, "producer": name},
-                        retries=1,
-                    )
-                continue
-            if self._worker_pids.get(idx) != pid:
-                self._worker_pids[idx] = pid
-                # gen-fenced: a respawn since the snapshot above must
-                # drop this install (the pid belongs to the OLD
-                # generation's counters; the next duty tick registers
-                # the fresh generation's own pid).
-                hp.set_worker_pid(idx, pid, gen=gen)
-            elif (retention > 0 and
-                  now - self._worker_pid_proposed.get(idx, 0.0)
-                  >= max(1.0, retention / 3)):
-                # Session refresh: the re-registration apply bumps the
-                # replicated seen counter the pid reaper keys on.
-                self._worker_pid_proposed[idx] = now
-                self.propose_cmd(
-                    {"op": OP_REGISTER_PRODUCER, "producer": name},
-                    retries=1,
-                )
 
     def _read_barrier(self) -> None:
         """linearizable_reads: confirm this broker still commands the
@@ -4053,15 +3780,6 @@ class BrokerServer:
 
         def read(items: list) -> list:
             (_, offset, _, _, _), = items
-            if self.hostplane is not None:
-                # Settled-mirror fast path: the owning worker serves the
-                # hot window off this process's GIL. Only a NON-EMPTY
-                # answer short-circuits — empty/behind/unavailable all
-                # fall through to the plane, which stays the authority
-                # (and owns the park).
-                got = self.hostplane.read(slot, offset, max_msgs)
-                if got is not None and got[0]:
-                    return [(got[0], offset, got[1])]
             msgs, end = dp.read(slot, offset, replica, max_msgs)
             return [(msgs, offset, end)]
 
@@ -4192,7 +3910,7 @@ class BrokerServer:
         if dp is None:
             return {"ok": False, "error": "not_controller",
                     "controller_addr": self._controller_addr()}
-        if t in ("engine.append", "engine.append_packed"):
+        if t == "engine.append":
             # Forwarded append from a non-controller leader: a sampled
             # produce's tctx rode the frame — the controller's rpc.recv
             # span closes the leader→controller cross-process edge and
@@ -4202,23 +3920,13 @@ class BrokerServer:
                                   {"op": t})
                   if self.spans is not None else NULL_SPAN)
             try:
-                if t == "engine.append":
-                    fut = dp.submit_append(
-                        int(req["slot"]), list(req["messages"]),
-                        pid=int(req.get("pid", 0) or 0),
-                        seq=int(req.get("seq", -1)
-                                if req.get("seq") is not None else -1),
-                        tctx=sp.ctx,
-                    )
-                else:
-                    fut = dp.submit_packed(
-                        int(req["slot"]), req["packed"],
-                        [int(x) for x in req["lens"]],
-                        pid=int(req.get("pid", 0) or 0),
-                        seq=int(req.get("seq", -1)
-                                if req.get("seq") is not None else -1),
-                        tctx=sp.ctx,
-                    )
+                fut = dp.submit_append(
+                    int(req["slot"]), list(req["messages"]),
+                    pid=int(req.get("pid", 0) or 0),
+                    seq=int(req.get("seq", -1)
+                            if req.get("seq") is not None else -1),
+                    tctx=sp.ctx,
+                )
                 return {"ok": True, "base_offset":
                         int(fut.result(self.config.rpc_timeout_s))}
             finally:
@@ -4383,16 +4091,6 @@ class BrokerServer:
                 self._m_ff_floors.inc()
             if req.get("floor_t_ns") is not None:
                 self._note_floor_lag(int(req["floor_t_ns"]))
-            if self.hostplane is not None:
-                # Worker-plane fan-out: mirror the replicated rows into
-                # the owning worker so follower reads ride the same
-                # settled-mirror path leader reads do. Mirror answers
-                # are floor-fenced per read (_follower_consume); the
-                # rows themselves are exactly the store's, so a later
-                # promotion of this broker serves them identically.
-                for t, s, b, p in recs:
-                    if t == REC_APPEND:
-                        self._mirror_publish(int(s), int(b), p)
         if self.config.durability == "strict":
             # durability=strict: this ack gates a settled round's
             # producer ack, so the records must be ON DISK before it
@@ -4598,7 +4296,6 @@ class BrokerServer:
                 self._beats_relay_duty()
                 self._metadata_leader_duty()
                 self._producer_pid_duty()
-                self._worker_pid_duty()
                 self._pid_reap_duty()
                 self._group_duty()
                 self._abdicate_duty()

@@ -638,7 +638,6 @@ def run_chaos(
     churn_storm: bool = False,
     replication_mode: str = "full",
     lock_witness: bool = False,
-    host_workers: int = 1,
     slo: bool = False,
     slo_target_p99_ms: float = 100.0,
     slo_recover_s: float = 45.0,
@@ -798,15 +797,9 @@ def run_chaos(
             linearizable_reads=True,  # same checker rationale as below
             # Short member sessions so a paused member's eviction (and
             # the rebalance it forces) lands INSIDE a chaos phase; the
-            # beat-relay cadence scales down with it (default 0.5 s
-            # leaves no margin against a 0.25 s workload heartbeat).
+            # brokers' beat-relay cadence scales down with it.
             group_session_timeout_s=0.8,
-            heartbeat_relay_s=0.2,
             replication=replication_mode,
-            # host_workers > 1 drives the multi-core host plane on real
-            # broker subprocesses: every produce stamps/packs through a
-            # worker, controller consumes serve off the settled mirror.
-            host_workers=host_workers,
             spare_slots=splits,
             **slo_kw,
         )
@@ -826,9 +819,7 @@ def run_chaos(
             # opts IN, so every surviving violation is a real bug.
             linearizable_reads=True,
             group_session_timeout_s=0.8,  # see the proc branch above
-            heartbeat_relay_s=0.2,  # see the proc branch above
             replication=replication_mode,
-            host_workers=host_workers,  # see the proc branch above
             spare_slots=splits,
         )
         cluster = InProcCluster(config, data_dir=data_dir)
@@ -836,7 +827,6 @@ def run_chaos(
     verdict: dict = {"seed": seed, "phases": phases,
                      "ops_per_phase": ops_per_phase, "backend": backend,
                      "replication": replication_mode,
-                     "host_workers": host_workers,
                      "follower_reads": follower_reads,
                      "splits": splits, "churn_storm": churn_storm}
     try:

@@ -123,12 +123,6 @@ def _config_yaml_dict(config: ClusterConfig) -> dict:
         "membership_poll_s": config.membership_poll_s,
         "group_session_timeout_s": config.group_session_timeout_s,
         "group_retention_s": config.group_retention_s,
-        # Control-plane wave batching: the wave cadence/size and the
-        # heartbeat relay interval must round-trip or the subprocess
-        # backend runs a different control-plane shape than in-proc.
-        "meta_batch_s": config.meta_batch_s,
-        "meta_batch_max": config.meta_batch_max,
-        "heartbeat_relay_s": config.heartbeat_relay_s,
         "metadata_refresh_s": config.metadata_refresh_s,
         "rpc_timeout_s": config.rpc_timeout_s,
         "controller_id": config.controller_id,
@@ -151,18 +145,15 @@ def _config_yaml_dict(config: ClusterConfig) -> dict:
         "chain_depth": config.chain_depth,
         "pipeline_depth": config.pipeline_depth,
         "rpc_workers": config.rpc_workers,
-        "host_workers": config.host_workers,
-        "host_ring_bytes": config.host_ring_bytes,
         "repl_pipeline_depth": config.repl_pipeline_depth,
         "linearizable_reads": config.linearizable_reads,
         "obs": config.obs,
         "lock_witness": config.lock_witness,
-        # Causal tracing: sampling cadence and ring sizing must
-        # round-trip — a proc-backend broker that silently ran
-        # trace_sample_n=0 would record no spans and the acceptance
-        # tree would mysteriously miss every broker-side hop.
+        # Causal tracing: the sampling cadence must round-trip — a
+        # proc-backend broker that silently ran trace_sample_n=0 would
+        # record no spans and the acceptance tree would mysteriously
+        # miss every broker-side hop.
         "trace_sample_n": config.trace_sample_n,
-        "span_ring_slots": config.span_ring_slots,
         "slo_rails_file": config.slo_rails_file,
         # SLO autopilot (the control loop must run the same operating
         # point on the subprocess backend as in-proc — the exact drop
@@ -179,12 +170,10 @@ def _config_yaml_dict(config: ClusterConfig) -> dict:
         "slo_shed_occupancy": config.slo_shed_occupancy,
         "slo_quotas": {t: r for t, r in config.slo_quotas},
         "slo_tenant_tiers": {t: v for t, v in config.slo_tenant_tiers},
-        # Elastic partitions: the trigger/hysteresis/handoff rails must
+        # Elastic partitions: the trigger and the handoff rails must
         # round-trip or an in-proc soak and its subprocess twin run
         # different reconfiguration behavior.
         "split_auto": config.split_auto,
-        "split_evidence_ticks": config.split_evidence_ticks,
-        "split_merge_idle_ticks": config.split_merge_idle_ticks,
         "split_handoff_timeout_s": config.split_handoff_timeout_s,
         "split_max_partitions": config.split_max_partitions,
     }

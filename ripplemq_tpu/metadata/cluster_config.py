@@ -17,7 +17,6 @@ import yaml
 
 from ripplemq_tpu.core.config import EngineConfig
 from ripplemq_tpu.metadata.models import BrokerInfo, Topic
-from ripplemq_tpu.obs.spans import DEFAULT_SLOTS as _SPAN_RING_SLOTS
 
 
 @dataclasses.dataclass(frozen=True)
@@ -56,28 +55,6 @@ class ClusterConfig:
     # caught exactly that). Members rejoining within the window resume
     # seamlessly.
     group_retention_s: float = 60.0
-    # --- Control-plane wave batching (BrokerServer._batch_duty) ---------
-    # The metadata leader drains its intake queue of membership/pid
-    # commands (group.join / group.leave / producer.register) into ONE
-    # OP_BATCH proposal per wave: at most every meta_batch_s, or as soon
-    # as meta_batch_max commands are queued. The apply expands the wave
-    # in order but defers each touched group's rebalance to the END of
-    # the wave, so N joins to one group cost one generation bump and one
-    # assignment recompute instead of N. 0 disables coalescing — every
-    # command proposes individually (the pre-wave shape).
-    meta_batch_s: float = 0.05
-    # Wave size cap: a wave is proposed early once this many commands
-    # are queued (bounds both proposal payload and the latency a full
-    # queue would add to the oldest waiter).
-    meta_batch_max: int = 256
-    # Heartbeat relay cadence: each broker aggregates the group
-    # heartbeats of its locally-connected members and forwards ONE
-    # group.beats frame per interval to the metadata leader's liveness
-    # ledger — leader heartbeat RPC load is O(brokers), not O(members).
-    # Per-member stamps are preserved; leader-change grace semantics
-    # are unchanged. Must sit well inside group_session_timeout_s or
-    # relayed beats arrive too late to keep sessions alive.
-    heartbeat_relay_s: float = 0.5
     metadata_refresh_s: float = 10.0
     rpc_timeout_s: float = 3.0
     # The broker that BOOTSTRAPS as the TPU mesh driver (device-program
@@ -184,13 +161,6 @@ class ClusterConfig:
     # span rings share the metrics plane's monotonic clock domain so
     # the engine's stage timestamps can be attributed verbatim.
     trace_sample_n: int = 0
-    # Per-process span-ring capacity (records, not bytes), allocated
-    # only when trace_sample_n > 0. Sized for a reader that pages the
-    # ring ONCE at the end of a run (benchmarks/run.py): the busiest
-    # ring measured records ~1,600 spans/s for ~35 s. A ring that
-    # overflows says so (spans.overwritten, admin.spans `dropped`);
-    # the default is obs/spans.py's.
-    span_ring_slots: int = _SPAN_RING_SLOTS
     # Runtime lock witness (obs/lockwitness.py): when true, every
     # host-path lock this process creates is a recording wrapper that
     # captures per-thread acquisition orderings, cross-checkable
@@ -199,19 +169,6 @@ class ClusterConfig:
     # zero overhead; debug/chaos harnesses turn it on (run_chaos
     # lock_witness=True, profiles/chaos_soak.py --witness).
     lock_witness: bool = False
-    # Multi-core host plane (parallel/hostplane.py): worker subprocesses
-    # per broker, each owning the disjoint partition-group slice
-    # `slot % host_workers` of the data-plane HOST path (submit
-    # validation, pid/seq stamping, payload packing, settled-mirror
-    # consume serving). 1 = no subprocess plane (everything in-process,
-    # the pre-PR-12 shape). The device program and replication plane
-    # are unaffected: committed prefixes are byte-identical across
-    # host_workers values.
-    host_workers: int = 1
-    # Shared-memory ring capacity per direction per worker (the
-    # dispatcher<->worker frame rings; parallel/shmring.py). Frames are
-    # capped at half the ring.
-    host_ring_bytes: int = 4 << 20
     # Standby replication stream pipelining: how many epoch-stamped,
     # per-stream-sequence-numbered repl.rounds frames one sender keeps
     # in flight before waiting on the oldest ack (broker/replication.py
@@ -329,16 +286,14 @@ class ClusterConfig:
     # --- Elastic partitions (broker/manager.py split/merge) -------------
     # SLO-driven reconfiguration trigger: when true, the controller
     # broker's SLO tick history arms an online split of the hottest
-    # partition after `split_evidence_ticks` breach-evidencing ticks,
-    # and proposes the reverse merge after `split_merge_idle_ticks`
-    # consecutive comfortable ticks (hysteresis like the shed machine).
+    # partition after a run of breach-evidencing ticks, and proposes the
+    # reverse merge after a long run of comfortable ticks (hysteresis
+    # like the shed machine; both runs are slo/controller.py's).
     # Splits spend SPARE engine slots (engine.partitions beyond the
     # configured topic total); with none left the proposal no-ops.
     # False (default): splits/merges happen only via admin.split /
     # admin.merge.
     split_auto: bool = False
-    split_evidence_ticks: int = 4
-    split_merge_idle_ticks: int = 64
     # Handoff bound: a split's dual-write window is closed (cutover
     # proposed) at the latest this many seconds after the controller's
     # reconfig duty first sees it, even if the parent's settled floor
@@ -364,34 +319,6 @@ class ClusterConfig:
             )
         if self.pid_retention_s < 0:
             raise ValueError("pid_retention_s must be >= 0 (0 disables)")
-        if not 1 <= self.host_workers <= 64:
-            raise ValueError(
-                f"host_workers must be in [1, 64], got {self.host_workers}"
-            )
-        if self.host_ring_bytes < (1 << 20):
-            raise ValueError(
-                f"host_ring_bytes={self.host_ring_bytes} below the 1 MiB "
-                f"floor: frames cap at half the ring, and a full "
-                f"max_batch mirror frame (max_batch x slot_bytes rows) "
-                f"must fit or every settled-mirror publish drops"
-            )
-        if self.host_workers > 1:
-            # The invariant the floor message states, checked against
-            # the ACTUAL engine shape: a full-round mirror frame
-            # (max_batch x slot_bytes rows + codec overhead) must fit
-            # the half-ring frame cap, or the worker plane silently
-            # degrades to ring hops that never serve anything.
-            round_bytes = self.engine.max_batch * self.engine.slot_bytes
-            if round_bytes + 4096 > self.host_ring_bytes // 2:
-                raise ValueError(
-                    f"host_ring_bytes={self.host_ring_bytes} cannot carry "
-                    f"one full round's mirror frame (max_batch "
-                    f"{self.engine.max_batch} x slot_bytes "
-                    f"{self.engine.slot_bytes} = {round_bytes} bytes vs "
-                    f"the {self.host_ring_bytes // 2}-byte frame cap) — "
-                    f"raise host_ring_bytes to at least "
-                    f"{2 * (round_bytes + 4096)}"
-                )
         if self.repl_pipeline_depth < 1:
             raise ValueError("repl_pipeline_depth must be >= 1")
         # Shards (~segment_bytes / 3 each) travel in single wire frames
@@ -431,8 +358,6 @@ class ClusterConfig:
                 "trace_sample_n > 0 requires obs=True: span attribution "
                 "reuses the metrics plane's stage timestamps"
             )
-        if self.span_ring_slots < 16:
-            raise ValueError("span_ring_slots must be >= 16")
         if self.slo_tick_s <= 0:
             raise ValueError("slo_tick_s must be > 0")
         if self.slo_recover_s <= 0:
@@ -476,24 +401,6 @@ class ClusterConfig:
                     f"'high' or 'low', got {tier!r}"
                 )
             tiers_seen.add(tenant)
-        if self.meta_batch_s < 0:
-            raise ValueError("meta_batch_s must be >= 0 (0 disables waves)")
-        if self.meta_batch_max < 1:
-            raise ValueError("meta_batch_max must be >= 1")
-        if self.heartbeat_relay_s <= 0:
-            raise ValueError("heartbeat_relay_s must be > 0")
-        if self.heartbeat_relay_s >= self.group_session_timeout_s:
-            raise ValueError(
-                f"heartbeat_relay_s={self.heartbeat_relay_s} must be well "
-                f"inside group_session_timeout_s="
-                f"{self.group_session_timeout_s}: a relay interval at or "
-                f"past the session timeout delivers every beat too late "
-                f"and the leader evicts healthy members"
-            )
-        if self.split_evidence_ticks < 1:
-            raise ValueError("split_evidence_ticks must be >= 1")
-        if self.split_merge_idle_ticks < 1:
-            raise ValueError("split_merge_idle_ticks must be >= 1")
         if self.split_handoff_timeout_s <= 0:
             raise ValueError("split_handoff_timeout_s must be > 0")
         if self.split_max_partitions < 0:
@@ -586,7 +493,14 @@ def load_cluster_config(path: str) -> ClusterConfig:
     return parse_cluster_config(raw)
 
 
-_RETIRED_ENGINE_KEYS = ("fused_control", "packed_writes")
+# Keys whose choice was removed, each with the one value a file may
+# still carry (what the program now always does) and the PR that removed
+# the choice. Any other value is refused at parse, never ignored.
+_RETIRED_KEYS = {
+    "engine.fused_control": (True, "PR 29"),
+    "engine.packed_writes": (True, "PR 29"),
+    "host_workers": (1, "PR 52"),
+}
 
 
 def parse_cluster_config(raw: dict) -> ClusterConfig:
@@ -607,16 +521,17 @@ def parse_cluster_config(raw: dict) -> ClusterConfig:
         engine_raw["partitions"] = max(1, total_parts)
     if "replicas" not in engine_raw:
         engine_raw["replicas"] = max_rf
-    # Cluster files written before PR 29 name the two switches the engine
-    # had then; what they chose when true is the only round there is.
-    for key in _RETIRED_ENGINE_KEYS:
-        if engine_raw.pop(key, True) is not True:
+    for key, (only, pr) in _RETIRED_KEYS.items():
+        section, _, name = key.rpartition(".")
+        got = (engine_raw if section else raw).get(name, only)
+        if got != only or type(got) is not type(only):
             raise ValueError(
-                f"engine.{key}: false is no longer possible: the legacy "
-                f"control phase / full-window writes were removed (PR 29); "
-                f"delete the key"
+                f"{key}: {got!r} is no longer possible: the choice was "
+                f"removed ({pr}) and {only!r} is all the key can still "
+                f"mean; delete the key"
             )
-    engine = EngineConfig(**engine_raw)
+    engine = EngineConfig(**{k: v for k, v in engine_raw.items()
+                             if f"engine.{k}" not in _RETIRED_KEYS})
     if engine.partitions < total_parts:
         raise ValueError(
             f"engine.partitions={engine.partitions} cannot hold the "
@@ -635,22 +550,14 @@ def parse_cluster_config(raw: dict) -> ClusterConfig:
         "rpc_timeout_s",
         "group_session_timeout_s",
         "group_retention_s",
-        "meta_batch_s",
-        "heartbeat_relay_s",
     )
     extra = {k: float(raw[k]) for k in timing_keys if k in raw}
-    if "meta_batch_max" in raw:
-        extra["meta_batch_max"] = int(raw["meta_batch_max"])
     if raw.get("controller_id") is not None:
         extra["controller_id"] = int(raw["controller_id"])
     if "standby_count" in raw:
         extra["standby_count"] = int(raw["standby_count"])
     if "rpc_workers" in raw:
         extra["rpc_workers"] = int(raw["rpc_workers"])
-    if "host_workers" in raw:
-        extra["host_workers"] = int(raw["host_workers"])
-    if "host_ring_bytes" in raw:
-        extra["host_ring_bytes"] = int(raw["host_ring_bytes"])
     if "repl_pipeline_depth" in raw:
         extra["repl_pipeline_depth"] = int(raw["repl_pipeline_depth"])
     if "linearizable_reads" in raw:
@@ -661,8 +568,6 @@ def parse_cluster_config(raw: dict) -> ClusterConfig:
         extra["lock_witness"] = bool(raw["lock_witness"])
     if "trace_sample_n" in raw:
         extra["trace_sample_n"] = int(raw["trace_sample_n"])
-    if "span_ring_slots" in raw:
-        extra["span_ring_slots"] = int(raw["span_ring_slots"])
     if "slo_rails_file" in raw:
         extra["slo_rails_file"] = str(raw["slo_rails_file"])
     if "durability" in raw:
@@ -712,10 +617,6 @@ def parse_cluster_config(raw: dict) -> ClusterConfig:
         )
     if "split_auto" in raw:
         extra["split_auto"] = bool(raw["split_auto"])
-    if "split_evidence_ticks" in raw:
-        extra["split_evidence_ticks"] = int(raw["split_evidence_ticks"])
-    if "split_merge_idle_ticks" in raw:
-        extra["split_merge_idle_ticks"] = int(raw["split_merge_idle_ticks"])
     if "split_handoff_timeout_s" in raw:
         extra["split_handoff_timeout_s"] = float(
             raw["split_handoff_timeout_s"])

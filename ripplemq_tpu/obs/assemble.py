@@ -10,9 +10,8 @@ The skew model: each process records spans against its own
 `time.monotonic()`, so a trace that crossed N processes arrives in N
 unrelated clock domains. But every cross-process hop left a matched
 pair behind — the requesting side's span (client.produce wrapping the
-RPC, worker.hop wrapping the shm round trip, repl.send wrapping the
-frame) PARENTS the serving side's span (rpc.recv, worker.serve,
-repl.apply). Assuming the serve sits at the midpoint of the request
+RPC, repl.send wrapping the frame) PARENTS the serving side's span
+(rpc.recv, repl.apply). Assuming the serve sits at the midpoint of the request
 (the classic NTP symmetric-delay assumption), the midpoint difference
 IS the offset between the two domains:
 

@@ -105,12 +105,6 @@ SPAN_KINDS = frozenset({
     "rpc.recv",
     # SLO admission decision on the produce front door.
     "admission",
-    # Multi-core host plane: broker-side shm-ring round trip
-    # (worker.hop) and the worker-subprocess side (worker.serve covers
-    # the op; validate/stamp/pack are its children). hop/serve pair for
-    # the worker-process skew estimate.
-    "worker.hop", "worker.serve",
-    "worker.validate", "worker.stamp", "worker.pack",
     # Engine round lifecycle, attributed to the sampled round: the PR 5
     # stage boundaries, now as spans (broker/dataplane.py emits all six
     # at settle release from the round ctx timestamps).
@@ -247,9 +241,9 @@ NULL_SPAN = _NullSpan()
 
 
 class SpanRing:
-    """Per-process span ring (one per broker, one per host worker, one
-    per tracing client). Lock-cheap like the flight recorder: slot via
-    atomic counter, single-reference stores, racy-consistent snapshot."""
+    """Per-process span ring (one per broker, one per tracing client).
+    Lock-cheap like the flight recorder: slot via atomic counter,
+    single-reference stores, racy-consistent snapshot."""
 
     def __init__(self, proc: str, capacity: int = DEFAULT_SLOTS,
                  clock: Optional[Callable[[], float]] = None,
@@ -315,25 +309,6 @@ class SpanRing:
         if seq - self._cap > self._served:
             self._overwritten.inc()  # seq - cap was never served
 
-    def ingest(self, records: list[dict]) -> None:
-        """Adopt already-built span records from another process (the
-        host workers ship theirs back inside the existing shm-ring
-        response frames; the broker ring is the one admin.spans serves).
-        Records keep their ORIGIN proc label and clock domain."""
-        for r in records:
-            try:
-                rec = (
-                    str(r["kind"]), int(r["trace"]), int(r["span"]),
-                    int(r["parent"]), float(r["t0"]), int(r["dur_us"]),
-                    str(r["proc"]),
-                    {k: v for k, v in r.items()
-                     if k not in ("seq", "kind", "trace", "span", "parent",
-                                  "t0", "dur_us", "proc")} or None,
-                )
-            except (KeyError, TypeError, ValueError):
-                continue  # a malformed record is dropped, never fatal
-            self._put(*rec)
-
     # ------------------------------------------------------------ read
 
     def snapshot(self, after: int = -1,
@@ -361,7 +336,7 @@ class SpanRing:
 
     @property
     def recorded(self) -> int:
-        """Records ever stored (own spans and ingested ones)."""
+        """Records ever stored."""
         return self._recorded.n
 
     @property

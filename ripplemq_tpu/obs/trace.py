@@ -50,9 +50,6 @@ EVENT_TYPES = frozenset({
     # Broker/controller lifecycle.
     "controller_boot", "boot_failed", "deposed", "abdicate",
     "standby_joined", "store_quarantine", "stripe_rebuild",
-    # Multi-core host plane (parallel/hostplane.py): a worker
-    # subprocess died / its respawn came up under a bumped generation.
-    "host_worker_down", "host_worker_restart",
     # Consumer-group coordinator (manager applies + fencing).
     "group_join", "group_leave", "group_delete", "fence",
     # Control-plane wave batching (broker/server.py _batch_duty +

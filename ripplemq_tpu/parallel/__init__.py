@@ -1,9 +1,8 @@
 """Mesh construction and SPMD execution of the core replication steps.
 
-Re-exports are lazy (PEP 562): `parallel.shmring` / `parallel.hostplane`
-are the jax-free modules the spawned host-plane workers import, and an
-eager mesh/engine import here would charge every worker boot the full
-jax initialization.
+Re-exports are lazy (PEP 562): `parallel.lockstep` is jax-free, and an
+eager mesh/engine import here would charge everything that imports it
+the full jax initialization.
 """
 
 __all__ = [

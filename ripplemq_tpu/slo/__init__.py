@@ -17,13 +17,12 @@ needs beyond dashboards:
   disengages it.
 - `slo/admission.py` — per-tenant token-bucket quotas plus the shed
   gate, consulted at the TOP of the produce RPC surface: a refused
-  produce costs a dict lookup, never payload packing or a worker-ring
-  hop. Refusals are the typed retryable `overloaded:` error
-  (wire/retry.py), so clients back off instead of hammering an
-  overloaded broker.
+  produce costs a dict lookup, never payload packing. Refusals are
+  the typed retryable `overloaded:` error (wire/retry.py), so clients
+  back off instead of hammering an overloaded broker.
 
-Lazy exports (PEP 562) to keep the worker-subprocess import path thin,
-matching the package convention established in PR 12.
+Lazy exports (PEP 562), matching the package convention: importing
+the package pulls in neither submodule.
 """
 
 from __future__ import annotations

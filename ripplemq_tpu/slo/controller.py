@@ -33,11 +33,11 @@ One controller per broker. Every `slo_tick_s` it:
    up here even when membership heals between ticks). A p99 breach
    alone deliberately does NOT shed: shedding helps when the pipe is
    QUEUEING (refusing work drains it), and a breach with an empty
-   settle window is structural slowness — boot-time compiles, the
-   worker-hop floor on a starved host — where refusing best-effort
-   traffic forever fixes nothing (observed exactly so while driving
-   this: a 2-core host_workers=2 boot breached a 50 ms target at zero
-   occupancy and shed-flapped a perfectly healthy cluster). The p99
+   settle window is structural slowness — boot-time compiles, a
+   starved host — where refusing best-effort traffic forever fixes
+   nothing (observed exactly so while driving this: a boot on a
+   2-core host breached a 50 ms target at zero occupancy and
+   shed-flapped a perfectly healthy cluster). The p99
    window drives the AIMD law instead. Consequence, stated plainly:
    every shed signal is engine-side, so shedding engages at the
    CONTROLLER broker's produce surface; a non-controller partition
@@ -111,6 +111,11 @@ MIN_ADJUST_SAMPLES = 4
 # drain phase between "recovered" and "collected").
 TICK_RING = 512
 TRANSITION_RING = 64
+# Elastic-partition hysteresis (split_auto): consecutive breach-
+# evidencing ticks before an automatic split fires, and consecutive
+# comfortable ticks before the reverse merge reabsorbs the child.
+SPLIT_EVIDENCE_TICKS = 4
+SPLIT_MERGE_IDLE_TICKS = 64
 
 
 class SloController:
@@ -156,8 +161,6 @@ class SloController:
         # evidence — proposing a reconfiguration is the broker's job,
         # where the metadata propose path and the engine live).
         self.split_auto = bool(config.split_auto)
-        self.split_evidence_ticks = int(config.split_evidence_ticks)
-        self.split_merge_idle_ticks = int(config.split_merge_idle_ticks)
         self._metrics = metrics
         self._recorder = recorder
         self._dataplane_fn = dataplane_fn
@@ -183,7 +186,7 @@ class SloController:
         self._ticks = 0
         # Split/merge evidence runs: consecutive breach ticks arm a
         # split; consecutive comfortable-or-idle ticks arm the reverse
-        # merge (hysteresis — split_merge_idle_ticks defaults deep).
+        # merge (hysteresis — SPLIT_MERGE_IDLE_TICKS is deep).
         self._breach_run = 0
         self._calm_run = 0
         # Per-signal evidence rings: 1 per tick the signal evidenced,
@@ -546,21 +549,21 @@ class SloController:
 
     def split_wanted(self) -> bool:
         """True when `split_auto` is on and the produce SLO has breached
-        for `split_evidence_ticks` CONSECUTIVE measured ticks — the
+        for SPLIT_EVIDENCE_TICKS CONSECUTIVE measured ticks — the
         broker's reconfig duty then proposes a split of the hottest
         partition and calls note_reconfig()."""
         with self._lock:
             return (self.split_auto
-                    and self._breach_run >= self.split_evidence_ticks)
+                    and self._breach_run >= SPLIT_EVIDENCE_TICKS)
 
     def merge_wanted(self) -> bool:
         """True when `split_auto` is on and the cluster has been
-        comfortable or idle for `split_merge_idle_ticks` consecutive
+        comfortable or idle for SPLIT_MERGE_IDLE_TICKS consecutive
         ticks — deep hysteresis, so a load lull between bursts does not
         merge what the next burst would immediately re-split."""
         with self._lock:
             return (self.split_auto
-                    and self._calm_run >= self.split_merge_idle_ticks)
+                    and self._calm_run >= SPLIT_MERGE_IDLE_TICKS)
 
     def note_reconfig(self) -> None:
         """A split/merge was just proposed off this controller's

@@ -217,51 +217,6 @@ def encode(v, bulk: bool = True) -> bytes:
     return raw
 
 
-def encode_dict_with_blob(meta: dict, key: str, blob) -> bytes:
-    """PREFIX bytes such that `prefix + blob` is byte-identical to
-    `encode({**meta, key: bytes(blob)})` with the blob entry LAST.
-
-    The scatter-gather half of the settled-mirror publish path
-    (parallel/hostplane.py): the mirror rows already live in the
-    broker's host mirror, and `encode()` would copy them TWICE more
-    (bytearray append + the final bytes() snapshot) just to prepend a
-    ~40-byte header. With this prefix the caller hands
-    `[prefix, rows]` to ShmRing.push_parts and the payload is touched
-    exactly once — the copy into shared memory. decode() cannot tell
-    the two forms apart (tests/test_shmring.py pins byte parity).
-
-    Stats account the LOGICAL frame (prefix + blob), mirroring
-    encode()."""
-    stats = _STATS_ENABLED
-    t0 = time.perf_counter_ns() if stats else 0
-    if key in meta:
-        raise ValueError(f"blob key {key!r} duplicates a meta key")
-    if type(blob) is memoryview:
-        blob = _flat_view(blob)
-    out = bytearray()
-    out += _DICT
-    _write_varint(out, len(meta) + 1)
-    for k, item in meta.items():
-        if not isinstance(k, str):
-            raise TypeError(f"dict keys must be str, got {type(k).__name__}")
-        raw = k.encode("utf-8")
-        _write_varint(out, len(raw))
-        out += raw
-        _encode_into(out, item, True)
-    raw = key.encode("utf-8")
-    _write_varint(out, len(raw))
-    out += raw
-    out += _BYTES
-    _write_varint(out, len(blob))
-    prefix = bytes(out)
-    if stats:
-        s = _STATS
-        s.encode_ns += time.perf_counter_ns() - t0
-        s.encode_frames += 1
-        s.encode_bytes += len(prefix) + len(blob)
-    return prefix
-
-
 def _read_length(buf: memoryview, pos: int) -> tuple[int, int]:
     """Decode a length/count prefix, rejecting malformed frames cleanly: a
     negative decoded length would make buf[pos:pos+n] silently yield an
@@ -326,92 +281,6 @@ def _decode_at(buf: memoryview, pos: int):
             d[k], pos = _decode_at(buf, pos)
         return d, pos
     raise ValueError(f"bad tag byte {tag!r} at {pos - 1}")
-
-
-def _skip_at(buf: memoryview, pos: int) -> int:
-    """Advance past one encoded value WITHOUT materializing it — the
-    raw-dispatch peek's workhorse (a packed message vector is skipped
-    by its length table alone; no per-element bytes() copies)."""
-    tag = bytes(buf[pos : pos + 1])
-    pos += 1
-    if tag in (_NONE, _TRUE, _FALSE):
-        return pos
-    if tag == _INT:
-        _, pos = _read_varint(buf, pos)
-        return pos
-    if tag == _FLOAT:
-        return pos + 8
-    if tag in (_STR, _BYTES):
-        n, pos = _read_length(buf, pos)
-        return pos + n
-    if tag == _VEC:
-        n, pos = _read_length(buf, pos)
-        if 4 * n > len(buf) - pos:
-            raise ValueError(f"vector table of {n} at {pos} exceeds buffer")
-        lens = struct.unpack_from(f"<{n}I", buf, pos)
-        pos += 4 * n
-        total = sum(lens)
-        if total > len(buf) - pos:
-            raise ValueError(f"vector blob at {pos} exceeds remaining buffer")
-        return pos + total
-    if tag == _LIST:
-        n, pos = _read_length(buf, pos)
-        for _ in range(n):
-            pos = _skip_at(buf, pos)
-        return pos
-    if tag == _DICT:
-        n, pos = _read_length(buf, pos)
-        for _ in range(n):
-            klen, pos = _read_length(buf, pos)
-            pos += klen
-            pos = _skip_at(buf, pos)
-        return pos
-    raise ValueError(f"bad tag byte {tag!r} at {pos - 1}")
-
-
-def peek_fields(raw, want) -> "dict | None":
-    """Decode ONLY the requested top-level fields of an encoded dict,
-    structurally skipping everything else (no payload materialization).
-
-    The raw-frame dispatch peek (broker/server.py _raw_produce): the
-    accept path needs the routing scalars — type, topic, partition, the
-    idempotence pid/seq — to route an undecoded produce frame to its
-    owning host worker, which then performs the frame's single full
-    decode. Requested fields that hold a packed vector or list decode
-    to their ELEMENT COUNT (int), bytes values to their byte length —
-    enough for admission/size checks without touching the blob.
-
-    Returns None for anything that is not a well-formed encoded dict:
-    the caller falls back to the ordinary decode path, which produces
-    the canonical error."""
-    buf = memoryview(raw)
-    try:
-        if bytes(buf[0:1]) != _DICT:
-            return None
-        n, pos = _read_length(buf, 1)
-        out: dict = {}
-        for _ in range(n):
-            klen, pos = _read_length(buf, pos)
-            k = str(buf[pos : pos + klen], "utf-8")
-            pos += klen
-            if k in want:
-                tag = bytes(buf[pos : pos + 1])
-                if tag in (_VEC, _LIST):
-                    out[k], _ = _read_length(buf, pos + 1)
-                    pos = _skip_at(buf, pos)
-                elif tag == _BYTES:
-                    ln, p2 = _read_length(buf, pos + 1)
-                    out[k] = ln
-                    pos = p2 + ln
-                else:
-                    out[k], pos = _decode_at(buf, pos)
-            else:
-                pos = _skip_at(buf, pos)
-        if pos != len(buf):
-            return None
-        return out
-    except (ValueError, IndexError, struct.error, UnicodeDecodeError):
-        return None
 
 
 def decode(raw: bytes | memoryview):

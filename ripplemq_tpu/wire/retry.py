@@ -100,10 +100,6 @@ RETRYABLE_ERROR_PREFIXES = (
     "store_quarantined",    # standby refuses acks until re-admitted
     "bad_stripe_frame",     # wire corruption: the re-send re-encodes
     "consumer_registration_failed",  # metadata round raced; re-propose
-    # Host-plane worker died mid-request (parallel/hostplane.py): the
-    # dispatcher already detected it and is respawning the worker —
-    # the retry lands on the fresh generation.
-    "worker_unavailable",
     # Pipelined replication stream gap (a predecessor frame was lost in
     # flight): the sender rewinds onto the standby's expected counter
     # and re-delivers in order.

@@ -29,7 +29,7 @@ import time
 # TimeoutError, so Future.result timeouts must be caught as both.
 from concurrent.futures import Future, ThreadPoolExecutor
 from concurrent.futures import TimeoutError as FuturesTimeoutError
-from typing import Callable, Optional
+from typing import Callable
 
 from ripplemq_tpu.obs.lockwitness import make_lock
 
@@ -263,7 +263,6 @@ class TcpServer:
         port: int,
         handler: Handler,
         workers: int = 16,
-        raw_handler: Optional[Callable[[bytes], Optional[dict]]] = None,
         metrics=None,
     ) -> None:
         self._handler = handler
@@ -278,13 +277,6 @@ class TcpServer:
             metrics = Metrics(enabled=False)
         self._clock = metrics.clock
         self._m_queue_wait_us = metrics.histogram("rpc.queue_wait_us")
-        # Raw-frame dispatch hook: sees the UNDECODED body before the
-        # codec runs and may answer the request itself (the broker's
-        # produce fast path peeks routing scalars and ships the frame
-        # to the owning host worker, which performs the only decode).
-        # Returning None falls through to the ordinary decode path —
-        # the hook must never raise for "not mine".
-        self._raw_handler = raw_handler
         self._sock = socket.create_server((host, port), reuse_port=False)
         self._sock.settimeout(0.2)
         self.host, self.port = self._sock.getsockname()[:2]
@@ -339,13 +331,10 @@ class TcpServer:
                     t_read: float) -> None:
         self._m_queue_wait_us.observe(self._clock() - t_read)
         try:
-            resp = (self._raw_handler(body)
-                    if self._raw_handler is not None else None)
-            if resp is None:
-                request = codec.decode(body)
-                if not isinstance(request, dict):
-                    raise ValueError("request must be a dict")
-                resp = self._handler(request)
+            request = codec.decode(body)
+            if not isinstance(request, dict):
+                raise ValueError("request must be a dict")
+            resp = self._handler(request)
         except Exception as e:
             resp = {"ok": False, "error": f"internal: {type(e).__name__}: {e}"}
         try:

@@ -10,6 +10,7 @@ import time
 import pytest
 
 from tests.broker_harness import InProcCluster, make_config
+from tests.helpers import wait_until
 
 
 @pytest.fixture(scope="module")
@@ -117,11 +118,6 @@ def test_unknown_topic_and_bad_requests(cluster):
     assert not resp["ok"]
     resp = call(cluster, b.addr, {"type": "wat"})
     assert not resp["ok"] and "unknown request type" in resp["error"]
-    leader = cluster.leader_broker("topic1", 0)
-    resp = call(cluster, leader.addr,
-                {"type": "produce", "topic": "topic1", "partition": 0,
-                 "messages": []})
-    assert not resp["ok"]
 
 
 def test_consumers_isolated_offsets(cluster):
@@ -234,6 +230,74 @@ def test_non_bytes_payload_rejected_not_fatal(cluster):
     assert resp["ok"], resp
     controller = cluster.brokers[cluster.config.controller]
     assert controller.dataplane.step_errors == 0
+
+
+@pytest.mark.parametrize("messages,error", [
+    (lambda cfg: [], "bad_request: empty messages"),
+    (lambda cfg: b"not-a-list", "bad_request: empty messages"),
+    (lambda cfg: [b"fine", "not-bytes"],
+     "bad_request: TypeError: payloads must be bytes"),
+    (lambda cfg: [b"x" * (cfg.payload_bytes + 1)],
+     "bad_request: ValueError: payload of {over} bytes exceeds "
+     "payload_bytes {payload_bytes}"),
+    (lambda cfg: [b"fine", b""],
+     "bad_request: ValueError: empty messages are not supported"),
+], ids=["empty-list", "non-list", "non-bytes-element", "over-payload-bytes",
+        "zero-length-message"])
+def test_malformed_produce_is_refused_never_acked(cluster, messages, error):
+    """What a `produce` answers to a batch it cannot take: a typed
+    refusal that says what was wrong, never an ack, and not one row in
+    the log - validation runs where the batch is packed
+    (`DataPlane._check_and_pack`), on the one path there is."""
+    cfg = cluster.config.engine
+    leader = cluster.leader_broker("topic1", 0)
+    dp = cluster.brokers[cluster.config.controller].dataplane
+    slot = leader.manager.slot_of(("topic1", 0))
+    end = int(dp._log_end[slot])
+    resp = call(cluster, leader.addr,
+                {"type": "produce", "topic": "topic1", "partition": 0,
+                 "messages": messages(cfg)})
+    assert resp["ok"] is False and "base_offset" not in resp, resp
+    assert resp["error"].startswith(error.format(
+        over=cfg.payload_bytes + 1, payload_bytes=cfg.payload_bytes)), resp
+    assert int(dp._log_end[slot]) == end
+    assert dp.step_errors == 0
+
+
+@pytest.mark.parametrize("batches", [1, "B", "B+1", "3B+2"])
+def test_pidless_produce_is_stamped_chunked_and_deduped(cluster, batches):
+    """A batch that names no pid is stamped with the LEADER's own pid
+    and `n` sequence numbers of its per-slot counter; it is cut into
+    max_batch-sized chunks and chunk k takes sequence `seq + k*B`, so
+    the same batch replayed under the same (pid, seq) - a duplicated
+    leader->controller frame, a client's retry - cuts the same way and
+    every chunk is acked from the dedup table: the original base
+    offset, no second append."""
+    B = cluster.config.engine.max_batch
+    n = {1: 1, "B": B, "B+1": B + 1, "3B+2": 3 * B + 2}[batches]
+    leader = cluster.leader_broker("topic2", 0)
+    dp = cluster.brokers[cluster.config.controller].dataplane
+    slot = leader.manager.slot_of(("topic2", 0))
+    name = leader._broker_pid_name
+    assert wait_until(lambda: leader.manager.producer_id(name) is not None,
+                      timeout=15)
+    pid = leader.manager.producer_id(name)
+    seq = leader._stamp_seqs.get(slot, 0)
+    req = {"type": "produce", "topic": "topic2", "partition": 0,
+           "messages": [b"s%d-%d" % (n, i) for i in range(n)]}
+    first = call(cluster, leader.addr, req, timeout=30.0)
+    assert first["ok"] and first["count"] == n, first
+    assert leader._stamp_seqs[slot] == seq + n
+    chunks = [(seq + k, min(seq + k + B, seq + n)) for k in range(0, n, B)]
+    with dp._lock:
+        entries = list(dp._pid_tab[(pid, slot)])[-len(chunks):]
+    assert [(s0, s1) for s0, s1, _ in entries] == chunks
+    assert entries[0][2] == first["base_offset"]
+    end = int(dp._log_end[slot])
+    again = call(cluster, leader.addr, {**req, "pid": pid, "seq": seq},
+                 timeout=30.0)
+    assert again == first
+    assert int(dp._log_end[slot]) == end  # nothing was appended twice
 
 
 def test_unknown_partition_is_terminal_not_retryable(cluster):

@@ -176,10 +176,7 @@ def test_incremental_assignment_reuses_unaffected_topic_slices():
 
 @pytest.fixture(scope="module")
 def cluster():
-    config = make_cluster_config(
-        3, topics=(Topic("t", 4, 3),), engine=None,
-        meta_batch_s=0.05,
-    )
+    config = make_cluster_config(3, topics=(Topic("t", 4, 3),), engine=None)
     with InProcCluster(config) as c:
         c.wait_for_leaders()
         yield c
@@ -284,7 +281,6 @@ def test_stats_control_plane_block(cluster):
     assert resp["ok"], resp
     stats = client.call(addr, {"type": "admin.stats"}, timeout=5.0)
     cp = stats["control_plane"]
-    assert cp["enabled"] is True
     assert cp["waves"] >= 1
     assert cp["wave_events"] >= cp["waves"]
     assert cp["proposals_saved"] == cp["wave_events"] - cp["waves"]

@@ -439,8 +439,9 @@ def test_trace_vocab_parses_live_set():
         repo.tree(trace_vocab.SPANS_PATH), trace_vocab.SPAN_VOCAB_NAME)
     # The span-kind vocabulary is the second closed set under this
     # rule; the cross-process skew pairs must both be present.
-    assert {"client.rpc", "rpc.recv", "worker.hop", "worker.serve",
-            "repl.send", "repl.apply"} <= kinds
+    assert {"client.rpc", "rpc.recv", "repl.send", "repl.apply",
+            "stripe.send", "stripe.apply"} <= kinds
+    assert not any(k.startswith("worker.") for k in kinds)  # PR 52
     stages = trace_vocab.vocabulary(
         repo.tree(trace_vocab.STAGES_PATH), trace_vocab.STAGE_VOCAB_NAME)
     # The third closed set: the five stages that partition the step

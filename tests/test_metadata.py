@@ -17,7 +17,10 @@ from ripplemq_tpu.metadata import (
     Topic,
     assign_partitions,
 )
-from ripplemq_tpu.metadata.cluster_config import parse_cluster_config
+from ripplemq_tpu.metadata.cluster_config import (
+    ClusterConfig,
+    parse_cluster_config,
+)
 from ripplemq_tpu.metadata.models import topics_from_wire, topics_to_wire
 
 
@@ -244,66 +247,65 @@ _ONE_BROKER = {
 }
 
 
-def test_parse_drops_retired_engine_keys_when_true():
-    """Cluster files written before PR 29 carry `fused_control: true` /
-    `packed_writes: true`; what they asked for is the only round there
-    is, so they parse to the same engine as a file without them."""
-    old = parse_cluster_config({**_ONE_BROKER, "engine": {
-        "slots": 128, "fused_control": True, "packed_writes": True}})
-    new = parse_cluster_config({**_ONE_BROKER, "engine": {"slots": 128}})
-    assert old.engine == new.engine and old.engine.slots == 128
-    assert not hasattr(old.engine, "fused_control")
-    assert not hasattr(old.engine, "packed_writes")
+@pytest.mark.parametrize("where,key,only", [
+    ("engine", "fused_control", True),
+    ("engine", "packed_writes", True),
+    (None, "host_workers", 1),
+])
+def test_parse_drops_retired_engine_keys_when_true(where, key, only):
+    """A retired key at the one value it can still mean parses to the
+    same cluster as a file without it. Cluster files written before
+    PR 29 carry `fused_control: true` / `packed_writes: true`: what
+    they asked for is the only round there is. Every deployment file
+    of the benchmark carries `host_workers: 1`: the one host path
+    (PR 52)."""
+    plain = {**_ONE_BROKER, "engine": {"slots": 128}}
+    old = {**plain, "engine": dict(plain["engine"])}
+    (old["engine"] if where else old)[key] = only
+    cfg = parse_cluster_config(old)
+    assert cfg == parse_cluster_config(plain) and cfg.engine.slots == 128
+    assert not hasattr(cfg.engine if where else cfg, key)
+
+
+@pytest.mark.parametrize("where,key,value,pr", [
+    ("engine", "fused_control", False, "PR 29"),
+    ("engine", "packed_writes", False, "PR 29"),
+    ("engine", "packed_writes", 1, "PR 29"),   # not "true": not the value
+    (None, "host_workers", 2, "PR 52"),
+    (None, "host_workers", 0, "PR 52"),
+    (None, "host_workers", True, "PR 52"),
+])
+def test_parse_refuses_retired_engine_keys_when_false(where, key, value, pr):
+    """Any other value of a retired key is refused at parse, by a
+    message that names the key, the value and the PR that removed the
+    choice: never ignored."""
+    raw = {**_ONE_BROKER, "engine": {}}
+    (raw["engine"] if where else raw)[key] = value
+    name = f"{where}.{key}" if where else key
+    with pytest.raises(ValueError, match=rf"{name}: {value!r} is no longer "
+                                         rf"possible.*removed \({pr}\)"):
+        parse_cluster_config(raw)
 
 
 @pytest.mark.parametrize("key", ["fused_control", "packed_writes"])
-def test_parse_refuses_retired_engine_keys_when_false(key):
-    raw = {**_ONE_BROKER, "engine": {key: False}}
-    with pytest.raises(ValueError, match="were removed"):
-        parse_cluster_config(raw)
+def test_engine_knows_no_retired_field(key):
     with pytest.raises(TypeError):
-        EngineConfig(**{key: True})  # the engine itself knows no such field
+        EngineConfig(**{key: True})
+
+
+@pytest.mark.parametrize("value", [1, 2])
+def test_cluster_config_knows_no_host_workers_field(value):
+    """The option is gone from the config itself: the keyword is a
+    TypeError at any value, the one a file may still say included."""
+    cfg = parse_cluster_config(_ONE_BROKER)
+    with pytest.raises(TypeError, match="host_workers"):
+        ClusterConfig(brokers=cfg.brokers, topics=cfg.topics,
+                      engine=cfg.engine, host_workers=value)
 
 
 def test_parse_unknown_engine_key_is_still_an_error():
     with pytest.raises(TypeError, match="fused_writes"):
         parse_cluster_config({**_ONE_BROKER, "engine": {"fused_writes": True}})
-
-
-def _benchmark_configs() -> list[str]:
-    import glob
-    import os
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    return sorted(glob.glob(
-        os.path.join(repo, "benchmarks", "configs", "*.json")))
-
-
-def test_benchmark_has_configurations():
-    assert _benchmark_configs()
-
-
-@pytest.mark.parametrize(
-    "path", _benchmark_configs(),
-    ids=lambda p: p.rsplit("/", 1)[-1])
-def test_benchmark_cluster_blocks_parse(path):
-    """Every deployment the benchmark boots - whatever files are there -
-    as run.py builds its cluster file (the `cluster` block, the
-    deployment's topics, one broker per port); two of them still name
-    the retired keys."""
-    import json
-
-    with open(path) as f:
-        config = json.load(f)
-    raw = dict(config["cluster"])
-    raw["brokers"] = [{"id": i, "host": "127.0.0.1", "port": 9000 + i}
-                      for i in range(config["deployment"]["brokers"])]
-    raw["topics"] = config["deployment"]["topics"]
-    cfg = parse_cluster_config(raw)
-    want = {k: v for k, v in config["cluster"].get("engine", {}).items()
-            if k not in ("fused_control", "packed_writes")}
-    for k, v in want.items():
-        assert getattr(cfg.engine, k) == v, (path, k)
 
 
 def test_parse_rejects_linearizable_reads_without_standbys():

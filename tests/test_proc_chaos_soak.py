@@ -52,33 +52,6 @@ def test_randomized_proc_soak_seed(seed):
     )
 
 
-def test_proc_chaos_with_host_workers():
-    """Multi-core host plane on the PROC backend (ISSUE 12): real
-    broker subprocesses, each running host_workers=2 worker
-    subprocesses over shared-memory rings, through a seeded SIGKILL +
-    disk-fault schedule — the safety checker's contract is unchanged."""
-    verdict = run_chaos(
-        seed=2,
-        n_brokers=3,
-        partitions=2,
-        phases=2,
-        phase_s=1.0,
-        ops_per_phase=2,
-        backend="proc",
-        host_workers=2,
-        converge_timeout_s=120.0,
-    )
-    assert verdict["host_workers"] == 2
-    assert verdict["violations"] == [], (
-        f"host-plane proc chaos: {verdict['violations']}\n"
-        f"replay: python profiles/chaos_soak.py --backend proc --seed 2 "
-        f"--phases 2 --host-workers 2\n"
-        f"trace: {trace_json(verdict['trace'])}"
-    )
-    assert verdict["converged"], verdict["convergence"]
-    assert verdict["counts"]["produce_ok"] > 0
-
-
 @pytest.mark.parametrize("durability", ["async", "strict"])
 def test_kill_all_durability_drill(durability):
     """Correlated full-cluster SIGKILL: with `durability=async`, acked

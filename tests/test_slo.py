@@ -273,12 +273,12 @@ def test_shed_engages_on_settle_failures_and_occupancy_evidence():
 def test_p99_breach_alone_never_sheds():
     """FAILING-BEFORE (caught live while driving the verify recipe): a
     p99 breach with an EMPTY settle window is structural slowness —
-    boot-time compiles, the worker-hop floor on a starved 2-core host —
-    not overload; shedding cannot drain a queue that does not exist,
-    and the first cut shed-flapped a perfectly healthy host_workers=2
-    cluster off exactly this. The breach must drive the AIMD law only;
-    shedding needs queueing/degradation evidence (the ISSUE's threshold
-    list: occupancy, stall streaks, quorum degradation — plus settle
+    boot-time compiles, a starved 2-core host — not overload; shedding
+    cannot drain a queue that does not exist, and the first cut
+    shed-flapped a perfectly healthy cluster off exactly this. The
+    breach must drive the AIMD law only; shedding needs
+    queueing/degradation evidence (the ISSUE's threshold list:
+    occupancy, stall streaks, quorum degradation — plus settle
     failures)."""
     plane = FakePlane()
     ctl, metrics, recorder, clock, _ = make_controller(plane=plane)

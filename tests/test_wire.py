@@ -290,45 +290,6 @@ def test_codec_rejects_out_of_range_ints():
     assert decode(encode(-(2**63))) == -(2**63)
 
 
-def test_peek_fields_scalars_counts_and_byte_lengths():
-    """Raw-frame dispatch peek (ISSUE 16 satellite): only the requested
-    top-level fields materialize — packed vectors/lists decode to their
-    ELEMENT COUNT, bytes to their byte length, everything else is
-    structurally skipped."""
-    from ripplemq_tpu.wire.codec import peek_fields
-
-    req = {"type": "produce", "topic": "t", "partition": 3,
-           "producer": "p", "pid": 7, "seq": 11,
-           "messages": [b"aa", b"bb", b"cc"], "blob": b"xyzw"}
-    raw = encode(req)
-    got = peek_fields(raw, ("type", "topic", "partition", "pid", "seq",
-                            "messages", "blob"))
-    assert got == {"type": "produce", "topic": "t", "partition": 3,
-                   "pid": 7, "seq": 11, "messages": 3, "blob": 4}
-    # Unrequested fields are skipped, not decoded.
-    assert peek_fields(raw, ("type",)) == {"type": "produce"}
-    assert peek_fields(raw, ("absent",)) == {}
-    # Both encoder forms peek identically (bulk <-> generic interop).
-    assert peek_fields(encode(req, bulk=False),
-                       ("type", "messages")) == {"type": "produce",
-                                                 "messages": 3}
-
-
-def test_peek_fields_refuses_malformed_frames():
-    """None — never an exception or a partial dict — for anything that
-    is not one clean encoded dict: the caller falls back to the
-    ordinary decode path for the canonical error."""
-    from ripplemq_tpu.wire.codec import peek_fields
-
-    assert peek_fields(encode([1, 2]), ("type",)) is None  # not a dict
-    assert peek_fields(encode("s"), ("type",)) is None
-    assert peek_fields(encode({"a": 1}) + b"x", ("a",)) is None  # trailing
-    assert peek_fields(b"", ("a",)) is None
-    assert peek_fields(b"\xfe\x01", ("a",)) is None
-    raw = encode({"a": 1, "b": b"xy"})
-    assert peek_fields(raw[:-1], ("a",)) is None  # truncated
-
-
 @pytest.mark.parametrize("workers,waits", [(8, False), (2, True)])
 def test_rpc_queue_wait_grows_when_pool_is_smaller_than_concurrency(
         workers, waits):
